@@ -66,7 +66,7 @@ def main(argv=None) -> None:
                          "default since round 16)")
     ap.add_argument("--max-wall", type=float, default=180.0)
     ap.add_argument("--no-ledger", action="store_true",
-                    help="skip the PERF_LEDGER.jsonl append (smoke runs)")
+                    help="skip the capture-log append (smoke runs)")
     args = ap.parse_args(argv)
 
     from bench_common import (attach_capture_context, finish,

@@ -1,10 +1,9 @@
 """Vector similarity bench: flat 1M x 128d device matmul top-k, plus the
 round-19 IVF acceptance mode (``--ivf``).
 
-Default mode (VERDICT r4 next-step #7 done-criterion): VECTOR_SIMILARITY
-runs on device at >= 1M x 128d with a PERF_LEDGER entry. Prints ONE JSON
-line with the size-keyed metric "vector_similarity_<rows>x<dim>d_qps";
-vs_baseline is the speedup over the single-thread numpy brute-force scan
+Default mode: VECTOR_SIMILARITY runs on device at >= 1M x 128d with a
+capture-log entry. Prints ONE JSON line with the size-keyed metric
+"vector_similarity_<rows>x<dim>d_qps"; vs_baseline is the speedup over the single-thread numpy brute-force scan
 of the same data (the stand-in for Lucene HNSW, which trades recall for
 speed — this path is exact, recall 1.0).
 

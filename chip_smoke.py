@@ -1,0 +1,491 @@
+"""chip_smoke.py — the served query path, once, on the chip.
+
+The quickest proof that the system still starts on a TPU. In ONE process
+(a chip belongs to one process; nothing here starts a JAX child) it
+
+1. refuses anything but a TPU (exit 1, naming what JAX found);
+2. builds an SSB table from ``--seed`` with bench.py's generator (18
+   columns, shapes unchanged): 2^26 lineorder rows as 8 segments of 2^23
+   — cut from one chip's share of 2^27 because the time limit forces it
+   (see LOG2_ROWS);
+3. starts Controller + ServerNode + BrokerNode as StartController /
+   StartServer / StartBroker construct them (tools/admin.py), registers
+   the table and the segments by location over the controller's REST
+   API, and waits for the server to load them;
+4. runs one SQL query per kernel family through the broker's HTTP
+   endpoint (clients.connect_url), once cold and twice warm, asserting
+   the plan stayed on the device with the expected strategy and that the
+   answer equals bench.py's numpy oracle;
+5. runs the per-lowering hardware checks of tests/tpu_hw_script.py;
+6. with more than one device, runs the same queries over a
+   DistributedTable on the mesh plus the multistage mesh join / device
+   window / set-op, and checks every device holds a shard
+   (``--mesh-only`` runs just this and the table build: four chips cost
+   four times the chip budget);
+7. fails on any staging fallback, interpreted or XLA compaction,
+   post-warm-up retrace, digest mismatch or phase that raised.
+
+The last stdout line of a pass is one JSON object,
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
+It claims no speed: the seconds it prints are observations.
+
+    python chip_smoke.py                 # on the chip, via the chip tool
+    python chip_smoke.py --mesh-only     # several chips: the mesh phase alone
+    python chip_smoke.py --rehearse-cpu  # tiny CPU walk-through; never a pass
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import shutil
+import sys
+import tempfile
+import time
+from concurrent.futures import ThreadPoolExecutor
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)
+sys.path.insert(0, os.path.join(HERE, "tests"))   # tpu_hw_script library
+
+# One chip's share of the deployment is 2^27 rows as 8 segments of 2^24
+# (ROADMAP R2). THE CUT: 2^26 rows as 8 segments of 2^23, because the
+# contract gives the smoke 1200 s with nothing compiled beforehand and the
+# batched compact kernel's compile time grows with the batch's rows. At
+# 2^27 on a v5e (chip run of PR 21) the dense queries compiled in 2 s but
+# the segmented compact kernel took 388 s to compile for q4.1 and more
+# than the 600 s query deadline for q2.1. Data, upload and HBM were not
+# the limit: 2^27 rows built in 42 s and sat resident.
+LOG2_ROWS = 26
+N_SEGMENTS = 8
+OPTION = " OPTION(timeoutMs=1000000)"   # outlasts the smoke itself
+EXIT_REHEARSAL = 2      # a CPU rehearsal never exits 0
+
+# HBM capacity by device_kind (Google Cloud documentation, "TPU v5e":
+# 16 GB of HBM per chip). A device that is not here is an error, not a
+# default.
+HBM_BYTES = {"TPU v5 lite": 16 << 30}
+
+# (qid, strategy, core, path): one query per kernel family the planner
+# has, with the path the server's batch dispatch (engine/batch.py) takes
+# for 8 same-bucket segments. q1.1, q4.1 and q4.3 are bench.py's SSB
+# specs. ``dgb`` is an SSB-shaped dense group-by: at these segment sizes
+# the planner's one-hot budget (segment rows x group space) makes every
+# SSB Q2-Q4 a compact plan, q4.1's 175 groups included, so the dense
+# small-space family needs a 7-group key. q4.1 is the factorized compact
+# family (8 x 175 groups in one segmented kernel); q4.3 is the sorted
+# one and runs per segment (8 x 1.75M groups exceed the segmented
+# kernel's limit). q2.1 and q3.2 would both take the segmented SORTED
+# kernel here, whose compile alone outlasts this smoke (see LOG2_ROWS);
+# bench.py runs them on one segment.
+SMOKE_QUERIES = [
+    ("q1.1", "dense", "scalar", "vmap"),
+    ("dgb", "dense", "onehot", "vmap"),
+    ("q4.1", "compact", "factorized", "segmented"),
+    ("q4.3", "compact", "sorted", "per-segment"),
+]
+DGB_SPEC = ("dgb", [("lo_quantity", "lt", 25)], ("lo_revenue",),
+            ["d_year"])
+
+T0 = time.perf_counter()
+
+
+def say(msg: str) -> None:
+    print(f"[{time.perf_counter() - T0:7.1f}s] {msg}", flush=True)
+
+
+def fail(msg: str) -> None:
+    raise SystemExit(f"chip_smoke: FAIL — {msg}")
+
+
+# ---------------------------------------------------------------------------
+# phases
+# ---------------------------------------------------------------------------
+
+def check_device(rehearse: bool):
+    """Exit unless JAX's first device is a TPU (or this is an explicit
+    CPU rehearsal). Returns the device as JAX reports it."""
+    import jax
+
+    dev = jax.devices()[0]
+    info = {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
+    if dev.platform != "tpu" and not rehearse:
+        # with JAX_PLATFORMS unset and libtpu failing to initialise, JAX
+        # itself drops to the CPU with a warning: that must not pass
+        fail(f"no TPU: jax.devices()[0].platform is {dev.platform!r} "
+             f"({dev.device_kind}); this smoke only runs on the chip")
+    import importlib.metadata as md
+    import jaxlib
+    try:
+        libtpu = md.version("libtpu")
+    except md.PackageNotFoundError:
+        libtpu = "not installed"
+    say(f"platform: {info['platform']}  device_kind: {info['kind']}  "
+        f"devices: {info['count']}")
+    say(f"jax {jax.__version__}  jaxlib {jaxlib.__version__}  "
+        f"libtpu {libtpu}  python {sys.version.split()[0]}")
+    if dev.platform == "tpu":
+        from pinot_tpu.engine import pipeline
+        if dev.device_kind not in HBM_BYTES:
+            fail(f"unknown device_kind {dev.device_kind!r}: add its HBM "
+                 "capacity (with the source) to HBM_BYTES")
+        limit = dev.memory_stats()["bytes_limit"]
+        say(f"HBM: documented {HBM_BYTES[dev.device_kind]} B, "
+            f"memory_stats bytes_limit {limit} B, engine/pipeline "
+            f"resident-scan budget {pipeline.hbm_budget_bytes()} B")
+        if not pipeline.hbm_budget_bytes() < limit <= \
+                HBM_BYTES[dev.device_kind]:
+            fail("engine/pipeline's budget, the device's bytes_limit and "
+                 "the documented capacity are out of order")
+    return info
+
+
+def cache_dir() -> str:
+    import jax
+    import pinot_tpu  # noqa: F401 — places the cache (pinot_tpu/__init__.py)
+    return jax.config.jax_compilation_cache_dir
+
+
+def cache_entries() -> int:
+    d = cache_dir()
+    return len(os.listdir(d)) if os.path.isdir(d) else 0
+
+
+def check_native() -> None:
+    from pinot_tpu import native
+    ok = native.available()
+    say(f"native.available(): {ok}")
+    if not ok and os.path.exists(native._SRC):
+        fail("native source present but the library did not build:\n"
+             + str(native.build_error()))
+
+
+def build_table(work: str, log2_rows: int, seed: int):
+    """Generate and build the SSB segments (host work, threads: numpy
+    releases the GIL); returns the segment directories."""
+    import bench
+    from pinot_tpu.segment import SegmentBuilder
+    from pinot_tpu.spi import Schema, TableConfig
+
+    n_seg = N_SEGMENTS
+    rows_per_seg = (1 << log2_rows) // n_seg
+    out_dir = os.path.join(work, "segments")
+
+    def one(k: int) -> str:
+        cols = bench.gen_columns(rows_per_seg, seed=(seed, k))
+        schema = Schema("lineorder", bench._ssb_fields(cols))
+        return SegmentBuilder(schema, TableConfig("lineorder")).build(
+            cols, out_dir, f"seg_{k}")
+
+    t = time.perf_counter()
+    with ThreadPoolExecutor(max_workers=min(n_seg, os.cpu_count() or 1)) \
+            as pool:
+        dirs = list(pool.map(one, range(n_seg)))
+    disk = sum(os.path.getsize(os.path.join(d, f))
+               for d in dirs for f in os.listdir(d))
+    say(f"data: {n_seg} segments x {rows_per_seg} rows = "
+        f"{n_seg * rows_per_seg} lineorder rows (seed {seed}), "
+        f"{disk / 1e9:.2f} GB on disk, built in "
+        f"{time.perf_counter() - t:.1f}s")
+    return dirs
+
+
+def start_cluster(work: str):
+    """Controller + ServerNode + BrokerNode in this process, as
+    StartController / StartServer / StartBroker construct them."""
+    from pinot_tpu.cluster import BrokerNode, Controller, ServerNode
+
+    controller = Controller(os.path.join(work, "controller"))
+    server = ServerNode("smoke_server", controller.url)
+    return BrokerNode(controller.url), server, controller
+
+
+def load_table(nodes, seg_dirs, schema) -> None:
+    """Register the table and its segments by location over the
+    controller's REST API; wait until the server holds every segment."""
+    from pinot_tpu.cluster.http_util import http_json
+
+    broker, server, controller = nodes
+    t = time.perf_counter()
+    http_json("POST", f"{controller.url}/tables",
+              {"name": "lineorder", "schema": schema.to_dict(),
+               "replication": 1})
+    for d in seg_dirs:
+        http_json("POST", f"{controller.url}/segments",
+                  {"table": "lineorder", "segment": os.path.basename(d),
+                   "location": d})
+    version = controller.routing_snapshot()["version"]
+    if not (server.wait_for_version(version, timeout=120.0)
+            and broker.wait_for_version(version, timeout=120.0)):
+        fail("server/broker did not reach the controller's routing "
+             f"version {version}")
+    dm = server._tables.get("lineorder")
+    held = len(dm.acquire_segments()) if dm is not None else 0
+    if held != len(seg_dirs):
+        fail(f"server holds {held} of {len(seg_dirs)} segments")
+    say(f"serving: controller {controller.url}, server {server.url}, "
+        f"broker {broker.url}; {held} segments loaded in "
+        f"{time.perf_counter() - t:.1f}s")
+
+
+def stop_nodes(nodes) -> None:
+    for node in nodes:
+        node.stop()
+
+
+def smoke_specs():
+    import bench
+    by_id = {q[0]: q for q in bench.QUERIES + [DGB_SPEC]}
+    return [by_id[qid] + (strategy, core, path)
+            for qid, strategy, core, path in SMOKE_QUERIES]
+
+
+def oracle_digest(host_segs, preds, vexpr, gcols):
+    """bench.py's numpy oracle per segment, group sums merged."""
+    import bench
+    acc: dict = {}
+    for seg in host_segs:
+        rows, _secs = bench.oracle_run(seg, preds, vexpr, gcols)
+        for r in rows:
+            acc[r[:-1]] = acc.get(r[:-1], 0) + r[-1]
+    return bench._digest([k + (v,) for k, v in acc.items()])
+
+
+def check_plan(seg, sql: str, n_seg: int, expected) -> None:
+    """The plan must be a device kernel of the expected family, routed as
+    expected by the server's batch dispatch (the rules of
+    engine/batch.execute_plans_batched) — a host plan that passes the
+    digest check is the fallback this smoke exists to refuse."""
+    from pinot_tpu.ops import kernels as K
+    from tpu_hw_script import assert_plan
+
+    plan = assert_plan(seg, sql, "kernel")
+    kp = plan.kernel_plan
+    space = kp.group_space
+    if kp.strategy == "dense":
+        path = "vmap"
+        core = "onehot" if kp.group_keys else "scalar"
+    else:
+        segmented = (K.segmented_compact_ok(kp)
+                     and n_seg * space <= K.COMPACT_GROUP_LIMIT)
+        path = "segmented" if segmented else "per-segment"
+        if segmented:
+            space *= n_seg      # the segment index leads the group key
+        core = "sorted" if (space > K.FACTORIZED_GROUP_LIMIT
+                            or K._needs_sort(kp)) else "factorized"
+    if (kp.strategy, core, path) != expected:
+        fail(f"{sql!r} planned {(kp.strategy, core, path)}, expected "
+             f"{expected}")
+
+
+def resident_bytes() -> str:
+    import jax
+    from pinot_tpu.utils.devmem import global_device_memory
+    tracked = global_device_memory.snapshot()["total"]["bytes"]
+    stats = jax.devices()[0].memory_stats() or {}
+    return (f"resident: devmem registry {tracked / 1e9:.2f} GB, "
+            f"device bytes_in_use {stats.get('bytes_in_use', 0) / 1e9:.2f}"
+            f" GB (peak {stats.get('peak_bytes_in_use', 0) / 1e9:.2f} GB)")
+
+
+def counter(name: str) -> float:
+    from pinot_tpu.utils.metrics import global_metrics
+    return global_metrics.snapshot()["counters"].get(name, 0)
+
+
+def overflow_retries() -> float:
+    return (counter("compact_overflow_retries")
+            + counter("group_xfer_overflow_retries"))
+
+
+def run_served_query(conn, host_segs, spec):
+    """One smoke query over HTTP: cold once, warm twice, vs the oracle."""
+    import bench
+    from pinot_tpu.ops.plan_cache import global_plan_cache
+
+    qid, preds, vexpr, gcols, strategy, core, path = spec
+    sql = bench.spec_to_sql(preds, vexpr, gcols)
+    check_plan(host_segs[0], sql, len(host_segs), (strategy, core, path))
+    retries0, compile0 = overflow_retries(), counter("compile_ms_total")
+    t = time.perf_counter()
+    res = conn.execute(sql + OPTION)
+    cold_s = time.perf_counter() - t
+    compile_s = (counter("compile_ms_total") - compile0) / 1e3
+    det0 = global_plan_cache.detector.retraces
+    warm_ms = []
+    for _ in range(2):
+        t = time.perf_counter()
+        res = conn.execute(sql + OPTION)
+        warm_ms.append((time.perf_counter() - t) * 1e3)
+    retraced = global_plan_cache.detector.retraces - det0
+    digest = bench._digest(res.rows)
+    ok = digest == oracle_digest(host_segs, preds, vexpr, gcols)
+    say(f"query {qid}: plan kernel  strategy {strategy}/{core} ({path})  "
+        f"cold {cold_s:.2f}s (lower+compile {compile_s:.2f}s)  warm "
+        f"{warm_ms[0]:.1f} / {warm_ms[1]:.1f} ms  overflow_retries "
+        f"{overflow_retries() - retries0:.0f}  retraces_post_warmup "
+        f"{retraced}  segments {res.num_segments}  rows {len(res.rows)}  "
+        f"digest_ok {ok}")
+    say("  " + resident_bytes())
+    if res.num_segments != len(host_segs):
+        fail(f"{qid} answered from {res.num_segments} of "
+             f"{len(host_segs)} segments")
+    if not ok:
+        fail(f"{qid} digest differs from the numpy oracle")
+    if retraced:
+        fail(f"{qid} retraced {retraced}x after warm-up")
+
+
+def run_served_queries(broker_url: str, host_segs):
+    """Every smoke query through the broker's HTTP endpoint."""
+    from pinot_tpu.clients import connect_url
+
+    conn = connect_url(broker_url, timeout=1100.0)
+    for spec in smoke_specs():
+        run_served_query(conn, host_segs, spec)
+
+
+def run_mesh_phase(seg_dirs, host_segs) -> None:
+    """More than one device: the smoke queries over a DistributedTable on
+    the mesh, checked against the same numpy oracle the one-chip answers
+    are held to, then the multistage mesh join / device window /
+    set-op."""
+    import bench
+    import jax
+    from pinot_tpu.broker import Broker
+    from pinot_tpu.multistage import device_join
+    from pinot_tpu.parallel import DistributedTable, segment_mesh
+    from pinot_tpu.query.context import build_query_context
+    from pinot_tpu.query.sql import parse_sql
+    from pinot_tpu.server import TableDataManager
+
+    def in_use(d):     # None where the backend reports no memory stats
+        return (d.memory_stats() or {}).get("bytes_in_use")
+
+    devices = jax.devices()
+    before = [in_use(d) for d in devices]
+    dm = TableDataManager("lineorder")
+    for d in seg_dirs:
+        dm.add_segment_dir(d)
+    dist = DistributedTable(dm.acquire_segments(), segment_mesh())
+    dm.set_distributed(dist)
+    broker = Broker()
+    broker.register_table(dm)
+    for qid, preds, vexpr, gcols, *_family in smoke_specs():
+        sql = bench.spec_to_sql(preds, vexpr, gcols)
+        ctx = build_query_context(parse_sql(sql))
+        t = time.perf_counter()
+        if dist.try_execute(ctx) is None:
+            fail(f"mesh: try_execute fell back for {qid}")
+        cold_s = time.perf_counter() - t
+        t = time.perf_counter()
+        res = broker.query(sql + OPTION)      # broker.py's mesh branch
+        warm_ms = (time.perf_counter() - t) * 1e3
+        ok = bench._digest(res.rows) == oracle_digest(
+            host_segs, preds, vexpr, gcols)
+        say(f"mesh query {qid}: try_execute partial  cold {cold_s:.2f}s  "
+            f"warm {warm_ms:.1f} ms  digest_ok {ok}")
+        if not ok:
+            fail(f"mesh: {qid} digest differs from the numpy oracle")
+    shard_devs = sorted({s.device.id for col in dist._cols.values()
+                         for s in col.addressable_shards})
+    grew = [None if b is None else in_use(d) - b
+            for d, b in zip(devices, before)]
+    say(f"mesh: column shards on device ids {shard_devs}; bytes_in_use "
+        f"grew per device by {grew} B")
+    if shard_devs != sorted(d.id for d in devices) \
+            or any(g is not None and g <= 0 for g in grew):
+        fail("mesh: not every device holds a shard")
+
+    import __graft_entry__ as graft
+    joins = device_join.STATS["mesh_joins"]
+    graft._dryrun_multistage(len(devices))
+    if device_join.STATS["mesh_joins"] <= joins:
+        fail("mesh: the join did not take the all_to_all backend")
+    say(f"mesh: multistage all_to_all join (mesh_joins "
+        f"{device_join.STATS['mesh_joins']}), device window and set-op "
+        "equal the host answers")
+
+
+def final_gates(rehearse: bool, rows_per_seg: int) -> None:
+    from pinot_tpu.ops import compact
+
+    fallbacks = counter("compile_staging_fallbacks")
+    say(f"compile_staging_fallbacks: {fallbacks}  Pallas interpret: "
+        f"{compact._interpret()}  _use_pallas({rows_per_seg}): "
+        f"{compact._use_pallas(rows_per_seg)}")
+    if fallbacks:
+        fail(f"{fallbacks} staged compile(s) fell back to implicit jit "
+             "(traceback logged above)")
+    if rehearse:
+        return
+    if compact._interpret() or not compact._use_pallas(rows_per_seg):
+        fail("the Pallas compactor is interpreted or not selected at the "
+             "smoke's sizes")
+
+
+# ---------------------------------------------------------------------------
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--seed", type=int, default=1992,
+                    help="data seed (default %(default)s)")
+    ap.add_argument("--mesh-only", action="store_true",
+                    help="with several devices: build the table and run "
+                         "the mesh phase alone")
+    ap.add_argument("--rehearse-cpu", action="store_true",
+                    help="tiny walk-through on the CPU; prints no result "
+                         f"and exits {EXIT_REHEARSAL}")
+    args = ap.parse_args(argv)
+    rehearse = args.rehearse_cpu
+    log2_rows = 19 if rehearse else LOG2_ROWS
+
+    device = check_device(rehearse)
+    if args.mesh_only and device["count"] < 2:
+        fail("--mesh-only needs more than one device")
+    from pinot_tpu.segment import ImmutableSegment
+    entries0 = cache_entries()
+    say(f"compile cache: {cache_dir()} ({entries0} entries before)")
+    check_native()
+
+    work = tempfile.mkdtemp(prefix="chip_smoke_")
+    tempfile.tempdir = work     # the checks' scratch tables land inside
+    nodes = ()
+    try:
+        seg_dirs = build_table(work, log2_rows, args.seed)
+        host_segs = [ImmutableSegment.load(d) for d in seg_dirs]  # oracle
+        if not args.mesh_only:
+            nodes = start_cluster(work)
+            load_table(nodes, seg_dirs, host_segs[0].schema)
+            run_served_queries(nodes[0].url, host_segs)
+            # free the served table's device residency for what follows
+            for seg in nodes[1]._tables["lineorder"].acquire_segments():
+                seg.evict_device()
+            stop_nodes(nodes)
+            nodes = ()
+        if not (rehearse or args.mesh_only):
+            import tpu_hw_script
+            checks: list = []
+            t = time.perf_counter()
+            tpu_hw_script.run_hardware_checks(checks)
+            say(f"hardware checks: {len(checks)} passed in "
+                f"{time.perf_counter() - t:.1f}s: {', '.join(checks)}")
+        if device["count"] > 1:
+            run_mesh_phase(seg_dirs, host_segs)
+        final_gates(rehearse, (1 << log2_rows) // N_SEGMENTS)
+    finally:
+        stop_nodes(nodes)
+        tempfile.tempdir = None
+        shutil.rmtree(work, ignore_errors=True)
+    say(f"compile cache: {cache_entries()} entries after "
+        f"({entries0} before)")
+    if rehearse:
+        say("CPU rehearsal finished — NOT a pass; no result is printed")
+        return EXIT_REHEARSAL
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

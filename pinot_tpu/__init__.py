@@ -21,8 +21,20 @@ jax x64 at import; accumulator dtypes degrade gracefully on backends
 where f64 is emulated (see pinot_tpu.ops.aggregations.acc_dtypes).
 """
 
+import os
+
 import jax
 
 jax.config.update("jax_enable_x64", True)
+
+# Persistent compile cache, placed from outside: when the environment
+# names a directory JAX reads it itself and nothing is set here.
+# Otherwise the cache lives at a FIXED path inside the checkout (the path
+# is part of how a later process finds the entries again — never a temp
+# dir, pid or timestamp). This is the only place that sets it.
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update("jax_compilation_cache_dir", os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache"))
 
 __version__ = "0.1.0"

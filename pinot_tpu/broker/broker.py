@@ -235,9 +235,9 @@ class Broker:
         from ..utils import ledger as uledger
         # explicit-ledger-only, like QueryForensics.record_trace: no
         # configured path means the trace is counted but not persisted —
-        # an implicit CWD PERF_LEDGER.jsonl write would pollute the repo
-        # bench ledger (and the span-diff gate reading it) with traces
-        # from whatever code version happens to be running
+        # an implicit write to the default capture log would pollute it
+        # (and the span-diff gate reading it) with traces from whatever
+        # code version happens to be running
         path = (getattr(stmt, "options", {}).get("ledgerPath")
                 or self._trace_ledger_path
                 or os.environ.get("PINOT_TPU_LEDGER_PATH"))
@@ -456,12 +456,9 @@ class Broker:
                              num_docs_scanned=inner.num_docs_scanned)
         result.trace = trace
         if _truthy(stmt.options.get("ledgerTrace")):
-            import os
-
             from ..utils import ledger as uledger
             path = (stmt.options.get("ledgerPath")
-                    or os.environ.get("PINOT_TPU_LEDGER_PATH")
-                    or "PERF_LEDGER.jsonl")
+                    or uledger.default_capture_log())
             uledger.append_record(uledger.trace_record(
                 root, getattr(stmt, "_raw_sql", str(stmt.table))), path)
         result.time_ms = (time.perf_counter() - t0) * 1e3

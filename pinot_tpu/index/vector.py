@@ -40,7 +40,7 @@ across the whole build+upload with a re-check inside, publish under
 ``_res_lock``.
 
 bench_vector.py measures the flat path at 1M x 128d and the IVF path
-(``--ivf``: recall@10 / QPS vs the exact scan) into PERF_LEDGER.jsonl.
+(``--ivf``: recall@10 / QPS vs the exact scan) into the capture log.
 """
 from __future__ import annotations
 

@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import fnmatch
 import os
+import sys
 from typing import Any, Dict, List, Optional
 
 from ..inputformat import read_records
@@ -45,6 +46,21 @@ from ..segment.builder import SegmentBuilder
 from ..spi.config import TableConfig
 from ..spi.schema import Schema
 from .transformers import CompositeTransformer
+
+
+def _worker_env() -> Dict[str, str]:
+    """Environment for a ``--file-task`` worker process. Workers import
+    pinot_tpu in a FRESH interpreter, so they carry the driver's
+    sys.path (REPL drivers patch it rather than installing the package).
+    Segment generation is host work and the driver may hold the chip —
+    a chip belongs to one process — so workers are pinned to the CPU
+    platform whatever the driver runs on."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [p for p in sys.path if p]
+        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
 
 
 class BatchIngestionJob:
@@ -92,7 +108,6 @@ class BatchIngestionJob:
         import json as _json
         import shutil
         import subprocess
-        import sys
         import tempfile
         import time as _time
 
@@ -103,13 +118,7 @@ class BatchIngestionJob:
         spec_path = os.path.join(work_dir, "spec.json")
         with open(spec_path, "w") as fh:
             _json.dump(self.spec, fh)
-        # workers must import pinot_tpu in a FRESH interpreter: carry
-        # the driver's sys.path (REPL drivers patch it rather than
-        # installing the package)
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.pathsep.join(
-            [p for p in sys.path if p]
-            + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+        env = _worker_env()
         procs: List[tuple] = []
         pending = list(enumerate(files))
         results: Dict[int, List[str]] = {}

@@ -182,7 +182,7 @@ def join_cardinality(l_rows: float, r_rows: float,
 # RelMdSelectivity guesses above (which cannot see through string
 # dictionaries). Costs are relative units where 1.0 ~ one streaming pass
 # over one row; constants are calibrated from CPU microbenchmarks
-# (PERF_LEDGER r06) and MXU throughput ratios, and only ever steer
+# (round-6 CPU captures) and MXU throughput ratios, and only ever steer
 # physical choices — correctness never depends on them (a wrong capacity
 # estimate triggers the executor's full-capacity overflow retry).
 # ---------------------------------------------------------------------------

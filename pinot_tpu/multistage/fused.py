@@ -317,7 +317,6 @@ def _fused_program(spec: Tuple, n_dev: int):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ..compat import shard_map as _shard_map
     from ..ops.join import SEG_AXIS, _splitmix32, device_equi_join
     from ..parallel.mesh import segment_mesh
     from ..utils.compileplane import staged
@@ -429,8 +428,8 @@ def _fused_program(spec: Tuple, n_dev: int):
         n_out += 1
     out_specs = tuple([P(SEG_AXIS)] * (n_out + 1))
 
-    fn = _shard_map(per_device, mesh=mesh, in_specs=tuple(in_specs),
-                    out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=out_specs, check_vma=False)
     return staged(jax.jit(fn), "multistage", ("fused_plan", spec, n_dev))
 
 

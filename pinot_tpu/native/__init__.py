@@ -22,16 +22,35 @@ _SO = os.path.join(_HERE, "libpinot_native.so")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_build_error: Optional[str] = None
 
 
 def _build() -> bool:
+    """Compile src/ into the (untracked) .so. The compiler writes a
+    per-process temp file that is renamed into place, so two processes
+    building at once (batch-ingestion workers) never load a torn file."""
+    global _build_error
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC,
-           "-o", _SO, "-lz", "-lzstd"]
+           "-o", tmp, "-lz", "-lzstd"]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _SO)
         return True
-    except Exception:
-        return False
+    except subprocess.CalledProcessError as e:
+        _build_error = e.stderr.decode(errors="replace")[-2000:]
+    except (OSError, subprocess.TimeoutExpired) as e:
+        _build_error = f"{type(e).__name__}: {e}"
+    if os.path.exists(tmp):
+        os.remove(tmp)
+    return False
+
+
+def build_error() -> Optional[str]:
+    """Why the last build attempt failed (None: it did not run or it
+    succeeded). The numpy fallbacks keep the engine running without a
+    compiler; callers that must not run degraded check this."""
+    return _build_error
 
 
 def load() -> Optional[ctypes.CDLL]:

@@ -40,7 +40,17 @@ import jax.numpy as jnp
 LANES = 128
 R = 32                 # sublane rows per subtile
 K_MIN = 8              # minimum subtiles per grid step (gate + capacity math)
-K_MAX = 32             # maximum (VMEM permitting — _choose_k)
+K_MAX = 32             # maximum the capacity margins are sized for (STAGE_MAX)
+# Largest K the installed toolchain compiles, whatever _choose_k's hand
+# budget says: on jax 0.9.0 / libtpu 0.0.34 / TPU v5e the K=32 kernel
+# needs 21.25 MB (1 column) to 23.95 MB (2 columns) of scoped VMEM against
+# a 16 MiB limit and is rejected at compile time ("Ran out of memory in
+# memory space vmem", chip run of PR 21) — the (R,R,128) one-hot and the
+# per-byte partial sums are compiler temporaries the estimate does not
+# count. K=16 compiled and ran exact for 1-6 columns at 2^24 rows in the
+# same run. Only the kernel's K is bounded here; the capacity math keeps
+# K_MAX, so no capacity (and no CPU path) changes with it.
+K_COMPILES = 16
 STEP = K_MIN * R       # minimum rows per grid step (pallas gate, caps)
 STAGE = K_MIN * R + R  # staging rows at K_MIN (capacity math only)
 
@@ -60,8 +70,10 @@ def _choose_k(n_cols: int, n: int) -> int:
     to K*R (the 128x128 MXU is depth-starved at 32). Rough VMEM budget
     per column stream: double-buffered input block (2*K*R*LANES*4B) +
     staging ((K+1)*R*LANES*4B) + the bf16 part tiles; cap the estimate
-    at ~10MB of the ~16MB core VMEM."""
-    k = K_MAX
+    at ~10MB of the 16 MiB scoped-VMEM limit. The estimate leaves out
+    the compiler's temporaries, which is why K_COMPILES bounds it from
+    above."""
+    k = min(K_MAX, K_COMPILES)
     while k > K_MIN and k * R * LANES > n:
         k //= 2               # don't pad small inputs up to a giant step
     while k > K_MIN:
@@ -306,8 +318,10 @@ def _kernel(mask_ref, *rest, n_cols: int, slots_cap: int, n_steps: int,
         sl = slice(k * R, (k + 1) * R)
         m = mask_ref[sl, :] != 0                       # (R, 128)
         mf = m.astype(jnp.int32).astype(jnp.float32)
-        # f32 reductions (exact: counts <= R=32): this jax's Mosaic cannot
-        # lower integer sum/max reductions
+        # f32 reductions (exact: counts <= R=32): written for a Mosaic
+        # that could not lower integer sum/max reductions; this form
+        # compiles and runs exact on jax 0.9.0 / libtpu 0.0.34 (whether
+        # the integer form lowers there now: not measured)
         cntf = jnp.sum(mf, axis=0, dtype=jnp.float32)  # (128,)
         cnt = cntf.astype(jnp.int32)
         adv = jnp.max(cntf).astype(jnp.int32)
@@ -321,8 +335,7 @@ def _kernel(mask_ref, *rest, n_cols: int, slots_cap: int, n_steps: int,
             x = col_refs[ci][sl, :]
             # byte-split BEFORE the one-hot gather-sum so the reduction
             # runs in f32 (exact: one-hot selects a single byte <= 255
-            # per output slot) — this jax's Mosaic cannot lower integer
-            # reductions at all
+            # per output slot) — no integer reduction (see above)
             for b in range(4):
                 if b < 3:
                     part = jax.lax.bitwise_and(
@@ -404,8 +417,9 @@ def _compact_pallas(mask, cols, n, slots_cap, k_sub, interp):
     step_rows = k_sub * R
     stage_rows = (k_sub + 1) * R
     n_steps = n // (step_rows * LANES)
-    # int8, not uint8: Mosaic's ir_constant cannot emit uint8 literals in
-    # this jax version, so `mask_ref != 0` failed TPU lowering
+    # int8, not uint8: written for a Mosaic whose ir_constant could not
+    # emit uint8 literals (`mask_ref != 0` failed TPU lowering); int8
+    # lowers on jax 0.9.0 / libtpu 0.0.34
     mask2d = mask.reshape(n // LANES, LANES).astype(jnp.int8)
     cols2d = [c.reshape(n // LANES, LANES) for c in cols]
 
@@ -433,8 +447,7 @@ def _compact_pallas(mask, cols, n, slots_cap, k_sub, interp):
         interpret=interp,
     )
     # the kernel is pure 32-bit; keep x64 promotion rules out of the trace
-    from ..compat import disable_x64
-    with disable_x64():
+    with jax.enable_x64(False):
         outs = call(mask2d, *cols2d)
 
     valid2d = outs[0]

@@ -33,8 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map as _shard_map
-
 SEG_AXIS = "seg"   # matches parallel.mesh.SEG_AXIS (ops cannot import
 # parallel without a cycle; segment_mesh builds the same axis name)
 
@@ -64,7 +62,7 @@ def _mesh_join_jit(lk, rk, max_dup, mesh):
     def per_device(lk_shard, rk_full):
         return device_equi_join(lk_shard, rk_full, max_dup)
 
-    return _shard_map(
+    return jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(P("seg"), P()),
         out_specs=(P("seg"), P("seg")),
@@ -118,7 +116,7 @@ def _shuffle_exchange_jit(codes, ids, n_dev, cap, mesh):
         ri = jax.lax.all_to_all(buckets_i, SEG_AXIS, 0, 0, tiled=True)
         return rc.reshape(-1), ri.reshape(-1), overflow[None]
 
-    return _shard_map(
+    return jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(P(SEG_AXIS), P(SEG_AXIS)),
         out_specs=(P(SEG_AXIS), P(SEG_AXIS), P(SEG_AXIS)),
@@ -135,7 +133,7 @@ def _partition_join_jit(lk, lids, rk, rids, max_dup, mesh):
         r_glob = jnp.take(ri, r_pos)
         return match, jnp.broadcast_to(li[:, None], match.shape), r_glob
 
-    return _shard_map(
+    return jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(P(SEG_AXIS), P(SEG_AXIS), P(SEG_AXIS), P(SEG_AXIS)),
         out_specs=(P(SEG_AXIS), P(SEG_AXIS), P(SEG_AXIS)),

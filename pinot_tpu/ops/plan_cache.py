@@ -44,12 +44,9 @@ from ..utils.spans import device_fence, span, span_tracer
 
 
 def _donation_supported() -> bool:
-    """Buffer donation is a TPU/GPU optimization; XLA:CPU ignores it (and
-    older jax versions warn). Enable only where it buys anything."""
-    try:
-        return jax.default_backend() != "cpu"
-    except Exception:
-        return False
+    """Buffer donation is a TPU/GPU optimization; XLA:CPU ignores it.
+    Enable only where it buys anything."""
+    return jax.default_backend() != "cpu"
 
 
 class RetraceDetector:

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map as _shard_map
 from ..engine.executor import extract_partial, resolve_params
 from ..utils.spans import annotate, device_fence, span
 from ..ops.kernels import build_kernel
@@ -307,6 +306,6 @@ def _distributed_kernel_cached(kernel_plan, bucket: int, mesh: Mesh,
     in_specs = (tuple(P(SEG_AXIS, None) for _ in range(n_cols)),
                 P(SEG_AXIS),
                 tuple(P() for _ in range(n_params)))
-    mapped = _shard_map(per_device, mesh=mesh, in_specs=in_specs,
+    mapped = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
                            out_specs=P(), check_vma=False)
     return jax.jit(mapped)

@@ -43,6 +43,7 @@ event), and tests pin <1% wall overhead on the SSB corpus
 """
 from __future__ import annotations
 
+import logging
 import os
 import threading
 import time
@@ -53,6 +54,8 @@ from typing import Any, Dict, List, Optional, Tuple
 from .metrics import global_metrics
 from .shapehash import shape_key
 from .spans import span, span_tracer
+
+_log = logging.getLogger(__name__)
 
 TRIGGERS = ("cold", "warmup", "overflow_retry", "drift_requantize",
             "lru_evict_rebuild", "retrace")
@@ -532,7 +535,9 @@ class StagedFn:
         except Exception:
             # staging infrastructure failure: permanent per-fn
             # fallback to the implicit jit (which re-raises any REAL
-            # kernel error on the normal path). The signature was
+            # kernel error on the normal path). Logged with its
+            # traceback: a compiler rejection is otherwise visible only
+            # as the counter below. The signature was
             # already CLASSIFIED above — mark it observed so the
             # fallback path never classifies the same compile twice
             # (the detector/compile_event reconciliation invariant).
@@ -543,6 +548,8 @@ class StagedFn:
             if ev is not None:
                 ev.set()
             global_metrics.count("compile_staging_fallbacks")
+            _log.warning("staged compile failed at site %r; falling back "
+                         "to implicit jit", self.site, exc_info=True)
             return None
         with self._lock:
             self._compiled[sig] = compiled

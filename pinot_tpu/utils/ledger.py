@@ -1,6 +1,6 @@
 """Unified perf ledger: ONE versioned JSONL schema for every writer.
 
-Before round 7 three writers appended ad-hoc shapes to PERF_LEDGER.jsonl
+Before round 7 three writers appended ad-hoc shapes to one JSONL file
 (bench_common.ledger_append, bench_common.ledger_append_raw for
 tools/profile_compact.py, and bench_vector/bench_taxi through finish()),
 so nothing could validate the history or diff captures field-for-field.
@@ -415,6 +415,16 @@ _ENVELOPE = {"v", "ts", "kind", "node"}
 # operational kinds); ``ts`` stays injectable for deterministic
 # emitters but must already be a formatted string.
 _RESERVED = ("kind", "node", "proc", "seq", "ts")
+
+
+def default_capture_log() -> str:
+    """The program's own capture log when no path is given: ONE default,
+    named here and nowhere else. ``PINOT_TPU_LEDGER_PATH`` overrides it.
+    ``<checkout>/PERF_LEDGER.jsonl`` is the driver's record, not this
+    program's — nothing in the repo opens it."""
+    return os.environ.get("PINOT_TPU_LEDGER_PATH") or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), "capture_log.jsonl")
 
 
 def make_record(kind: str, /, **fields: Any) -> Dict[str, Any]:

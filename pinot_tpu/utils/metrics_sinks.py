@@ -99,8 +99,9 @@ class LedgerSink(MetricsSink):
     unified perf ledger (utils/ledger.py) — engine counters land in the
     same validated JSONL history the bench and phase profiles use."""
 
-    def __init__(self, path: str = "PERF_LEDGER.jsonl"):
-        self.path = path
+    def __init__(self, path: Optional[str] = None):
+        from .ledger import default_capture_log
+        self.path = path or default_capture_log()
 
     def emit(self, snapshot: Dict[str, Any]) -> None:
         from . import ledger as uledger

@@ -1,0 +1,84 @@
+"""What the chip bring-up added: the smoke refuses a CPU, the benches
+refuse a CPU, the compile cache is placed from outside, and segment
+build workers never ask for the chip.
+
+Everything here runs on the CPU; interpreters that must start fresh are
+subprocesses (a few seconds each).
+"""
+import json
+import os
+import re
+import subprocess
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run(args, **env_overrides):
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    for k, v in env_overrides.items():
+        if v is None:
+            env.pop(k, None)
+        else:
+            env[k] = v
+    return subprocess.run([sys.executable] + args, cwd=REPO, env=env,
+                          capture_output=True, text=True, timeout=120)
+
+
+def test_chip_smoke_refuses_cpu():
+    proc = _run(["chip_smoke.py"])
+    assert proc.returncode not in (0, 2), proc.stdout + proc.stderr
+    assert "platform is 'cpu'" in proc.stderr
+    # no result line: nothing on stdout parses as a pass
+    assert '"ok"' not in proc.stdout
+
+
+def test_bench_refuses_cpu_before_building_data():
+    proc = _run(["bench.py"], PINOT_BENCH_FORCE_CPU=None)
+    assert proc.returncode == 1
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["error"] == "no_tpu_backend" and "'cpu'" in out["detail"]
+
+
+_CACHE_PROBE = ("import pinot_tpu, jax; "
+                "print(jax.config.jax_compilation_cache_dir)")
+
+
+def test_compile_cache_env_is_not_overridden(tmp_path):
+    outside = str(tmp_path / "cc")
+    proc = _run(["-c", _CACHE_PROBE], JAX_COMPILATION_CACHE_DIR=outside)
+    assert proc.stdout.strip() == outside, proc.stderr
+
+
+def test_compile_cache_default_is_fixed_in_checkout():
+    dirs = {_run(["-c", _CACHE_PROBE],
+                 JAX_COMPILATION_CACHE_DIR=None).stdout.strip()
+            for _ in range(2)}
+    assert dirs == {os.path.join(REPO, ".jax_cache")}
+
+
+def test_compile_cache_has_one_setter():
+    setters = []
+    for root, _dirs, files in os.walk(REPO):
+        if any(part.startswith(".") or part == "chiprun_out"
+               for part in os.path.relpath(root, REPO).split(os.sep)
+               if part != "."):
+            continue
+        for f in files:
+            if not f.endswith(".py") or f == os.path.basename(__file__):
+                continue
+            with open(os.path.join(root, f)) as fh:
+                if re.search(r"""update\(\s*["']jax_compilation_cache_dir""",
+                             fh.read()):
+                    setters.append(os.path.relpath(
+                        os.path.join(root, f), REPO))
+    assert setters == [os.path.join("pinot_tpu", "__init__.py")]
+
+
+def test_ingestion_workers_are_pinned_to_cpu(monkeypatch):
+    from pinot_tpu.ingestion.batch import _worker_env
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    env = _worker_env()
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert REPO in env["PYTHONPATH"].split(os.pathsep)

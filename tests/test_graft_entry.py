@@ -1,6 +1,6 @@
 """The driver entry points must work as the driver invokes them.
 
-Round-1 regression: the dryrun failed (MULTICHIP_r01 ok=false) because
+Round-1 regression: the multichip dryrun failed because
 bare jax.device_put in resolve_params targeted the default (TPU) backend
 instead of the CPU mesh. These tests run the actual entry functions.
 """

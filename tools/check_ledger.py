@@ -1,4 +1,4 @@
-"""Validate PERF_LEDGER.jsonl against the unified v2 schema.
+"""Validate a capture log against the unified v2 schema.
 
 Every line must parse as JSON; lines carrying ``"v": 2`` must satisfy
 the per-kind field contract in pinot_tpu/utils/ledger.py — unknown or
@@ -8,8 +8,10 @@ schema. Lines WITHOUT a ``v`` field are grandfathered pre-v2 history
 
     python tools/check_ledger.py [path ...] [--strict]
 
-Exit 0 when every line validates, 1 otherwise (tier-1 runs this over
-the repo ledger — tests/test_observability.py).
+With no path, checks the program's default capture log
+(pinot_tpu/utils/ledger.default_capture_log). Exit 0 when every line
+validates, 1 otherwise (tier-1 runs this over a fixture ledger —
+tests/test_span_tracer.py).
 """
 from __future__ import annotations
 
@@ -20,8 +22,6 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from pinot_tpu.utils import ledger as uledger  # noqa: E402
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def check(path: str, strict: bool = False) -> int:
@@ -45,7 +45,7 @@ def main(argv=None) -> int:
     args = list(sys.argv[1:] if argv is None else argv)
     strict = "--strict" in args
     paths = [a for a in args if a != "--strict"] \
-        or [os.path.join(REPO, "PERF_LEDGER.jsonl")]
+        or [uledger.default_capture_log()]
     rc = 0
     for p in paths:
         if not os.path.exists(p):

@@ -65,6 +65,7 @@ sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
 import span_diff  # noqa: E402 — shared env pin (capture_env/env_mismatch)
+from pinot_tpu.utils.ledger import default_capture_log  # noqa: E402
 
 DEFAULT_BASELINE = os.path.join(REPO, "tools", "freshness_baseline.json")
 DEFAULT_BAR = 1.8          # < 2.0 so a 2x single-metric regression fails
@@ -247,7 +248,7 @@ def main(argv=None) -> int:
     ap.add_argument("mode", choices=["check", "update", "capture"])
     ap.add_argument("ledgers", nargs="*",
                     help="ingest_bench ledger path(s); default: the "
-                         "repo PERF_LEDGER.jsonl")
+                         "program's capture log")
     ap.add_argument("--baseline", default=DEFAULT_BASELINE)
     ap.add_argument("--bar", type=float, default=DEFAULT_BAR)
     ap.add_argument("--last", type=int, default=DEFAULT_LAST)
@@ -266,7 +267,7 @@ def main(argv=None) -> int:
                           "records": n, "ok": True}))
         return 0
 
-    ledgers = args.ledgers or [os.path.join(REPO, "PERF_LEDGER.jsonl")]
+    ledgers = args.ledgers or [default_capture_log()]
     records = load_bench_records(ledgers)
 
     if args.mode == "update":

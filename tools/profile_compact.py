@@ -3,11 +3,11 @@
 Decomposes kernel time into the round-6 pipeline's phases —
 mask / fuse (key + payload materialization) / compact / sort /
 aggregate / transfer — for the slow compact-path queries, so
-strategy-ladder regressions are visible between captures (VERDICT r4
-next-step #1b, round-6 satellite). The decomposition itself lives in
+strategy-ladder regressions are visible between captures (round-6
+satellite). The decomposition itself lives in
 pinot_tpu/ops/phase_profile.py (EXPLAIN ANALYZE's
 OPTION(profilePhases=true) shares it); this CLI appends one validated
-v2 ``phase_profile`` record per query to PERF_LEDGER.jsonl
+v2 ``phase_profile`` record per query to the capture log
 (pinot_tpu/utils/ledger.py), so the ledger keeps a phase-attribution
 history alongside the headline captures.
 

@@ -42,6 +42,7 @@ from typing import Any, Dict, List, Optional
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+from pinot_tpu.utils.ledger import default_capture_log  # noqa: E402
 from pinot_tpu.utils.slo import (  # noqa: E402
     DEFAULT_BURN_THRESHOLD, DEFAULT_FAST_WINDOW_S,
     DEFAULT_OBJECTIVE, DEFAULT_SLOW_WINDOW_S, plan_alert_stream)
@@ -157,8 +158,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("mode", choices=["report", "gate"])
     ap.add_argument("ledgers", nargs="*",
-                    help="ledger path(s); default: the repo "
-                         "PERF_LEDGER.jsonl")
+                    help="ledger path(s); default: the program's "
+                         "capture log")
     ap.add_argument("--latency-bar-ms", type=float, default=None,
                     help="latency SLO bar in ms (omit: no latency "
                          "objective)")
@@ -183,7 +184,7 @@ def main(argv=None) -> int:
                          "pending)")
     args = ap.parse_intermixed_args(argv)
 
-    ledgers = args.ledgers or [os.path.join(REPO, "PERF_LEDGER.jsonl")]
+    ledgers = args.ledgers or [default_capture_log()]
     kinds = GATE_KINDS + ("rca_verdict",) if args.autopsy else GATE_KINDS
     records = load_records(ledgers, kinds=kinds)
     objectives = build_objectives(

@@ -39,6 +39,7 @@ sys.path.insert(0, REPO)
 
 from pinot_tpu.utils.compileplane import (  # noqa: E402
     POST_WARMUP_TRIGGERS, TRIGGERS)
+from pinot_tpu.utils.ledger import default_capture_log  # noqa: E402
 
 POST_WARMUP = set(POST_WARMUP_TRIGGERS)
 
@@ -107,8 +108,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("mode", choices=["report", "gate"])
     ap.add_argument("ledgers", nargs="*",
-                    help="ledger path(s); default: the repo "
-                         "PERF_LEDGER.jsonl")
+                    help="ledger path(s); default: the program's "
+                         "capture log")
     ap.add_argument("--max-post-warmup", type=int, default=0,
                     help="gate: allowed retrace + lru_evict_rebuild "
                          "compiles (default %(default)s)")
@@ -118,7 +119,7 @@ def main(argv=None) -> int:
     ap.add_argument("--top", type=int, default=20)
     args = ap.parse_intermixed_args(argv)
 
-    ledgers = args.ledgers or [os.path.join(REPO, "PERF_LEDGER.jsonl")]
+    ledgers = args.ledgers or [default_capture_log()]
     events = load_compile_events(ledgers)
     rep = summarize(events)
 
