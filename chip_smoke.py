@@ -7,30 +7,34 @@ The quickest proof that the system still starts on a TPU. In ONE process
 2. builds an SSB table from ``--seed`` with bench.py's generator (18
    columns, shapes unchanged): 2^26 lineorder rows as 8 segments of 2^23
    — cut from one chip's share of 2^27 because the time limit forces it
-   (see LOG2_ROWS);
+   (printed at run time; see CUT_REASON);
 3. starts Controller + ServerNode + BrokerNode as StartController /
    StartServer / StartBroker construct them (tools/admin.py), registers
    the table and the segments by location over the controller's REST
    API, and waits for the server to load them;
 4. runs one SQL query per kernel family through the broker's HTTP
    endpoint (clients.connect_url), once cold and twice warm, asserting
-   the plan stayed on the device with the expected strategy and that the
-   answer equals bench.py's numpy oracle;
+   that the answer equals bench.py's numpy oracle and — from the span
+   tree ``EXPLAIN ANALYZE`` brings back from the server — that every
+   segment was answered by the expected device dispatch, none by a host
+   plan;
 5. runs the per-lowering hardware checks of tests/tpu_hw_script.py;
-6. with more than one device, runs the same queries over a
-   DistributedTable on the mesh plus the multistage mesh join / device
-   window / set-op, and checks every device holds a shard
-   (``--mesh-only`` runs just this and the table build: four chips cost
-   four times the chip budget);
-7. fails on any staging fallback, interpreted or XLA compaction,
-   post-warm-up retrace, digest mismatch or phase that raised.
+6. fails on any staging fallback or compiler rejection, interpreted or
+   XLA compaction, post-warm-up retrace, digest mismatch or phase that
+   raised.
+
+On a machine with more than one device the same command runs the mesh
+phase instead of 3-5: the same queries over a DistributedTable on the
+mesh, plus the multistage mesh join / device window / set-op, and checks
+that every device holds a shard. ServerNode has no device argument
+(ROADMAP R4), so the served trio is a one-chip layout; each invocation is
+what was run and proven on that machine (PERF.md section 5).
 
 The last stdout line of a pass is one JSON object,
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
 It claims no speed: the seconds it prints are observations.
 
-    python chip_smoke.py                 # on the chip, via the chip tool
-    python chip_smoke.py --mesh-only     # several chips: the mesh phase alone
+    python chip_smoke.py                 # on the chip(s), via the chip tool
     python chip_smoke.py --rehearse-cpu  # tiny CPU walk-through; never a pass
 """
 from __future__ import annotations
@@ -49,13 +53,14 @@ sys.path.insert(0, HERE)
 sys.path.insert(0, os.path.join(HERE, "tests"))   # tpu_hw_script library
 
 # One chip's share of the deployment is 2^27 rows as 8 segments of 2^24
-# (ROADMAP R2). THE CUT: 2^26 rows as 8 segments of 2^23, because the
-# contract gives the smoke 1200 s with nothing compiled beforehand and the
-# batched compact kernel's compile time grows with the batch's rows. At
-# 2^27 on a v5e (chip run of PR 21) the dense queries compiled in 2 s but
-# the segmented compact kernel took 388 s to compile for q4.1 and more
-# than the 600 s query deadline for q2.1. Data, upload and HBM were not
-# the limit: 2^27 rows built in 42 s and sat resident.
+# (ROADMAP R2). THE CUT, printed at run time: 2^26 rows as 8 x 2^23.
+FULL_LOG2_ROWS = 27
+CUT_REASON = (
+    "the contract gives the smoke 1200 s with nothing compiled beforehand, "
+    "and at 2^27 rows on a v5e (chip run of PR 21) the segmented compact "
+    "kernel alone took 388 s to compile for q4.1 (187-195 s at 2^26; the "
+    "dense kernels 2 s) beside 472 s of hardware checks. Data, upload and "
+    "HBM were not the limit: 2^27 rows built in 42 s and sat resident")
 LOG2_ROWS = 26
 N_SEGMENTS = 8
 OPTION = " OPTION(timeoutMs=1000000)"   # outlasts the smoke itself
@@ -66,24 +71,36 @@ EXIT_REHEARSAL = 2      # a CPU rehearsal never exits 0
 # default.
 HBM_BYTES = {"TPU v5 lite": 16 << 30}
 
-# (qid, strategy, core, path): one query per kernel family the planner
-# has, with the path the server's batch dispatch (engine/batch.py) takes
-# for 8 same-bucket segments. q1.1, q4.1 and q4.3 are bench.py's SSB
-# specs. ``dgb`` is an SSB-shaped dense group-by: at these segment sizes
-# the planner's one-hot budget (segment rows x group space) makes every
-# SSB Q2-Q4 a compact plan, q4.1's 175 groups included, so the dense
-# small-space family needs a 7-group key. q4.1 is the factorized compact
-# family (8 x 175 groups in one segmented kernel); q4.3 is the sorted
-# one and runs per segment (8 x 1.75M groups exceed the segmented
-# kernel's limit). q2.1 and q3.2 would both take the segmented SORTED
-# kernel here, whose compile alone outlasts this smoke (see LOG2_ROWS);
-# bench.py runs them on one segment.
+# (qid, strategy, dispatch span, launches): one query per kernel family
+# the planner has, with what the server is expected to be SEEN doing for 8
+# same-bucket segments — the span engine/batch.py or engine/executor.py
+# opens around each device launch, and how many of them. q1.1, q2.1,
+# q4.1 and q4.3 are bench.py's SSB specs. ``dgb`` is an SSB-shaped dense
+# group-by: at these segment sizes the planner's one-hot budget (segment
+# rows x group space) makes every SSB Q2-Q4 a compact plan, q4.1's 175
+# groups included, so the dense small-space family needs a 7-group key.
+# The compact families: q4.1 (175 groups a segment) stays on the
+# factorized core as one segmented program; q2.1 (7,000 groups a
+# segment) is factorized on a segment but would land on the sort core
+# as a batch, and q4.3 (1.75M groups) is the sort core outright — both
+# go per segment (ops/kernels.segmented_compact_fits).
 SMOKE_QUERIES = [
-    ("q1.1", "dense", "scalar", "vmap"),
-    ("dgb", "dense", "onehot", "vmap"),
-    ("q4.1", "compact", "factorized", "segmented"),
-    ("q4.3", "compact", "sorted", "per-segment"),
+    ("q1.1", "dense", "vmap_dispatch", 1),
+    ("dgb", "dense", "vmap_dispatch", 1),
+    ("q4.1", "compact", "segmented_compact_dispatch", 1),
+    ("q2.1", "compact", "segment_kernel", N_SEGMENTS),
+    ("q4.3", "compact", "segment_kernel", N_SEGMENTS),
 ]
+# every span a segment's execution can open on the server, with the
+# site its first launch compiles at (utils/compileplane compile_event);
+# anything but the expected one in a query's tree fails the smoke
+DISPATCH_SPANS = {
+    "vmap_dispatch": "vmap_kernel",
+    "segmented_compact_dispatch": "segmented_kernel",
+    "segment_kernel": "plan_cache",
+    "segment_kselect": None, "segment_host": None, "ragged_dispatch": None,
+    "overflow_retry": None, "group_overflow_retry": None,
+}
 DGB_SPEC = ("dgb", [("lo_quantity", "lt", 25)], ("lo_revenue",),
             ["d_year"])
 
@@ -147,9 +164,9 @@ def cache_dir() -> str:
     return jax.config.jax_compilation_cache_dir
 
 
-def cache_entries() -> int:
+def cache_entries() -> set:
     d = cache_dir()
-    return len(os.listdir(d)) if os.path.isdir(d) else 0
+    return set(os.listdir(d)) if os.path.isdir(d) else set()
 
 
 def check_native() -> None:
@@ -237,8 +254,8 @@ def stop_nodes(nodes) -> None:
 def smoke_specs():
     import bench
     by_id = {q[0]: q for q in bench.QUERIES + [DGB_SPEC]}
-    return [by_id[qid] + (strategy, core, path)
-            for qid, strategy, core, path in SMOKE_QUERIES]
+    return [by_id[qid] + (strategy, span_name, launches)
+            for qid, strategy, span_name, launches in SMOKE_QUERIES]
 
 
 def oracle_digest(host_segs, preds, vexpr, gcols):
@@ -252,31 +269,29 @@ def oracle_digest(host_segs, preds, vexpr, gcols):
     return bench._digest([k + (v,) for k, v in acc.items()])
 
 
-def check_plan(seg, sql: str, n_seg: int, expected) -> None:
-    """The plan must be a device kernel of the expected family, routed as
-    expected by the server's batch dispatch (the rules of
-    engine/batch.execute_plans_batched) — a host plan that passes the
-    digest check is the fallback this smoke exists to refuse."""
-    from pinot_tpu.ops import kernels as K
-    from tpu_hw_script import assert_plan
+def observed_dispatch(conn, sql: str) -> dict:
+    """What the server RAN for ``sql``, from the span tree EXPLAIN
+    ANALYZE brings back over HTTP: {dispatch span: [launches, segments
+    covered, strategy]}. A host plan shows up as ``segment_host``, the
+    ragged batcher, a retry or a solo rerun as their own spans — none of
+    it is re-derived here from the routing rules."""
+    seen: dict = {}
+    for node, _id, _parent, _ms, detail in conn.execute(
+            "EXPLAIN ANALYZE " + sql + OPTION).rows:
+        if node not in DISPATCH_SPANS:
+            continue
+        attrs = dict(kv.split("=", 1) for kv in detail.split())
+        entry = seen.setdefault(node, [0, 0, attrs.get("strategy")])
+        entry[0] += 1
+        entry[1] += int(attrs.get("segments", 1))
+    return seen
 
-    plan = assert_plan(seg, sql, "kernel")
-    kp = plan.kernel_plan
-    space = kp.group_space
-    if kp.strategy == "dense":
-        path = "vmap"
-        core = "onehot" if kp.group_keys else "scalar"
-    else:
-        segmented = (K.segmented_compact_ok(kp)
-                     and n_seg * space <= K.COMPACT_GROUP_LIMIT)
-        path = "segmented" if segmented else "per-segment"
-        if segmented:
-            space *= n_seg      # the segment index leads the group key
-        core = "sorted" if (space > K.FACTORIZED_GROUP_LIMIT
-                            or K._needs_sort(kp)) else "factorized"
-    if (kp.strategy, core, path) != expected:
-        fail(f"{sql!r} planned {(kp.strategy, core, path)}, expected "
-             f"{expected}")
+
+def compile_sites(since_seq: int) -> list:
+    """Sites of the compile events recorded after ``since_seq``."""
+    from pinot_tpu.utils.compileplane import global_compile_log
+    return sorted({e["site"] for e in global_compile_log.events()
+                   if e["seq"] > since_seq})
 
 
 def resident_bytes() -> str:
@@ -300,34 +315,47 @@ def overflow_retries() -> float:
 
 
 def run_served_query(conn, host_segs, spec):
-    """One smoke query over HTTP: cold once, warm twice, vs the oracle."""
+    """One smoke query over HTTP: cold once, warm twice, vs the oracle,
+    then once more under EXPLAIN ANALYZE for what the server ran."""
     import bench
     from pinot_tpu.ops.plan_cache import global_plan_cache
+    from pinot_tpu.utils.compileplane import global_compile_log
 
-    qid, preds, vexpr, gcols, strategy, core, path = spec
+    qid, preds, vexpr, gcols, strategy, span_name, launches = spec
     sql = bench.spec_to_sql(preds, vexpr, gcols)
-    check_plan(host_segs[0], sql, len(host_segs), (strategy, core, path))
     retries0, compile0 = overflow_retries(), counter("compile_ms_total")
+    seq0 = max([e["seq"] for e in global_compile_log.events()], default=0)
     t = time.perf_counter()
     res = conn.execute(sql + OPTION)
     cold_s = time.perf_counter() - t
     compile_s = (counter("compile_ms_total") - compile0) / 1e3
+    sites = compile_sites(seq0)
     det0 = global_plan_cache.detector.retraces
     warm_ms = []
     for _ in range(2):
         t = time.perf_counter()
         res = conn.execute(sql + OPTION)
         warm_ms.append((time.perf_counter() - t) * 1e3)
+    seen = observed_dispatch(conn, sql)
     retraced = global_plan_cache.detector.retraces - det0
     digest = bench._digest(res.rows)
     ok = digest == oracle_digest(host_segs, preds, vexpr, gcols)
-    say(f"query {qid}: plan kernel  strategy {strategy}/{core} ({path})  "
+    on_device = "segment_host" not in seen and \
+        sum(v[1] for v in seen.values()) == len(host_segs)
+    say(f"query {qid}: plan {'kernel' if on_device else 'NOT kernel'}  "
+        f"server ran {seen}  compiled at {sites}  "
         f"cold {cold_s:.2f}s (lower+compile {compile_s:.2f}s)  warm "
         f"{warm_ms[0]:.1f} / {warm_ms[1]:.1f} ms  overflow_retries "
         f"{overflow_retries() - retries0:.0f}  retraces_post_warmup "
         f"{retraced}  segments {res.num_segments}  rows {len(res.rows)}  "
         f"digest_ok {ok}")
     say("  " + resident_bytes())
+    if seen != {span_name: [launches, len(host_segs), strategy]}:
+        fail(f"{qid}: the server ran {seen}, expected {launches} x "
+             f"{span_name} ({strategy}) over {len(host_segs)} segments")
+    if sites != [DISPATCH_SPANS[span_name]]:
+        fail(f"{qid}: compiled at {sites}, expected "
+             f"{DISPATCH_SPANS[span_name]!r}")
     if res.num_segments != len(host_segs):
         fail(f"{qid} answered from {res.num_segments} of "
              f"{len(host_segs)} segments")
@@ -412,12 +440,14 @@ def final_gates(rehearse: bool, rows_per_seg: int) -> None:
     from pinot_tpu.ops import compact
 
     fallbacks = counter("compile_staging_fallbacks")
-    say(f"compile_staging_fallbacks: {fallbacks}  Pallas interpret: "
-        f"{compact._interpret()}  _use_pallas({rows_per_seg}): "
-        f"{compact._use_pallas(rows_per_seg)}")
-    if fallbacks:
+    rejections = counter("compile_rejections")
+    say(f"compile_staging_fallbacks: {fallbacks}  compile_rejections: "
+        f"{rejections}  Pallas interpret: {compact._interpret()}  "
+        f"_use_pallas({rows_per_seg}): {compact._use_pallas(rows_per_seg)}")
+    if fallbacks or rejections:
         fail(f"{fallbacks} staged compile(s) fell back to implicit jit "
-             "(traceback logged above)")
+             f"(traceback logged above), {rejections} program(s) were "
+             "rejected by the compiler")
     if rehearse:
         return
     if compact._interpret() or not compact._use_pallas(rows_per_seg):
@@ -431,31 +461,42 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--seed", type=int, default=1992,
                     help="data seed (default %(default)s)")
-    ap.add_argument("--mesh-only", action="store_true",
-                    help="with several devices: build the table and run "
-                         "the mesh phase alone")
     ap.add_argument("--rehearse-cpu", action="store_true",
                     help="tiny walk-through on the CPU; prints no result "
                          f"and exits {EXIT_REHEARSAL}")
     args = ap.parse_args(argv)
     rehearse = args.rehearse_cpu
-    log2_rows = 19 if rehearse else LOG2_ROWS
+    log2_rows = LOG2_ROWS
+    if rehearse:
+        # 2^19 rows, and the segmented kernel's row limit scaled down with
+        # them so the server routes each query as it does at full size
+        from pinot_tpu.ops import kernels
+        log2_rows = 19
+        kernels.SEGMENTED_SORT_ROW_LIMIT >>= LOG2_ROWS - log2_rows
 
     device = check_device(rehearse)
-    if args.mesh_only and device["count"] < 2:
-        fail("--mesh-only needs more than one device")
+    several = device["count"] > 1
     from pinot_tpu.segment import ImmutableSegment
     entries0 = cache_entries()
-    say(f"compile cache: {cache_dir()} ({entries0} entries before)")
+    say(f"compile cache: {cache_dir()} ({len(entries0)} entries before)")
     check_native()
 
     work = tempfile.mkdtemp(prefix="chip_smoke_")
     tempfile.tempdir = work     # the checks' scratch tables land inside
     nodes = ()
     try:
+        if LOG2_ROWS < FULL_LOG2_ROWS and not rehearse:
+            say(f"CUT: 2^{LOG2_ROWS} rows, not one chip's share of "
+                f"2^{FULL_LOG2_ROWS}: {CUT_REASON}")
         seg_dirs = build_table(work, log2_rows, args.seed)
         host_segs = [ImmutableSegment.load(d) for d in seg_dirs]  # oracle
-        if not args.mesh_only:
+        if several:
+            say(f"{device['count']} devices: this invocation runs the mesh "
+                "phase; the served trio and the hardware checks are the "
+                "one-chip invocation's (ServerNode has no device argument, "
+                "ROADMAP R4)")
+            run_mesh_phase(seg_dirs, host_segs)
+        else:
             nodes = start_cluster(work)
             load_table(nodes, seg_dirs, host_segs[0].schema)
             run_served_queries(nodes[0].url, host_segs)
@@ -464,22 +505,25 @@ def main(argv=None) -> int:
                 seg.evict_device()
             stop_nodes(nodes)
             nodes = ()
-        if not (rehearse or args.mesh_only):
-            import tpu_hw_script
-            checks: list = []
-            t = time.perf_counter()
-            tpu_hw_script.run_hardware_checks(checks)
-            say(f"hardware checks: {len(checks)} passed in "
-                f"{time.perf_counter() - t:.1f}s: {', '.join(checks)}")
-        if device["count"] > 1:
-            run_mesh_phase(seg_dirs, host_segs)
+            if not rehearse:
+                import tpu_hw_script
+                checks: list = []
+                t = time.perf_counter()
+                tpu_hw_script.run_hardware_checks(checks)
+                say(f"hardware checks: {len(checks)} passed in "
+                    f"{time.perf_counter() - t:.1f}s: {', '.join(checks)}")
         final_gates(rehearse, (1 << log2_rows) // N_SEGMENTS)
     finally:
         stop_nodes(nodes)
         tempfile.tempdir = None
         shutil.rmtree(work, ignore_errors=True)
-    say(f"compile cache: {cache_entries()} entries after "
-        f"({entries0} before)")
+    entries = cache_entries()
+    say(f"compile cache: {len(entries)} entries after "
+        f"({len(entries0)} before)")
+    if entries0 and entries - entries0:
+        # JAX persists only compiles that took over 1 s, so a program near
+        # that line can be written on a later run: name what was added
+        say(f"  added to a warm cache: {sorted(entries - entries0)}")
     if rehearse:
         say("CPU rehearsal finished — NOT a pass; no result is printed")
         return EXIT_REHEARSAL

@@ -89,7 +89,6 @@ def _stacked_cols(plans: List[CompiledPlan], bucket: int
             _STACK_CACHE.move_to_end(key)
             return hit
         epoch = _EVICT_EPOCH
-    _make_room(plans, bucket)
     cols = tuple(
         jnp.stack([p.segment.device_col(c, bucket) for p in plans])
         for c in plans[0].col_names)
@@ -117,34 +116,6 @@ def _stacked_cols(plans: List[CompiledPlan], bucket: int
     from .tier import global_tier
     global_tier.enforce(protect={u for u, _n in key[0]})
     return cols
-
-
-def _make_room(plans: List[CompiledPlan], bucket: int) -> None:
-    """Evict least-recently-used stacks until the device reports room
-    for this group's new stack. Stacks are derived copies of the
-    per-segment resident columns and every query shape over a table
-    builds its own, so together they outgrow HBM long before the table
-    does (SSB as 8 segments of 2^24 rows: 7.7 GB of resident columns,
-    16 GB of stacks over six query shapes, on a 16 GB chip). The
-    headroom asked for is 3x the stack: the group's not-yet-resident
-    column uploads (at most one stack's worth), the stack itself, and
-    the kernel's temporaries. Driven by what the device reports: a
-    backend without memory stats (CPU) keeps the count bound only, and
-    when nothing is left to evict the build goes ahead regardless."""
-    device = jax.local_devices()[0]
-    stats = device.memory_stats()
-    if not stats or "bytes_limit" not in stats:
-        return
-    from .pipeline import group_stack_bytes
-    need = 3 * group_stack_bytes(plans, bucket)
-    while stats["bytes_limit"] - stats["bytes_in_use"] < need:
-        with _STACK_LOCK:
-            if not _STACK_CACHE:
-                return
-            old_key, _old = _STACK_CACHE.popitem(last=False)
-            global_device_memory.remove("stack_cache", old_key)
-        del _old    # the last reference: the buffers free now
-        stats = device.memory_stats()
 
 
 def evict_stacks_containing(segment_name: str) -> None:
@@ -176,7 +147,7 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
     groups: Dict[Tuple, List[int]] = {}
     resolved: Dict[int, Tuple[jax.Array, ...]] = {}
 
-    from ..ops.kernels import COMPACT_GROUP_LIMIT, segmented_compact_ok
+    from ..ops.kernels import segmented_compact_fits, segmented_compact_ok
     from .accounting import global_accountant
     for i, plan in enumerate(plans):
         # preemption point between per-segment launches (the hot-loop
@@ -230,8 +201,8 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
                     results[i] = fused[k]
                 continue
         n_seg = len(idxs)
-        if n_seg == 1 or (kind == "segc" and n_seg * plan_struct.group_space
-                          > COMPACT_GROUP_LIMIT):
+        if n_seg == 1 or (kind == "segc" and not segmented_compact_fits(
+                plan_struct, bucket, n_seg)):
             for i in idxs:
                 results[i] = execute_plan(plans[i])
             continue
@@ -328,7 +299,8 @@ def _run_segmented_compact(plans, idxs, plan_struct, bucket, cols, n_docs,
     cap = scaled_compact_cap(plans[idxs[0]],
                              sum(plans[i].segment.n_docs for i in idxs))
     with span("segmented_compact_dispatch", segments=n_seg, bucket=bucket,
-              slots_cap=cap, est_sel=plans[idxs[0]].est_selectivity):
+              strategy=plan_struct.strategy, slots_cap=cap,
+              est_sel=plans[idxs[0]].est_selectivity):
         _maybe_profile_phases(plans[idxs[0]])
         fn = jitted_segmented_compact(plan_struct, bucket, n_seg, cap)
         with span("device_execute"):

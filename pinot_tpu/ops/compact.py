@@ -40,17 +40,15 @@ import jax.numpy as jnp
 LANES = 128
 R = 32                 # sublane rows per subtile
 K_MIN = 8              # minimum subtiles per grid step (gate + capacity math)
-K_MAX = 32             # maximum the capacity margins are sized for (STAGE_MAX)
-# Largest K the installed toolchain compiles, whatever _choose_k's hand
-# budget says: on jax 0.9.0 / libtpu 0.0.34 / TPU v5e the K=32 kernel
-# needs 21.25 MB (1 column) to 23.95 MB (2 columns) of scoped VMEM against
-# a 16 MiB limit and is rejected at compile time ("Ran out of memory in
-# memory space vmem", chip run of PR 21) — the (R,R,128) one-hot and the
-# per-byte partial sums are compiler temporaries the estimate does not
-# count. K=16 compiled and ran exact for 1-6 columns at 2^24 rows in the
-# same run. Only the kernel's K is bounded here; the capacity math keeps
-# K_MAX, so no capacity (and no CPU path) changes with it.
-K_COMPILES = 16
+# Largest K the kernel is built with: what the installed toolchain
+# compiles, whatever _choose_k's hand budget says. On jax 0.9.0 / libtpu
+# 0.0.34 / TPU v5e the K=32 kernel needs 21.25 MB (1 column) to 23.95 MB
+# (2 columns) of scoped VMEM against a 16 MiB limit and is rejected at
+# compile time ("Ran out of memory in memory space vmem", chip run of
+# PR 21) — the (R,R,128) one-hot and the per-byte partial sums are
+# compiler temporaries the estimate does not count. K=16 compiled and
+# ran exact for 1-6 columns at 2^24 rows in the same run.
+K_MAX = 16
 STEP = K_MIN * R       # minimum rows per grid step (pallas gate, caps)
 STAGE = K_MIN * R + R  # staging rows at K_MIN (capacity math only)
 
@@ -71,9 +69,9 @@ def _choose_k(n_cols: int, n: int) -> int:
     per column stream: double-buffered input block (2*K*R*LANES*4B) +
     staging ((K+1)*R*LANES*4B) + the bf16 part tiles; cap the estimate
     at ~10MB of the 16 MiB scoped-VMEM limit. The estimate leaves out
-    the compiler's temporaries, which is why K_COMPILES bounds it from
+    the compiler's temporaries, which is why K_MAX bounds it from
     above."""
-    k = min(K_MAX, K_COMPILES)
+    k = K_MAX
     while k > K_MIN and k * R * LANES > n:
         k //= 2               # don't pad small inputs up to a giant step
     while k > K_MIN:
@@ -90,12 +88,15 @@ def _choose_k(n_cols: int, n: int) -> int:
 
 # the full-capacity margin must cover the LARGEST staging block any
 # chosen K can write ((K_MAX+1)*R rows) — the kernel's fits check is
-# off+stage<=cap. The DEFAULT caps keep the small K_MIN-based floor:
+# off+stage<=cap. It is sized for K up to 32, twice K_MAX, and is part
+# of every full_slots_cap (hence of compiled shapes and plan-cache keys
+# on every platform): do not re-tune it with the kernel's K_MAX. The
+# DEFAULT caps keep the small K_MIN-based floor:
 # compact() shrinks K until the staging block fits the cap, so a small
 # cap simply runs a smaller grid step — quadrupling the floors would
 # quadruple every small-segment kernel's post-aggregation for nothing
 # (measured ~2x CPU kernel time at 200k rows).
-STAGE_MAX = (K_MAX + 1) * R
+STAGE_MAX = (32 + 1) * R
 
 # smallest capacity the XLA fallback compaction accepts: it has no staging
 # block, so the floor is only about keeping the ladder/post shapes sane.

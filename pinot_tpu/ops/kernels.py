@@ -1326,11 +1326,13 @@ def _factorized_post(sum_jobs, keys, valid, payloads, space, m, out):
                 out[name] = row
 
 
-def _needs_sort(plan: KernelPlan) -> bool:
+def _needs_sort(plan: KernelPlan, n_segments: int = 1) -> bool:
     """Whether the compact strategy takes the sort path (vs factorized
-    one-hot matmuls). Shared by _compact_group_aggs (path selection) and
-    build_kernel (capacity selection) so the two can never disagree."""
-    return (plan.group_space > FACTORIZED_GROUP_LIMIT
+    one-hot matmuls). Shared by _compact_group_aggs (path selection),
+    build_kernel (capacity selection) and segmented_compact_fits (batch
+    routing, where the segment index multiplies the space) so they can
+    never disagree."""
+    return (n_segments * plan.group_space > FACTORIZED_GROUP_LIMIT
             or any(s.kind in ("min", "max") for s in plan.aggs))
 
 
@@ -1704,6 +1706,31 @@ def segmented_compact_ok(plan: KernelPlan) -> bool:
         return False
     key_cols = {ci for ci, _ in plan.group_keys}
     return not (key_cols & set(_dict_value_cols(plan)))
+
+
+# Most rows one segmented program may hold when its combined group space
+# (S x space) puts it on the SORT core. On a TPU v5e (jax 0.9.0 / libtpu
+# 0.0.34, chip runs of PR 21) the sort core compiled and ran exact on one
+# 2^24-row segment (bench.py, every sorted SSB query) and per segment at
+# 2^23 rows in 18 s, but as ONE program over 8 x 2^24 rows XLA rejected
+# it ("Ran out of memory in memory space vmem ... reduce-window ...
+# 19.07M and limit 16.00M"). 2^24 is the largest size shown to compile;
+# nothing between was tried.
+SEGMENTED_SORT_ROW_LIMIT = 1 << 24
+
+
+def segmented_compact_fits(plan: KernelPlan, bucket: int,
+                           n_segments: int) -> bool:
+    """Whether S same-plan compact segments fuse into ONE segmented
+    program (engine/batch.py launches them per segment otherwise). The
+    segment index multiplies the group space, so a plan that is
+    factorized on one segment can land on the sort core as a batch —
+    and the sort core does not compile at every batch size
+    (SEGMENTED_SORT_ROW_LIMIT)."""
+    if n_segments * plan.group_space > COMPACT_GROUP_LIMIT:
+        return False
+    return (not _needs_sort(plan, n_segments)
+            or n_segments * bucket <= SEGMENTED_SORT_ROW_LIMIT)
 
 
 def build_segmented_compact_kernel(plan: KernelPlan, bucket: int,

@@ -407,6 +407,8 @@ class StagedFn:
         # (staging off/broken): the retrace-detection plane predates
         # staging and must never be disabled with it
         self._observed: Dict[Tuple, bool] = {}
+        # sig -> the compiler's own error for a program it rejected
+        self._rejected: Dict[Tuple, Exception] = {}
         # sig -> Event while that signature's compile is in flight:
         # single-flight is per SIGNATURE (the CubeCache idiom), so
         # concurrent DIFFERENT shapes keep compiling in parallel
@@ -494,6 +496,8 @@ class StagedFn:
                 compiled = self._compiled.get(sig)
                 if compiled is not None:
                     return compiled
+                if sig in self._rejected:
+                    raise self._rejected[sig]
                 if self._broken:
                     return None
                 waiting = self._building.get(sig)
@@ -514,6 +518,7 @@ class StagedFn:
         # signature so a naturally shape-polymorphic kernel's second
         # shape reads cold/warmup, never a phantom retrace
         token = self.token if first else (self.token, sig)
+        import jax
         try:
             trigger = self._classify(token, hints)
             with span("build_kernel", staged=True, site=self.site,
@@ -532,12 +537,26 @@ class StagedFn:
                 self.site, trigger, (t1 - t0) * 1e3,
                 (t2 - t1) * 1e3, self.key_fp, self.donated,
                 memory_bytes=mem, flops=flops)
+        except jax.errors.JaxRuntimeError as e:
+            # the COMPILER rejected the program (XLA / Mosaic
+            # RESOURCE_EXHAUSTED and the like): the implicit jit would
+            # run the same compile to the same verdict, so falling back
+            # only pays it twice — minutes, at SSB sizes on the chip.
+            # The query fails with the compiler's message, and so does
+            # every later call with this signature, without compiling
+            # again.
+            with self._lock:
+                self._rejected[sig] = e
+                ev = self._building.pop(sig, None)
+            if ev is not None:
+                ev.set()
+            global_metrics.count("compile_rejections")
+            raise
         except Exception:
             # staging infrastructure failure: permanent per-fn
             # fallback to the implicit jit (which re-raises any REAL
-            # kernel error on the normal path). Logged with its
-            # traceback: a compiler rejection is otherwise visible only
-            # as the counter below. The signature was
+            # kernel error on the normal path), logged with its
+            # traceback. The signature was
             # already CLASSIFIED above — mark it observed so the
             # fallback path never classifies the same compile twice
             # (the detector/compile_event reconciliation invariant).

@@ -92,6 +92,58 @@ def test_segmented_compact_overflow_retry(setup):
     assert got == oracle
 
 
+def test_sort_core_batch_over_row_limit_runs_per_segment(setup,
+                                                         monkeypatch):
+    """The segment index multiplies the group space, so this batch
+    (4 x 8400 groups) lands on the sort core — which XLA refuses to
+    compile as one program at SSB sizes on the chip
+    (ops/kernels.SEGMENTED_SORT_ROW_LIMIT). Above the row limit the
+    group runs per segment, where each plan keeps its own smaller
+    space; a batch that stays factorized is not bounded by it."""
+    from pinot_tpu.engine import batch as eb
+
+    b, dm, data = setup
+    bucket = dm.acquire_segments()[0].bucket
+    monkeypatch.setattr(K, "SEGMENTED_SORT_ROW_LIMIT", N_SEG * bucket - 1)
+    eb.clear_stack_cache()
+    before = K.jitted_segmented_compact.cache_info()
+    sql = ("SELECT ka, kb, SUM(price), COUNT(*) FROM t WHERE sel < 37 "
+           "GROUP BY ka, kb LIMIT 100000 OPTION(timeoutMs=300000)")
+    res = b.query(sql)
+    after = K.jitted_segmented_compact.cache_info()
+    assert (after.misses, after.hits) == (before.misses, before.hits), \
+        "a sort-core batch over the row limit must not reach the " \
+        "segmented kernel"
+    mask = data["sel"] < 37
+    oracle = {}
+    for i in np.nonzero(mask)[0]:
+        k = (data["ka"][i], data["kb"][i])
+        s, c = oracle.get(k, (0, 0))
+        oracle[k] = (s + int(data["price"][i]), c + 1)
+    assert {(r[0], r[1]): (r[2], r[3]) for r in res.rows} == oracle
+
+
+def test_segmented_compact_fits_rules():
+    from pinot_tpu.ops.ir import AggSpec, KernelPlan
+
+    def plan(space, kind="sum"):
+        return KernelPlan(pred=None, aggs=(AggSpec(kind, None),),
+                          group_keys=((0, space),), strategy="compact")
+
+    big = K.SEGMENTED_SORT_ROW_LIMIT
+    # factorized as a batch: no row bound
+    assert K.segmented_compact_fits(plan(175), big, 8)
+    # 8 x 7000 > FACTORIZED_GROUP_LIMIT: the sort core, bounded by rows
+    assert not K._needs_sort(plan(7000))
+    assert K.segmented_compact_fits(plan(7000), big // 8, 8)
+    assert not K.segmented_compact_fits(plan(7000), big // 4, 8)
+    # min/max always sorts
+    assert not K.segmented_compact_fits(plan(175, "max"), big // 4, 8)
+    # the combined space ceiling is unchanged
+    assert not K.segmented_compact_fits(
+        plan(K.COMPACT_GROUP_LIMIT // 4), 1024, 8)
+
+
 def test_stack_cache_not_fooled_by_recurring_segment_names(tmp_path):
     """Two tables whose segments share names, column names, and bucket
     must not share stacked device columns: the batch stack cache keys on

@@ -34,6 +34,23 @@ def test_chip_smoke_refuses_cpu():
     assert '"ok"' not in proc.stdout
 
 
+def test_chip_smoke_rehearsal_reads_what_the_server_ran():
+    """The CPU walk-through never passes, and its per-query dispatch
+    comes from the server's span tree (EXPLAIN ANALYZE over HTTP), not
+    from a copy of the routing rules: q4.1 is one segmented program,
+    q2.1 — a sort-core batch over the (scaled) row limit — runs per
+    segment."""
+    proc = _run(["chip_smoke.py", "--rehearse-cpu"], XLA_FLAGS=None,
+                PINOT_CPU_FAST_GROUPBY=None)
+    assert proc.returncode == 2, proc.stdout + proc.stderr
+    assert '"ok"' not in proc.stdout
+    ran = dict(re.findall(r"query (\S+): plan kernel  server ran (\{.*?\})  ",
+                          proc.stdout))
+    assert ran["q4.1"] == "{'segmented_compact_dispatch': [1, 8, 'compact']}"
+    assert ran["q2.1"] == "{'segment_kernel': [8, 8, 'compact']}"
+    assert len(ran) == 5
+
+
 def test_bench_refuses_cpu_before_building_data():
     proc = _run(["bench.py"], PINOT_BENCH_FORCE_CPU=None)
     assert proc.returncode == 1

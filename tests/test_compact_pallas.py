@@ -103,7 +103,7 @@ def test_pallas_kernel_empty_and_ragged(interp):
 
 
 def test_choose_k_respects_vmem_budget():
-    assert C._choose_k(1, 1 << 27) == min(C.K_MAX, C.K_COMPILES)
+    assert C._choose_k(1, 1 << 27) == C.K_MAX
     assert C._choose_k(3, 1 << 27) >= C.K_MIN
     assert C._choose_k(12, 1 << 27) >= C.K_MIN
     for n_cols in (1, 3, 6, 12):

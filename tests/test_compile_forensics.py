@@ -529,3 +529,45 @@ def test_staged_fn_fallback_when_disabled():
     # retrace — counters/span annotation survive the hatch
     assert det.retraces == r0 + 1
     assert isinstance(fn, StagedFn)
+
+
+def test_compiler_rejection_is_not_recompiled():
+    """A program the COMPILER rejects (XLA / Mosaic RESOURCE_EXHAUSTED)
+    fails the call with the compiler's own error: no fallback to the
+    implicit jit — it would pay the same doomed compile again, minutes
+    at SSB sizes on the chip — and no second compile on the next call
+    with the same signature."""
+    import jax
+
+    calls = {"compile": 0, "implicit": 0}
+
+    class _Lowered:
+        def compile(self):
+            calls["compile"] += 1
+            raise jax.errors.JaxRuntimeError(
+                "RESOURCE_EXHAUSTED: Ran out of memory in memory space "
+                "vmem")
+
+    class _Jitted:
+        def lower(self, *args):
+            return _Lowered()
+
+        def __call__(self, *args):
+            calls["implicit"] += 1
+
+    fn = staged(_Jitted(), "unit", ("cf_rejected_tok",))
+    counters = global_metrics.snapshot()["counters"]
+    fallbacks0 = counters.get("compile_staging_fallbacks", 0)
+    rejected0 = counters.get("compile_rejections", 0)
+    for _ in range(2):
+        with pytest.raises(jax.errors.JaxRuntimeError,
+                           match="RESOURCE_EXHAUSTED"):
+            fn(jnp.arange(3))
+    assert calls == {"compile": 1, "implicit": 0}
+    counters = global_metrics.snapshot()["counters"]
+    assert counters.get("compile_staging_fallbacks", 0) == fallbacks0
+    assert counters["compile_rejections"] == rejected0 + 1
+    # another signature is another program: it gets its own verdict
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        fn(jnp.arange(4))
+    assert calls == {"compile": 2, "implicit": 0}
