@@ -864,11 +864,10 @@ class Broker:
 
         # mesh-resident table: one shard_map program + ICI combine replaces
         # the per-segment scatter-gather entirely
-        from ..utils.spans import span
+        from ..utils.spans import phase
         if dm.distributed is not None and ctx.is_aggregation \
                 and not stmt.explain:
-            with Tracing.phase(ph.DISTRIBUTED_EXECUTE), \
-                    span(ph.DISTRIBUTED_EXECUTE):
+            with phase(ph.DISTRIBUTED_EXECUTE):
                 partial = dm.distributed.try_execute(ctx)
             if partial is not None:
                 result = reduce_partials(ctx, [partial])
@@ -915,8 +914,7 @@ class Broker:
             raise QueryTimeoutError(
                 f"query timed out (>{int((deadline - t0) * 1e3)}ms)")
 
-        with Tracing.phase(ph.REDUCE), span(ph.REDUCE,
-                                          partials=len(partials)):
+        with phase(ph.REDUCE, partials=len(partials)):
             result = reduce_partials(ctx, partials)
         result.num_segments = len(segments)
         result.num_segments_pruned = ex.pruned

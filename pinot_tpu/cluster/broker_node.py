@@ -28,7 +28,8 @@ from ..query.context import build_query_context
 from ..query.sql import SetOpStmt, SqlError, parse_sql, to_sql
 from ..utils import phases as ph
 from ..utils.metrics import global_metrics, ingest_health
-from ..utils.spans import Span, sample_decision, span, span_tracer
+from ..utils.spans import (Span, phase, sample_decision, set_query_id,
+                           span_tracer)
 from ..utils.slo import SLOWQ_TAIL, global_incidents, global_slo
 from .autopsy import global_autopsy, load_corpus, whydown
 from .forensics import (QueryForensics, debug_index,
@@ -422,57 +423,60 @@ class BrokerNode:
 
     def query(self, sql: str) -> ResultTable:
         t0 = time.perf_counter()
-        stmt = parse_sql(sql)
-        from ..query.sql import DdlStmt
-        if isinstance(stmt, DdlStmt):
-            raise SqlError(
-                "view DDL runs on the in-process broker (views are "
-                "broker-local state; the networked broker carries no "
-                "catalog yet)")
-        # validate the forensics options up front (400-class, pre-dispatch)
-        options = getattr(stmt, "options", {}) or {}
-        slow_ms = parse_slow_query_ms(options,
-                                      self.forensics.default_slow_ms)
-        ratio = parse_trace_ratio(options, self.forensics.trace_ratio)
-        # a client-supplied OPTION(queryId=...) is what makes the
-        # deterministic sampling AND shed decisions hold ACROSS broker
-        # replicas and client retries — without it each broker draws a
-        # fresh uuid and only same-broker machinery (failover/hedge
-        # attempts, which share this qid via traceContext) agrees
-        qid = str(options.get("queryId") or uuid.uuid4().hex[:12])[:64]
-        table = getattr(stmt, "table", None)
-        # overload admission (ISSUE 12, broker/workload.py) once per
-        # user query, before any planning/dispatch work. Plan-only
-        # EXPLAIN skips (nothing to protect); a shed is recorded as a
-        # query_stats row (tenant/rung/retryAfterMs) so the fleet
-        # rollup trends shed rates, then surfaces as the structured
-        # 429 (the /query/sql handler renders e.payload()).
-        from ..broker.workload import (OverloadShedError, clamp_brownout,
-                                       leaf_table, parse_retry_attempt)
-        retry_attempt = parse_retry_attempt(options)
-        ticket = None
-        if not getattr(stmt, "explain", False) or \
-                getattr(stmt, "analyze", False):
-            wl_table = table or leaf_table(stmt)
-            self._resolve_workload_tenant(wl_table)
-            try:
-                ticket = self.workload.admit(
-                    qid, wl_table, retry_attempt=retry_attempt)
-            except OverloadShedError as e:
-                self.forensics.record(
-                    qid, table, sql, t0, None, [], slow_ms, error=e,
-                    workload={"tenant": e.tenant, "tier": e.tier,
-                              "shed": True, "shed_rung": e.rung,
-                              "retry_after_ms": e.retry_after_ms})
-                raise
-            if ticket.brownout:
-                # rung-3 brownout: every admitted query clamps to the
-                # floor deadline and runs with partial-result
-                # semantics — a degraded answer beats a metastable
-                # retry storm (one shared helper so the two brokers'
-                # ladders can't drift)
-                from ..broker.broker import DEFAULT_TIMEOUT_MS
-                clamp_brownout(stmt.options, DEFAULT_TIMEOUT_MS)
+        with phase(ph.BROKER_PARSE):
+            stmt = parse_sql(sql)
+            from ..query.sql import DdlStmt
+            if isinstance(stmt, DdlStmt):
+                raise SqlError(
+                    "view DDL runs on the in-process broker (views are "
+                    "broker-local state; the networked broker carries no "
+                    "catalog yet)")
+            # validate the forensics options up front (400-class,
+            # pre-dispatch)
+            options = getattr(stmt, "options", {}) or {}
+            slow_ms = parse_slow_query_ms(options,
+                                          self.forensics.default_slow_ms)
+            ratio = parse_trace_ratio(options, self.forensics.trace_ratio)
+            # a client-supplied OPTION(queryId=...) is what makes the
+            # deterministic sampling AND shed decisions hold ACROSS broker
+            # replicas and client retries — without it each broker draws a
+            # fresh uuid and only same-broker machinery (failover/hedge
+            # attempts, which share this qid via traceContext) agrees
+            qid = str(options.get("queryId") or uuid.uuid4().hex[:12])[:64]
+            set_query_id(qid)
+            table = getattr(stmt, "table", None)
+            # overload admission (ISSUE 12, broker/workload.py) once per
+            # user query, before any planning/dispatch work. Plan-only
+            # EXPLAIN skips (nothing to protect); a shed is recorded as a
+            # query_stats row (tenant/rung/retryAfterMs) so the fleet
+            # rollup trends shed rates, then surfaces as the structured
+            # 429 (the /query/sql handler renders e.payload()).
+            from ..broker.workload import (OverloadShedError, clamp_brownout,
+                                           leaf_table, parse_retry_attempt)
+            retry_attempt = parse_retry_attempt(options)
+            ticket = None
+            if not getattr(stmt, "explain", False) or \
+                    getattr(stmt, "analyze", False):
+                wl_table = table or leaf_table(stmt)
+                self._resolve_workload_tenant(wl_table)
+                try:
+                    ticket = self.workload.admit(
+                        qid, wl_table, retry_attempt=retry_attempt)
+                except OverloadShedError as e:
+                    self.forensics.record(
+                        qid, table, sql, t0, None, [], slow_ms, error=e,
+                        workload={"tenant": e.tenant, "tier": e.tier,
+                                  "shed": True, "shed_rung": e.rung,
+                                  "retry_after_ms": e.retry_after_ms})
+                    raise
+                if ticket.brownout:
+                    # rung-3 brownout: every admitted query clamps to the
+                    # floor deadline and runs with partial-result
+                    # semantics — a degraded answer beats a metastable
+                    # retry storm (one shared helper so the two brokers'
+                    # ladders can't drift)
+                    from ..broker.broker import DEFAULT_TIMEOUT_MS
+                    clamp_brownout(stmt.options, DEFAULT_TIMEOUT_MS)
         result: Optional[ResultTable] = None
         try:
             if getattr(stmt, "analyze", False):
@@ -559,29 +563,32 @@ class BrokerNode:
                            "plane arrive with the dispatch stage; use the "
                            "in-process broker for them")
 
-        # one snapshot for the whole query: hybrid detection, quota, time
-        # boundary, pruning, and scatter must agree on routing state (the
-        # refresh thread swaps self._routing underneath)
-        snap = self._snapshot()
-        # the query's timeoutMs is a BUDGET for the whole scatter: every
-        # server call gets the remaining slice, and servers receive it as
-        # deadlineMs so their accountant deadline is min(own, remaining)
-        timeout_ms = _parse_timeout_ms(stmt.options)
-        deadline = t0 + timeout_ms / 1e3
-        snap_tables = snap.get("tables", {})
-        if stmt.table not in snap_tables and \
+        with phase(ph.BROKER_ROUTE):
+            # one snapshot for the whole query: hybrid detection, quota,
+            # time boundary, pruning, and scatter must agree on routing
+            # state (the refresh thread swaps self._routing underneath)
+            snap = self._snapshot()
+            # the query's timeoutMs is a BUDGET for the whole scatter:
+            # every server call gets the remaining slice, and servers
+            # receive it as deadlineMs so their accountant deadline is
+            # min(own, remaining)
+            timeout_ms = _parse_timeout_ms(stmt.options)
+            deadline = t0 + timeout_ms / 1e3
+            snap_tables = snap.get("tables", {})
+            hybrid = stmt.table not in snap_tables and \
                 f"{stmt.table}_OFFLINE" in snap_tables and \
-                f"{stmt.table}_REALTIME" in snap_tables:
+                f"{stmt.table}_REALTIME" in snap_tables
+            if not hybrid:
+                self._check_quota(stmt.table, snap)
+                ctx = build_query_context(stmt)
+        if hybrid:
             return self._query_hybrid(stmt, t0, snap, deadline, qid,
                                       scatters, workload)
-
-        self._check_quota(stmt.table, snap)
-        ctx = build_query_context(stmt)
         if stmt.explain:
             return self._explain_remote(sql, ctx.table, deadline)
         sc = self._scatter(sql, ctx, snap, deadline, qid, workload)
         scatters.append(sc)
-        with span(ph.REDUCE, partials=len(sc.partials)):
+        with phase(ph.REDUCE, partials=len(sc.partials)):
             result = reduce_partials(ctx, sc.partials)
         result.num_segments = sc.segments_queried
         result.num_segments_pruned = sc.pruned
@@ -604,6 +611,7 @@ class BrokerNode:
         from ..query.explain import finalize_analyze
         stmt.analyze = False  # the re-entrant path executes normally
         qid = uuid.uuid4().hex[:12]
+        set_query_id(qid)
         table = getattr(stmt, "table", None)
         scatters: List[ScatterResult] = []
         root = span_tracer.start(ph.QUERY, table=table, query_id=qid)
@@ -696,8 +704,8 @@ class BrokerNode:
                               qid, workload))
         if scatters_out is not None:
             scatters_out.extend(scatters)
-        with span(ph.REDUCE,
-                  partials=sum(len(s.partials) for s in scatters)):
+        with phase(ph.REDUCE,
+                   partials=sum(len(s.partials) for s in scatters)):
             result = reduce_partials(
                 build_query_context(off),
                 [p for s in scatters for p in s.partials])
@@ -793,84 +801,85 @@ class BrokerNode:
                  qid: Optional[str] = None,
                  workload: Optional[Dict[str, Any]] = None
                  ) -> ScatterResult:
-        # one snapshot for assignment + segment metadata: the refresh
-        # thread swaps self._routing, and mixing two snapshots could
-        # silently drop segments assigned in one but absent in the other
-        if snap is None:
-            snap = self._snapshot()
-        # tracing: when this query runs under the span tracer (EXPLAIN
-        # ANALYZE rooted a tree on THIS thread), every dispatch attempt
-        # gets a scatter_call span. call() runs on pool threads, so the
-        # spans are built explicitly and collected here (list.append is
-        # GIL-atomic), then stitched under the scatter span start-ordered
-        collect: Optional[List[Span]] = \
-            [] if span_tracer.active() else None
-        sampled = collect is not None
-        assignment = snap.get("assignment", {}).get(ctx.table)
-        if assignment is None:
-            raise SqlError(f"table {ctx.table!r} not found in routing")
-        seg_entries = snap.get("segments", {}).get(ctx.table) or {}
+        with phase(ph.BROKER_SELECT):
+            # one snapshot for assignment + segment metadata: the refresh
+            # thread swaps self._routing, and mixing two snapshots could
+            # silently drop segments assigned in one but absent in the other
+            if snap is None:
+                snap = self._snapshot()
+            # tracing: when this query runs under the span tracer (EXPLAIN
+            # ANALYZE rooted a tree on THIS thread), every dispatch attempt
+            # gets a scatter_call span. call() runs on pool threads, so the
+            # spans are built explicitly and collected here (list.append is
+            # GIL-atomic), then stitched under the scatter span start-ordered
+            collect: Optional[List[Span]] = \
+                [] if span_tracer.active() else None
+            sampled = collect is not None
+            assignment = snap.get("assignment", {}).get(ctx.table)
+            if assignment is None:
+                raise SqlError(f"table {ctx.table!r} not found in routing")
+            seg_entries = snap.get("segments", {}).get(ctx.table) or {}
 
-        from ..query.planner import _truthy
-        allow_partial = _truthy(ctx.options.get("allowPartialResults"))
-        hedge_opt = self._parse_hedge_option(ctx)
-        res = ScatterResult()
+            from ..query.planner import _truthy
+            allow_partial = _truthy(ctx.options.get("allowPartialResults"))
+            hedge_opt = self._parse_hedge_option(ctx)
+            res = ScatterResult()
 
-        # broker-side pruning over controller-held segment metadata; an
-        # assigned segment with no metadata entry is never pruned
-        from ..broker.routing import prune_segments
-        meta = {s: (seg_entries.get(s) or {}).get("meta")
-                for s in assignment}
-        keep, res.pruned = prune_segments(
-            meta, ctx.filter,
-            (snap.get("tables", {}).get(ctx.table) or {}).get("config"))
-        keep_set = set(keep)
-        assignment = {s: h for s, h in assignment.items() if s in keep_set}
+            # broker-side pruning over controller-held segment metadata; an
+            # assigned segment with no metadata entry is never pruned
+            from ..broker.routing import prune_segments
+            meta = {s: (seg_entries.get(s) or {}).get("meta")
+                    for s in assignment}
+            keep, res.pruned = prune_segments(
+                meta, ctx.filter,
+                (snap.get("tables", {}).get(ctx.table) or {}).get("config"))
+            keep_set = set(keep)
+            assignment = {s: h for s, h in assignment.items() if s in keep_set}
 
-        # drop holders with no known URL up front so selector fallbacks
-        # can only pick reachable servers
-        assignment = {s: [h for h in holders if self._server_url(h)]
-                      for s, holders in assignment.items()}
+            # drop holders with no known URL up front so selector fallbacks
+            # can only pick reachable servers
+            assignment = {s: [h for h in holders if self._server_url(h)]
+                          for s, holders in assignment.items()}
 
-        # instance selection (pluggable: balanced / replicaGroup /
-        # strictReplicaGroup / adaptive) — placement-aware: the
-        # residency heartbeats tell the adaptive selector which
-        # replicas already hold each segment hot (HBM tier)
-        def healthy(h: str) -> bool:
-            return self._failures.healthy(h)
+            # instance selection (pluggable: balanced / replicaGroup /
+            # strictReplicaGroup / adaptive) — placement-aware: the
+            # residency heartbeats tell the adaptive selector which
+            # replicas already hold each segment hot (HBM tier)
+            def healthy(h: str) -> bool:
+                return self._failures.healthy(h)
 
-        placement = self._placement(ctx.table, snap)
-        picks = self._selector.select(assignment, healthy,
-                                      placement=placement)
-        if placement:
-            # avoided-vs-paid uploads: a pick landing on a replica
-            # that holds the segment hot (or a warm cube) skips the
-            # column upload entirely. Segments NO server reported
-            # residency for (heartbeat cap, table not yet surveyed)
-            # count neither way — they would understate the hit ratio
-            # through no fault of the routing
+            placement = self._placement(ctx.table, snap)
+            picks = self._selector.select(assignment, healthy,
+                                          placement=placement)
+            if placement:
+                # avoided-vs-paid uploads: a pick landing on a replica
+                # that holds the segment hot (or a warm cube) skips the
+                # column upload entirely. Segments NO server reported
+                # residency for (heartbeat cap, table not yet surveyed)
+                # count neither way — they would understate the hit ratio
+                # through no fault of the routing
+                for seg, pick in picks.items():
+                    tiers = placement.get(seg)
+                    if pick is None or not tiers:
+                        continue
+                    if tiers.get(pick) in ("hot", "cube"):
+                        res.affinity_hits += 1
+                        global_metrics.count("tier_affinity_hits")
+                    else:
+                        global_metrics.count("tier_affinity_misses")
+            unserved = [s for s, p in picks.items() if p is None]
+            if unserved:
+                msg = (f"no live replica for segments {unserved[:3]}"
+                       f"{'...' if len(unserved) > 3 else ''}")
+                if not allow_partial:
+                    raise SqlError(msg)
+                res.exceptions.append({"errorCode": ERR_SERVER_NOT_RESPONDED,
+                                       "message": msg})
+                res.partial = True
+            by_server: Dict[str, List[str]] = {}
             for seg, pick in picks.items():
-                tiers = placement.get(seg)
-                if pick is None or not tiers:
-                    continue
-                if tiers.get(pick) in ("hot", "cube"):
-                    res.affinity_hits += 1
-                    global_metrics.count("tier_affinity_hits")
-                else:
-                    global_metrics.count("tier_affinity_misses")
-        unserved = [s for s, p in picks.items() if p is None]
-        if unserved:
-            msg = (f"no live replica for segments {unserved[:3]}"
-                   f"{'...' if len(unserved) > 3 else ''}")
-            if not allow_partial:
-                raise SqlError(msg)
-            res.exceptions.append({"errorCode": ERR_SERVER_NOT_RESPONDED,
-                                   "message": msg})
-            res.partial = True
-        by_server: Dict[str, List[str]] = {}
-        for seg, pick in picks.items():
-            if pick is not None:
-                by_server.setdefault(pick, []).append(seg)
+                if pick is not None:
+                    by_server.setdefault(pick, []).append(seg)
 
         adaptive = getattr(self._selector, "record_start", None)
 
@@ -929,13 +938,14 @@ class BrokerNode:
                     # the server clamps its accountant deadline to
                     # min(its own timeoutMs, this remaining budget)
                     body["deadlineMs"] = int(rem * 1e3)
-                raw = http_raw("POST", f"{url}/query/bin", body,
-                               timeout=10.0 if rem is None
-                               else max(rem, 0.05))
+                with phase(ph.SCATTER_CALL, qid) as sent:
+                    raw = http_raw("POST", f"{url}/query/bin", body,
+                                   timeout=10.0 if rem is None
+                                   else max(rem, 0.05))
+                global_metrics.count("wire_bytes_in", len(raw))
                 raw = corrupt_bytes("wire.corrupt", server, raw)
-                t_dec = time.perf_counter()
-                header, decoded = decode_wire_frame(raw)
-                dec_ms = (time.perf_counter() - t_dec) * 1e3
+                with phase(ph.WIRE_DECODE, qid) as dec:
+                    header, decoded = decode_wire_frame(raw)
                 n_run = int(header.get("segmentsQueried", 0))
                 if n_run < len(segs):
                     raise _SegmentShortfall(
@@ -943,24 +953,22 @@ class BrokerNode:
                         f"requested segments (still loading after a "
                         f"reassignment?)")
                 self._failures.record_success(server)
-                # serde vs network split of the round-10 net gap: the
-                # server timed its frame encode (serdeEncodeMs in the
-                # header), the decode was timed above
-                serde = dec_ms + float(header.get("serdeEncodeMs")
+                # serde vs network split of the call: the server timed
+                # its frame encode (serdeEncodeMs in the header) and its
+                # whole stay (serverMs: arrival to frame encoded), the
+                # request and the decode were timed above — the same
+                # clock reads that feed the phase counters, sampled or not
+                serde = dec.ms + float(header.get("serdeEncodeMs")
                                        or 0.0)
-                net = 0.0
+                net = max(sent.ms - float(header.get("serverMs")
+                                          or sent.ms), 0.0)
                 if sp is not None:
                     sp.finish()
                     remote = header.get("trace")
                     if remote:
-                        rt = Span.from_dict(remote)
-                        sp.children.append(rt)
-                        # call span - remote tree - serde = true
-                        # network time
-                        net = max(sp.duration_ms - rt.duration_ms
-                                  - serde, 0.0)
-                        sp.annotate(net_ms=round(net, 3))
-                    sp.annotate(status="ok", serde_ms=round(serde, 3))
+                        sp.children.append(Span.from_dict(remote))
+                    sp.annotate(status="ok", serde_ms=round(serde, 3),
+                                net_ms=round(net, 3))
                 res.add_wire_times(serde, net)
                 if header.get("batched"):
                     res.add_batching(header.get("batched", 0),
@@ -1042,9 +1050,10 @@ class BrokerNode:
                     self._selector.record_end(
                         server, (time.perf_counter() - tcall) * 1e3)
 
-        with span(ph.SCATTER, table=ctx.table, servers=len(by_server),
-                  segments=sum(len(s) for s in by_server.values())
-                  ) as sc_span:
+        with phase(ph.SCATTER, table=ctx.table, servers=len(by_server),
+                   segments=sum(len(s) for s in by_server.values())
+                   ) as sc_phase:
+            sc_span = sc_phase.span
             try:
                 self._gather(hedge_opt, assignment, by_server, call, res,
                              remaining, allow_partial)
@@ -1370,7 +1379,9 @@ class BrokerNode:
             if not sql:
                 return 400, {"error": "missing sql"}
             try:
-                return 200, node.query(sql).to_dict()
+                # the result object: JsonHandler renders it (to_dict,
+                # JSON) inside the broker_respond phase
+                return 200, node.query(sql)
             except OverloadShedError as e:
                 # the structured 429: errorCode + retryAfterMs +
                 # tenant/tier/rung — NEVER a 500/stack trace (the
@@ -1456,6 +1467,8 @@ class BrokerNode:
                     200, ("text/html", node.ui_page())),
                 ("POST", "/query/sql"): q,
             }
+            metered = {("POST", "/query/sql"): (ph.BROKER_QUERY,
+                                                ph.BROKER_RESPOND)}
         return Handler
 
     def ui_page(self) -> str:

@@ -4,6 +4,7 @@ the host-side control/data planes; intra-query device combines ride ICI via
 parallel/distributed.py, which is where the bandwidth actually matters)."""
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 import urllib.error
@@ -11,12 +12,18 @@ import urllib.request
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable, Dict, Optional, Tuple
 
+from ..utils.spans import phase, set_query_id
+
 
 class JsonHandler(BaseHTTPRequestHandler):
     """Dispatches (method, path-prefix) to registered handlers returning
-    (status, json-able)."""
+    (status, json-able or an object with ``to_dict``)."""
 
     routes: Dict[Tuple[str, str], Callable] = {}
+    # query routes: (method, prefix) -> (phase of the whole crossing, from
+    # the body parsed to the response written; phase of the response
+    # alone, or None) — names of utils/phases.METERED_PHASES
+    metered: Dict[Tuple[str, str], Tuple[str, Optional[str]]] = {}
     protocol_version = "HTTP/1.1"
 
     def log_message(self, fmt, *args):  # quiet
@@ -44,48 +51,62 @@ class JsonHandler(BaseHTTPRequestHandler):
         for (m, prefix), fn in sorted(self.routes.items(),
                                       key=lambda kv: -len(kv[0][1])):
             if m == method and self.path.split("?")[0].startswith(prefix):
-                try:
-                    status, payload = fn(self, body)
-                except Exception as e:
-                    # capacity/shed rejections (broker/workload.
-                    # OverloadShedError, engine/scheduler.
-                    # SchedulerRejectedError) must surface as
-                    # STRUCTURED retryable JSON — HTTP 429 with
-                    # errorCode + retryAfterMs — never a 500/stack
-                    # trace a client can't act on
-                    if getattr(e, "retry_after_ms", None) is not None \
-                            and hasattr(e, "error_code"):
-                        payload = (e.payload() if hasattr(e, "payload")
-                                   else {"error": str(e),
-                                         "errorCode": e.error_code,
-                                         "retryAfterMs":
-                                             e.retry_after_ms})
-                        status = 429
-                    else:  # surface handler errors as 500 JSON
-                        status, payload = 500, {
-                            "error": f"{type(e).__name__}: {e}"}
-                if isinstance(payload, (bytes, bytearray)):
-                    # binary data plane (DataTable-over-Netty analog)
-                    data = bytes(payload)
-                    ctype = "application/octet-stream"
-                elif isinstance(payload, tuple) and len(payload) == 2 \
-                        and isinstance(payload[0], str):
-                    # (content_type, body) — e.g. the controller UI page
-                    ctype, body = payload
-                    data = body if isinstance(body, bytes) \
-                        else str(body).encode()
-                else:
-                    data = json.dumps(payload).encode()
-                    ctype = "application/json"
-                self.send_response(status)
-                self.send_header("Content-Type", ctype)
-                self.send_header("Content-Length", str(len(data)))
-                self.end_headers()
-                self.wfile.write(data)
+                # a query route's whole crossing is one phase; its handler
+                # names the query (set_query_id) once it knows it
+                whole, respond = self.metered.get((m, prefix), (None, None))
+                set_query_id(None)
+                with phase(whole) if whole else contextlib.nullcontext():
+                    self._serve(fn, body, respond)
                 return
         self.send_response(404)
         self.send_header("Content-Length", "0")
         self.end_headers()
+
+    def _serve(self, fn: Callable, body: Any, respond: Optional[str]
+               ) -> None:
+        """Run the route's handler and write what it returns; ``respond``
+        meters everything after the handler (``to_dict`` of a result
+        object, JSON encode, the socket write) as a phase of its own."""
+        try:
+            status, payload = fn(self, body)
+        except Exception as e:
+            # capacity/shed rejections (broker/workload.
+            # OverloadShedError, engine/scheduler.
+            # SchedulerRejectedError) must surface as
+            # STRUCTURED retryable JSON — HTTP 429 with
+            # errorCode + retryAfterMs — never a 500/stack
+            # trace a client can't act on
+            if getattr(e, "retry_after_ms", None) is not None \
+                    and hasattr(e, "error_code"):
+                payload = (e.payload() if hasattr(e, "payload")
+                           else {"error": str(e),
+                                 "errorCode": e.error_code,
+                                 "retryAfterMs": e.retry_after_ms})
+                status = 429
+            else:  # surface handler errors as 500 JSON
+                status, payload = 500, {
+                    "error": f"{type(e).__name__}: {e}"}
+        with phase(respond) if respond else contextlib.nullcontext():
+            if hasattr(payload, "to_dict"):
+                payload = payload.to_dict()
+            if isinstance(payload, (bytes, bytearray)):
+                # binary data plane (DataTable-over-Netty analog)
+                data = bytes(payload)
+                ctype = "application/octet-stream"
+            elif isinstance(payload, tuple) and len(payload) == 2 \
+                    and isinstance(payload[0], str):
+                # (content_type, body) — e.g. the controller UI page
+                ctype, body = payload
+                data = body if isinstance(body, bytes) \
+                    else str(body).encode()
+            else:
+                data = json.dumps(payload).encode()
+                ctype = "application/json"
+            self.send_response(status)
+            self.send_header("Content-Type", ctype)
+            self.send_header("Content-Length", str(len(data)))
+            self.end_headers()
+            self.wfile.write(data)
 
     def do_GET(self):
         self._dispatch("GET")

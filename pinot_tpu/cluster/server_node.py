@@ -26,6 +26,8 @@ from ..query.context import build_query_context
 from ..query.sql import parse_sql
 from ..segment.immutable import ImmutableSegment
 from ..server.data_manager import TableDataManager
+from ..utils import phases as ph
+from ..utils.spans import phase, record_phase, set_query_id
 from .http_util import (JsonHandler, http_json, start_http,
                         trace_context_from)
 
@@ -242,19 +244,24 @@ class ServerNode:
         # queue time is inside the broker's budget, not in addition to it
         t_arrive = time.perf_counter()
         sampled = bool((trace_ctx or {}).get("sampled"))
+        # the broker's id names this query's profiler events on both of
+        # the server's threads, as it does on the broker's
+        broker_qid = (trace_ctx or {}).get("queryId")
+        set_query_id(broker_qid)
 
         def run() -> Dict[str, Any]:
             # the scheduler runs this on a worker thread — the span
             # tracer is thread-local, so the tree must root HERE, not in
             # the HTTP handler thread that admitted the query
+            record_phase(ph.SERVER_QUEUE, time.perf_counter() - t_arrive)
+            set_query_id(broker_qid)
             if not sampled:
                 return self._execute(sql, segment_names, query_id,
                                      deadline_ms, t_arrive)
-            from ..utils import phases as ph
             from ..utils.spans import span_tracer
             root = span_tracer.start(
                 ph.SERVER_QUERY, server=self.instance_id,
-                query_id=trace_ctx.get("queryId") or query_id,
+                query_id=broker_qid or query_id,
                 parent_span_id=trace_ctx.get("parentSpanId"))
             try:
                 resp = self._execute(sql, segment_names, query_id,
@@ -290,41 +297,42 @@ class ServerNode:
                  query_id: Optional[str] = None,
                  deadline_ms: Optional[float] = None,
                  t_arrive: Optional[float] = None) -> Dict[str, Any]:
-        t0 = time.perf_counter()
-        stmt = parse_sql(sql)
-        from ..query.sql import DdlStmt, SetOpStmt
-        if isinstance(stmt, (SetOpStmt, DdlStmt)):
-            raise ValueError("leaf servers execute single-table stages; "
-                             "set operations and DDL belong to the broker")
-        from ..multistage.window import has_window
-        if has_window(stmt):
-            raise ValueError("leaf servers execute single-table stages; "
-                             "window functions run in the dispatch stage")
-        if query_id is not None:
-            # enforce the query's timeoutMs where the work actually runs
-            # (the broker-side deadline lives in a different process in
-            # cluster mode), clamped to the broker's forwarded remaining
-            # budget so a re-dispatched straggler cannot outlive the
-            # scatter that asked for it
-            from ..broker.broker import DEFAULT_TIMEOUT_MS
-            timeout_ms = int(stmt.options.get("timeoutMs",
-                                              DEFAULT_TIMEOUT_MS))
-            if deadline_ms is not None:
-                timeout_ms = min(timeout_ms, int(deadline_ms))
-            global_accountant.set_deadline(
-                query_id, (t_arrive or t0) + timeout_ms / 1e3)
-        if stmt.joins:
-            raise ValueError("leaf servers execute single-table stages")
-        from ..utils.faults import fault_point
-        fault_point("segment.slow", key=self.instance_id)
-        ctx = build_query_context(stmt)
-        dm = self._tables.get(ctx.table)
+        with phase(ph.SERVER_PARSE):
+            t0 = time.perf_counter()
+            stmt = parse_sql(sql)
+            from ..query.sql import DdlStmt, SetOpStmt
+            if isinstance(stmt, (SetOpStmt, DdlStmt)):
+                raise ValueError("leaf servers execute single-table stages; "
+                                 "set operations and DDL belong to the broker")
+            from ..multistage.window import has_window
+            if has_window(stmt):
+                raise ValueError("leaf servers execute single-table stages; "
+                                 "window functions run in the dispatch stage")
+            if query_id is not None:
+                # enforce the query's timeoutMs where the work actually runs
+                # (the broker-side deadline lives in a different process in
+                # cluster mode), clamped to the broker's forwarded remaining
+                # budget so a re-dispatched straggler cannot outlive the
+                # scatter that asked for it
+                from ..broker.broker import DEFAULT_TIMEOUT_MS
+                timeout_ms = int(stmt.options.get("timeoutMs",
+                                                  DEFAULT_TIMEOUT_MS))
+                if deadline_ms is not None:
+                    timeout_ms = min(timeout_ms, int(deadline_ms))
+                global_accountant.set_deadline(
+                    query_id, (t_arrive or t0) + timeout_ms / 1e3)
+            if stmt.joins:
+                raise ValueError("leaf servers execute single-table stages")
+            from ..utils.faults import fault_point
+            fault_point("segment.slow", key=self.instance_id)
+            ctx = build_query_context(stmt)
+            dm = self._tables.get(ctx.table)
+            segments = [] if dm is None else dm.acquire_segments()
+            if segment_names is not None:
+                wanted = set(segment_names)
+                segments = [s for s in segments if s.name in wanted]
         if dm is None:
             return {"partials_raw": [], "segmentsQueried": 0}
-        segments = dm.acquire_segments()
-        if segment_names is not None:
-            wanted = set(segment_names)
-            segments = [s for s in segments if s.name in wanted]
         # shared loop with the in-process broker (engine/serving.py)
         from ..engine.serving import execute_segments, plan_segments
         if stmt.explain:
@@ -362,16 +370,20 @@ class ServerNode:
         with ``serdeEncodeMs`` — the partial-encode time this side of
         the wire, so the broker can split its call-span gap into serde
         vs true network time (the encode is timed BEFORE the header is
-        assembled; header serialization itself is negligible)."""
+        assembled; header serialization itself is negligible), and
+        ``serverMs``, this node's whole stay up to the encoded frame."""
         from ..engine.datablock import (encode_partial,
                                         encode_wire_frame_blocks)
+        t_arrive = time.perf_counter()
         resp = self.execute(sql, segment_names, deadline_ms=deadline_ms,
                             trace_ctx=trace_ctx, workload=workload)
         raw = resp.pop("partials_raw", [])
-        t_enc = time.perf_counter()
-        blocks = [encode_partial(p) for p in raw]
-        resp["serdeEncodeMs"] = round(
-            (time.perf_counter() - t_enc) * 1e3, 3)
+        with phase(ph.SERVER_ENCODE, partials=len(raw)) as enc:
+            blocks = [encode_partial(p) for p in raw]
+        resp["serdeEncodeMs"] = round(enc.ms, 3)
+        # arrival to frame encoded: the broker's call time less this is
+        # the wire (ScatterResult.net_ms), on sampled and plain calls alike
+        resp["serverMs"] = round((enc.t0 - t_arrive) * 1e3 + enc.ms, 3)
         return encode_wire_frame_blocks(resp, blocks)
 
     def handle_reload(self, body: Dict[str, Any]) -> Dict[str, Any]:
@@ -455,6 +467,8 @@ class ServerNode:
                     200, node.handle_stage(b, trace_context_from(
                         h.headers))),
             }
+            metered = {("POST", "/query/bin"): (ph.SERVER_HTTP, None),
+                       ("POST", "/query"): (ph.SERVER_HTTP, None)}
         return Handler
 
     def stop(self) -> None:

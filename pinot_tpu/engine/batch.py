@@ -24,9 +24,11 @@ import numpy as np
 
 from ..ops.kernels import build_kernel
 from ..query.planner import CompiledPlan
+from ..utils import phases as ph
 from ..utils.devmem import global_device_memory
 from ..utils.metrics import global_metrics
-from ..utils.spans import annotate, device_fence, span
+from ..utils.spans import (annotate, count_dispatch, device_fence, phase,
+                           span)
 from .executor import execute_plan, extract_partial, resolve_params
 
 # stacked-column cache: ((segment uid, name) pairs, cols, bucket) -> tuple
@@ -62,9 +64,10 @@ def _seg_key(seg) -> Tuple[int, str]:
 
 @functools.lru_cache(maxsize=512)
 def _vmapped_kernel_cached(plan_struct, bucket: int, scatter: bool):
-    from ..utils.compileplane import staged
-    return staged(jax.jit(jax.vmap(build_kernel(plan_struct, bucket,
-                                                scatter=scatter))),
+    from ..utils.compileplane import kernel_jit, staged
+    return staged(kernel_jit(jax.vmap(build_kernel(plan_struct, bucket,
+                                                   scatter=scatter)),
+                             ph.DENSE_VMAP),
                   "vmap_kernel", ("vmap", plan_struct, bucket, scatter))
 
 
@@ -171,7 +174,8 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
                 # segment index becomes the leading group-key factor
                 # (ops/kernels.build_segmented_compact_kernel), replacing
                 # the per-segment launches the Pallas compaction forced
-                params = resolve_params(plan)
+                with phase(ph.DISPATCH_PREPARE):
+                    params = resolve_params(plan)
                 resolved[i] = params
                 key = ("segc", kp, plan.segment.bucket,
                        _param_sig(params) + shape_sig)
@@ -179,7 +183,8 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
             else:
                 results[i] = execute_plan(plan)
             continue
-        params = resolve_params(plan)
+        with phase(ph.DISPATCH_PREPARE):
+            params = resolve_params(plan)
         resolved[i] = params
         key = ("dense", kp, plan.segment.bucket,
                _param_sig(params) + shape_sig)
@@ -232,12 +237,14 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
         # cache accumulator registration — must pick victims outside it
         # (engine/tier.py, anti-thrash)
         with global_tier.pinned({p.segment.uid for p in group_plans}):
-            cols = _stacked_cols(group_plans, bucket)
-            n_docs = jnp.asarray([p.segment.n_docs for p in group_plans],
-                                 dtype=jnp.int32)
-            params = tuple(
-                jnp.stack([resolved[i][j] for i in idxs])
-                for j in range(len(resolved[idxs[0]])))
+            with phase(ph.DISPATCH_PREPARE, segments=n_seg):
+                cols = _stacked_cols(group_plans, bucket)
+                n_docs = jnp.asarray(
+                    [p.segment.n_docs for p in group_plans],
+                    dtype=jnp.int32)
+                params = tuple(
+                    jnp.stack([resolved[i][j] for i in idxs])
+                    for j in range(len(resolved[idxs[0]])))
             if kind == "segc":
                 _run_segmented_compact(plans, idxs, plan_struct, bucket,
                                        cols, n_docs, params, results)
@@ -246,25 +253,29 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
                       strategy=plan_struct.strategy):
                 _maybe_profile_phases(group_plans[0])
                 fn = _vmapped_kernel(plan_struct, bucket)
-                with span("device_execute"):
+                count_dispatch(ph.DENSE_VMAP)
+                with phase(ph.DEVICE_EXECUTE):
                     dev = fn(cols, n_docs, params)
                     device_fence(dev)
-                with span("device_transfer"):
+                with phase(ph.DEVICE_TRANSFER):
                     out = jax.device_get(dev)  # jaxlint: ok host-sync
                 global_accountant.track_result(out)
                 # per-segment slicing below runs on host numpy behind
                 # the single fence above — host-sync [jaxlint baseline]
-                for k, i in enumerate(idxs):
-                    per_seg = {name: v[k] for name, v in out.items()}
-                    if int(per_seg.pop("group_overflow", 0)):
-                        # this segment alone exceeded the transfer-
-                        # compaction cap; rerun it solo, straight to
-                        # dense outputs
-                        from .executor import run_kernel
-                        dense = run_kernel(plans[i], xfer_compact=False)
-                        results[i] = extract_partial(plans[i], dense)
-                    else:
-                        results[i] = extract_partial(plans[i], per_seg)
+                spilled: List[int] = []
+                with phase(ph.EXTRACT_PARTIAL, segments=n_seg):
+                    for k, i in enumerate(idxs):
+                        per_seg = {name: v[k] for name, v in out.items()}
+                        if int(per_seg.pop("group_overflow", 0)):
+                            spilled.append(i)
+                        else:
+                            results[i] = extract_partial(plans[i], per_seg)
+                for i in spilled:
+                    # this segment alone exceeded the transfer-
+                    # compaction cap; rerun it solo, straight to dense
+                    # outputs (outside the phase above: the rerun
+                    # crosses the same boundaries again)
+                    results[i] = execute_plan(plans[i], xfer_compact=False)
     return results
 
 
@@ -303,10 +314,7 @@ def _run_segmented_compact(plans, idxs, plan_struct, bucket, cols, n_docs,
               est_sel=plans[idxs[0]].est_selectivity):
         _maybe_profile_phases(plans[idxs[0]])
         fn = jitted_segmented_compact(plan_struct, bucket, n_seg, cap)
-        with span("device_execute"):
-            dev = fn(cols, n_docs, params)
-            device_fence(dev)
-        out = jax.device_get(dev)  # jaxlint: ok host-sync
+        out = _launch_segmented(fn, cols, n_docs, params)
         # retry-ladder checks + slicing below read host numpy behind the
         # fence above — host-sync [jaxlint baseline]
         from ..ops.plan_cache import global_plan_cache
@@ -320,7 +328,7 @@ def _run_segmented_compact(plans, idxs, plan_struct, bucket, cols, n_docs,
                     global_plan_cache.detector.expected():
                 fn = jitted_segmented_compact(plan_struct, bucket, n_seg,
                                               cap)
-                out = jax.device_get(fn(cols, n_docs, params))
+                out = _launch_segmented(fn, cols, n_docs, params)
             out.pop("overflow", None)
             annotate(overflow_retry=True, slots_cap=cap)
         if int(out.pop("group_overflow", 0)):
@@ -329,29 +337,40 @@ def _run_segmented_compact(plans, idxs, plan_struct, bucket, cols, n_docs,
                     global_plan_cache.detector.expected():
                 fn = jitted_segmented_compact(plan_struct, bucket, n_seg,
                                               cap, xfer_compact=False)
-                out = jax.device_get(fn(cols, n_docs, params))
+                out = _launch_segmented(fn, cols, n_docs, params)
             out.pop("overflow", None)
             annotate(group_overflow_retry=True)
         global_accountant.track_result(out)
     space = plan_struct.group_space
-    matched = out.pop("matched")
-    gi = out.pop("group_idx", None)
-    for k, i in enumerate(idxs):
-        per_seg = {"matched": matched[k]}
-        if gi is not None:
-            # transfer-compacted: rows are live groups of the combined
-            # S*space; this segment owns flat ids [k*space, (k+1)*space)
-            rows = np.nonzero((gi >= k * space) & (gi < (k + 1) * space)
-                              & (np.asarray(out["group_count"]) > 0))[0]
-            per_seg["group_idx"] = np.asarray(gi)[rows] - k * space
-            for name, v in out.items():
-                per_seg[name] = np.asarray(v)[rows]
-        else:
-            for name, v in out.items():
-                v = np.asarray(v)
-                if v.ndim >= 1 and v.shape[0] == n_seg * space:
-                    per_seg[name] = v.reshape(
-                        (n_seg, space) + v.shape[1:])[k]
-                else:
-                    per_seg[name] = v
-        results[i] = extract_partial(plans[i], per_seg)
+    with phase(ph.EXTRACT_PARTIAL, segments=n_seg):
+        matched = out.pop("matched")
+        gi = out.pop("group_idx", None)
+        for k, i in enumerate(idxs):
+            per_seg = {"matched": matched[k]}
+            if gi is not None:
+                # transfer-compacted: rows are live groups of the combined
+                # S*space; this segment owns flat ids [k*space, (k+1)*space)
+                rows = np.nonzero((gi >= k * space) & (gi < (k + 1) * space)
+                                  & (np.asarray(out["group_count"]) > 0))[0]
+                per_seg["group_idx"] = np.asarray(gi)[rows] - k * space
+                for name, v in out.items():
+                    per_seg[name] = np.asarray(v)[rows]
+            else:
+                for name, v in out.items():
+                    v = np.asarray(v)
+                    if v.ndim >= 1 and v.shape[0] == n_seg * space:
+                        per_seg[name] = v.reshape(
+                            (n_seg, space) + v.shape[1:])[k]
+                    else:
+                        per_seg[name] = v
+            results[i] = extract_partial(plans[i], per_seg)
+
+
+def _launch_segmented(fn, cols, n_docs, params) -> Dict[str, Any]:
+    """One launch of the segmented compact program and its copy back."""
+    count_dispatch(ph.COMPACT_SEGMENTED)
+    with phase(ph.DEVICE_EXECUTE):
+        dev = fn(cols, n_docs, params)
+        device_fence(dev)
+    with phase(ph.DEVICE_TRANSFER):
+        return jax.device_get(dev)  # jaxlint: ok host-sync

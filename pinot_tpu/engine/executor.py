@@ -21,8 +21,9 @@ from ..query.context import QueryContext
 from ..query.sql import Star
 from ..query.planner import AggBinding, CompiledPlan, SegmentPlanner
 from ..segment.immutable import ImmutableSegment
+from ..utils import phases as ph
 from ..utils.metrics import global_metrics
-from ..utils.spans import annotate, span
+from ..utils.spans import annotate, count_dispatch, phase, span
 from . import host_eval
 
 
@@ -71,7 +72,9 @@ def execute_segment(ctx: QueryContext, segment: ImmutableSegment):
     return SegmentExecutor(segment).execute(ctx)
 
 
-def execute_plan(plan: CompiledPlan):
+def execute_plan(plan: CompiledPlan, xfer_compact: bool = True):
+    """``xfer_compact=False`` reruns a kernel plan straight to dense
+    group outputs (run_kernel)."""
     ctx, seg = plan.ctx, plan.segment
     if plan.kind == "pruned":
         if not ctx.is_aggregation and plan.select_names:
@@ -98,10 +101,12 @@ def execute_plan(plan: CompiledPlan):
             labels, rows, okeys = host_eval.host_selection(ctx, seg, mask)
             return SelectionPartial(labels, rows, okeys)
     if plan.kind == "kselect":
-        return extract_select(plan, run_select_kernel(plan))
+        out = run_select_kernel(plan)
+        with phase(ph.EXTRACT_PARTIAL, segment=seg.name):
+            return extract_select(plan, out)
     assert plan.kind == "kernel"
-    out = run_kernel(plan)
-    with span("extract_partial", segment=seg.name):
+    out = run_kernel(plan, xfer_compact)
+    with phase(ph.EXTRACT_PARTIAL, segment=seg.name):
         return extract_partial(plan, out)
 
 
@@ -110,13 +115,15 @@ def run_select_kernel(plan: CompiledPlan) -> Dict[str, np.ndarray]:
     from ..utils.spans import device_fence
     seg = plan.segment
     with span("segment_kselect", segment=seg.name, bucket=seg.bucket):
-        cols = seg.device_cols(plan.col_names)
-        params = resolve_params(plan)
+        with phase(ph.DISPATCH_PREPARE):
+            cols = seg.device_cols(plan.col_names)
+            params = resolve_params(plan)
         fn = jitted_select_kernel(plan.select_plan, seg.bucket)
-        with span("device_execute"):
+        count_dispatch(ph.SELECT_TOPK)
+        with phase(ph.DEVICE_EXECUTE):
             out = fn(cols, np.int32(seg.n_docs), params)
             device_fence(out)
-        with span("device_transfer"):
+        with phase(ph.DEVICE_TRANSFER):
             host = jax.device_get(out)  # jaxlint: ok host-sync
         from .accounting import global_accountant
         global_accountant.track_result(host)
@@ -219,8 +226,9 @@ def run_kernel(plan: CompiledPlan,
         # first-run accumulator registration enforces the tier budget,
         # and without the pin it could demote the very segment whose
         # columns this query just uploaded (engine/tier anti-thrash)
-        cols = seg.device_cols(plan.col_names)
-        params = resolve_params(plan)
+        with phase(ph.DISPATCH_PREPARE):
+            cols = seg.device_cols(plan.col_names)
+            params = resolve_params(plan)
         n = np.int32(seg.n_docs)
         cap = plan.slots_cap
         # drift_requantized: the compile at the measured-selectivity

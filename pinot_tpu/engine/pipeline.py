@@ -26,6 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..query.planner import CompiledPlan
+from ..utils.phases import plan_family
+from ..utils.spans import count_dispatch
 from ..utils.stats import make_bump
 
 # default budget: v5e has 16GB HBM; leave headroom for outputs/compile
@@ -77,6 +79,7 @@ def execute_kernel_plans_pipelined(plans: List[CompiledPlan],
 
     fn = jitted_kernel(plan_struct, bucket)  # lru-cached jit: repeated
     # over-budget queries must not pay a fresh XLA compile per group
+    family = plan_family(plan_struct)
     group = [plans[i] for i in idxs]
 
     def stage(k: int):
@@ -94,6 +97,7 @@ def execute_kernel_plans_pipelined(plans: List[CompiledPlan],
         # enqueue the NEXT transfer before compute: async dispatch lets
         # the H2D copy overlap this kernel on the transfer engine
         staged = stage(k + 1) if k + 1 < len(group) else None
+        count_dispatch(family)
         out = fn(cur, jnp.int32(plan.segment.n_docs),
                  resolved_params[idxs[k]])
         outs.append(out)
@@ -122,6 +126,7 @@ def execute_kernel_plans_pipelined(plans: List[CompiledPlan],
             cols = tuple(jax.device_put(seg.host_col_padded(c, bucket))
                          for c in plan.col_names)
             from ..ops.plan_cache import global_plan_cache
+            count_dispatch(family)
             with global_plan_cache.detector.expected():
                 # a deliberate dense rerun (compile-event taxonomy:
                 # overflow_retry, never a retrace)

@@ -64,7 +64,8 @@ import numpy as np
 
 from ..utils import phases as ph
 from ..utils.metrics import global_metrics
-from ..utils.spans import annotate, device_fence, span
+from ..utils.spans import (annotate, count_dispatch, device_fence, phase,
+                           span)
 from .scheduler import MicroBatchQueue
 
 # cost-model caps: the cube must stay small relative to the data it
@@ -334,6 +335,7 @@ def build_cube_kernel(spec: CubeSpec):
         if slot is not None and slot not in slot_values:
             slot_values[slot] = agg.value
 
+    @jax.named_scope(ph.SCOPE_AGGREGATE)
     def kernel(cols, n_docs, params):
         valid = jnp.arange(spec.bucket, dtype=jnp.int32) < n_docs
         key, ok = _dim_digits(spec, cols)
@@ -362,6 +364,7 @@ def build_cube_combine_kernel(spec: CubeSpec):
     G, P = spec.group_space, spec.pred_space
     grouped = spec.kp.is_group_by
 
+    @jax.named_scope(ph.SCOPE_COMBINE)
     def kernel(cubes, seg_idx, params):
         grid = _grid_cols(spec)
 
@@ -424,13 +427,13 @@ class _KernelRegistry:
         self._evicted: "OrderedDict[Tuple, bool]" = OrderedDict()
         self._maxsize = maxsize
 
-    def get(self, key: Tuple, make):
+    def get(self, key: Tuple, make, family: str = ph.RAGGED_FUSED):
         # the whole miss path stays under the lock so concurrent
         # leaders of one key can't double-build the wrapper; the
         # compile itself classifies + lands its compile_event at first
         # call (utils/compileplane.StagedFn, single-flight under the
         # wrapper's own lock). Cheap to hold: jax.jit() is lazy.
-        from ..utils.compileplane import staged
+        from ..utils.compileplane import kernel_jit, staged
         with self._lock:
             fn = self._fns.get(key)
             if fn is not None:
@@ -440,7 +443,8 @@ class _KernelRegistry:
             if key in self._evicted:
                 del self._evicted[key]
                 hints = {"evicted": True}
-            fn = staged(jax.jit(make()), "ragged", key, hints=hints)
+            fn = staged(kernel_jit(make(), family), "ragged", key,
+                        hints=hints)
             self._fns[key] = fn
             while len(self._fns) > self._maxsize:
                 old_key, _old = self._fns.popitem(last=False)
@@ -720,13 +724,16 @@ class RaggedBatcher:
         fn = _kernels.get(
             ("combine", spec, len(cubes), npad,
              tuple((tuple(p.shape), str(p.dtype)) for p in params0)),
-            lambda: build_cube_combine_kernel(spec))
+            lambda: build_cube_combine_kernel(spec), ph.RAGGED_FUSED)
         with span(ph.FUSED_EXECUTE, queries=len(batch), items=n_items,
                   padded=npad, segments=len(cubes),
                   cube_space=spec.cube_space):
-            dev = fn(stacked, jnp.asarray(seg_idx), stacked_params)
-            device_fence(dev)
-            host = jax.device_get(dev)  # jaxlint: ok host-sync
+            count_dispatch(ph.RAGGED_FUSED)
+            with phase(ph.DEVICE_EXECUTE):
+                dev = fn(stacked, jnp.asarray(seg_idx), stacked_params)
+                device_fence(dev)
+            with phase(ph.DEVICE_TRANSFER):
+                host = jax.device_get(dev)  # jaxlint: ok host-sync
         from .accounting import global_accountant
         # memory accounting is apportioned per participant (outputs are
         # [npad, ...] so every item owns an equal slice): piling the
@@ -742,22 +749,27 @@ class RaggedBatcher:
         # unpack + extract per item on host numpy behind the single
         # fence above — host-sync [jaxlint baseline]
         results: Dict[int, List[Any]] = {id(s): [] for s in batch}
-        for k, (sub, plan, _p) in enumerate(items):
-            per_item = {name: v[k] for name, v in host.items()}
-            results[id(sub)].append(extract_partial(plan, per_item))
+        with phase(ph.EXTRACT_PARTIAL, items=n_items):
+            for k, (sub, plan, _p) in enumerate(items):
+                per_item = {name: v[k] for name, v in host.items()}
+                results[id(sub)].append(extract_partial(plan, per_item))
         return results
 
     def _build_cube(self, spec: CubeSpec, plan) -> Dict[str, jax.Array]:
         from .executor import resolve_params
         seg = plan.segment
         fn = _kernels.get(("cube", spec),
-                          lambda: build_cube_kernel(spec))
+                          lambda: build_cube_kernel(spec),
+                          ph.CUBE_BUILD_KERNEL)
         with span(ph.CUBE_BUILD, segment=seg.name, bucket=seg.bucket,
                   cube_space=spec.cube_space):
-            cols = seg.device_cols(plan.col_names)
-            params = resolve_params(plan)
-            out = fn(cols, jnp.int32(seg.n_docs), params)
-            device_fence(out)
+            with phase(ph.DISPATCH_PREPARE):
+                cols = seg.device_cols(plan.col_names)
+                params = resolve_params(plan)
+            count_dispatch(ph.CUBE_BUILD_KERNEL)
+            with phase(ph.DEVICE_EXECUTE):
+                out = fn(cols, jnp.int32(seg.n_docs), params)
+                device_fence(out)
             return out
 
     def clear(self) -> None:

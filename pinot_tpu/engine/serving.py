@@ -14,8 +14,7 @@ from ..query.context import QueryContext
 from ..query.planner import CompiledPlan, SegmentPlanner
 from ..startree.query import try_rollup_execute
 from ..utils import phases as ph
-from ..utils.spans import annotate, span
-from ..utils.trace import Tracing
+from ..utils.spans import annotate, phase
 from .batch import execute_plans_batched
 
 
@@ -49,8 +48,7 @@ def plan_segments(ctx: QueryContext, segments: List[Any],
         global_accountant.current_query_id())
     plans: List[Optional[CompiledPlan]] = []
     precomputed: Dict[int, Any] = {}
-    with Tracing.phase(ph.PLANNING), span(ph.PLANNING,
-                                        segments=len(segments)):
+    with phase(ph.PLANNING, segments=len(segments)):
         for i, seg in enumerate(segments):
             partial = (try_rollup_execute(ctx, seg)
                        if use_rollups and hasattr(seg, "metadata") else None)
@@ -85,8 +83,7 @@ def plan_segments(ctx: QueryContext, segments: List[Any],
 def execute_planned(ex: TableExecution) -> List[Any]:
     """Run the batched device dispatch and interleave rollup partials back
     into input order."""
-    with Tracing.phase(ph.EXECUTION), span(ph.EXECUTION,
-                                          segments=len(ex.real_plans)):
+    with phase(ph.EXECUTION, segments=len(ex.real_plans)):
         executed = list(execute_plans_batched(ex.real_plans))
     precomputed = getattr(ex, "_precomputed", {})
     executed = iter(executed)

@@ -37,6 +37,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from ..utils import phases as ph
+
 LANES = 128
 R = 32                 # sublane rows per subtile
 K_MIN = 8              # minimum subtiles per grid step (gate + capacity math)
@@ -146,6 +148,7 @@ def f64_bitcast_ok(platform: str = None) -> bool:
     return (platform or jax.default_backend()) == "cpu"
 
 
+@jax.named_scope(ph.SCOPE_COMPACT)
 def compact(mask: jax.Array, cols: Tuple[jax.Array, ...], slots_cap: int,
             platform: str = None):
     """Compact masked elements of 1-D arrays toward the front (lane-wise).
@@ -446,6 +449,7 @@ def _compact_pallas(mask, cols, n, slots_cap, k_sub, interp):
         ] + [pltpu.VMEM((stage_rows, LANES), jnp.int32)] * (n_cols + 1)
           + [pltpu.SemaphoreType.DMA((n_cols + 1,))],
         interpret=interp,
+        name="pinot_compact",
     )
     # the kernel is pure 32-bit; keep x64 promotion rules out of the trace
     with jax.enable_x64(False):

@@ -39,6 +39,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..utils import phases as ph
 from .ir import (AggSpec, And, Bin, Case, Cmp, Col, EqId, FalseP, Func,
                  IdRange, InBitmap, InSet, KernelPlan, Lit, MaskParam,
                  MvReduce, Not, Or, Pred, SelectPlan, TrueP, ValueExpr)
@@ -122,7 +123,8 @@ def _eval_value(ve: ValueExpr, cols, params, promote: bool = False
     if isinstance(ve, Col):
         arr = cols[ve.col]
         if ve.dict_param is not None:
-            arr = jnp.take(params[ve.dict_param], arr)
+            with jax.named_scope(ph.SCOPE_DECODE_DICT):
+                arr = jnp.take(params[ve.dict_param], arr)
         if promote and jnp.issubdtype(arr.dtype, jnp.integer):
             arr = arr.astype(int_acc_dtype())
         return arr
@@ -135,7 +137,8 @@ def _eval_value(ve: ValueExpr, cols, params, promote: bool = False
             return present.sum(-1).astype(int_acc_dtype())
         vals = ids
         if ve.dict_param is not None:
-            vals = jnp.take(params[ve.dict_param], jnp.maximum(ids, 0))
+            with jax.named_scope(ph.SCOPE_DECODE_DICT):
+                vals = jnp.take(params[ve.dict_param], jnp.maximum(ids, 0))
         if promote and jnp.issubdtype(vals.dtype, jnp.integer):
             vals = vals.astype(int_acc_dtype())
         if ve.mode == "sum":
@@ -576,6 +579,7 @@ def _group_hll(name: str, spec: AggSpec, mask, keys_s, space: int, cols,
 # scalar (non-group-by) aggregation
 # ---------------------------------------------------------------------------
 
+@jax.named_scope(ph.SCOPE_AGGREGATE)
 def _scalar_agg(i: int, spec: AggSpec, mask, cols, params,
                 out: Dict[str, jax.Array]) -> None:
     name = _agg_name(i, spec)
@@ -631,6 +635,7 @@ def _scalar_agg(i: int, spec: AggSpec, mask, cols, params,
 # group-by aggregation (one-hot dot_general; scatter on CPU)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope(ph.SCOPE_GROUP_KEY)
 def _group_keys_sentinel(plan: KernelPlan, mask, cols, params):
     """Shared cartesian dict-id key build (DictionaryBasedGroupKeyGenerator
     .java:63 arithmetic) + sentinel application: returns (mask, keys_s)
@@ -702,6 +707,7 @@ def _scatter_group(plan: KernelPlan, mask, keys_s, cols, params, space: int,
             raise ValueError(f"unknown agg kind {spec.kind!r}")
 
 
+@jax.named_scope(ph.SCOPE_AGGREGATE)
 def _group_aggs(plan: KernelPlan, mask, cols, params, bucket: int,
                 out: Dict[str, jax.Array], scatter: bool = False) -> None:
     space = plan.group_space
@@ -979,6 +985,7 @@ def _ladder_switch(sizes: List[int], n_valid, make_branch,
     return jax.lax.switch(idx, branches)
 
 
+@jax.named_scope(ph.SCOPE_PAYLOAD)
 def _payload_columns(plan: KernelPlan, mask, cols, params,
                      platform: str = None):
     """Fused aggregation-input materialization (round-6 tentpole).
@@ -1112,6 +1119,7 @@ def _compact_group_aggs(plan: KernelPlan, mask, cols, params, bucket: int,
     out["overflow"] = overflow
     out["matched"] = matched.astype(int_acc_dtype())
 
+    @jax.named_scope(ph.SCOPE_AGGREGATE)
     def post(valid_a, comp_t, rows: int) -> Dict[str, jax.Array]:
         v = valid_a[:rows]
         # compacted garbage slots were zeroed; re-sentinel their keys so
@@ -1538,16 +1546,17 @@ def build_kernel(plan: KernelPlan, bucket: int,
 
     def kernel(cols: Tuple[jax.Array, ...], n_docs: jax.Array,
                params: Tuple[jax.Array, ...]) -> Dict[str, jax.Array]:
-        if local_segments == 1:
-            valid = jnp.arange(total, dtype=jnp.int32) < n_docs
-        else:
-            # cols are local_segments same-bucket segments concatenated
-            # along the row axis (the mesh path's per-device shard);
-            # n_docs is (local_segments,)
-            iota = jax.lax.broadcasted_iota(
-                jnp.int32, (local_segments, bucket), 1)
-            valid = (iota < n_docs[:, None]).reshape(total)
-        mask = valid & _eval_pred(plan.pred, cols, params, total)
+        with jax.named_scope(ph.SCOPE_MASK):
+            if local_segments == 1:
+                valid = jnp.arange(total, dtype=jnp.int32) < n_docs
+            else:
+                # cols are local_segments same-bucket segments
+                # concatenated along the row axis (the mesh path's
+                # per-device shard); n_docs is (local_segments,)
+                iota = jax.lax.broadcasted_iota(
+                    jnp.int32, (local_segments, bucket), 1)
+                valid = (iota < n_docs[:, None]).reshape(total)
+            mask = valid & _eval_pred(plan.pred, cols, params, total)
         out: Dict[str, jax.Array] = {}
         if plan.is_group_by and plan.strategy == "compact":
             from .compact import default_slots_cap, sorted_default_slots_cap
@@ -1571,7 +1580,8 @@ def build_kernel(plan: KernelPlan, bucket: int,
             if xfer_compact and not scatter and not sparse:
                 _compact_group_xfer(plan, out)
             return out
-        out["matched"] = jnp.sum(mask, dtype=int_acc_dtype())
+        with jax.named_scope(ph.SCOPE_AGGREGATE):
+            out["matched"] = jnp.sum(mask, dtype=int_acc_dtype())
         if plan.is_group_by:
             _group_aggs(plan, mask, cols, params, total, out, scatter)
             if xfer_compact and not scatter:
@@ -1592,6 +1602,7 @@ GROUP_XFER_SPACE = 1 << 15
 GROUP_XFER_CAP = 1 << 15
 
 
+@jax.named_scope(ph.SCOPE_XFER_COMPACT)
 def _compact_group_xfer(plan: KernelPlan, out: Dict[str, jax.Array]) -> None:
     """Replace dense (space,) group outputs with gathered non-empty rows:
     group_idx holds the dense space ids (sentinel=space past the count),
@@ -1641,42 +1652,51 @@ def build_select_kernel(plan: SelectPlan, bucket: int):
     """
     def kernel(cols: Tuple[jax.Array, ...], n_docs: jax.Array,
                params: Tuple[jax.Array, ...]) -> Dict[str, jax.Array]:
-        mask = (jnp.arange(bucket, dtype=jnp.int32) < n_docs) \
-            & _eval_pred(plan.pred, cols, params, bucket)
-        if plan.order:
-            key = jnp.zeros(bucket, dtype=jnp.int64)
-            for col, desc, card in plan.order:
-                v = cols[col].astype(jnp.int64)
-                if card:  # dict ids: sorted dictionary => id order
-                    if desc:
-                        v = jnp.int64(card - 1) - v
-                    key = key * jnp.int64(card) + v
-                else:     # raw integral key — the planner only emits it
-                    # alone (card-free values can't pack into a radix)
-                    key = -v if desc else v
-            # ascending composite wins smallest; top_k wants max -> negate
-            sort_key = jnp.where(mask, -key, jnp.iinfo(jnp.int64).min)
-        else:
-            # doc order: earliest rows win
-            iota = jnp.arange(bucket, dtype=jnp.int64)
-            sort_key = jnp.where(mask, -iota, jnp.iinfo(jnp.int64).min)
-        _, idx = jax.lax.top_k(sort_key, plan.k)
-        out: Dict[str, jax.Array] = {
-            "matched": jnp.sum(mask, dtype=int_acc_dtype()),
-        }
-        for i, col in enumerate(plan.select_cols):
-            out[f"sel_{i}"] = jnp.take(cols[col], idx, axis=0)
-        for j, (col, _d, _c) in enumerate(plan.order):
-            out[f"ord_{j}"] = jnp.take(cols[col], idx)
-        return out
+        with jax.named_scope(ph.SCOPE_MASK):
+            mask = (jnp.arange(bucket, dtype=jnp.int32) < n_docs) \
+                & _eval_pred(plan.pred, cols, params, bucket)
+        with jax.named_scope(ph.SCOPE_TOPK):
+            return _select_topk(plan, bucket, mask, cols)
 
     return kernel
 
 
+def _select_topk(plan: SelectPlan, bucket: int, mask, cols
+                 ) -> Dict[str, jax.Array]:
+    """Composite order key, top_k and the winners' gathers."""
+    if plan.order:
+        key = jnp.zeros(bucket, dtype=jnp.int64)
+        for col, desc, card in plan.order:
+            v = cols[col].astype(jnp.int64)
+            if card:  # dict ids: sorted dictionary => id order
+                if desc:
+                    v = jnp.int64(card - 1) - v
+                key = key * jnp.int64(card) + v
+            else:     # raw integral key — the planner only emits it
+                # alone (card-free values can't pack into a radix)
+                key = -v if desc else v
+        # ascending composite wins smallest; top_k wants max -> negate
+        sort_key = jnp.where(mask, -key, jnp.iinfo(jnp.int64).min)
+    else:
+        # doc order: earliest rows win
+        iota = jnp.arange(bucket, dtype=jnp.int64)
+        sort_key = jnp.where(mask, -iota, jnp.iinfo(jnp.int64).min)
+    _, idx = jax.lax.top_k(sort_key, plan.k)
+    out: Dict[str, jax.Array] = {
+        "matched": jnp.sum(mask, dtype=int_acc_dtype()),
+    }
+    for i, col in enumerate(plan.select_cols):
+        out[f"sel_{i}"] = jnp.take(cols[col], idx, axis=0)
+    for j, (col, _d, _c) in enumerate(plan.order):
+        out[f"ord_{j}"] = jnp.take(cols[col], idx)
+    return out
+
+
 @functools.lru_cache(maxsize=512)
 def jitted_select_kernel(plan: SelectPlan, bucket: int):
-    from ..utils.compileplane import staged
-    return staged(jax.jit(build_select_kernel(plan, bucket)),
+    from ..utils.compileplane import kernel_jit, staged
+    return staged(kernel_jit(build_select_kernel(plan, bucket),
+                             ph.SELECT_TOPK),
                   "select_kernel", ("select", plan, bucket))
 
 
@@ -1779,16 +1799,18 @@ def build_segmented_compact_kernel(plan: KernelPlan, bucket: int,
             valid = jnp.arange(bucket, dtype=jnp.int32) < n
             return valid & _eval_pred(plan.pred, c, p, bucket)
 
-        masks = jax.vmap(pred_one)(cols, n_docs, params)   # (S, bucket)
+        with jax.named_scope(ph.SCOPE_MASK):
+            masks = jax.vmap(pred_one)(cols, n_docs, params)  # (S, bucket)
         seg2d = jax.lax.broadcasted_iota(jnp.int32, (n_segments, bucket), 0)
 
         flat_cols: List[jax.Array] = []
-        for ci, c in enumerate(cols):
-            pi = dict_cols.get(ci)
-            if pi is not None:  # offset ids into the flattened dictionary
-                card = params[pi].shape[1]
-                c = c.astype(jnp.int32) + seg2d * jnp.int32(card)
-            flat_cols.append(c.reshape(total))
+        with jax.named_scope(ph.SCOPE_DECODE_DICT):
+            for ci, c in enumerate(cols):
+                pi = dict_cols.get(ci)
+                if pi is not None:  # offset ids into the flat dictionary
+                    card = params[pi].shape[1]
+                    c = c.astype(jnp.int32) + seg2d * jnp.int32(card)
+                flat_cols.append(c.reshape(total))
         while len(flat_cols) <= seg_col:
             flat_cols.append(jnp.zeros(total, dtype=jnp.int32))
         flat_cols[seg_col] = seg2d.reshape(total)
@@ -1822,12 +1844,13 @@ def build_segmented_compact_kernel(plan: KernelPlan, bucket: int,
 def _jitted_segmented_cached(plan, bucket, n_segments, slots_cap, platform,
                              xfer_compact, scatter, two_pass_mode,
                              ladder_min):
-    from ..utils.compileplane import staged
+    from ..utils.compileplane import kernel_jit, staged
     key = ("segc", plan, bucket, n_segments, slots_cap, platform,
            xfer_compact, scatter, two_pass_mode, ladder_min)
-    return staged(jax.jit(build_segmented_compact_kernel(
+    return staged(kernel_jit(build_segmented_compact_kernel(
         plan, bucket, n_segments, slots_cap, platform, xfer_compact,
-        scatter, two_pass_mode, ladder_min)), "segmented_kernel", key)
+        scatter, two_pass_mode, ladder_min), ph.COMPACT_SEGMENTED),
+        "segmented_kernel", key)
 
 
 def jitted_segmented_compact(plan: KernelPlan, bucket: int,
@@ -1852,13 +1875,14 @@ jitted_segmented_compact.cache_clear = _jitted_segmented_cached.cache_clear
 @functools.lru_cache(maxsize=1024)
 def _jitted_kernel_cached(plan, bucket, slots_cap, platform, xfer_compact,
                           scatter, two_pass_mode, ladder_min):
-    from ..utils.compileplane import staged
+    from ..utils.compileplane import kernel_jit, staged
     key = ("kern", plan, bucket, slots_cap, platform, xfer_compact,
            scatter, two_pass_mode, ladder_min)
-    return staged(jax.jit(build_kernel(plan, bucket, slots_cap, platform,
-                                       xfer_compact, scatter=scatter,
-                                       two_pass_mode=two_pass_mode,
-                                       ladder_min=ladder_min)),
+    return staged(kernel_jit(build_kernel(plan, bucket, slots_cap, platform,
+                                          xfer_compact, scatter=scatter,
+                                          two_pass_mode=two_pass_mode,
+                                          ladder_min=ladder_min),
+                             ph.plan_family(plan)),
                   "kernel", key)
 
 

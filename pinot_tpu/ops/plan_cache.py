@@ -38,9 +38,11 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..utils import phases as ph
 from ..utils.devmem import global_device_memory, nbytes_of
 from ..utils.metrics import global_metrics
-from ..utils.spans import device_fence, span, span_tracer
+from ..utils.spans import (count_dispatch, device_fence, phase, span,
+                           span_tracer)
 
 
 def _donation_supported() -> bool:
@@ -173,7 +175,8 @@ class PlanCacheEntry:
     def __init__(self, base_fn, donate: bool, plan: Any = None,
                  key: Any = None,
                  stage_hints: Optional[Dict[str, Any]] = None):
-        from ..utils.compileplane import key_fingerprint, staged
+        from ..utils.compileplane import (kernel_jit, key_fingerprint,
+                                          staged)
         self._base = base_fn     # unjitted builder (eval_shape surface)
         self.donate = donate
         # compile-plane forensics: the jit is wrapped in explicit AOT
@@ -189,16 +192,18 @@ class PlanCacheEntry:
             # detector's generation map (the round-19 memo rule)
             import uuid
             plan = ("plan_cache", uuid.uuid4().hex)
+        self.family = ph.plan_family(plan)
         if donate:
             def _wrapped(cols, n_docs, params, acc):
                 del acc          # aliasing source only, never read
                 return base_fn(cols, n_docs, params)
-            self.fn = staged(jax.jit(_wrapped, donate_argnums=(3,)),
+            self.fn = staged(kernel_jit(_wrapped, self.family,
+                                        donate_argnums=(3,)),
                              "plan_cache", plan, donated=True,
                              hints=stage_hints)
         else:
-            self.fn = staged(jax.jit(base_fn), "plan_cache", plan,
-                             hints=stage_hints)
+            self.fn = staged(kernel_jit(base_fn, self.family),
+                             "plan_cache", plan, hints=stage_hints)
         if key is not None:
             self.fn.key_fp = key_fingerprint(key)
         self._acc: Any = None
@@ -243,10 +248,11 @@ class PlanCacheEntry:
             with self.lock:
                 self.runs += 1
                 first = self.runs == 1
-            with span("device_execute", compiled=first):
+            count_dispatch(self.family)
+            with phase(ph.DEVICE_EXECUTE, compiled=first):
                 out = self.fn(cols, n_docs, params)
                 device_fence(out)
-            with span("device_transfer"):
+            with phase(ph.DEVICE_TRANSFER):
                 # THE transfer fence for undonated entries
                 return jax.device_get(out)  # jaxlint: ok host-sync
         with self.lock:
@@ -254,10 +260,11 @@ class PlanCacheEntry:
             first = self.runs == 1
             if self._acc is None:
                 self._acc = self.make_acc(cols, n_docs, params)
-            with span("device_execute", compiled=first, donated=True):
+            count_dispatch(self.family)
+            with phase(ph.DEVICE_EXECUTE, compiled=first, donated=True):
                 out = self.fn(cols, n_docs, params, self._acc)
                 device_fence(out)
-            with span("device_transfer"):
+            with phase(ph.DEVICE_TRANSFER):
                 # THE transfer fence for donated entries (must complete
                 # inside the lock, before the buffers are re-donated)
                 host = jax.device_get(out)  # jaxlint: ok host-sync

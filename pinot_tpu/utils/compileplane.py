@@ -51,6 +51,7 @@ import uuid
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
+from . import phases as ph
 from .metrics import global_metrics
 from .shapehash import shape_key
 from .spans import span, span_tracer
@@ -576,6 +577,19 @@ class StagedFn:
         if ev is not None:
             ev.set()
         return compiled
+
+
+def kernel_jit(fn, family: str, **jit_kwargs):
+    """``jax.jit`` of a kernel family's function under its stable name:
+    the profiler's ``XLA Modules`` line then reads
+    ``jit_pinot_<family>(...)`` whatever the builder's closure is called
+    (the module name is part of the persistent compile cache's key, so a
+    rename compiles every program of the family once more)."""
+    import jax
+    if family not in ph.KERNEL_FAMILIES:
+        raise KeyError(f"{family!r} is not in phases.KERNEL_FAMILIES")
+    fn.__name__ = fn.__qualname__ = ph.MODULE_PREFIX + family
+    return jax.jit(fn, **jit_kwargs)
 
 
 def staged(fn, site: str, token: Any, donated: bool = False,

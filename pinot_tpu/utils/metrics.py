@@ -33,6 +33,15 @@ class MetricsRegistry:
         with self._lock:
             self._counters[name] = self._counters.get(name, 0) + n
 
+    def count_pair(self, a: str, na: int, b: str, nb: int) -> None:
+        """Two counters under one lock acquisition: a phase's elapsed
+        microseconds and its call count, a kernel launch and its
+        family's (utils/spans.py)."""
+        with self._lock:
+            c = self._counters
+            c[a] = c.get(a, 0) + na
+            c[b] = c.get(b, 0) + nb
+
     def gauge(self, name: str, value: float) -> None:
         with self._lock:
             self._gauges[name] = value

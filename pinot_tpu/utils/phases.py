@@ -1,12 +1,12 @@
-"""One shared vocabulary for phase and span names.
+"""One shared vocabulary for phase, span, kernel-family and scope names.
 
-utils/trace.py (``Tracing.phase`` — flat wall-ms per phase in the
-response envelope when ``OPTION(trace=true)``) and utils/spans.py (the
-span TREE that EXPLAIN ANALYZE renders) time the same code regions, and
-before round 10 each site named its region with its own string literal.
-The two vocabularies agreed only by luck; one drifted rename would have
-made the envelope and the analyze rows disagree about what "planning"
-means. Every instrumentation site now imports its name from here, and
+The flat ``OPTION(trace=true)`` envelope (utils/trace.py), the span
+TREE that EXPLAIN ANALYZE renders and the always-on phase counters (both
+utils/spans.py) time the same code regions, and before round 10 each
+site named its region with its own string literal. The vocabularies
+agreed only by luck; one drifted rename would have made the envelope and
+the analyze rows disagree about what "planning" means. Every
+instrumentation site now imports its name from here, and
 tests/test_span_tracer.py pins envelope keys == span names for the
 shared phases.
 
@@ -19,7 +19,7 @@ dispatched it.
 """
 from __future__ import annotations
 
-# broker/engine phases (Tracing.phase AND span names — must stay one set)
+# broker/engine phases (envelope keys AND span names — must stay one set)
 QUERY = "query"
 PLANNING = "planning"
 EXECUTION = "execution"
@@ -70,15 +70,85 @@ FUSED_EXECUTE = "fused_execute"
 # (query, segment) device search — batched or solo annotated on it
 VECTOR_SEARCH = "vector_search"
 
-# names Tracing.phase may emit into the flat trace envelope
+# layer boundaries of the served path, in the order a query crosses them
+# (PERF.md section 3 has the table). Each is entered through
+# utils/spans.phase, which always feeds the counters phase_us_<name> /
+# phase_n_<name>, writes a "pinot.<name>" event into a running profiler
+# session and builds the tree node when the query is sampled. The
+# device_execute / device_transfer / extract_partial names predate the
+# counters as plain span names and keep their place in the tree.
+BROKER_QUERY = "broker_query"        # HTTP handler: body parsed -> written
+BROKER_PARSE = "broker_parse"        # parse_sql, options, admission
+BROKER_ROUTE = "broker_route"        # routing snapshot, quota, context
+BROKER_SELECT = "broker_select"      # segment pruning, replica choice
+WIRE_DECODE = "wire_decode"          # response frame -> partials
+BROKER_RESPOND = "broker_respond"    # to_dict, JSON encode, write
+SERVER_HTTP = "server_http"          # HTTP handler: body parsed -> written
+SERVER_QUEUE = "server_queue"        # arrival -> scheduler worker starts
+SERVER_PARSE = "server_parse"        # parse_sql, deadline, context, acquire
+DISPATCH_PREPARE = "dispatch_prepare"  # stacks, params: host work pre-launch
+DEVICE_EXECUTE = "device_execute"    # the dispatch call (fenced if sampled)
+DEVICE_TRANSFER = "device_transfer"  # jax.device_get: wait + copy back
+EXTRACT_PARTIAL = "extract_partial"  # host numpy -> mergeable partials
+SERVER_ENCODE = "server_encode"      # partials -> DataBlock frame
+
+# names that may appear in the flat trace envelope
 TRACED_PHASES = frozenset(
     {PLANNING, EXECUTION, REDUCE, DISTRIBUTED_EXECUTE})
 
-# every name above (the span tree uses these plus dynamic kernel-level
-# names like segment_kernel/device_execute owned by their emit sites)
+# every name above (the span tree uses these, the metered boundary names
+# below, and dynamic kernel-level names like segment_kernel owned by
+# their emit sites)
 SPAN_NAMES = TRACED_PHASES | frozenset(
     {QUERY, BROKER_OVERHEAD, SCATTER, SCATTER_CALL, SERVER_QUERY,
      LEAF_SCAN, JOIN_STAGE, EXCHANGE, WINDOW_STAGE, FINAL_STAGE,
      FUSED_PLAN, COLLECTIVE_EXCHANGE,
      STAGE, STAGE_CALL, STAGE_DISPATCH,
      RAGGED_DISPATCH, CUBE_BUILD, FUSED_EXECUTE})
+
+# every name utils/spans.phase accepts (anything else is a KeyError at
+# the call site: a metered boundary is named here or not at all)
+METERED_PHASES = TRACED_PHASES | frozenset(
+    {BROKER_QUERY, BROKER_PARSE, BROKER_ROUTE, BROKER_SELECT, SCATTER,
+     SCATTER_CALL, WIRE_DECODE, BROKER_RESPOND, SERVER_HTTP, SERVER_QUEUE,
+     SERVER_PARSE, DISPATCH_PREPARE, DEVICE_EXECUTE, DEVICE_TRANSFER,
+     EXTRACT_PARTIAL, SERVER_ENCODE})
+
+# kernel families: the jitted function of each is named
+# "pinot_<family>" (utils/compileplane.kernel_jit), so the profiler's
+# XLA Modules line reads jit_pinot_<family>(...), and every launch counts
+# kernel_dispatches_<family> (utils/spans.count_dispatch)
+DENSE_VMAP = "dense_vmap"                    # engine/batch: S segments vmapped
+DENSE_PER_SEGMENT = "dense_per_segment"      # plan cache, one segment
+COMPACT_SEGMENTED = "compact_segmented"      # one program over S segments
+COMPACT_PER_SEGMENT = "compact_per_segment"  # plan cache, one segment
+SELECT_TOPK = "select"                       # selection ORDER BY/LIMIT
+RAGGED_FUSED = "ragged_fused"                # cross-query cube combine
+CUBE_BUILD_KERNEL = "cube_build"             # micro-batcher's cube scan
+KERNEL_FAMILIES = frozenset(
+    {DENSE_VMAP, DENSE_PER_SEGMENT, COMPACT_SEGMENTED, COMPACT_PER_SEGMENT,
+     SELECT_TOPK, RAGGED_FUSED, CUBE_BUILD_KERNEL})
+MODULE_PREFIX = "pinot_"
+
+
+def plan_family(plan) -> str:
+    """Family of a single-segment kernel built from ``plan``."""
+    return (COMPACT_PER_SEGMENT if getattr(plan, "strategy", None) == "compact"
+            else DENSE_PER_SEGMENT)
+
+
+# jax.named_scope names of the stages inside the kernels (HLO metadata
+# only: they ride an operation's op_name, never its numerics)
+SCOPE_MASK = "pinot.mask"                # row validity & predicate
+SCOPE_DECODE_DICT = "pinot.decode_dict"  # dict id -> value gather
+SCOPE_GROUP_KEY = "pinot.group_key"      # cartesian key + sentinel
+SCOPE_PAYLOAD = "pinot.payload"          # aggregation inputs, pre-compaction
+SCOPE_COMPACT = "pinot.compact"          # ops/compact.compact
+SCOPE_AGGREGATE = "pinot.aggregate"      # scalar, one-hot, sorted, scatter
+SCOPE_XFER_COMPACT = "pinot.xfer_compact"  # live-group gather pre-transfer
+SCOPE_TOPK = "pinot.topk"                # selection order key + top_k
+SCOPE_COMBINE = "pinot.combine"          # cube mask + cell reduction
+KERNEL_SCOPES = frozenset(
+    {SCOPE_MASK, SCOPE_DECODE_DICT, SCOPE_GROUP_KEY, SCOPE_PAYLOAD,
+     SCOPE_COMPACT, SCOPE_AGGREGATE, SCOPE_XFER_COMPACT, SCOPE_TOPK,
+     SCOPE_COMBINE})

@@ -21,6 +21,16 @@ paths (per-segment launches) permanently. EXPLAIN ANALYZE
 (query/explain.py) renders the tree; utils/ledger.py emits it as a
 versioned ``query_trace`` ledger record so CPU-smoke and TPU hardware
 rounds diff span-for-span.
+
+``phase()`` is the same thing for a LAYER BOUNDARY (utils/phases.py
+METERED_PHASES): one call, three outputs. It always adds its elapsed
+microseconds and a count to ``global_metrics`` (``phase_us_<name>``,
+``phase_n_<name>``: the /metrics scrape and the benchmark's per-layer
+readers); while a ``jax.profiler`` session runs it is also a
+``TraceAnnotation("pinot.<name>", qid=...)``, so the program's spans sit
+on the clock of the device's ``XLA Ops`` (tools/trace_phases.py); and
+when the query is sampled it is the tree node ``span()`` would have
+been, and feeds the flat ``OPTION(trace=true)`` envelope.
 """
 from __future__ import annotations
 
@@ -28,6 +38,10 @@ import threading
 import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
+
+from . import phases as ph
+from .metrics import global_metrics
+from .trace import Tracing
 
 
 class Span:
@@ -190,6 +204,106 @@ def add_event(name: str, duration_ms: float, **attrs: Any) -> None:
 
 def tracing_active() -> bool:
     return span_tracer.active()
+
+
+# ---------------------------------------------------------------------------
+# layer boundaries: counters always, profiler events and tree nodes on demand
+# ---------------------------------------------------------------------------
+
+# per metered phase: its two counters and its profiler event's name (no
+# "#" in one: TraceMe cuts a name there)
+_KEYS = {n: ("phase_us_" + n, "phase_n_" + n, "pinot." + n)
+         for n in ph.METERED_PHASES}
+_DISPATCH = {f: "kernel_dispatches_" + f for f in ph.KERNEL_FAMILIES}
+_query = threading.local()      # .qid: the broker's id of the query in hand
+_annotation: Any = None         # jax.profiler.TraceAnnotation, on first use
+
+
+def _annotation_cls():
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+def set_query_id(qid: Optional[str]) -> None:
+    """Name the query this thread works for from here on: the broker's
+    query id, which rides every scatter call in ``traceContext.queryId``
+    sampled or not, so one request's profiler events share ``qid`` across
+    the two nodes. A phase still open picks it up when it closes."""
+    _query.qid = qid
+
+
+class phase:
+    """Context manager for one crossing of a metered layer boundary.
+    ``.ms`` holds the elapsed wall-ms after exit and ``.t0`` the start
+    (``time.perf_counter``), for callers that report the same
+    measurement elsewhere (``ScatterResult.serde_ms``, the wire header's
+    ``serdeEncodeMs``); ``.span`` is the tree node, or None."""
+
+    __slots__ = ("name", "attrs", "qid", "span", "t0", "ms", "_keys",
+                 "_event")
+
+    def __init__(self, name: str, qid: Optional[str] = None, **attrs: Any):
+        self.name = name
+        self.attrs = attrs
+        self.qid = qid
+        self.ms = 0.0
+
+    def __enter__(self) -> "phase":
+        # KeyError: the name is not in phases.METERED_PHASES
+        self._keys = _KEYS[self.name]
+        self.span = self._event = None
+        stack = getattr(span_tracer._local, "stack", None)
+        if stack:
+            s = self.span = Span(self.name, **self.attrs)
+            stack[-1].children.append(s)
+            stack.append(s)
+        if (_annotation or _annotation_cls()).is_enabled():
+            qid = self.qid = self.qid or getattr(_query, "qid", None)
+            kw = self.attrs if qid is None else {"qid": qid, **self.attrs}
+            self._event = _annotation(self._keys[2], **kw)
+            self._event.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self.t0
+        name = self.name
+        self.ms = ms = dt * 1e3
+        if self._event is not None:
+            if self.qid is None:
+                qid = getattr(_query, "qid", None)
+                if qid is not None:
+                    self._event.set_metadata(qid=qid)
+            self._event.__exit__(*exc)
+        s = self.span
+        if s is not None:
+            s._t0, s.duration_ms = self.t0, ms
+            stack = getattr(span_tracer._local, "stack", None)
+            if stack and stack[-1] is s:
+                stack.pop()
+        if name in ph.TRACED_PHASES:
+            scope = Tracing.active()
+            if scope is not None:
+                scope.add_phase(name, ms)
+        us_key, n_key, _event = self._keys
+        global_metrics.count_pair(us_key, round(dt * 1e6), n_key, 1)
+
+
+def record_phase(name: str, seconds: float) -> None:
+    """Counters of a boundary crossed on two threads (``server_queue``:
+    arrival on the handler's thread to the scheduler worker's start),
+    which no ``with`` block can bracket."""
+    us_key, n_key, _event = _KEYS[name]
+    global_metrics.count_pair(us_key, round(seconds * 1e6), n_key, 1)
+
+
+def count_dispatch(family: str) -> None:
+    """One kernel program launched: ``kernel_dispatches`` and
+    ``kernel_dispatches_<family>`` (phases.KERNEL_FAMILIES)."""
+    global_metrics.count_pair("kernel_dispatches", 1, _DISPATCH[family], 1)
 
 
 def device_fence(out: Any) -> None:

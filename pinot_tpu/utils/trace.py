@@ -4,15 +4,15 @@ Reference parity: pinot-spi/.../trace/Tracing.java (global tracer
 registry, request registration) + BuiltInTracer per-operator timings when
 the query sets trace=true, and the phase timers of
 ServerQueryExecutorV1Impl.java:154-159 (ServerQueryPhase). Python-native:
-a thread-local request scope; `with scope.phase("planning"):` records
-wall-ms; operators attach counters (docs scanned, segments matched). The
-scope serializes into the response envelope when tracing is on.
+a thread-local request scope; the layer-boundary primitive
+(utils/spans.phase) adds each traced phase's wall-ms to it; operators
+attach counters (docs scanned, segments matched). The scope serializes
+into the response envelope when tracing is on.
 """
 from __future__ import annotations
 
 import threading
 import time
-from contextlib import contextmanager
 from typing import Any, Dict, Optional
 
 
@@ -24,17 +24,9 @@ class RequestScope:
         self.counters: Dict[str, int] = {}
         self._t0 = time.perf_counter()
 
-    @contextmanager
-    def phase(self, name: str):
-        if not self.enabled:
-            yield
-            return
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.phases[name] = self.phases.get(name, 0.0) + \
-                (time.perf_counter() - t0) * 1e3
+    def add_phase(self, name: str, ms: float) -> None:
+        if self.enabled:
+            self.phases[name] = self.phases.get(name, 0.0) + ms
 
     def count(self, name: str, n: int = 1) -> None:
         if self.enabled:
@@ -62,15 +54,6 @@ class _Tracing:
 
     def active(self) -> Optional[RequestScope]:
         return getattr(self._local, "scope", None)
-
-    @contextmanager
-    def phase(self, name: str):
-        scope = self.active()
-        if scope is None:
-            yield
-            return
-        with scope.phase(name):
-            yield
 
     def count(self, name: str, n: int = 1) -> None:
         scope = self.active()

@@ -28,9 +28,16 @@ calibration — that class is what bench.py's vs_baseline wall gate is
 for.) Candidate phases below ``--min-ms`` are skipped and sub-ms
 baselines are floored at ``--min-ms`` (sub-ms-vs-sub-ms jitter cannot
 trip the bar, but a tiny phase regressing to something large still
-does), and medians over the capture iterations absorb per-run jitter. The baseline is a ratchet like jaxlint_baseline.json:
-edit the corpus or materially change an engine phase's cost profile and
-re-capture with ``update`` — in the same environment tier-1 runs in.
+does), and medians over the capture iterations absorb per-run jitter.
+The baseline is a ratchet like jaxlint_baseline.json: edit the corpus or
+materially change an engine phase's cost profile and re-capture with
+``update`` — on the machine whose later captures it will be compared
+with. tools/span_baseline.json holds the wall-ms of the machine it was
+captured on; no tier-1 test compares this machine's timings with it any
+more (PR 25): tests/test_perf_forensics.py drives capture, update and
+check end to end against a baseline of its own session, and
+tests/test_fleet_forensics.py reads the checked-in file as fixture data
+(shapes and phase names, scaled copies of its own numbers).
 
     python tools/span_diff.py capture --out /tmp/trace.jsonl [--iters 5]
     python tools/span_diff.py update  /tmp/trace.jsonl
@@ -53,7 +60,7 @@ board) must not false-trip the ratchet, while a single node's single
 phase regressing still does.
 
 Exit 0 when no phase regresses; one summary JSON line last,
-check_ledger-style. tier-1 runs capture+check through
+check_ledger-style. tier-1 runs capture+update+check through
 tests/test_perf_forensics.py; bench_common.finish() runs check over the
 repo ledger so a bench capture fails loudly on a span regression.
 """
