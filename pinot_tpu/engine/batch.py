@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.kernels import build_kernel
+from ..ops.kernels import build_kernel, dict_decode_forms
 from ..query.planner import CompiledPlan
 from ..utils import phases as ph
 from ..utils.devmem import global_device_memory
@@ -253,7 +253,8 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
                       strategy=plan_struct.strategy):
                 _maybe_profile_phases(group_plans[0])
                 fn = _vmapped_kernel(plan_struct, bucket)
-                count_dispatch(ph.DENSE_VMAP)
+                count_dispatch(ph.DENSE_VMAP,
+                               dict_decode_forms(plan_struct, params))
                 with phase(ph.DEVICE_EXECUTE):
                     dev = fn(cols, n_docs, params)
                     device_fence(dev)
@@ -314,7 +315,8 @@ def _run_segmented_compact(plans, idxs, plan_struct, bucket, cols, n_docs,
               est_sel=plans[idxs[0]].est_selectivity):
         _maybe_profile_phases(plans[idxs[0]])
         fn = jitted_segmented_compact(plan_struct, bucket, n_seg, cap)
-        out = _launch_segmented(fn, cols, n_docs, params)
+        forms = dict_decode_forms(plan_struct, params, segmented=True)
+        out = _launch_segmented(fn, cols, n_docs, params, forms)
         # retry-ladder checks + slicing below read host numpy behind the
         # fence above — host-sync [jaxlint baseline]
         from ..ops.plan_cache import global_plan_cache
@@ -328,7 +330,7 @@ def _run_segmented_compact(plans, idxs, plan_struct, bucket, cols, n_docs,
                     global_plan_cache.detector.expected():
                 fn = jitted_segmented_compact(plan_struct, bucket, n_seg,
                                               cap)
-                out = _launch_segmented(fn, cols, n_docs, params)
+                out = _launch_segmented(fn, cols, n_docs, params, forms)
             out.pop("overflow", None)
             annotate(overflow_retry=True, slots_cap=cap)
         if int(out.pop("group_overflow", 0)):
@@ -337,7 +339,7 @@ def _run_segmented_compact(plans, idxs, plan_struct, bucket, cols, n_docs,
                     global_plan_cache.detector.expected():
                 fn = jitted_segmented_compact(plan_struct, bucket, n_seg,
                                               cap, xfer_compact=False)
-                out = _launch_segmented(fn, cols, n_docs, params)
+                out = _launch_segmented(fn, cols, n_docs, params, forms)
             out.pop("overflow", None)
             annotate(group_overflow_retry=True)
         global_accountant.track_result(out)
@@ -366,9 +368,10 @@ def _run_segmented_compact(plans, idxs, plan_struct, bucket, cols, n_docs,
             results[i] = extract_partial(plans[i], per_seg)
 
 
-def _launch_segmented(fn, cols, n_docs, params) -> Dict[str, Any]:
+def _launch_segmented(fn, cols, n_docs, params,
+                      dict_forms: Tuple[int, int]) -> Dict[str, Any]:
     """One launch of the segmented compact program and its copy back."""
-    count_dispatch(ph.COMPACT_SEGMENTED)
+    count_dispatch(ph.COMPACT_SEGMENTED, dict_forms)
     with phase(ph.DEVICE_EXECUTE):
         dev = fn(cols, n_docs, params)
         device_fence(dev)

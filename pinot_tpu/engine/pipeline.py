@@ -73,13 +73,15 @@ def execute_kernel_plans_pipelined(plans: List[CompiledPlan],
     (jax frees the buffers when the last reference dies after the
     dependent computation completes).
     """
-    from ..ops.kernels import jitted_kernel
+    from ..ops.kernels import dict_decode_forms, jitted_kernel
     from .accounting import global_accountant
     from .executor import extract_partial
 
     fn = jitted_kernel(plan_struct, bucket)  # lru-cached jit: repeated
     # over-budget queries must not pay a fresh XLA compile per group
     family = plan_family(plan_struct)
+    # one signature group: every segment's dictionaries have one shape
+    forms = dict_decode_forms(plan_struct, resolved_params[idxs[0]])
     group = [plans[i] for i in idxs]
 
     def stage(k: int):
@@ -97,7 +99,7 @@ def execute_kernel_plans_pipelined(plans: List[CompiledPlan],
         # enqueue the NEXT transfer before compute: async dispatch lets
         # the H2D copy overlap this kernel on the transfer engine
         staged = stage(k + 1) if k + 1 < len(group) else None
-        count_dispatch(family)
+        count_dispatch(family, forms)
         out = fn(cur, jnp.int32(plan.segment.n_docs),
                  resolved_params[idxs[k]])
         outs.append(out)
@@ -126,7 +128,7 @@ def execute_kernel_plans_pipelined(plans: List[CompiledPlan],
             cols = tuple(jax.device_put(seg.host_col_padded(c, bucket))
                          for c in plan.col_names)
             from ..ops.plan_cache import global_plan_cache
-            count_dispatch(family)
+            count_dispatch(family, forms)
             with global_plan_cache.detector.expected():
                 # a deliberate dense rerun (compile-event taxonomy:
                 # overflow_retry, never a retrace)

@@ -756,6 +756,7 @@ class RaggedBatcher:
         return results
 
     def _build_cube(self, spec: CubeSpec, plan) -> Dict[str, jax.Array]:
+        from ..ops.kernels import dict_decode_forms
         from .executor import resolve_params
         seg = plan.segment
         fn = _kernels.get(("cube", spec),
@@ -766,7 +767,8 @@ class RaggedBatcher:
             with phase(ph.DISPATCH_PREPARE):
                 cols = seg.device_cols(plan.col_names)
                 params = resolve_params(plan)
-            count_dispatch(ph.CUBE_BUILD_KERNEL)
+            count_dispatch(ph.CUBE_BUILD_KERNEL,
+                           dict_decode_forms(spec.kp, params))
             with phase(ph.DEVICE_EXECUTE):
                 out = fn(cols, jnp.int32(seg.n_docs), params)
                 device_fence(out)

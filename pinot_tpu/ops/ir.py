@@ -30,8 +30,9 @@ class ValueExpr:
 class Col(ValueExpr):
     """A projected column. If dict_param is set, the stored array holds dict
     ids and params[dict_param] is the device-resident sorted dictionary
-    values array: value = dict_values[ids] (one gather, mirrors Pinot's
-    dictionary.get on the read path)."""
+    values array: value = dict_values[ids] (ops/kernels._decode_dict: a
+    fused select chain for a small dictionary, a gather for a long one;
+    mirrors Pinot's dictionary.get on the read path)."""
     col: int
     dict_param: Optional[int] = None
 

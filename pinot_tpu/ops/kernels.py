@@ -55,6 +55,14 @@ DISTINCT_ONEHOT_CARD = 1 << 12
 # unrolled masked-reduce limit for group MIN/MAX (no matmul form exists;
 # above this the planner routes to segment ops on CPU or the host path)
 MINMAX_UNROLL_GROUPS = 64
+# longest dictionary _decode_dict decodes by a select chain instead of a
+# gather. From the v5e rows of PERF.md section 6 (PR 26): the Q1-shaped
+# dense kernel over 8 x 2^23 rows, chain | gather in ms a launch (chain's
+# compile seconds): K=11 3.2 | 490 (1.0), 64 4.6 | 490 (4.7), 256 10.7 |
+# 639 (11.4), 1,024 129 | 638 (62.4; XLA cuts the chain into 60 fusions),
+# 4,096 did not compile (40 GiB of host memory). 256 is the largest power
+# of two that is at least twice as fast and compiles in seconds.
+DICT_SELECT_MAX = 256
 
 
 def cpu_scatter_default(platform: Optional[str] = None) -> bool:
@@ -116,6 +124,39 @@ def _limb_base_bits(bucket: int) -> int:
 # value expressions
 # ---------------------------------------------------------------------------
 
+def _decodes_by_select(length: int) -> bool:
+    """The one predicate of the decode's form: _decode_dict calls it on
+    the static length of the dictionary it is handed, dict_decode_forms
+    on the same length when the launch is counted."""
+    return length <= DICT_SELECT_MAX
+
+
+def _decode_dict(table: jax.Array, ids: jax.Array) -> jax.Array:
+    """value = table[ids] for a 1-D dictionary (under vmap the stacked
+    (S, K) arrives as (K,); the segmented compact kernel hands over the
+    flattened S*K). The form follows table.shape[-1], static at trace
+    time:
+
+    - up to DICT_SELECT_MAX entries: a select chain, K compares and K
+      selects an element and no gather in the HLO. It is elementwise, so
+      XLA fuses it into the scan that consumes the value (a TPU gather
+      is serial in rows: 7-10 ns an element; PERF.md section 6, PR 26);
+    - longer: jnp.take.
+
+    Both hand back the entry's own bits, whatever the dtype. They differ
+    only on an id outside [0, K): jnp.take fills (NaN, or the integer's
+    extreme), the chain yields table[0]. No such id reaches a result:
+    padding rows carry id 0 and are masked, and MV pads (-1) are clamped
+    to 0 by the caller before the decode and masked by `present` after."""
+    n = table.shape[-1]
+    if not _decodes_by_select(n):
+        return jnp.take(table, ids)
+    out = jnp.broadcast_to(table[0], ids.shape)
+    for k in range(1, n):
+        out = jnp.where(ids == k, table[k], out)
+    return out
+
+
 def _eval_value(ve: ValueExpr, cols, params, promote: bool = False
                 ) -> jax.Array:
     """promote=True upcasts integral column leaves to int64 so products in
@@ -124,7 +165,7 @@ def _eval_value(ve: ValueExpr, cols, params, promote: bool = False
         arr = cols[ve.col]
         if ve.dict_param is not None:
             with jax.named_scope(ph.SCOPE_DECODE_DICT):
-                arr = jnp.take(params[ve.dict_param], arr)
+                arr = _decode_dict(params[ve.dict_param], arr)
         if promote and jnp.issubdtype(arr.dtype, jnp.integer):
             arr = arr.astype(int_acc_dtype())
         return arr
@@ -138,7 +179,8 @@ def _eval_value(ve: ValueExpr, cols, params, promote: bool = False
         vals = ids
         if ve.dict_param is not None:
             with jax.named_scope(ph.SCOPE_DECODE_DICT):
-                vals = jnp.take(params[ve.dict_param], jnp.maximum(ids, 0))
+                vals = _decode_dict(params[ve.dict_param],
+                                    jnp.maximum(ids, 0))
         if promote and jnp.issubdtype(vals.dtype, jnp.integer):
             vals = vals.astype(int_acc_dtype())
         if ve.mode == "sum":
@@ -455,12 +497,12 @@ def _device_splitmix64(v: jax.Array) -> jax.Array:
 
 def _agg_hashes(spec: AggSpec, cols, params) -> jax.Array:
     """The 64-bit hash stream for a sketch aggregation: dict columns
-    gather a precomputed per-id hash table (params[dict_param], host
+    decode a precomputed per-id hash table (params[dict_param], host
     _hash64 over the dictionary values — md5 for strings); raw numeric
     columns hash on device."""
     ve = spec.value
     if isinstance(ve, Col) and ve.dict_param is not None:
-        return jnp.take(params[ve.dict_param], cols[ve.col])
+        return _decode_dict(params[ve.dict_param], cols[ve.col])
     return _device_splitmix64(_eval_value(ve, cols, params))
 
 
@@ -1718,6 +1760,29 @@ def _dict_value_cols(plan: KernelPlan) -> Dict[int, int]:
     return found
 
 
+@functools.lru_cache(maxsize=1024)
+def _dict_value_params(plan: KernelPlan) -> Tuple[int, ...]:
+    """params index of each dictionary-decoded value column of the plan
+    (the plan walked once, not at every launch)."""
+    return tuple(_dict_value_cols(plan).values())
+
+
+def dict_decode_forms(plan: KernelPlan, params,
+                      segmented: bool = False) -> Tuple[int, int]:
+    """(select, gather): how many of the plan's dictionary-decoded value
+    columns a launch with these params decodes in each form of
+    _decode_dict. The form is the helper's own predicate on the length it
+    sees: the last axis of the (stacked) dictionary, or all S*K entries
+    where the segmented compact kernel flattens it."""
+    n_select = 0
+    pis = _dict_value_params(plan)
+    for pi in pis:
+        shape = params[pi].shape
+        n_select += _decodes_by_select(
+            shape[0] * shape[1] if segmented else shape[-1])
+    return n_select, len(pis) - n_select
+
+
 def segmented_compact_ok(plan: KernelPlan) -> bool:
     """Whether a compact group-by plan can run the segmented batch kernel:
     no column may serve as both a group key and a dictionary-value source
@@ -1773,7 +1838,8 @@ def build_segmented_compact_kernel(plan: KernelPlan, bucket: int,
       ranges differ across segment dictionaries);
     - per-segment dictionary-value params (S, card) flatten to (S*card,)
       and the referencing dict-id columns are offset by seg*card, so
-      value gathers hit the right segment's dictionary after rows mix;
+      value decodes hit the right segment's dictionary after rows mix
+      (_decode_dict sees all S*card entries and picks its form by that);
     - group space becomes S*space; the executor slices (S, space) rows
       apart host-side and decodes each against its own dictionaries.
 

@@ -43,6 +43,7 @@ from ..utils.devmem import global_device_memory, nbytes_of
 from ..utils.metrics import global_metrics
 from ..utils.spans import (count_dispatch, device_fence, phase, span,
                            span_tracer)
+from .ir import KernelPlan
 
 
 def _donation_supported() -> bool:
@@ -193,6 +194,9 @@ class PlanCacheEntry:
             import uuid
             plan = ("plan_cache", uuid.uuid4().hex)
         self.family = ph.plan_family(plan)
+        # the structure dict_decode_forms reads at each launch (direct
+        # constructions pass a bare token: nothing to count)
+        self._kernel_plan = plan if isinstance(plan, KernelPlan) else None
         if donate:
             def _wrapped(cols, n_docs, params, acc):
                 del acc          # aliasing source only, never read
@@ -231,6 +235,12 @@ class PlanCacheEntry:
         return jax.tree_util.tree_map(
             lambda s: jnp.zeros(s.shape, s.dtype), shapes)
 
+    def _dict_forms(self, params) -> Tuple[int, int]:
+        if self._kernel_plan is None:
+            return (0, 0)
+        from .kernels import dict_decode_forms
+        return dict_decode_forms(self._kernel_plan, params)
+
     def run(self, cols, n_docs, params) -> Dict[str, Any]:
         """Execute and return HOST numpy outputs.
 
@@ -248,7 +258,7 @@ class PlanCacheEntry:
             with self.lock:
                 self.runs += 1
                 first = self.runs == 1
-            count_dispatch(self.family)
+            count_dispatch(self.family, self._dict_forms(params))
             with phase(ph.DEVICE_EXECUTE, compiled=first):
                 out = self.fn(cols, n_docs, params)
                 device_fence(out)
@@ -260,7 +270,7 @@ class PlanCacheEntry:
             first = self.runs == 1
             if self._acc is None:
                 self._acc = self.make_acc(cols, n_docs, params)
-            count_dispatch(self.family)
+            count_dispatch(self.family, self._dict_forms(params))
             with phase(ph.DEVICE_EXECUTE, compiled=first, donated=True):
                 out = self.fn(cols, n_docs, params, self._acc)
                 device_fence(out)

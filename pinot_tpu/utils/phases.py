@@ -140,7 +140,7 @@ def plan_family(plan) -> str:
 # jax.named_scope names of the stages inside the kernels (HLO metadata
 # only: they ride an operation's op_name, never its numerics)
 SCOPE_MASK = "pinot.mask"                # row validity & predicate
-SCOPE_DECODE_DICT = "pinot.decode_dict"  # dict id -> value gather
+SCOPE_DECODE_DICT = "pinot.decode_dict"  # dict id -> value (select | gather)
 SCOPE_GROUP_KEY = "pinot.group_key"      # cartesian key + sentinel
 SCOPE_PAYLOAD = "pinot.payload"          # aggregation inputs, pre-compaction
 SCOPE_COMPACT = "pinot.compact"          # ops/compact.compact

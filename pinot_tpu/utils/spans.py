@@ -37,7 +37,7 @@ from __future__ import annotations
 import threading
 import time
 from contextlib import contextmanager
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from . import phases as ph
 from .metrics import global_metrics
@@ -300,10 +300,18 @@ def record_phase(name: str, seconds: float) -> None:
     global_metrics.count_pair(us_key, round(seconds * 1e6), n_key, 1)
 
 
-def count_dispatch(family: str) -> None:
+def count_dispatch(family: str,
+                   dict_forms: Tuple[int, int] = (0, 0)) -> None:
     """One kernel program launched: ``kernel_dispatches`` and
-    ``kernel_dispatches_<family>`` (phases.KERNEL_FAMILIES)."""
+    ``kernel_dispatches_<family>`` (phases.KERNEL_FAMILIES). Where the
+    launched plan decodes dictionary-encoded value columns,
+    ``dict_forms`` (ops/kernels.dict_decode_forms) says how many by a
+    select chain and how many by a gather: ``dict_decode_select`` and
+    ``dict_decode_gather``."""
     global_metrics.count_pair("kernel_dispatches", 1, _DISPATCH[family], 1)
+    if dict_forms != (0, 0):
+        global_metrics.count_pair("dict_decode_select", dict_forms[0],
+                                  "dict_decode_gather", dict_forms[1])
 
 
 def device_fence(out: Any) -> None:

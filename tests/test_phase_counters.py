@@ -37,6 +37,11 @@ DENSE = ("SELECT region, SUM(amount), COUNT(*) FROM ptab "
          "GROUP BY region ORDER BY region")
 COMPACT = DENSE + " OPTION(groupByStrategy=compact)"
 SELECT = "SELECT region, amount FROM ptab ORDER BY amount DESC LIMIT 5"
+# Q1-shaped: a dictionary-encoded numeric dimension summed and filtered.
+# tier's dictionary (5 entries) decodes by the select chain, code's (300,
+# over ops/kernels.DICT_SELECT_MAX) by the gather
+SMALL_DICT = "SELECT SUM(amount * tier) FROM ptab WHERE tier BETWEEN 1 AND 3"
+LONG_DICT = "SELECT SUM(amount * code) FROM ptab WHERE code < 200"
 
 # what one plain DENSE statement crosses: every phase of PERF.md's table,
 # once, except the host work before a launch (params of each segment, then
@@ -65,7 +70,8 @@ CHILDREN = {
 
 def counters():
     return {k: v for k, v in global_metrics.snapshot()["counters"].items()
-            if k.startswith(("phase_", "kernel_dispatches", "wire_bytes"))}
+            if k.startswith(("phase_", "kernel_dispatches", "wire_bytes",
+                             "dict_decode_"))}
 
 
 def moved(before, after):
@@ -81,12 +87,15 @@ def trio(tmp_path_factory):
     broker = BrokerNode(ctrl.url, routing_refresh=0.1)
     schema = Schema("ptab", [
         FieldSpec("region", DataType.STRING),
+        FieldSpec("tier", DataType.INT), FieldSpec("code", DataType.INT),
         FieldSpec("amount", DataType.INT, FieldType.METRIC)])
     builder = SegmentBuilder(schema, TableConfig("ptab"))
     ctrl.add_table("ptab", schema.to_dict(), replication=1)
     rng = np.random.default_rng(25)
     for i in range(N_SEGMENTS):
         cols = {"region": rng.choice(["east", "west", "north"], ROWS),
+                "tier": rng.integers(0, 5, ROWS).astype(np.int32),
+                "code": rng.integers(0, 300, ROWS).astype(np.int32),
                 "amount": rng.integers(0, 1000, ROWS).astype(np.int32)}
         d = builder.build(cols, str(tmp / "segments"), f"ptab_seg_{i}")
         ctrl.add_segment("ptab", f"ptab_seg_{i}", d)
@@ -110,6 +119,7 @@ def trio(tmp_path_factory):
         assert "resultTable" in query(sql + (
             " OPTION(timeoutMs=280000)" if "OPTION" not in sql else ""))
     query.segment_dir = str(tmp / "segments" / "ptab_seg_0")
+    query.broker_url = broker.url
     yield query
     broker.stop()
     server.stop()
@@ -199,6 +209,38 @@ def test_kernel_dispatches_by_family(trio, sql, family, launches):
               if k.startswith("kernel_dispatches_")
               and k != "kernel_dispatches_" + family}
     assert not any(others.values()), others
+
+
+@pytest.mark.parametrize("sql,form,other", [
+    (SMALL_DICT, "select", "gather"), (LONG_DICT, "gather", "select"),
+], ids=["small_dictionary", "long_dictionary"])
+def test_dict_decode_forms_count_per_launch(trio, sql, form, other):
+    """One vmapped launch decodes one dictionary-encoded value column: the
+    counter of the form its dictionary's length selects moves by one, the
+    other by none, and an operator reads both off the broker."""
+    import urllib.request
+
+    from pinot_tpu.ops.kernels import DICT_SELECT_MAX
+    assert 5 <= DICT_SELECT_MAX < 300
+    trio(sql + " OPTION(timeoutMs=280000)")          # compile
+    before = counters()
+    trio(sql)
+    d = moved(before, counters())
+    assert d["kernel_dispatches_" + ph.DENSE_VMAP] == 1
+    assert d["kernel_dispatches"] == 1
+    assert d["dict_decode_" + form] == 1
+    assert d["dict_decode_" + other] == 0
+    with urllib.request.urlopen(
+            f"{trio.broker_url}/metrics/prometheus") as r:
+        text = r.read().decode()
+    assert "dict_decode_select" in text and "dict_decode_gather" in text
+
+
+def test_statements_without_a_dictionary_value_leave_the_forms(trio):
+    before = counters()
+    trio(DENSE)
+    d = moved(before, counters())
+    assert not any(v for k, v in d.items() if k.startswith("dict_decode_"))
 
 
 class _Counted:

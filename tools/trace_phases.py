@@ -27,7 +27,10 @@ first form keeps the file itself: it calls ``benchmark.run.run_cell`` with
 ``benchmark.trace.xplane.load`` wrapped to copy the file to ``--keep``
 first (default ``chiprun_out/<cell>.<seed>.xplane.pb``), prints the run's
 result line, then reads the kept file. Nothing under ``benchmark/``
-changes for it. Interval arithmetic (``merge``, ``clip``, ``self_times``)
+changes for it. After the result line it prints how far the work counters
+``kernel_dispatches``, ``dict_decode_select`` and ``dict_decode_gather``
+(``pinot_tpu/utils/spans.count_dispatch``) moved a request of the window.
+Interval arithmetic (``merge``, ``clip``, ``self_times``)
 is ``benchmark/trace/reduce.py``'s, by import.
 
 Self times group events by their ``qid`` stat, so they are right for any
@@ -60,6 +63,8 @@ from benchmark.trace import xplane  # noqa: E402
 PHASE_PREFIX = "pinot."
 SCOPE = re.compile(r"pinot\.[a-z_]+")
 RUN_ID = re.compile(r"\(\d+\)$")          # jit_pinot_dense_vmap(1234567)
+WORK_COUNTERS = ("kernel_dispatches", "dict_decode_select",
+                 "dict_decode_gather")
 UNATTRIBUTED = "(in request, no program phase open)"
 NO_REQUEST = "(no request open)"
 Event = Tuple[str, float, float, dict]    # name, start s, end s, stats
@@ -278,12 +283,29 @@ def traced_run(cell: str, seed: int, seconds: float, keep: str) -> None:
         shutil.copyfile(path, keep)
         return load(path)
 
-    xplane.load = keep_then_load
+    # the window's own delta of the program's work counters: run_cell
+    # keeps it for its metric readers and prints none of it
+    from pinot_tpu.utils.metrics import global_metrics
+    drive = run.tr.drive
+    work: Dict[str, float] = {}
+
+    def counted_drive(*a, **kw):
+        before = global_metrics.snapshot()["counters"]
+        t0, requests = drive(*a, **kw)
+        after = global_metrics.snapshot()["counters"]
+        for name in WORK_COUNTERS:
+            work[name] = ((after.get(name, 0) - before.get(name, 0))
+                          / max(len(requests), 1))
+        return t0, requests
+
+    xplane.load, run.tr.drive = keep_then_load, counted_drive
     try:
         result = run.run_cell(cell, seed, seconds, True)
     finally:
-        xplane.load = load
+        xplane.load, run.tr.drive = load, drive
     print(json.dumps(result), flush=True)
+    print("work counters, a request of the window: " + ", ".join(
+        f"{name} {value:.4f}" for name, value in work.items()), flush=True)
 
 
 def sample_device_events(path: str, n: int) -> List[dict]:
