@@ -24,11 +24,13 @@ The quickest proof that the system still starts on a TPU. In ONE process
    raised.
 
 On a machine with more than one device the same command runs the mesh
-phase instead of 3-5: the same queries over a DistributedTable on the
-mesh, plus the multistage mesh join / device window / set-op, and checks
-that every device holds a shard. ServerNode has no device argument
-(ROADMAP R4), so the served trio is a one-chip layout; each invocation is
-what was run and proven on that machine (PERF.md section 5).
+phase instead of 3-5: the layout of the benchmark's four-chip cell (2^27
+rows as 16 segments of 2^23, four a device on four chips) behind the same
+served trio, the ServerNode constructed over the devices as one mesh
+(cluster/server_node.py), the same queries over HTTP, each held to the
+oracle and to ONE mesh program of the expected route in the server's span
+tree (no fallback to the per-segment path); then the multistage mesh join
+/ device window / set-op, and a check that every device holds a shard.
 
 The last stdout line of a pass is one JSON object,
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
@@ -103,6 +105,22 @@ DISPATCH_SPANS = {
 }
 DGB_SPEC = ("dgb", [("lo_quantity", "lt", 25)], ("lo_revenue",),
             ["d_year"])
+# several devices: the table of the benchmark's four-chip cell, and the
+# route of the one mesh program that answers each query
+# (parallel/distributed.DistributedTable._route): dense plans vmap the
+# local segments; a compact plan on the factorized core (q4.1, q2.1: the
+# shared dictionaries leave the group space unmultiplied) flattens the
+# local shard; q4.3's sort core over a local shard past
+# SEGMENTED_SORT_ROW_LIMIT runs per local segment inside the program
+MESH_LOG2_ROWS = 27
+MESH_SEGMENTS = 16
+MESH_QUERIES = [
+    ("q1.1", "dense", "mesh_dense"),
+    ("dgb", "dense", "mesh_dense"),
+    ("q4.1", "compact", "mesh_compact"),
+    ("q2.1", "compact", "mesh_compact"),
+    ("q4.3", "compact", "mesh_compact_per_segment"),
+]
 
 T0 = time.perf_counter()
 
@@ -178,14 +196,14 @@ def check_native() -> None:
              + str(native.build_error()))
 
 
-def build_table(work: str, log2_rows: int, seed: int):
+def build_table(work: str, log2_rows: int, seed: int,
+                n_seg: int = N_SEGMENTS):
     """Generate and build the SSB segments (host work, threads: numpy
     releases the GIL); returns the segment directories."""
     import bench
     from pinot_tpu.segment import SegmentBuilder
     from pinot_tpu.spi import Schema, TableConfig
 
-    n_seg = N_SEGMENTS
     rows_per_seg = (1 << log2_rows) // n_seg
     out_dir = os.path.join(work, "segments")
 
@@ -208,13 +226,14 @@ def build_table(work: str, log2_rows: int, seed: int):
     return dirs
 
 
-def start_cluster(work: str):
+def start_cluster(work: str, mesh=None):
     """Controller + ServerNode + BrokerNode in this process, as
-    StartController / StartServer / StartBroker construct them."""
+    StartController / StartServer / StartBroker construct them; with
+    ``mesh`` (devices) the server keeps its table across them."""
     from pinot_tpu.cluster import BrokerNode, Controller, ServerNode
 
     controller = Controller(os.path.join(work, "controller"))
-    server = ServerNode("smoke_server", controller.url)
+    server = ServerNode("smoke_server", controller.url, mesh=mesh)
     return BrokerNode(controller.url), server, controller
 
 
@@ -374,53 +393,69 @@ def run_served_queries(broker_url: str, host_segs):
         run_served_query(conn, host_segs, spec)
 
 
-def run_mesh_phase(seg_dirs, host_segs) -> None:
-    """More than one device: the smoke queries over a DistributedTable on
-    the mesh, checked against the same numpy oracle the one-chip answers
-    are held to, then the multistage mesh join / device window /
-    set-op."""
+def run_mesh_query(conn, host_segs, spec, strategy, route) -> None:
+    """One smoke query over HTTP against the mesh-holding server: cold
+    once, warm twice, vs the oracle, then under EXPLAIN ANALYZE for the
+    one mesh program the server ran."""
     import bench
-    import jax
-    from pinot_tpu.broker import Broker
+
+    qid, preds, vexpr, gcols = spec
+    sql = bench.spec_to_sql(preds, vexpr, gcols)
+    fallbacks0 = counter("mesh_fallbacks")
+    t = time.perf_counter()
+    res = conn.execute(sql + OPTION)
+    cold_s = time.perf_counter() - t
+    warm_ms = []
+    for _ in range(2):
+        t = time.perf_counter()
+        res = conn.execute(sql + OPTION)
+        warm_ms.append((time.perf_counter() - t) * 1e3)
+    seen = []
+    for node, _id, _parent, _ms, detail in conn.execute(
+            "EXPLAIN ANALYZE " + sql + OPTION).rows:
+        if node == "mesh_dispatch" or node in DISPATCH_SPANS:
+            attrs = dict(kv.split("=", 1) for kv in detail.split())
+            seen.append((node, attrs.get("route"), attrs.get("strategy")))
+    ok = bench._digest(res.rows) == oracle_digest(
+        host_segs, preds, vexpr, gcols)
+    fell_back = counter("mesh_fallbacks") - fallbacks0
+    say(f"mesh query {qid}: server ran {seen}  cold {cold_s:.2f}s  warm "
+        f"{warm_ms[0]:.1f} / {warm_ms[1]:.1f} ms  mesh_fallbacks "
+        f"{fell_back:.0f}  segments {res.num_segments}  rows "
+        f"{len(res.rows)}  digest_ok {ok}")
+    if seen != [("mesh_dispatch", route, strategy)] or fell_back:
+        fail(f"mesh: {qid}: the server ran {seen} with {fell_back:.0f} "
+             f"fallbacks, expected one mesh_dispatch by {route} "
+             f"({strategy})")
+    if res.num_segments != len(host_segs):
+        fail(f"mesh: {qid} answered from {res.num_segments} of "
+             f"{len(host_segs)} segments")
+    if not ok:
+        fail(f"mesh: {qid} digest differs from the numpy oracle")
+
+
+def run_mesh_phase(nodes, host_segs, devices, before) -> None:
+    """More than one device: the smoke queries through the served trio
+    whose server holds the mesh, then that every device holds a shard,
+    then the multistage mesh join / device window / set-op."""
+    import bench
+    from pinot_tpu.clients import connect_url
     from pinot_tpu.multistage import device_join
-    from pinot_tpu.parallel import DistributedTable, segment_mesh
-    from pinot_tpu.query.context import build_query_context
-    from pinot_tpu.query.sql import parse_sql
-    from pinot_tpu.server import TableDataManager
 
     def in_use(d):     # None where the backend reports no memory stats
         return (d.memory_stats() or {}).get("bytes_in_use")
 
-    devices = jax.devices()
-    before = [in_use(d) for d in devices]
-    dm = TableDataManager("lineorder")
-    for d in seg_dirs:
-        dm.add_segment_dir(d)
-    dist = DistributedTable(dm.acquire_segments(), segment_mesh())
-    dm.set_distributed(dist)
-    broker = Broker()
-    broker.register_table(dm)
-    for qid, preds, vexpr, gcols, *_family in smoke_specs():
-        sql = bench.spec_to_sql(preds, vexpr, gcols)
-        ctx = build_query_context(parse_sql(sql))
-        t = time.perf_counter()
-        if dist.try_execute(ctx) is None:
-            fail(f"mesh: try_execute fell back for {qid}")
-        cold_s = time.perf_counter() - t
-        t = time.perf_counter()
-        res = broker.query(sql + OPTION)      # broker.py's mesh branch
-        warm_ms = (time.perf_counter() - t) * 1e3
-        ok = bench._digest(res.rows) == oracle_digest(
-            host_segs, preds, vexpr, gcols)
-        say(f"mesh query {qid}: try_execute partial  cold {cold_s:.2f}s  "
-            f"warm {warm_ms:.1f} ms  digest_ok {ok}")
-        if not ok:
-            fail(f"mesh: {qid} digest differs from the numpy oracle")
+    by_id = {q[0]: q for q in bench.QUERIES + [DGB_SPEC]}
+    conn = connect_url(nodes[0].url, timeout=1100.0)
+    for qid, strategy, route in MESH_QUERIES:
+        run_mesh_query(conn, host_segs, by_id[qid], strategy, route)
+    dist = nodes[1]._tables["lineorder"].distributed
     shard_devs = sorted({s.device.id for col in dist._cols.values()
                          for s in col.addressable_shards})
     grew = [None if b is None else in_use(d) - b
             for d, b in zip(devices, before)]
-    say(f"mesh: column shards on device ids {shard_devs}; bytes_in_use "
+    say(f"mesh: {len(dist.segments)} segments, {dist.local_segments} a "
+        f"device; column shards on device ids {shard_devs}; bytes_in_use "
         f"grew per device by {grew} B")
     if shard_devs != sorted(d.id for d in devices) \
             or any(g is not None and g <= 0 for g in grew):
@@ -466,16 +501,18 @@ def main(argv=None) -> int:
                          f"and exits {EXIT_REHEARSAL}")
     args = ap.parse_args(argv)
     rehearse = args.rehearse_cpu
-    log2_rows = LOG2_ROWS
-    if rehearse:
-        # 2^19 rows, and the segmented kernel's row limit scaled down with
-        # them so the server routes each query as it does at full size
-        from pinot_tpu.ops import kernels
-        log2_rows = 19
-        kernels.SEGMENTED_SORT_ROW_LIMIT >>= LOG2_ROWS - log2_rows
-
     device = check_device(rehearse)
     several = device["count"] > 1
+    full_log2, n_seg = ((MESH_LOG2_ROWS, MESH_SEGMENTS) if several
+                        else (LOG2_ROWS, N_SEGMENTS))
+    log2_rows = full_log2
+    if rehearse:
+        # 2^16 rows a segment, and the sort core's row limit scaled down
+        # with them so the server routes each query as it does at full
+        # size
+        from pinot_tpu.ops import kernels
+        log2_rows = full_log2 - 7
+        kernels.SEGMENTED_SORT_ROW_LIMIT >>= full_log2 - log2_rows
     from pinot_tpu.segment import ImmutableSegment
     entries0 = cache_entries()
     say(f"compile cache: {cache_dir()} ({len(entries0)} entries before)")
@@ -485,17 +522,25 @@ def main(argv=None) -> int:
     tempfile.tempdir = work     # the checks' scratch tables land inside
     nodes = ()
     try:
-        if LOG2_ROWS < FULL_LOG2_ROWS and not rehearse:
+        if not several and LOG2_ROWS < FULL_LOG2_ROWS and not rehearse:
             say(f"CUT: 2^{LOG2_ROWS} rows, not one chip's share of "
                 f"2^{FULL_LOG2_ROWS}: {CUT_REASON}")
-        seg_dirs = build_table(work, log2_rows, args.seed)
+        seg_dirs = build_table(work, log2_rows, args.seed, n_seg)
         host_segs = [ImmutableSegment.load(d) for d in seg_dirs]  # oracle
         if several:
-            say(f"{device['count']} devices: this invocation runs the mesh "
-                "phase; the served trio and the hardware checks are the "
-                "one-chip invocation's (ServerNode has no device argument, "
-                "ROADMAP R4)")
-            run_mesh_phase(seg_dirs, host_segs)
+            import jax
+            devices = jax.devices()
+            say(f"{len(devices)} devices: this invocation runs the mesh "
+                "phase, the served trio with the server's table across "
+                "the devices; the hardware checks are the one-chip "
+                "invocation's")
+            before = [(d.memory_stats() or {}).get("bytes_in_use")
+                      for d in devices]
+            nodes = start_cluster(work, mesh=devices)
+            load_table(nodes, seg_dirs, host_segs[0].schema)
+            run_mesh_phase(nodes, host_segs, devices, before)
+            stop_nodes(nodes)
+            nodes = ()
         else:
             nodes = start_cluster(work)
             load_table(nodes, seg_dirs, host_segs[0].schema)
@@ -512,7 +557,7 @@ def main(argv=None) -> int:
                 tpu_hw_script.run_hardware_checks(checks)
                 say(f"hardware checks: {len(checks)} passed in "
                     f"{time.perf_counter() - t:.1f}s: {', '.join(checks)}")
-        final_gates(rehearse, (1 << log2_rows) // N_SEGMENTS)
+        final_gates(rehearse, (1 << log2_rows) // n_seg)
     finally:
         stop_nodes(nodes)
         tempfile.tempdir = None

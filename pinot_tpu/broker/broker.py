@@ -865,15 +865,15 @@ class Broker:
         # mesh-resident table: one shard_map program + ICI combine replaces
         # the per-segment scatter-gather entirely
         from ..utils.spans import phase
-        if dm.distributed is not None and ctx.is_aggregation \
-                and not stmt.explain:
-            with phase(ph.DISTRIBUTED_EXECUTE):
-                partial = dm.distributed.try_execute(ctx)
+        dist = dm.distributed
+        if dist is not None and not stmt.explain:
+            from ..engine.serving import execute_on_mesh
+            partial = execute_on_mesh(ctx, dist)
             if partial is not None:
                 result = reduce_partials(ctx, [partial])
-                result.num_segments = len(dm.distributed.segments)
+                result.num_segments = len(dist.segments)
                 result.num_docs_scanned = sum(
-                    s.n_docs for s in dm.distributed.segments)
+                    s.n_docs for s in dist.segments)
                 result.time_ms = (time.perf_counter() - t0) * 1e3
                 return result
 

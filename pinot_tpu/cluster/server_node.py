@@ -12,6 +12,7 @@ DataTable response analog.
 """
 from __future__ import annotations
 
+import logging
 import os
 import threading
 import time
@@ -38,8 +39,18 @@ class ServerNode:
                  scheduler_config: Optional[Dict[str, Any]] = None,
                  tags: Optional[List[str]] = None,
                  advertise_host: Optional[str] = None,
-                 ledger_path: Optional[str] = None):
+                 ledger_path: Optional[str] = None,
+                 mesh=None):
+        """``mesh``: a ``jax.sharding.Mesh`` or a list of devices. A node
+        given one keeps each table's segments resident across it
+        (parallel/distributed.DistributedTable) and answers an
+        aggregation over all of them with one mesh program; a node given
+        none holds its segments on the default device."""
         self.instance_id = instance_id
+        if mesh is not None and not hasattr(mesh, "devices"):
+            from ..parallel.mesh import segment_mesh
+            mesh = segment_mesh(devices=list(mesh))
+        self.mesh = mesh
         self.controller_url = controller_url
         self.poll_interval = poll_interval
         # optional node-local perf ledger (ingest_stats writers etc.)
@@ -207,9 +218,40 @@ class ServerNode:
                     shutil.rmtree(local, ignore_errors=True)
         for table in list(self._tables):
             if table not in a["tables"]:
-                del self._tables[table]
+                self._replace_residency(self._tables.pop(table), None)
+        if self.mesh is not None:
+            for dm in self._tables.values():
+                self._place_on_mesh(dm)
         if ok:
             self._assignment_version = a["version"]
+
+    def _place_on_mesh(self, dm: TableDataManager) -> None:
+        """Keep ``dm``'s mesh residency equal to its loaded segments: a
+        new DistributedTable when the set changed (columns go up at
+        their first query), none for an empty table or for segments
+        that share no table dictionaries (those queries take the
+        per-segment path and count mesh_fallbacks)."""
+        segments = dm.acquire_segments()
+        held = dm.distributed
+        if held is not None and [s.uid for s in held.segments] \
+                == [s.uid for s in segments]:
+            return
+        fresh = None
+        if segments:
+            from ..parallel.distributed import DistributedTable
+            try:
+                fresh = DistributedTable(segments, self.mesh)
+            except ValueError as e:
+                logging.getLogger(__name__).warning(
+                    "table %s stays off the mesh: %s", dm.table_name, e)
+        self._replace_residency(dm, fresh)
+
+    @staticmethod
+    def _replace_residency(dm: TableDataManager, fresh) -> None:
+        held = dm.distributed
+        dm.set_distributed(fresh)
+        if held is not None:
+            held.evict_device()    # queries in flight keep their arrays
 
     def wait_for_version(self, version: int, timeout: float = 10.0) -> bool:
         deadline = time.monotonic() + timeout
@@ -333,8 +375,20 @@ class ServerNode:
                 segments = [s for s in segments if s.name in wanted]
         if dm is None:
             return {"partials_raw": [], "segmentsQueried": 0}
-        # shared loop with the in-process broker (engine/serving.py)
-        from ..engine.serving import execute_segments, plan_segments
+        # shared with the in-process broker (engine/serving.py): the mesh
+        # program of a mesh-resident table, else the per-segment loop
+        from ..engine.serving import (execute_on_mesh, execute_segments,
+                                      plan_segments)
+        if self.mesh is not None and not stmt.explain:
+            dist = dm.distributed
+            if dist is None:   # the table could not be placed
+                from ..utils.metrics import global_metrics
+                global_metrics.count("mesh_fallbacks")
+            else:
+                partial = execute_on_mesh(ctx, dist, segment_names)
+                if partial is not None:
+                    return {"partials_raw": [partial],
+                            "segmentsQueried": len(dist.segments)}
         if stmt.explain:
             ex = plan_segments(ctx, segments, use_rollups=False)
             from ..query.explain import explain_rows
@@ -406,6 +460,8 @@ class ServerNode:
                 raise ValueError(f"no table config for {table!r} at the "
                                  "controller; pass tableConfig inline")
         changes = dm.reload(TableConfig.from_dict(cfg_dict))
+        if self.mesh is not None:
+            self._place_on_mesh(dm)    # reloaded segments are new ones
         return {"reloaded": len(dm.acquire_segments()), **changes}
 
     def handle_mailbox(self, data: bytes) -> Dict[str, Any]:
@@ -479,6 +535,8 @@ class ServerNode:
                 faults.clear()
             self._fault_plan = None
         self.scheduler.stop()
+        for dm in self._tables.values():
+            self._replace_residency(dm, None)
         if self.heap_watcher is not None:
             self.heap_watcher.stop()
         if self.grpc_server is not None:

@@ -97,3 +97,32 @@ def execute_segments(ctx: QueryContext, segments: List[Any],
     ex = plan_segments(ctx, segments, use_rollups)
     execute_planned(ex)
     return ex
+
+
+def execute_on_mesh(ctx: QueryContext, dist,
+                    segment_names: Optional[List[str]] = None):
+    """The partial of ``ctx`` from ONE mesh program over ``dist``, the
+    table's mesh residency (parallel/distributed.DistributedTable; the
+    caller reads ``dm.distributed`` once), or None where the caller has
+    to take the per-segment path: a selection, a subset of the resident
+    segments, or a plan the mesh cannot combine
+    (DistributedTable.mesh_plan); each counts ``mesh_fallbacks``. Shared
+    by the in-process broker and the server node; it crosses the
+    boundaries of the per-segment path (planning, then execution around
+    distributed_execute), so the phase counters read alike."""
+    from ..ops.plan_cache import global_plan_cache
+    from ..utils.metrics import global_metrics
+    from .accounting import global_accountant
+    plan = None
+    if ctx.is_aggregation and (segment_names is None or
+                               set(segment_names) == dist.segment_names):
+        global_plan_cache.detector.begin_query(
+            global_accountant.current_query_id())
+        with phase(ph.PLANNING, segments=1):
+            plan = dist.mesh_plan(ctx)
+    if plan is None:
+        global_metrics.count("mesh_fallbacks")
+        return None
+    with phase(ph.EXECUTION, segments=len(dist.segments)), \
+            phase(ph.DISTRIBUTED_EXECUTE):
+        return dist.execute(plan)

@@ -1804,6 +1804,19 @@ def segmented_compact_ok(plan: KernelPlan) -> bool:
 SEGMENTED_SORT_ROW_LIMIT = 1 << 24
 
 
+def sort_core_fits(plan: KernelPlan, rows: int, space_factor: int = 1,
+                   row_limit: Optional[int] = None) -> bool:
+    """The one rule for how many rows a compact program may hold: any
+    number on the factorized core, at most SEGMENTED_SORT_ROW_LIMIT
+    (``row_limit`` where a caller was constructed with its own) on the
+    sort core. ``space_factor`` is what the program multiplies the
+    plan's group space by: the segment count in the segmented batch
+    kernel, 1 on a mesh shard (shared dictionaries, one space)."""
+    if row_limit is None:
+        row_limit = SEGMENTED_SORT_ROW_LIMIT
+    return not _needs_sort(plan, space_factor) or rows <= row_limit
+
+
 def segmented_compact_fits(plan: KernelPlan, bucket: int,
                            n_segments: int) -> bool:
     """Whether S same-plan compact segments fuse into ONE segmented
@@ -1811,11 +1824,10 @@ def segmented_compact_fits(plan: KernelPlan, bucket: int,
     segment index multiplies the group space, so a plan that is
     factorized on one segment can land on the sort core as a batch —
     and the sort core does not compile at every batch size
-    (SEGMENTED_SORT_ROW_LIMIT)."""
+    (sort_core_fits)."""
     if n_segments * plan.group_space > COMPACT_GROUP_LIMIT:
         return False
-    return (not _needs_sort(plan, n_segments)
-            or n_segments * bucket <= SEGMENTED_SORT_ROW_LIMIT)
+    return sort_core_fits(plan, n_segments * bucket, n_segments)
 
 
 def build_segmented_compact_kernel(plan: KernelPlan, bucket: int,

@@ -28,8 +28,12 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..engine.executor import extract_partial, resolve_params
-from ..utils.spans import annotate, device_fence, span
-from ..ops.kernels import build_kernel
+from ..ops.kernels import build_kernel, dict_decode_forms, sort_core_fits
+from ..utils import phases as ph
+from ..utils.devmem import global_device_memory
+from ..utils.metrics import global_metrics
+from ..utils.spans import (annotate, count_dispatch, device_fence, phase,
+                           span)
 from ..query.context import QueryContext
 from ..query.planner import CompiledPlan, SegmentPlanner
 from ..segment.immutable import ImmutableSegment, bucket_for
@@ -50,17 +54,26 @@ class DistributedTable:
     """A table resident across a device mesh as stacked sharded columns."""
 
     def __init__(self, segments: List[ImmutableSegment],
-                 mesh: Optional[Mesh] = None):
+                 mesh: Optional[Mesh] = None,
+                 sort_row_limit: Optional[int] = None):
+        """``sort_row_limit``: the most rows of a local shard the sort
+        core takes as one flattened program (None: the one-chip constant,
+        ops/kernels.SEGMENTED_SORT_ROW_LIMIT); over it the shard runs per
+        local segment inside the same mesh program (_route)."""
         if not segments:
             raise ValueError("no segments")
         self.segments = segments
+        self.segment_names = frozenset(s.name for s in segments)
         self.mesh = mesh or segment_mesh()
+        self.sort_row_limit = sort_row_limit
         self.n_dev = self.mesh.devices.size
         self.bucket = max(bucket_for(s.n_docs) for s in segments)
         # pad segment count to a multiple of the mesh (empty segments are
         # inert: n_docs=0 -> all-false validity masks)
         self.n_slots = -(-len(segments) // self.n_dev) * self.n_dev
         self._cols: Dict[str, jax.Array] = {}
+        self._wide_columns = None    # _plan_view's widened column metadata
+        self._overflowed = set()     # (kernel plan, capacity) that overflowed
         self._n_docs = self._shard_1d(np.array(
             [s.n_docs for s in segments] +
             [0] * (self.n_slots - len(segments)), dtype=np.int32))
@@ -93,20 +106,23 @@ class DistributedTable:
         import copy
         s0 = self.segments[0]
         view = copy.copy(s0)
-        view.columns = {}
-        for name, m0 in s0.columns.items():
-            m = copy.copy(m0)
-            for s in self.segments[1:]:
-                m2 = s.columns[name]
-                if m.min is not None:
-                    m.min = (None if m2.min is None
-                             else min(m.min, m2.min))
-                if m.max is not None:
-                    m.max = (None if m2.max is None
-                             else max(m.max, m2.max))
-                m.has_nulls = m.has_nulls or m2.has_nulls
-                m.is_sorted = m.is_sorted and m2.is_sorted
-            view.columns[name] = m
+        if self._wide_columns is None:   # segments are immutable: once
+            wide = {}
+            for name, m0 in s0.columns.items():
+                m = copy.copy(m0)
+                for s in self.segments[1:]:
+                    m2 = s.columns[name]
+                    if m.min is not None:
+                        m.min = (None if m2.min is None
+                                 else min(m.min, m2.min))
+                    if m.max is not None:
+                        m.max = (None if m2.max is None
+                                 else max(m.max, m2.max))
+                    m.has_nulls = m.has_nulls or m2.has_nulls
+                    m.is_sorted = m.is_sorted and m2.is_sorted
+                wide[name] = m
+            self._wide_columns = wide
+        view.columns = self._wide_columns
         # ANY segment with upsert-invalidated docs forces the validdocs
         # param into the plan (-> try_execute falls back to the per-segment
         # path), not just segment 0
@@ -133,22 +149,30 @@ class DistributedTable:
                 stack[i, : s.n_docs] = arr.astype(stack.dtype, copy=False)
             self._cols[name] = jax.device_put(
                 stack, self._sharding(P(SEG_AXIS, None)))
+            global_device_memory.add("mesh_cols", (id(self), name),
+                                     int(stack.nbytes))
         return self._cols[name]
+
+    def evict_device(self) -> None:
+        """Drop the sharded columns (a replaced or stopped residency);
+        queries in flight keep their own references."""
+        for name in list(self._cols):
+            self._cols.pop(name, None)
+            global_device_memory.remove("mesh_cols", (id(self), name))
 
     # -- execution ---------------------------------------------------------
     def plan(self, ctx: QueryContext) -> CompiledPlan:
         """Plan against the widened table view; shared dictionaries make the
         dict-id params valid table-wide, and widened min/max keep raw-column
-        constant folds and limb sizing correct for every segment. Compact-
-        strategy group-bys run flattened per device (local segments
-        concatenate along the row axis — _distributed_kernel), so the
-        planner chooses strategies exactly as the single-chip path does."""
+        constant folds and limb sizing correct for every segment. The
+        planner chooses strategies exactly as the single-chip path does;
+        how a compact group-by runs on a device's shard is _route's."""
         return SegmentPlanner(ctx, self._plan_view()).plan()
 
-    def try_execute(self, ctx: QueryContext):
-        """Distributed partial, or None when the plan needs the per-segment
-        path (host fallbacks, per-segment null masks, metadata fast paths
-        whose states differ per segment)."""
+    def mesh_plan(self, ctx: QueryContext) -> Optional[CompiledPlan]:
+        """The table-wide plan, or None when the statement needs the
+        per-segment path (host fallbacks, per-segment null masks, metadata
+        fast paths whose states differ per segment)."""
         plan = self.plan(ctx)
         if plan.kind != "kernel":
             return None
@@ -170,64 +194,137 @@ class DistributedTable:
             # positionally combinable across shards (HLL presence is —
             # it rides the 'or' reduce); per-segment path merges them
             return None
-        out = self._run(plan)
-        return extract_partial(plan, out)
+        return plan
 
-    def _cost_model_cap(self, plan: CompiledPlan) -> Optional[int]:
-        """Scale the planner's cost-model compaction capacity to one
-        device's LOCAL shard (local segment count x bucket) — the mesh
+    def execute(self, plan: CompiledPlan):
+        """One mesh program for ``plan`` (of mesh_plan): its partial."""
+        out = self._run(plan)
+        with phase(ph.EXTRACT_PARTIAL, segments=len(self.segments)):
+            return extract_partial(plan, out)
+
+    def try_execute(self, ctx: QueryContext):
+        """Distributed partial, or None when the plan needs the
+        per-segment path."""
+        plan = self.mesh_plan(ctx)
+        return None if plan is None else self.execute(plan)
+
+    @property
+    def local_segments(self) -> int:
+        return self.n_slots // self.n_dev
+
+    def _route(self, kernel_plan) -> str:
+        """The mesh program's family. A compact group-by flattens the
+        local shard into one row axis while the one-chip rule allows that
+        many rows (ops/kernels.sort_core_fits: any number on the
+        factorized core, SEGMENTED_SORT_ROW_LIMIT on the sort core; the
+        shared dictionaries leave the group space as it is); over it the
+        program maps the kernel over the local segments instead."""
+        if not (kernel_plan.is_group_by
+                and kernel_plan.strategy == "compact"):
+            return ph.MESH_DENSE
+        local = self.local_segments
+        if local == 1 or sort_core_fits(kernel_plan, local * self.bucket,
+                                        row_limit=self.sort_row_limit):
+            return ph.MESH_COMPACT
+        return ph.MESH_COMPACT_PER_SEGMENT
+
+    def _cost_model_cap(self, plan: CompiledPlan,
+                        rows: int) -> Optional[int]:
+        """Scale the planner's cost-model compaction capacity to the
+        ``rows`` one compaction of the mesh program sees — the local
+        shard, or one local segment on the routed sort core; the mesh
         kernels must not run at the heuristic default caps (ROADMAP).
         Shares multistage/costs.scaled_compact_cap with the fused batch
         dispatch so the scaling rule cannot fork."""
         if plan.kernel_plan.strategy != "compact":
             return None
-        from ..multistage.costs import scaled_compact_cap
-        local = self.n_slots // self.n_dev
-        return scaled_compact_cap(plan, local * self.bucket,
-                                  self.mesh.devices.flat[0].platform)
+        from ..multistage.costs import _pow2_at_least, scaled_compact_cap
+        cap = scaled_compact_cap(plan, rows,
+                                 self.mesh.devices.flat[0].platform)
+        # the cost model's floor (3 x STAGE = 864 slot rows, q3.4) is its
+        # one capacity that is no power of two, and XLA:TPU refuses the
+        # sort core at it inside the mesh program, with either post
+        # ("vmem ... reduce-window ... u32[7,128]": described v5e:2x2
+        # compiles and a chip run of PR 29); at 1,024 it compiles
+        return None if cap is None else _pow2_at_least(cap)
+
+    def _launch(self, plan: CompiledPlan, family: str, cap: Optional[int],
+                cols, params, xfer_compact: bool = True
+                ) -> Dict[str, np.ndarray]:
+        """One launch of the mesh program and its copy back."""
+        from ..engine.accounting import global_accountant
+        global_accountant.sample()   # kill/timeout before the launch
+        fn = _distributed_kernel(plan.kernel_plan, self.bucket, self.mesh,
+                                 len(cols), len(params), cap, family,
+                                 xfer_compact)
+        count_dispatch(family, dict_decode_forms(plan.kernel_plan, params))
+        with phase(ph.DEVICE_EXECUTE):
+            dev = fn(cols, self._n_docs, params)
+            device_fence(dev)
+        with phase(ph.DEVICE_TRANSFER):
+            return jax.device_get(dev)  # jaxlint: ok host-sync
 
     def _run(self, plan: CompiledPlan) -> Dict[str, np.ndarray]:
-        cols = tuple(self.device_col(n) for n in plan.col_names)
-        # replicated placement on THIS mesh's devices — never the default
-        # backend (the driver's dryrun runs a CPU mesh under a TPU default)
-        params = resolve_params(plan, sharding=self._sharding(P()))
-        cap = self._cost_model_cap(plan)
-        local = self.n_slots // self.n_dev
+        from ..engine.accounting import global_accountant
+        from ..ops.compact import full_slots_cap
+        from ..ops.plan_cache import global_plan_cache
+        with phase(ph.DISPATCH_PREPARE):
+            cols = tuple(self.device_col(n) for n in plan.col_names)
+            # replicated placement on THIS mesh's devices — never the
+            # default backend (the driver's dryrun runs a CPU mesh under
+            # a TPU default)
+            params = resolve_params(plan, sharding=self._sharding(P()))
+        local = self.local_segments
+        family = self._route(plan.kernel_plan)
+        # rows under one compaction: it sizes the capacity and the retry
+        rows = self.bucket * (1 if family == ph.MESH_COMPACT_PER_SEGMENT
+                              else local)
+        cap = self._cost_model_cap(plan, rows)
+        if (plan.kernel_plan, cap) in self._overflowed:
+            # this capacity overflowed for this plan before: straight to
+            # the full one, not the doomed launch and its retry again
+            cap = full_slots_cap(rows)
         with span("mesh_dispatch", devices=self.n_dev,
                   local_segments=local, bucket=self.bucket,
-                  strategy=plan.kernel_plan.strategy, slots_cap=cap,
-                  est_sel=plan.est_selectivity):
-            fn = _distributed_kernel(plan.kernel_plan, self.bucket,
-                                     self.mesh, len(cols), len(params),
-                                     slots_cap=cap)
-            with span("device_execute"):
-                dev = fn(cols, self._n_docs, params)
-                device_fence(dev)
-            with span("device_transfer"):
-                host = jax.device_get(dev)
+                  strategy=plan.kernel_plan.strategy, route=family,
+                  slots_cap=cap, est_sel=plan.est_selectivity):
+            host = self._launch(plan, family, cap, cols, params)
             if int(host.pop("overflow", 0)):
                 # compact capacity exceeded on some device: rerun at the
-                # cannot-overflow capacity of a full local shard
-                from ..ops.compact import full_slots_cap
-                full = full_slots_cap(local * self.bucket)
-                with span("overflow_retry", slots_cap=full):
-                    fn = _distributed_kernel(
-                        plan.kernel_plan, self.bucket, self.mesh,
-                        len(cols), len(params), slots_cap=full)
-                    host = jax.device_get(fn(cols, self._n_docs, params))
+                # cannot-overflow capacity of what one compaction sees
+                self._overflowed.add((plan.kernel_plan, cap))
+                cap = full_slots_cap(rows)
+                global_metrics.count("mesh_overflow_retries")
+                with span("overflow_retry", slots_cap=cap), \
+                        global_plan_cache.detector.expected():
+                    host = self._launch(plan, family, cap, cols, params)
                 host.pop("overflow", None)
-                annotate(overflow_retry=True, slots_cap=full)
+                annotate(overflow_retry=True, slots_cap=cap)
+            # host numpy behind _launch's device_get, like the checks
+            # around it — host-sync [jaxlint baseline]
+            if int(host.pop("group_overflow", 0)):  # jaxlint: ok host-sync
+                # more live groups than the transfer compaction (or one
+                # segment's sparse post) holds: dense (space,) all the way
+                global_metrics.count("group_xfer_overflow_retries")
+                with span("group_overflow_retry"), \
+                        global_plan_cache.detector.expected():
+                    host = self._launch(plan, family, cap, cols, params,
+                                        xfer_compact=False)
+                host.pop("overflow", None)
+                annotate(group_overflow_retry=True)
             if "matched" in host:
                 matched = int(np.asarray(host["matched"]).sum())
                 annotate(matched=matched,
                          meas_sel=matched / max(
                              sum(s.n_docs for s in self.segments), 1))
+            global_accountant.track_result(host)
             return host
 
 
 def _distributed_kernel(kernel_plan, bucket: int, mesh: Mesh,
                         n_cols: int, n_params: int,
-                        slots_cap: int = None):
+                        slots_cap: Optional[int], family: str,
+                        xfer_compact: bool = True):
     from ..ops.kernels import (_ladder_min_elems, _two_pass_mode,
                                cpu_scatter_default)
 
@@ -236,71 +333,117 @@ def _distributed_kernel(kernel_plan, bucket: int, mesh: Mesh,
     # cache key (the jitted_kernel convention) — flipping them between
     # calls must never hit a stale cached mesh program
     return _distributed_kernel_cached(kernel_plan, bucket, mesh, n_cols,
-                                      n_params, slots_cap,
+                                      n_params, slots_cap, family,
+                                      xfer_compact,
                                       cpu_scatter_default(platform),
                                       _two_pass_mode(),
                                       _ladder_min_elems())
 
 
+def _fold(name: str, v: jax.Array) -> jax.Array:
+    """Per-local-segment outputs (L, ...) -> this device's partial."""
+    op = _reduce_op(name)
+    if op == "sum":
+        return v.sum(axis=0)
+    return v.min(axis=0) if op == "min" else v.max(axis=0)  # max, 'or'
+
+
+def _densify(out: Dict[str, jax.Array], space: int) -> Dict[str, jax.Array]:
+    """Sparse group outputs — (group_idx, value) rows as the sorted core's
+    sparse post and _compact_group_xfer emit them, of one kernel call or
+    stacked over the local segments — scattered into this device's dense
+    (space,) partial, which the positional collectives can combine. A
+    sentinel row (group_idx == space) falls outside and is dropped."""
+    from ..ops.kernels import _extreme
+    idx = out["group_idx"].reshape(-1)
+    dense = {"group_overflow": out["group_overflow"].sum()}
+    for k, v in out.items():
+        if k in ("group_idx", "group_overflow"):
+            continue
+        if v.shape != out["group_idx"].shape:     # matched, overflow
+            dense[k] = v.sum(axis=0) if v.ndim else v
+            continue
+        op = _reduce_op(k)
+        fill = 0 if op == "sum" else _extreme(
+            v.dtype, 1 if op == "min" else -1)
+        at = jnp.full(space, fill, v.dtype).at[idx]
+        combine = {"sum": at.add, "min": at.min}.get(op, at.max)
+        dense[k] = combine(v.reshape(-1), mode="drop")
+    return dense
+
+
 @functools.lru_cache(maxsize=512)
 def _distributed_kernel_cached(kernel_plan, bucket: int, mesh: Mesh,
                                n_cols: int, n_params: int,
-                               slots_cap: int, scatter: bool,
+                               slots_cap: Optional[int], family: str,
+                               xfer_compact: bool, scatter: bool,
                                two_pass_mode: str = "auto",
                                ladder_min: int = 1 << 22):
-    """jit(shard_map(kernel + collectives)) cached per plan/mesh."""
-    # dense (space,) outputs only: psum/pmin/pmax combine positionally
-    # across shards, which device-side transfer compaction would break.
-    # platform pins the kernel lowering to the mesh's backend (the
-    # driver's dryrun runs a CPU mesh under a TPU process default).
+    """jit(shard_map(kernel + collectives)) cached per plan/mesh/route,
+    named pinot_<family> and staged like every other kernel program."""
+    from ..ops.kernels import _compact_group_xfer
+    from ..utils.compileplane import kernel_jit, staged
+
+    # psum/pmin/pmax combine dense (space,) partials positionally across
+    # shards. With ``xfer_compact`` a compact kernel may still emit its
+    # groups sparse (the sorted core's sparse post: cost by compacted
+    # rows, not by the space — q4.3's 1.75M groups); _densify scatters
+    # them into the device's dense partial before the collectives, and
+    # the combined result is compacted to its live groups for the
+    # transfer, as on one chip. platform pins the kernel lowering to the
+    # mesh's backend (the driver's dryrun runs a CPU mesh under a TPU
+    # process default).
     platform = mesh.devices.flat[0].platform
-    compact_gb = (kernel_plan.is_group_by
-                  and kernel_plan.strategy == "compact")
+    compact = family != ph.MESH_DENSE
 
     def per_device(cols, n_docs, params):
         # cols: tuple of (L, bucket) local shards; n_docs: (L,)
         local_segs = n_docs.shape[0]
-        if compact_gb:
+        kern = build_kernel(
+            kernel_plan, bucket, slots_cap, platform,
+            xfer_compact=xfer_compact and compact, scatter=scatter,
+            local_segments=local_segs if family == ph.MESH_COMPACT else 1,
+            two_pass_mode=two_pass_mode, ladder_min=ladder_min)
+        if family == ph.MESH_COMPACT:
             # flatten local segments into one row axis: shared table
             # dictionaries make params segment-agnostic, so one Pallas
             # compaction + group pass serves the whole local shard
-            kern = build_kernel(kernel_plan, bucket, slots_cap, platform,
-                                xfer_compact=False,
-                                local_segments=local_segs,
-                                scatter=scatter,
-                                two_pass_mode=two_pass_mode,
-                                ladder_min=ladder_min)
             flat = tuple(c.reshape(local_segs * bucket) for c in cols)
             local = kern(flat, n_docs, params)
+        elif family == ph.MESH_COMPACT_PER_SEGMENT:
+            # the routed sort core: one local segment at a time, the
+            # body compiled once (a flattened shard over the row limit
+            # is what XLA refuses: ops/kernels SEGMENTED_SORT_ROW_LIMIT)
+            local = jax.lax.map(lambda cn: kern(cn[0], cn[1], params),
+                                (cols, n_docs))
         else:
-            kern = build_kernel(kernel_plan, bucket, slots_cap, platform,
-                                xfer_compact=False, scatter=scatter,
-                                two_pass_mode=two_pass_mode,
-                                ladder_min=ladder_min)
-            out = jax.vmap(lambda c, n: kern(c, n, params))(cols, n_docs)
-            local = {}
-            for k, v in out.items():
-                op = _reduce_op(k)
-                if op == "sum":
-                    local[k] = v.sum(axis=0)
-                elif op == "min":
-                    local[k] = v.min(axis=0)
-                elif op == "max":
-                    local[k] = v.max(axis=0)
-                else:
-                    local[k] = v.max(axis=0)
+            local = jax.vmap(lambda c, n: kern(c, n, params))(cols, n_docs)
+        if "group_idx" in local:
+            local = _densify(local, kernel_plan.group_space)
+        elif family != ph.MESH_COMPACT:
+            local = {k: _fold(k, v) for k, v in local.items()}
         red = {}
-        for k, v in local.items():
-            op = _reduce_op(k)
-            if k == "overflow" or op == "sum":
-                red[k] = jax.lax.psum(v, SEG_AXIS)
-            elif op == "min":
-                red[k] = jax.lax.pmin(v, SEG_AXIS)
-            elif op == "max":
-                red[k] = jax.lax.pmax(v, SEG_AXIS)
-            else:  # 'or' on bool presence
-                red[k] = jax.lax.pmax(
-                    v.astype(jnp.int32), SEG_AXIS).astype(bool)
+        with jax.named_scope(ph.SCOPE_COMBINE):
+            for k, v in local.items():
+                op = _reduce_op(k)
+                if k in ("overflow", "group_overflow") or op == "sum":
+                    red[k] = jax.lax.psum(v, SEG_AXIS)
+                elif op == "min":
+                    red[k] = jax.lax.pmin(v, SEG_AXIS)
+                elif op == "max":
+                    red[k] = jax.lax.pmax(v, SEG_AXIS)
+                else:  # 'or' on bool presence
+                    red[k] = jax.lax.pmax(
+                        v.astype(jnp.int32), SEG_AXIS).astype(bool)
+        if xfer_compact and kernel_plan.is_group_by:
+            # live groups only over the wire to the host; a segment whose
+            # sparse post overflowed counts with a result that does
+            spilled = red.pop("group_overflow", 0)
+            _compact_group_xfer(kernel_plan, red)
+            if "group_overflow" in red:
+                red["group_overflow"] = red["group_overflow"] + spilled
+            else:
+                red["group_overflow"] = jnp.asarray(spilled, jnp.int32)
         return red
 
     in_specs = (tuple(P(SEG_AXIS, None) for _ in range(n_cols)),
@@ -308,4 +451,6 @@ def _distributed_kernel_cached(kernel_plan, bucket: int, mesh: Mesh,
                 tuple(P() for _ in range(n_params)))
     mapped = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
                            out_specs=P(), check_vma=False)
-    return jax.jit(mapped)
+    key = ("mesh", kernel_plan, bucket, mesh, n_cols, n_params, slots_cap,
+           family, xfer_compact, scatter, two_pass_mode, ladder_min)
+    return staged(kernel_jit(mapped, family), "mesh_kernel", key)

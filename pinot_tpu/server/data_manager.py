@@ -23,8 +23,10 @@ class TableDataManager:
         self._lock = threading.Lock()
         self._reload_lock = threading.Lock()
         self._schema = None
-        # optional mesh-resident DistributedTable (parallel/distributed.py);
-        # the broker prefers it for kernel-plan aggregations
+        # optional mesh-resident DistributedTable (parallel/distributed.py):
+        # the in-process broker and a mesh-holding server node answer
+        # kernel-plan aggregations from it (engine/serving.execute_on_mesh);
+        # the server node keeps it equal to the loaded segments
         self.distributed = None
 
     def set_distributed(self, distributed) -> None:

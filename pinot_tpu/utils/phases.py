@@ -125,9 +125,14 @@ COMPACT_PER_SEGMENT = "compact_per_segment"  # plan cache, one segment
 SELECT_TOPK = "select"                       # selection ORDER BY/LIMIT
 RAGGED_FUSED = "ragged_fused"                # cross-query cube combine
 CUBE_BUILD_KERNEL = "cube_build"             # micro-batcher's cube scan
+# parallel/distributed.py: one shard_map program a query over a mesh
+MESH_DENSE = "mesh_dense"                    # local segments vmapped
+MESH_COMPACT = "mesh_compact"                # the local shard flattened
+MESH_COMPACT_PER_SEGMENT = "mesh_compact_per_segment"  # routed sort core
 KERNEL_FAMILIES = frozenset(
     {DENSE_VMAP, DENSE_PER_SEGMENT, COMPACT_SEGMENTED, COMPACT_PER_SEGMENT,
-     SELECT_TOPK, RAGGED_FUSED, CUBE_BUILD_KERNEL})
+     SELECT_TOPK, RAGGED_FUSED, CUBE_BUILD_KERNEL, MESH_DENSE, MESH_COMPACT,
+     MESH_COMPACT_PER_SEGMENT})
 MODULE_PREFIX = "pinot_"
 
 

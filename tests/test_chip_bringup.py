@@ -51,6 +51,33 @@ def test_chip_smoke_rehearsal_reads_what_the_server_ran():
     assert len(ran) == 5
 
 
+def test_chip_smoke_mesh_rehearsal_goes_through_the_serving_node():
+    """With several devices the walk-through serves the four-chip cell's
+    layout (16 segments, four a device) from a ServerNode that holds the
+    mesh: every query is one mesh program of the expected route, read
+    from the server's span tree over HTTP, and none falls back; q4.3's
+    sort core, over the (scaled) row limit on a local shard, runs per
+    local segment inside the program."""
+    proc = _run(["chip_smoke.py", "--rehearse-cpu"],
+                XLA_FLAGS="--xla_force_host_platform_device_count=4",
+                PINOT_CPU_FAST_GROUPBY=None)
+    assert proc.returncode == 2, proc.stdout + proc.stderr
+    assert '"ok"' not in proc.stdout
+    ran = dict(re.findall(
+        r"mesh query (\S+): server ran (\[.*?\])  .*?mesh_fallbacks 0  "
+        r"segments 16  .*?digest_ok True", proc.stdout))
+    assert ran == {
+        "q1.1": "[('mesh_dispatch', 'mesh_dense', 'dense')]",
+        "dgb": "[('mesh_dispatch', 'mesh_dense', 'dense')]",
+        "q4.1": "[('mesh_dispatch', 'mesh_compact', 'compact')]",
+        "q2.1": "[('mesh_dispatch', 'mesh_compact', 'compact')]",
+        "q4.3": "[('mesh_dispatch', 'mesh_compact_per_segment', 'compact')]",
+    }
+    assert "16 segments, 4 a device; column shards on device ids " \
+        "[0, 1, 2, 3]" in proc.stdout
+    assert "multistage all_to_all join" in proc.stdout
+
+
 def test_bench_refuses_cpu_before_building_data():
     proc = _run(["bench.py"], PINOT_BENCH_FORCE_CPU=None)
     assert proc.returncode == 1
