@@ -29,18 +29,24 @@ from ..utils.devmem import global_device_memory
 from ..utils.metrics import global_metrics
 from ..utils.spans import (annotate, count_dispatch, device_fence, phase,
                            span)
-from .executor import execute_plan, extract_partial, resolve_params
+from .executor import (execute_plan, extract_partial, param_sig,
+                       resident_param, resolve_params_host, stack_params)
 
-# stacked-column cache: ((segment uid, name) pairs, cols, bucket) -> tuple
-# of stacked device arrays; bounded LRU since segment sets change under
+# stack cache: ((segment uid, name) pairs, what, bucket) -> (stamp, tuple
+# of stacked device arrays), where `what` is a plan's column names
+# (_stacked_cols) or ("param", marker) for ONE segment-resident param
+# (_stacked_resident) and `stamp` is what the entry was built from where
+# that can change under a live segment (upsert validity versions; else
+# None); bounded LRU since segment sets change under
 # realtime. Keyed by the segments' process-unique LOAD uid, not the name:
 # segment names recur across tables and across reloads at the same bucket,
 # and a name-only key served the PREVIOUS table's device data to exact-
 # looking queries (round-9 chaos-soak find). The name rides along only for
 # evict_stacks_containing.
-_STACK_CACHE: "OrderedDict[Tuple, Tuple[jax.Array, ...]]" = OrderedDict()
+_STACK_CACHE: "OrderedDict[Tuple, Tuple[Any, Tuple[jax.Array, ...]]]" = \
+    OrderedDict()
 _STACK_CACHE_MAX = 32
-# _stacked_cols runs on broker pool / scheduler worker threads and
+# _cached_stack runs on broker pool / scheduler worker threads and
 # evict_stacks_containing on the reload path: OrderedDict LRU mutation
 # (move_to_end/popitem) is a multi-step linked-list relink that is NOT
 # GIL-atomic (the segdir._CACHE_LOCK lesson; surfaced by concur CC201).
@@ -78,23 +84,23 @@ def _vmapped_kernel(plan_struct, bucket: int):
                                   cpu_scatter_default())
 
 
-def _param_sig(params: Tuple[jax.Array, ...]) -> Tuple:
-    return tuple((tuple(p.shape), str(p.dtype)) for p in params)
-
-
-def _stacked_cols(plans: List[CompiledPlan], bucket: int
+def _cached_stack(key: Tuple, build, stamp: Any = None,
+                  counters: Optional[Tuple[str, str]] = None
                   ) -> Tuple[jax.Array, ...]:
-    key = (tuple(_seg_key(p.segment) for p in plans),
-           tuple(plans[0].col_names), bucket)
+    """The stack under ``key`` (key[0] = the segments' _seg_key pairs),
+    built by ``build()`` on a miss or when the cached one carries
+    another ``stamp``. ``counters`` = (hits, builds) names to count."""
     with _STACK_LOCK:
         hit = _STACK_CACHE.get(key)
-        if hit is not None:
+        if hit is not None and hit[0] == stamp:
             _STACK_CACHE.move_to_end(key)
-            return hit
+            if counters:
+                global_metrics.count(counters[0])
+            return hit[1]
         epoch = _EVICT_EPOCH
-    cols = tuple(
-        jnp.stack([p.segment.device_col(c, bucket) for p in plans])
-        for c in plans[0].col_names)
+    stack = build()
+    if counters:
+        global_metrics.count(counters[1])
     # a reload's superseded entry (same names, older uids) is left to
     # the 32-entry LRU: proactively deleting same-name entries would
     # make two LIVE tables with generic segment names evict each other's
@@ -103,12 +109,13 @@ def _stacked_cols(plans: List[CompiledPlan], bucket: int
         if _EVICT_EPOCH != epoch:
             # an eviction ran mid-build: this stack may include the
             # evicted segment — serve it to THIS query but never cache
-            return cols
-        _STACK_CACHE[key] = cols
+            return stack
+        _STACK_CACHE[key] = (stamp, stack)
+        _STACK_CACHE.move_to_end(key)  # a re-stamped entry is fresh
         # device-memory telemetry: the stack cache is an HBM resident
         # the tiered store manages (utils/devmem, /debug/memory)
         global_device_memory.add("stack_cache", key,
-                                 sum(int(c.nbytes) for c in cols))
+                                 sum(int(c.nbytes) for c in stack))
         while len(_STACK_CACHE) > _STACK_CACHE_MAX:
             old_key, _old = _STACK_CACHE.popitem(last=False)
             global_device_memory.remove("stack_cache", old_key)
@@ -118,7 +125,34 @@ def _stacked_cols(plans: List[CompiledPlan], bucket: int
     # outside this group's working set
     from .tier import global_tier
     global_tier.enforce(protect={u for u, _n in key[0]})
-    return cols
+    return stack
+
+
+def _stacked_cols(plans: List[CompiledPlan], bucket: int
+                  ) -> Tuple[jax.Array, ...]:
+    key = (tuple(_seg_key(p.segment) for p in plans),
+           tuple(plans[0].col_names), bucket)
+    return _cached_stack(key, lambda: tuple(
+        jnp.stack([p.segment.device_col(c, bucket) for p in plans])
+        for c in plans[0].col_names))
+
+
+def _stacked_resident(plans: List[CompiledPlan], marker: Tuple[str, Any],
+                      bucket: int) -> jax.Array:
+    """One segment-resident param (executor.RESIDENT_PARAMS) stacked over
+    the group's segments, kept beside the column stacks: it does not
+    depend on the statement, so a repeat of the group is a lookup. An
+    upsert table's validity masks change under the same segments: their
+    versions stamp the entry, and a newer version replaces it."""
+    key = (tuple(_seg_key(p.segment) for p in plans),
+           ("param",) + tuple(marker), bucket)
+    stamp = tuple(p.segment.valid_docs_version for p in plans) \
+        if marker[0] == "validdocs" else None
+    return _cached_stack(
+        key,
+        lambda: (jnp.stack([resident_param(p.segment, marker)
+                            for p in plans]),),
+        stamp, ("param_stack_hits", "param_stack_builds"))[0]
 
 
 def evict_stacks_containing(segment_name: str) -> None:
@@ -148,7 +182,11 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
     vmapped dispatch. Returns partials in input order."""
     results: List[Any] = [None] * len(plans)
     groups: Dict[Tuple, List[int]] = {}
-    resolved: Dict[int, Tuple[jax.Array, ...]] = {}
+    # plan index -> its params in host form (executor.resolve_params_host):
+    # the group key reads shapes and dtypes from it, a batched group
+    # stacks it, the per-segment route takes it along — nothing reaches
+    # the device before a launch does
+    hosts: Dict[int, Tuple[Any, ...]] = {}
 
     from ..ops.kernels import segmented_compact_fits, segmented_compact_ok
     from .accounting import global_accountant
@@ -161,7 +199,7 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
             continue
         kp = plan.kernel_plan
         # column shapes join the group key: same-plan segments can differ
-        # in MV padded width (maxValues), and jnp.stack needs equal shapes
+        # in MV padded width (maxValues), and a stack needs equal shapes
         shape_sig = tuple(
             getattr(plan.segment.columns[c], "max_values", None) or 0
             for c in plan.col_names)
@@ -174,20 +212,15 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
                 # segment index becomes the leading group-key factor
                 # (ops/kernels.build_segmented_compact_kernel), replacing
                 # the per-segment launches the Pallas compaction forced
-                with phase(ph.DISPATCH_PREPARE):
-                    params = resolve_params(plan)
-                resolved[i] = params
-                key = ("segc", kp, plan.segment.bucket,
-                       _param_sig(params) + shape_sig)
-                groups.setdefault(key, []).append(i)
+                kind = "segc"
             else:
                 results[i] = execute_plan(plan)
-            continue
-        with phase(ph.DISPATCH_PREPARE):
-            params = resolve_params(plan)
-        resolved[i] = params
-        key = ("dense", kp, plan.segment.bucket,
-               _param_sig(params) + shape_sig)
+                continue
+        else:
+            kind = "dense"
+        hosts[i] = resolve_params_host(plan)
+        key = (kind, kp, plan.segment.bucket,
+               param_sig(plan, hosts[i]) + shape_sig)
         groups.setdefault(key, []).append(i)
 
     from .ragged import global_batcher
@@ -199,7 +232,7 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
             # plan structure fuse into one cube-contraction launch.
             # None means dispatch solo (reason counted/annotated).
             fused = global_batcher.submit(
-                [plans[i] for i in idxs], [resolved[i] for i in idxs],
+                [plans[i] for i in idxs], [hosts[i] for i in idxs],
                 bucket, (kind,) + sig)
             if fused is not None:
                 for k, i in enumerate(idxs):
@@ -209,7 +242,7 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
         if n_seg == 1 or (kind == "segc" and not segmented_compact_fits(
                 plan_struct, bucket, n_seg)):
             for i in idxs:
-                results[i] = execute_plan(plans[i])
+                results[i] = execute_plan(plans[i], host_params=hosts[i])
             continue
         group_plans = [plans[i] for i in idxs]
         if kind == "dense":
@@ -220,7 +253,7 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
                 # through the double-buffered pipeline instead of
                 # staking everything resident (engine/pipeline.py)
                 partials = execute_kernel_plans_pipelined(
-                    plans, plan_struct, bucket, resolved, idxs)
+                    plans, plan_struct, bucket, hosts, idxs)
                 for k, i in enumerate(idxs):
                     results[i] = partials[k]
                 continue
@@ -239,12 +272,12 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
         with global_tier.pinned({p.segment.uid for p in group_plans}):
             with phase(ph.DISPATCH_PREPARE, segments=n_seg):
                 cols = _stacked_cols(group_plans, bucket)
-                n_docs = jnp.asarray(
-                    [p.segment.n_docs for p in group_plans],
-                    dtype=jnp.int32)
-                params = tuple(
-                    jnp.stack([resolved[i][j] for i in idxs])
-                    for j in range(len(resolved[idxs[0]])))
+                n_docs, params = stack_params(
+                    [hosts[i] for i in idxs],
+                    np.asarray(  # jaxlint: ok host-sync — host ints
+                        [p.segment.n_docs for p in group_plans],
+                        dtype=np.int32),
+                    lambda m: _stacked_resident(group_plans, m, bucket))
             if kind == "segc":
                 _run_segmented_compact(plans, idxs, plan_struct, bucket,
                                        cols, n_docs, params, results)
@@ -276,7 +309,8 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
                     # compaction cap; rerun it solo, straight to dense
                     # outputs (outside the phase above: the rerun
                     # crosses the same boundaries again)
-                    results[i] = execute_plan(plans[i], xfer_compact=False)
+                    results[i] = execute_plan(plans[i], xfer_compact=False,
+                                              host_params=hosts[i])
     return results
 
 

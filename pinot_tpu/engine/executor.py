@@ -72,9 +72,11 @@ def execute_segment(ctx: QueryContext, segment: ImmutableSegment):
     return SegmentExecutor(segment).execute(ctx)
 
 
-def execute_plan(plan: CompiledPlan, xfer_compact: bool = True):
+def execute_plan(plan: CompiledPlan, xfer_compact: bool = True,
+                 host_params: Optional[Tuple[Any, ...]] = None):
     """``xfer_compact=False`` reruns a kernel plan straight to dense
-    group outputs (run_kernel)."""
+    group outputs, ``host_params`` hands a kernel plan its already
+    resolved host params (both: run_kernel)."""
     ctx, seg = plan.ctx, plan.segment
     if plan.kind == "pruned":
         if not ctx.is_aggregation and plan.select_names:
@@ -105,7 +107,7 @@ def execute_plan(plan: CompiledPlan, xfer_compact: bool = True):
         with phase(ph.EXTRACT_PARTIAL, segment=seg.name):
             return extract_select(plan, out)
     assert plan.kind == "kernel"
-    out = run_kernel(plan, xfer_compact)
+    out = run_kernel(plan, xfer_compact, host_params)
     with phase(ph.EXTRACT_PARTIAL, segment=seg.name):
         return extract_partial(plan, out)
 
@@ -161,48 +163,146 @@ def extract_select(plan: CompiledPlan, out: Dict[str, np.ndarray]
     return SelectionPartial(labels, rows, okeys)
 
 
-def resolve_params(plan: CompiledPlan, sharding=None) -> Tuple[jax.Array, ...]:
-    """Materialize planner params: symbolic markers hit the segment device
-    cache; literal scalars/arrays upload (tiny).
+# planner param markers whose value is a device array the segment
+# already caches: they do not depend on the statement. Every other param
+# (a literal, a "docmask", a "hash64" table) is a value the host computes
+# for this statement and uploads with the launch.
+RESIDENT_PARAMS = ("dictvals", "nullmask", "validdocs")
 
-    `sharding` pins placement (e.g. a mesh-replicated NamedSharding for the
-    distributed path) so params never land on the default backend — required
-    when the process default is a real TPU but the query runs on a CPU mesh.
-    """
+
+def _marker(p: Any) -> Optional[str]:
+    """The name of a symbolic planner param; None for a literal."""
+    if isinstance(p, tuple) and len(p) == 2 and isinstance(p[0], str):
+        return p[0]
+    return None
+
+
+def resolve_params_host(plan: CompiledPlan) -> Tuple[Any, ...]:
+    """Planner params as far as the host takes them, with no transfer:
+    a literal becomes the numpy array the device would hold (the dtype
+    canonicalisation ``jax.device_put`` applies, so an int64 literal
+    with x64 off still reads int32); a segment-resident marker
+    (RESIDENT_PARAMS) stays the marker tuple. ``resolve_params`` and the
+    batched dispatch (engine/batch.py) finish the job."""
     seg = plan.segment
-
-    def put(x):
-        return jax.device_put(x, sharding)  # sharding None = default
-
-    out = []
+    out: List[Any] = []
     for p in plan.params:
-        if isinstance(p, tuple) and len(p) == 2 and p[0] == "dictvals":
-            out.append(seg.device_dict_values(p[1], sharding=sharding))
-        elif isinstance(p, tuple) and len(p) == 2 and p[0] == "hash64":
+        m = _marker(p)
+        if m in RESIDENT_PARAMS:
+            out.append(p)
+            continue
+        if m == "hash64":
             # per-dict-id 64-bit hash table for sketch aggregations
             # (host _hash64 — md5 for strings — so device and host
             # sketches agree bit-for-bit)
             from ..ops.aggregations import _hash64
-            vals = np.asarray(seg.dictionary(p[1]).values)
-            out.append(put(_hash64(vals)))
-        elif isinstance(p, tuple) and len(p) == 2 and p[0] == "nullmask":
-            out.append(seg.device_null_mask(p[1], sharding=sharding))
-        elif isinstance(p, tuple) and len(p) == 2 and p[0] == "validdocs":
-            out.append(seg.device_valid_mask(sharding=sharding))
-        elif isinstance(p, tuple) and len(p) == 2 and p[0] == "docmask":
+            x = _hash64(np.asarray(seg.dictionary(p[1]).values))
+        elif m == "docmask":
             # index-predicate doc mask (TEXT_MATCH/JSON_MATCH/
             # VECTOR_SIMILARITY): pad to the segment bucket
             mask = np.asarray(p[1], dtype=bool)
-            padded = np.zeros(seg.bucket, dtype=bool)
-            padded[: len(mask)] = mask
-            out.append(put(padded))
+            x = np.zeros(seg.bucket, dtype=bool)
+            x[: len(mask)] = mask
         else:
-            out.append(put(p))
+            x = np.asarray(p)  # jaxlint: ok host-sync — planner literal
+        out.append(x.astype(jax.dtypes.canonicalize_dtype(x.dtype),
+                            copy=False))
     return tuple(out)
 
 
-def run_kernel(plan: CompiledPlan,
-               xfer_compact: bool = True) -> Dict[str, np.ndarray]:
+def resident_param(seg: ImmutableSegment, p: Tuple[str, Any],
+                   sharding=None) -> jax.Array:
+    """The device array behind a RESIDENT_PARAMS marker (a lookup in the
+    segment's device cache; the first use uploads)."""
+    if p[0] == "dictvals":
+        return seg.device_dict_values(p[1], sharding=sharding)
+    if p[0] == "nullmask":
+        return seg.device_null_mask(p[1], sharding=sharding)
+    assert p[0] == "validdocs", p
+    return seg.device_valid_mask(sharding=sharding)
+
+
+def param_sig(plan: CompiledPlan, host: Tuple[Any, ...]) -> Tuple:
+    """((shape, dtype), ...) of the params as the device will hold them,
+    read from the host form: what groups same-shaped launches
+    (engine/batch.py) and keys the fused kernels (engine/ragged.py)
+    without an upload."""
+    seg = plan.segment
+    sig = []
+    for p in host:
+        if not isinstance(p, tuple):
+            sig.append((tuple(p.shape), str(p.dtype)))
+        elif p[0] == "dictvals":
+            sig.append(((len(seg.dictionary(p[1])),), str(
+                jax.dtypes.canonicalize_dtype(
+                    seg.columns[p[1]].data_type.np_dtype))))
+        else:  # nullmask / validdocs: one flag a padded row
+            sig.append(((seg.bucket,), "bool"))
+    return tuple(sig)
+
+
+def upload_params(arrays: List[np.ndarray], sharding=None) -> List[Any]:
+    """THE hand-over of a launch's literal params to the device, once a
+    launch (counter ``param_uploads``). On the default device the arrays
+    go as they are: the compiled call transfers its numpy arguments
+    itself, which the chip showed cheaper than one ``jax.device_put`` of
+    the list in front of it (1.21 against 1.68 ms a synced launch of
+    Q1's shapes on a v5e: PERF.md, PR 30). A ``sharding`` (the mesh path,
+    ``resolve_params``) needs the placement said: one ``device_put`` of
+    the list."""
+    if not arrays:
+        return []
+    global_metrics.count("param_uploads")
+    if sharding is None:
+        return arrays
+    return jax.device_put(arrays, sharding)
+
+
+def stack_params(hosts: List[Tuple[Any, ...]], lead: np.ndarray,
+                 resident_stack) -> Tuple[Any, Tuple[Any, ...]]:
+    """(lead, params) of ONE launch over many plans of one signature,
+    every param with a new leading axis over ``hosts`` (one
+    ``resolve_params_host`` a plan). Literals are stacked on the host and
+    reach the device together with ``lead`` (the launch's own per-plan
+    host array: n_docs, a segment index) in ONE hand-over
+    (``upload_params``); ``resident_stack(marker)`` supplies a
+    segment-resident param stacked the same way. No eager jax operation
+    runs here."""
+    lead, *stacked = upload_params([lead] + [
+        np.stack([h[j] for h in hosts])
+        for j, p in enumerate(hosts[0]) if not isinstance(p, tuple)])
+    literals = iter(stacked)
+    return lead, tuple(
+        resident_stack(p) if isinstance(p, tuple) else next(literals)
+        for p in hosts[0])
+
+
+def resolve_params(plan: CompiledPlan, sharding=None,
+                   host: Optional[Tuple[Any, ...]] = None
+                   ) -> Tuple[Any, ...]:
+    """Materialize planner params as a kernel's ``params`` argument:
+    symbolic markers hit the segment device cache; literal scalars/arrays
+    (tiny) go up in one hand-over (``upload_params``: with the launch
+    itself on the default device, so they are numpy until then).
+
+    `sharding` pins placement (e.g. a mesh-replicated NamedSharding for the
+    distributed path) so params never land on the default backend — required
+    when the process default is a real TPU but the query runs on a CPU mesh.
+    `host` is this plan's ``resolve_params_host`` where the caller already
+    has it (engine/batch.py hands it down the per-segment route).
+    """
+    if host is None:
+        host = resolve_params_host(plan)
+    literals = iter(upload_params(
+        [p for p in host if not isinstance(p, tuple)], sharding))
+    return tuple(
+        resident_param(plan.segment, p, sharding) if isinstance(p, tuple)
+        else next(literals) for p in host)
+
+
+def run_kernel(plan: CompiledPlan, xfer_compact: bool = True,
+               host_params: Optional[Tuple[Any, ...]] = None
+               ) -> Dict[str, np.ndarray]:
     """Execute the compiled kernel through the keyed plan cache
     (ops/plan_cache.py): one compiled XLA program + donated accumulator
     buffers per (plan, bucket, slots_cap, platform, flags), so repeated
@@ -214,7 +314,9 @@ def run_kernel(plan: CompiledPlan,
     overflow and retries once at full_slots_cap. xfer_compact=False goes
     straight to dense (space,) group outputs — used when the caller
     already knows the transfer compaction spilled (engine/batch.py's
-    vmapped path)."""
+    vmapped path). ``host_params`` is the plan's ``resolve_params_host``
+    where engine/batch.py made it for its group key; called on its own
+    the kernel resolves for itself."""
     from ..ops.plan_cache import global_plan_cache
     from .tier import global_tier
     seg = plan.segment
@@ -228,7 +330,7 @@ def run_kernel(plan: CompiledPlan,
         # columns this query just uploaded (engine/tier anti-thrash)
         with phase(ph.DISPATCH_PREPARE):
             cols = seg.device_cols(plan.col_names)
-            params = resolve_params(plan)
+            params = resolve_params(plan, host=host_params)
         n = np.int32(seg.n_docs)
         cap = plan.slots_cap
         # drift_requantized: the compile at the measured-selectivity

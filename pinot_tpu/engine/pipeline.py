@@ -62,10 +62,12 @@ def group_stack_bytes(plans: List[CompiledPlan], bucket: int) -> int:
 
 def execute_kernel_plans_pipelined(plans: List[CompiledPlan],
                                    plan_struct, bucket: int,
-                                   resolved_params: Dict[int, Tuple],
+                                   host_params: Dict[int, Tuple],
                                    idxs: List[int]) -> List[Any]:
     """Run same-structure kernel plans one segment at a time with the
     next segment's transfer in flight; returns partials in plans order.
+    ``host_params``: plan index -> ``executor.resolve_params_host``
+    (uploaded here: this path does stream).
 
     Double-buffer discipline: at any moment device memory holds the
     in-flight transfer (i+1) plus the executing segment (i); segment
@@ -75,14 +77,16 @@ def execute_kernel_plans_pipelined(plans: List[CompiledPlan],
     """
     from ..ops.kernels import dict_decode_forms, jitted_kernel
     from .accounting import global_accountant
-    from .executor import extract_partial
+    from .executor import extract_partial, resolve_params
 
     fn = jitted_kernel(plan_struct, bucket)  # lru-cached jit: repeated
     # over-budget queries must not pay a fresh XLA compile per group
     family = plan_family(plan_struct)
-    # one signature group: every segment's dictionaries have one shape
-    forms = dict_decode_forms(plan_struct, resolved_params[idxs[0]])
     group = [plans[i] for i in idxs]
+    params = [resolve_params(p, host=host_params[i])
+              for p, i in zip(group, idxs)]
+    # one signature group: every segment's dictionaries have one shape
+    forms = dict_decode_forms(plan_struct, params[0])
 
     def stage(k: int):
         seg = group[k].segment
@@ -100,8 +104,7 @@ def execute_kernel_plans_pipelined(plans: List[CompiledPlan],
         # the H2D copy overlap this kernel on the transfer engine
         staged = stage(k + 1) if k + 1 < len(group) else None
         count_dispatch(family, forms)
-        out = fn(cur, jnp.int32(plan.segment.n_docs),
-                 resolved_params[idxs[k]])
+        out = fn(cur, jnp.int32(plan.segment.n_docs), params[k])
         outs.append(out)
         del cur  # last py-reference; freed once the kernel consumes it
         bump("pipelined_segments")
@@ -133,8 +136,7 @@ def execute_kernel_plans_pipelined(plans: List[CompiledPlan],
                 # a deliberate dense rerun (compile-event taxonomy:
                 # overflow_retry, never a retrace)
                 dense = jax.device_get(dense_fn(  # jaxlint: ok host-sync
-                    cols, jnp.int32(seg.n_docs),
-                    resolved_params[idxs[k]]))
+                    cols, jnp.int32(seg.n_docs), params[k]))
             del cols
             dense.pop("group_overflow", None)
             global_accountant.track_result(dense)

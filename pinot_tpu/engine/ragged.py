@@ -477,12 +477,14 @@ def default_enabled() -> bool:
 
 
 class _Submission:
-    __slots__ = ("plans", "resolved", "future", "query_id", "t0",
+    __slots__ = ("plans", "hosts", "future", "query_id", "t0",
                  "n_items", "abandoned")
 
-    def __init__(self, plans, resolved, query_id):
+    def __init__(self, plans, hosts, query_id):
         self.plans = plans
-        self.resolved = resolved
+        # per plan, executor.resolve_params_host: nothing is uploaded
+        # for a query that ends up dispatching solo
+        self.hosts = hosts
         self.future: "Future[Any]" = Future()
         self.query_id = query_id
         self.t0 = time.perf_counter()
@@ -544,9 +546,10 @@ class RaggedBatcher:
         annotate(batched=False, solo_reason=reason)
         return None
 
-    def submit(self, plans: List[Any], resolved: List[Tuple],
+    def submit(self, plans: List[Any], hosts: List[Tuple],
                bucket: int, group_sig: Tuple) -> Optional[List[Any]]:
-        """Try to fuse one query's compatible kernel-plan group with
+        """Try to fuse one query's compatible kernel-plan group (its
+        params in host form, one tuple a plan) with
         concurrent peers. Returns per-plan partials, or None — the
         caller then runs the ordinary solo dispatch (reason counted in
         solo_fallback_* and annotated on the span). Never queue-blocks
@@ -588,7 +591,7 @@ class RaggedBatcher:
             est = self.estimate_ms(key) or window_ms
             if rem_ms < window_ms + 2.0 * est:
                 return self._solo("deadline")
-        sub = _Submission(plans, resolved, qid)
+        sub = _Submission(plans, hosts, qid)
         # weight cap = largest pow2 <= the budgeted item count, so the
         # PADDED batch still fits ITEM_CELL_BUDGET on device
         budget_items = max(ITEM_CELL_BUDGET // max(spec.cube_space, 1), 1)
@@ -685,11 +688,12 @@ class RaggedBatcher:
     def _execute_fused(self, key, spec: CubeSpec,
                        batch: List[_Submission]) -> Dict[int, List]:
         from ..ops.plan_cache import global_cube_cache
-        from .executor import extract_partial
+        from .executor import (extract_partial, param_sig, resident_param,
+                               stack_params)
 
         items: List[Tuple[_Submission, Any, Tuple]] = []
         for sub in batch:
-            for plan, params in zip(sub.plans, sub.resolved):
+            for plan, params in zip(sub.plans, sub.hosts):
                 items.append((sub, plan, params))
 
         # per-unique-segment cubes (cached device-resident; one unmasked
@@ -716,21 +720,21 @@ class RaggedBatcher:
         seg_idx = np.zeros(npad, dtype=np.int32)
         for k, (_s, plan, _p) in enumerate(items):
             seg_idx[k] = seg_order[plan.segment.uid]
-        params0 = items[0][2]
-        stacked_params = tuple(
-            jnp.stack([items[k][2][j] if k < n_items else params0[j]
-                       for k in range(npad)])
-            for j in range(len(params0)))
+        padded = [items[k if k < n_items else 0] for k in range(npad)]
+        dev_seg_idx, stacked_params = stack_params(
+            [hosts for _s, _plan, hosts in padded], seg_idx,
+            lambda m: jnp.stack([resident_param(plan.segment, m)
+                                 for _s, plan, _h in padded]))
         fn = _kernels.get(
             ("combine", spec, len(cubes), npad,
-             tuple((tuple(p.shape), str(p.dtype)) for p in params0)),
+             param_sig(items[0][1], items[0][2])),
             lambda: build_cube_combine_kernel(spec), ph.RAGGED_FUSED)
         with span(ph.FUSED_EXECUTE, queries=len(batch), items=n_items,
                   padded=npad, segments=len(cubes),
                   cube_space=spec.cube_space):
             count_dispatch(ph.RAGGED_FUSED)
             with phase(ph.DEVICE_EXECUTE):
-                dev = fn(stacked, jnp.asarray(seg_idx), stacked_params)
+                dev = fn(stacked, dev_seg_idx, stacked_params)
                 device_fence(dev)
             with phase(ph.DEVICE_TRANSFER):
                 host = jax.device_get(dev)  # jaxlint: ok host-sync

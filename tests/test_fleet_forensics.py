@@ -416,7 +416,7 @@ def test_stack_cache_pool_tracks_bytes():
     for key in new_keys:
         tracked = global_device_memory._pools["stack_cache"][key]
         assert tracked == sum(int(c.nbytes)
-                              for c in eb._STACK_CACHE[key])
+                              for c in eb._STACK_CACHE[key][1])
     ev0 = global_device_memory.snapshot()["stack_cache"]["evictions"]
     for seg in dm.acquire_segments():
         eb.evict_stacks_containing(seg.name)

@@ -44,14 +44,15 @@ SMALL_DICT = "SELECT SUM(amount * tier) FROM ptab WHERE tier BETWEEN 1 AND 3"
 LONG_DICT = "SELECT SUM(amount * code) FROM ptab WHERE code < 200"
 
 # what one plain DENSE statement crosses: every phase of PERF.md's table,
-# once, except the host work before a launch (params of each segment, then
-# the stack) — one vmapped launch answers the four segments
+# once — one vmapped launch answers the four segments, and the host work
+# before it is one crossing too (the stacks and the one params upload;
+# the per-segment resolve_params passes went with PR 30)
 CROSSINGS = {
     ph.BROKER_QUERY: 1, ph.BROKER_PARSE: 1, ph.BROKER_ROUTE: 1,
     ph.BROKER_SELECT: 1, ph.SCATTER: 1, ph.SCATTER_CALL: 1,
     ph.WIRE_DECODE: 1, ph.REDUCE: 1, ph.BROKER_RESPOND: 1,
     ph.SERVER_HTTP: 1, ph.SERVER_QUEUE: 1, ph.SERVER_PARSE: 1,
-    ph.PLANNING: 1, ph.EXECUTION: 1, ph.DISPATCH_PREPARE: N_SEGMENTS + 1,
+    ph.PLANNING: 1, ph.EXECUTION: 1, ph.DISPATCH_PREPARE: 1,
     ph.DEVICE_EXECUTE: 1, ph.DEVICE_TRANSFER: 1, ph.EXTRACT_PARTIAL: 1,
     ph.SERVER_ENCODE: 1,
 }
