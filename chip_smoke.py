@@ -4,17 +4,18 @@ The quickest proof that the system still starts on a TPU. In ONE process
 (a chip belongs to one process; nothing here starts a JAX child) it
 
 1. refuses anything but a TPU (exit 1, naming what JAX found);
-2. builds an SSB table from ``--seed`` with bench.py's generator (18
-   columns, shapes unchanged): 2^26 lineorder rows as 8 segments of 2^23
-   — cut from one chip's share of 2^27 because the time limit forces it
-   (printed at run time; see CUT_REASON);
+2. builds an SSB table from ``--seed`` with the test corpus's generator
+   (pinot_tpu/tools/corpus.py; 18 columns, shapes unchanged): 2^26
+   lineorder rows as 8 segments of 2^23 — cut from one chip's share of
+   2^27 because the time limit forces it (printed at run time; see
+   CUT_REASON);
 3. starts Controller + ServerNode + BrokerNode as StartController /
    StartServer / StartBroker construct them (tools/admin.py), registers
    the table and the segments by location over the controller's REST
    API, and waits for the server to load them;
 4. runs one SQL query per kernel family through the broker's HTTP
    endpoint (clients.connect_url), once cold and twice warm, asserting
-   that the answer equals bench.py's numpy oracle and — from the span
+   that the answer equals the corpus's numpy oracle and — from the span
    tree ``EXPLAIN ANALYZE`` brings back from the server — that every
    segment was answered by the expected device dispatch, none by a host
    plan;
@@ -77,7 +78,7 @@ HBM_BYTES = {"TPU v5 lite": 16 << 30}
 # the planner has, with what the server is expected to be SEEN doing for 8
 # same-bucket segments — the span engine/batch.py or engine/executor.py
 # opens around each device launch, and how many of them. q1.1, q2.1,
-# q4.1 and q4.3 are bench.py's SSB specs. ``dgb`` is an SSB-shaped dense
+# q4.1 and q4.3 are the corpus's SSB specs. ``dgb`` is an SSB-shaped dense
 # group-by: at these segment sizes the planner's one-hot budget (segment
 # rows x group space) makes every SSB Q2-Q4 a compact plan, q4.1's 175
 # groups included, so the dense small-space family needs a 7-group key.
@@ -200,16 +201,16 @@ def build_table(work: str, log2_rows: int, seed: int,
                 n_seg: int = N_SEGMENTS):
     """Generate and build the SSB segments (host work, threads: numpy
     releases the GIL); returns the segment directories."""
-    import bench
     from pinot_tpu.segment import SegmentBuilder
     from pinot_tpu.spi import Schema, TableConfig
+    from pinot_tpu.tools import corpus
 
     rows_per_seg = (1 << log2_rows) // n_seg
     out_dir = os.path.join(work, "segments")
 
     def one(k: int) -> str:
-        cols = bench.gen_columns(rows_per_seg, seed=(seed, k))
-        schema = Schema("lineorder", bench._ssb_fields(cols))
+        cols = corpus.ssb_columns(rows_per_seg, seed=(seed, k))
+        schema = Schema("lineorder", corpus.ssb_fields(cols))
         return SegmentBuilder(schema, TableConfig("lineorder")).build(
             cols, out_dir, f"seg_{k}")
 
@@ -271,21 +272,20 @@ def stop_nodes(nodes) -> None:
 
 
 def smoke_specs():
-    import bench
-    by_id = {q[0]: q for q in bench.QUERIES + [DGB_SPEC]}
+    from pinot_tpu.tools import corpus
+    by_id = {q[0]: q for q in corpus.SSB_QUERIES + [DGB_SPEC]}
     return [by_id[qid] + (strategy, span_name, launches)
             for qid, strategy, span_name, launches in SMOKE_QUERIES]
 
 
 def oracle_digest(host_segs, preds, vexpr, gcols):
-    """bench.py's numpy oracle per segment, group sums merged."""
-    import bench
+    """The corpus's numpy oracle per segment, group sums merged."""
+    from pinot_tpu.tools import corpus
     acc: dict = {}
     for seg in host_segs:
-        rows, _secs = bench.oracle_run(seg, preds, vexpr, gcols)
-        for r in rows:
+        for r in corpus.ssb_oracle(seg, preds, vexpr, gcols):
             acc[r[:-1]] = acc.get(r[:-1], 0) + r[-1]
-    return bench._digest([k + (v,) for k, v in acc.items()])
+    return corpus.digest([k + (v,) for k, v in acc.items()])
 
 
 def observed_dispatch(conn, sql: str) -> dict:
@@ -336,12 +336,12 @@ def overflow_retries() -> float:
 def run_served_query(conn, host_segs, spec):
     """One smoke query over HTTP: cold once, warm twice, vs the oracle,
     then once more under EXPLAIN ANALYZE for what the server ran."""
-    import bench
     from pinot_tpu.ops.plan_cache import global_plan_cache
+    from pinot_tpu.tools import corpus
     from pinot_tpu.utils.compileplane import global_compile_log
 
     qid, preds, vexpr, gcols, strategy, span_name, launches = spec
-    sql = bench.spec_to_sql(preds, vexpr, gcols)
+    sql = corpus.spec_to_sql(preds, vexpr, gcols)
     retries0, compile0 = overflow_retries(), counter("compile_ms_total")
     seq0 = max([e["seq"] for e in global_compile_log.events()], default=0)
     t = time.perf_counter()
@@ -357,7 +357,7 @@ def run_served_query(conn, host_segs, spec):
         warm_ms.append((time.perf_counter() - t) * 1e3)
     seen = observed_dispatch(conn, sql)
     retraced = global_plan_cache.detector.retraces - det0
-    digest = bench._digest(res.rows)
+    digest = corpus.digest(res.rows)
     ok = digest == oracle_digest(host_segs, preds, vexpr, gcols)
     on_device = "segment_host" not in seen and \
         sum(v[1] for v in seen.values()) == len(host_segs)
@@ -397,10 +397,10 @@ def run_mesh_query(conn, host_segs, spec, strategy, route) -> None:
     """One smoke query over HTTP against the mesh-holding server: cold
     once, warm twice, vs the oracle, then under EXPLAIN ANALYZE for the
     one mesh program the server ran."""
-    import bench
+    from pinot_tpu.tools import corpus
 
     qid, preds, vexpr, gcols = spec
-    sql = bench.spec_to_sql(preds, vexpr, gcols)
+    sql = corpus.spec_to_sql(preds, vexpr, gcols)
     fallbacks0 = counter("mesh_fallbacks")
     t = time.perf_counter()
     res = conn.execute(sql + OPTION)
@@ -416,7 +416,7 @@ def run_mesh_query(conn, host_segs, spec, strategy, route) -> None:
         if node == "mesh_dispatch" or node in DISPATCH_SPANS:
             attrs = dict(kv.split("=", 1) for kv in detail.split())
             seen.append((node, attrs.get("route"), attrs.get("strategy")))
-    ok = bench._digest(res.rows) == oracle_digest(
+    ok = corpus.digest(res.rows) == oracle_digest(
         host_segs, preds, vexpr, gcols)
     fell_back = counter("mesh_fallbacks") - fallbacks0
     say(f"mesh query {qid}: server ran {seen}  cold {cold_s:.2f}s  warm "
@@ -438,14 +438,14 @@ def run_mesh_phase(nodes, host_segs, devices, before) -> None:
     """More than one device: the smoke queries through the served trio
     whose server holds the mesh, then that every device holds a shard,
     then the multistage mesh join / device window / set-op."""
-    import bench
     from pinot_tpu.clients import connect_url
     from pinot_tpu.multistage import device_join
+    from pinot_tpu.tools import corpus
 
     def in_use(d):     # None where the backend reports no memory stats
         return (d.memory_stats() or {}).get("bytes_in_use")
 
-    by_id = {q[0]: q for q in bench.QUERIES + [DGB_SPEC]}
+    by_id = {q[0]: q for q in corpus.SSB_QUERIES + [DGB_SPEC]}
     conn = connect_url(nodes[0].url, timeout=1100.0)
     for qid, strategy, route in MESH_QUERIES:
         run_mesh_query(conn, host_segs, by_id[qid], strategy, route)

@@ -144,7 +144,7 @@ def aggregate_tables(records: List[Dict[str, Any]]) -> Dict[str, Any]:
             e["t_min"] = ts if e["t_min"] is None else min(e["t_min"], ts)
             e["t_max"] = ts if e["t_max"] is None else max(e["t_max"], ts)
     # latest ingest freshness per table (the freshness ledger); round 16
-    # writers (engine/loadgen, bench_ingest) also carry the sustained-run
+    # writers (engine/loadgen) also carry the sustained-run
     # percentiles — trended per table when present
     freshness: Dict[str, float] = {}
     fresh_pctl: Dict[str, Dict[str, float]] = {}

@@ -6,7 +6,7 @@ chaos substrate — six fault points, recovery muscle, the
 drives it to a drained stream, but nothing exercised the RATE half:
 freshness under sustained multi-partition pressure WHILE a concurrent
 query mix runs, chaos armed. This module is that closed-loop harness,
-the robustness analogue of what bench.py's query loop is for latency:
+the robustness analogue of what a query benchmark is for latency:
 
 - **producers** push seeded row sequences into real wire-protocol
   stream backends (the kafka / kinesis / pulsar protocol fakes, the
@@ -34,8 +34,8 @@ The summary dict is shaped for the validated ``ingest_bench`` ledger
 kind (utils/ledger.py); ``write_ingest_bench`` appends it, and each
 table also lands an ``ingest_stats`` record carrying its freshness
 percentiles so the round-14 fleet rollup trends them per table.
-Consumers: bench_ingest.py (the CLI bench), tools/freshness_gate.py
-(the ratchet's capture corpus), tools/chaos_smoke.py --rate and
+Consumers: tools/freshness_gate.py (the ratchet's capture corpus),
+tools/chaos_smoke.py --rate (the CLI) and
 tests/test_ingest_bench.py.
 """
 from __future__ import annotations
@@ -850,8 +850,8 @@ def _jax_backend() -> str:
 
 
 def run_load(data_dir: str, config: LoadgenConfig) -> Dict[str, Any]:
-    """Build, run, tear down. The one-call entry point the bench, the
-    freshness gate's capture corpus and the smoke tests share. With
+    """Build, run, tear down. The one-call entry point the freshness
+    gate's capture corpus and the smoke tests share. With
     ``config.ledger_path`` set, the summary lands as one validated
     ``ingest_bench`` record plus one per-table ``ingest_stats`` record
     (freshness percentiles included) before teardown."""

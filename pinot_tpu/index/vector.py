@@ -39,8 +39,8 @@ the matrix — analysis/concur CC205) is now a ``_build_lock`` held
 across the whole build+upload with a re-check inside, publish under
 ``_res_lock``.
 
-bench_vector.py measures the flat path at 1M x 128d and the IVF path
-(``--ivf``: recall@10 / QPS vs the exact scan) into the capture log.
+No benchmark cell measures the flat or the IVF path on the chip yet
+(PERF.md section 7, ``vector_10m_1chip``).
 """
 from __future__ import annotations
 
@@ -103,7 +103,7 @@ def default_n_lists(n_docs: int) -> int:
 
 def default_nprobe(n_lists: int) -> int:
     """Probe ~1/32 of the lists by default — the recall/QPS knee the
-    bench's nprobe sweep documents (recall ~0.98 at ~5x the exact
+    round 19's CPU nprobe sweep found (recall ~0.98 at ~5x the exact
     scan's QPS on the CPU smoke with balanced lists; raise per query
     via the 4th VECTOR_SIMILARITY argument when recall matters more)."""
     return max(1, (n_lists + 31) // 32)
@@ -290,7 +290,7 @@ def _batched_flat_kernel(metric: str, k_pad: int, n_docs: int,
     construction. ``dim``/``b_pad`` are cache-key-only (the jit
     re-specializes per input shape anyway): every XLA compile lands on
     a cold cache slot, so ``vector_kernel_compiles`` counts real
-    compiles and the bench's zero-post-warmup-retrace gate can pin
+    compiles and a zero-post-warmup-retrace check can pin
     it."""
     import jax
     import jax.numpy as jnp

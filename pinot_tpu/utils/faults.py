@@ -104,7 +104,7 @@ by the in-process broker) and use ``match`` — the match filter tests
 the COMPOSITE ``qid|site-key`` stream name. Note that p<1 draws hash
 the stream name, so cross-run reproducibility of probabilistic specs
 at query-context sites requires deterministically named query ids
-(chaos tooling — chaos_smoke, engine/loadgen, bench_ingest — names
+(chaos tooling — chaos_smoke, engine/loadgen — names
 them); ``p=1``/``times``/``after`` specs are reproducible regardless,
 because the per-stream counters do not depend on the id's value.
 

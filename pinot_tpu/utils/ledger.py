@@ -1,9 +1,9 @@
 """Unified perf ledger: ONE versioned JSONL schema for every writer.
 
-Before round 7 three writers appended ad-hoc shapes to one JSONL file
-(bench_common.ledger_append, bench_common.ledger_append_raw for
-tools/profile_compact.py, and bench_vector/bench_taxi through finish()),
-so nothing could validate the history or diff captures field-for-field.
+Before round 7 the writers appended ad-hoc shapes to one JSONL file, so
+nothing could validate the history or diff captures field-for-field.
+``bench_capture``, ``multistage_bench`` and ``vector_bench`` lost their
+writer with the pre-chip harness (PR 31); they validate old captures.
 Now every line is a **v2 record**: common envelope
 ``{"v": 2, "ts": ..., "kind": ...}`` plus a per-kind field contract
 below. tools/check_ledger.py validates the whole file (tier-1 runs it);
@@ -11,9 +11,9 @@ lines WITHOUT a ``v`` field are grandfathered pre-v2 history and only
 parse-checked.
 
 Kinds:
-- ``bench_capture``    — bench.py / bench_vector.py / bench_taxi.py
-  headline summaries (metric, value, vs_baseline, per-query detail).
-- ``phase_profile``    — tools/profile_compact.py (ops/phase_profile.py)
+- ``bench_capture``    — the old harness's headline summaries (metric,
+  value, vs_baseline, per-query detail); no writer since PR 31.
+- ``phase_profile``    — ops/phase_profile.py, OPTION(profilePhases=true):
   kernel phase decompositions (mask/fuse/compact/sort/aggregate/
   transfer) with the cost-model trace.
 - ``query_trace``      — utils/spans.py span trees (EXPLAIN ANALYZE /
@@ -29,7 +29,7 @@ Kinds:
   freshness ledger (rows/sec, end-to-end freshness ms, commit retries,
   rebalance/replay/orphan recovery counts, faults fired) — the ingest
   plane's first-class counterpart to query latency.
-- ``ingest_bench``     — bench_ingest.py / pinot_tpu/engine/loadgen.py
+- ``ingest_bench``     — pinot_tpu/engine/loadgen.py write_ingest_bench:
   sustained ingest-while-query harness headlines (rows/s per partition,
   freshness p50/p99, commit latency, query p50/p99 under ingest
   pressure, chaos seed, batched flag) — tools/freshness_gate.py
@@ -37,9 +37,9 @@ Kinds:
 - ``replay_bench``     — tools/traffic_replay.py closed-loop overload
   replay gate headlines (goodput at N x recorded load, shed counts by
   tenant/rung, per-tier p50/p99, shed-stream determinism, recovery
-  back to the pre-spike baseline) — chaos_smoke --overload and the
-  bench_common.finish() overload gate consume these.
-- ``vector_bench``     — bench_vector.py ``--ivf`` vector-search
+  back to the pre-spike baseline) — chaos_smoke --overload consumes
+  these.
+- ``vector_bench``     — the old harness's ``--ivf`` vector-search
   headlines (rows/dim/k/nprobe, recall@10 vs the exact numpy oracle,
   IVF vs exact-scan QPS, latency percentiles, batched-equality and
   zero-retrace flags, vector-pool reconciliation) — the recall/QPS
@@ -115,7 +115,7 @@ SCHEMA_VERSION = 2
 KINDS: Dict[str, Dict[str, set]] = {
     "bench_capture": {
         # concurrency/qps*/p50_ms/p99_ms/fused_ratio/solo_latency_ratio:
-        # the concurrent-QPS mode (bench.py --concurrency N, PR 8) —
+        # the old harness's concurrent-QPS mode (PR 8; no writer now) —
         # queries/sec through the broker with cross-query micro-batching
         # fused vs the serial per-query dispatch path, so throughput
         # trends in this ledger the way latency always has
@@ -200,8 +200,8 @@ KINDS: Dict[str, Dict[str, set]] = {
                      "freshness_p50_ms", "freshness_p99_ms"},
     },
     "ingest_bench": {
-        # one sustained ingest-while-query harness run (bench_ingest.py
-        # / pinot_tpu/engine/loadgen.py): multi-partition ingest through
+        # one sustained ingest-while-query harness run
+        # (pinot_tpu/engine/loadgen.py): multi-partition ingest through
         # the wire-protocol consumers concurrent with a broker query
         # mix, chaos-armed — the freshness-vs-throughput headline the
         # way bench_capture is the latency headline. ``scenario`` keys
@@ -248,7 +248,7 @@ KINDS: Dict[str, Dict[str, set]] = {
                      "structured_429", "error", "extra"},
     },
     "multistage_bench": {
-        # one bench.py --multistage capture: the join+window+set-op SSB
+        # the old harness's multistage capture: the join+window+set-op SSB
         # mix through BOTH planes. ``qps_fused`` runs whole-plan mesh
         # compilation (multistage/fused.py), ``qps_mailbox`` the same
         # statements forced OPTION(multistageFused=false) with device
@@ -266,7 +266,7 @@ KINDS: Dict[str, Dict[str, set]] = {
                      "extra"},
     },
     "vector_bench": {
-        # one bench_vector.py --ivf capture: ``recall_at_10`` is mean
+        # the old harness's --ivf capture: ``recall_at_10`` is mean
         # |ivf top-10 ∩ exact top-10| / 10 over the query draw at the
         # DEFAULT nprobe; ``qps_ratio`` = qps_ivf / qps_exact (the
         # same-data exact full-matrix device scan); ``p50_ms/p99_ms``

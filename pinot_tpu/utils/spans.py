@@ -3,8 +3,8 @@
 Reference parity: Pinot's per-request ``Tracing``/``ServerQueryPhase``
 timers (pinot-spi trace SPI), generalized the way "Query Processing on
 Tensor Computation Runtimes" attributes tensor-runtime query time —
-plan -> compile -> phase -> transfer — so the engine is tunable without
-hand-running tools/profile_compact.py.
+plan -> compile -> phase -> transfer — so the engine is tunable from
+the tree a query brings back (EXPLAIN ANALYZE, traceRatio).
 
 Unlike utils/trace.py (flat phase wall-ms for the response envelope,
 kept for API parity), spans form a TREE: each span has a name, wall-ms

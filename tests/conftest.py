@@ -4,6 +4,7 @@ Mirrors the driver's multi-chip dry-run environment: sharding/collective
 tests exercise a jax.sharding.Mesh over 8 virtual CPU devices
 (xla_force_host_platform_device_count), per SURVEY.md build notes.
 """
+import collections
 import os
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -38,6 +39,25 @@ def pytest_configure(config):
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(42)
+
+
+@pytest.fixture
+def count_calls(monkeypatch):
+    """``count_calls(owner, *names)`` wraps ``owner.<name>`` for the
+    length of a test so that every call is counted, and returns the one
+    ``collections.Counter`` (keyed by name) that all its wrappers share.
+    Overhead contracts are held this way, by work done: a ratio of two
+    CPU clocks under six xdist workers is not a gate (ROADMAP D10)."""
+    calls = collections.Counter()
+
+    def wrap(owner, *names):
+        for name in names:
+            def counted(*a, _real=getattr(owner, name), _name=name, **kw):
+                calls[_name] += 1
+                return _real(*a, **kw)
+            monkeypatch.setattr(owner, name, counted)
+        return calls
+    return wrap
 
 
 @pytest.fixture(autouse=True)

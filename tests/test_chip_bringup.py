@@ -1,11 +1,10 @@
-"""What the chip bring-up added: the smoke refuses a CPU, the benches
-refuse a CPU, the compile cache is placed from outside, and segment
-build workers never ask for the chip.
+"""What the chip bring-up added: the smoke refuses a CPU, the compile
+cache is placed from outside, and segment build workers never ask for
+the chip.
 
 Everything here runs on the CPU; interpreters that must start fresh are
 subprocesses (a few seconds each).
 """
-import json
 import os
 import re
 import subprocess
@@ -76,13 +75,6 @@ def test_chip_smoke_mesh_rehearsal_goes_through_the_serving_node():
     assert "16 segments, 4 a device; column shards on device ids " \
         "[0, 1, 2, 3]" in proc.stdout
     assert "multistage all_to_all join" in proc.stdout
-
-
-def test_bench_refuses_cpu_before_building_data():
-    proc = _run(["bench.py"], PINOT_BENCH_FORCE_CPU=None)
-    assert proc.returncode == 1
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert out["error"] == "no_tpu_backend" and "'cpu'" in out["detail"]
 
 
 _CACHE_PROBE = ("import pinot_tpu, jax; "

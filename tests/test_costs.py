@@ -175,12 +175,12 @@ from pinot_tpu.multistage.costs import (choose_group_strategy,  # noqa: E402
 from pinot_tpu.ops.ir import And, Cmp, Col, EqId, IdRange, InSet, \
     Or, TrueP  # noqa: E402
 
-SSB_ROWS = 1 << 27      # the 134M-row bench scale
+SSB_ROWS = 1 << 27      # SSB at 134M rows
 
 
 def _ssb_shape(qid):
     """(pred, param_values, col_cards, space, needs_sort, n_payloads)
-    mirroring bench.py's SSB query shapes."""
+    mirroring the corpus's SSB query shapes (tools/corpus.py)."""
     if qid == "q2.2":   # p_brand1 BETWEEN (8 of 1000) AND s_region eq
         pred = And((IdRange(0, 0, 1), EqId(1, 2)))
         params = [100, 107, 1]

@@ -27,8 +27,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
-import bench  # noqa: E402
-
 from pinot_tpu.broker.routing import make_selector  # noqa: E402
 from pinot_tpu.cluster import (BrokerNode, Controller,  # noqa: E402
                                ServerNode)
@@ -38,6 +36,7 @@ from pinot_tpu.cluster.http_util import http_json  # noqa: E402
 from pinot_tpu.segment import SegmentBuilder  # noqa: E402
 from pinot_tpu.spi import (DataType, FieldSpec, FieldType,  # noqa: E402
                            Schema, TableConfig)
+from pinot_tpu.tools import corpus  # noqa: E402
 from pinot_tpu.utils import faults  # noqa: E402
 from pinot_tpu.utils.metrics import global_metrics  # noqa: E402
 
@@ -527,8 +526,8 @@ def test_explain_survives_fault_and_deadline(cluster):
 
 @pytest.fixture(scope="module")
 def ssb_broker(tmp_path_factory):
-    seg = bench.build_segment(1 << 12,
-                              str(tmp_path_factory.mktemp("ssb_flt")))
+    seg = corpus.build_ssb_segment(1 << 12,
+                                   str(tmp_path_factory.mktemp("ssb_flt")))
     from pinot_tpu.broker import Broker
     from pinot_tpu.server import TableDataManager
     dm = TableDataManager("lineorder")
@@ -539,16 +538,16 @@ def ssb_broker(tmp_path_factory):
 
 
 def test_device_overflow_forced_retry_identical(ssb_broker):
-    by_id = {q[0]: q for q in bench.QUERIES}
+    by_id = {q[0]: q for q in corpus.SSB_QUERIES}
     _, preds, vexpr, gcols = by_id["q2.1"]
-    sql = bench.spec_to_sql(preds, vexpr, gcols) + \
+    sql = corpus.spec_to_sql(preds, vexpr, gcols) + \
         " OPTION(timeoutMs=300000,groupByStrategy=compact)"
-    baseline = bench._digest(ssb_broker.query(sql).rows)
+    baseline = corpus.digest(ssb_broker.query(sql).rows)
     r0 = _counter("compact_overflow_retries")
     plan = faults.install("seed=11; device.overflow: times=1")
     rows = ssb_broker.query(sql).rows
     faults.clear()
-    assert bench._digest(rows) == baseline
+    assert corpus.digest(rows) == baseline
     assert len(plan.fired) == 1
     assert _counter("compact_overflow_retries") == r0 + 1
 

@@ -14,8 +14,7 @@ Contract under test (ISSUE 9 acceptance):
   (a uniformly 3x-slower node never false-trips; one node's one-phase
   2x regression does, tagged with the node);
 - environment pinning: ``check`` fails loudly (exit 3) on a baseline/
-  environment mismatch and bench_common's gate surfaces it as an
-  explicit skip;
+  environment mismatch;
 - device-memory telemetry: ``GET /debug/memory`` live-byte gauges
   reconcile with cache entry counts across an eviction, for the
   segment-column, stack-cache and cube-cache pools.
@@ -566,24 +565,6 @@ def test_env_mismatch_fails_loudly(tmp_path, capsys):
         json.dump(data, fh)
     assert span_diff.main(["check", led, "--baseline", bad]) == 0
     capsys.readouterr()
-
-
-def test_bench_gate_surfaces_env_mismatch_as_skip(tmp_path):
-    import bench_common
-    led = str(tmp_path / "trace.jsonl")
-    _synth_traces(led, "x")
-    bad = str(tmp_path / "baseline.json")
-    with open(span_diff.DEFAULT_BASELINE) as fh:
-        data = json.load(fh)
-    data["env"] = {"jax_platforms": "tpu", "x64": True,
-                   "backend": "tpu"}
-    with open(bad, "w") as fh:
-        json.dump(data, fh)
-    gate = bench_common.span_regression_gate(
-        led, capture_if_empty=False, baseline_path=bad)
-    assert gate["ok"] is True
-    assert "environment mismatch" in gate["skipped"]
-    assert gate["env_mismatch"]
 
 
 def test_update_stamps_env_header(tmp_path, capsys):

@@ -248,8 +248,7 @@ def test_freshness_gate_saturated_calibration_skips(tmp_path, capsys):
 
 def test_freshness_gate_env_mismatch_exit_3(tmp_path, capsys):
     """The shared span_diff environment pin: a baseline captured on a
-    foreign backend fails LOUDLY with exit 3 (bench_common surfaces
-    it as an explicit skip)."""
+    foreign backend fails LOUDLY with exit 3."""
     bp = str(tmp_path / "b.json")
     FG.write_baseline(bp, {"gate_corpus": {
         "n": 3, "wall_s": 0.4, "metrics": dict(BASE_METRICS)}},
@@ -273,19 +272,6 @@ def test_freshness_gate_newest_records_win(tmp_path, capsys):
     _write_ledger(lp, 0.4, bad, n=5)                  # fresh regression
     assert FG.main(["check", str(lp), "--baseline", bp]) == 1
     capsys.readouterr()
-
-
-def test_bench_common_gate_maps_env_mismatch_to_skip(tmp_path):
-    import bench_common
-    bp = str(tmp_path / "b.json")
-    FG.write_baseline(bp, {"gate_corpus": {
-        "n": 3, "wall_s": 0.4, "metrics": dict(BASE_METRICS)}},
-        env={"jax_platforms": "tpu", "x64": False, "backend": "tpu"})
-    lp = str(tmp_path / "cand.jsonl")
-    _write_ledger(lp, 0.4, BASE_METRICS)
-    res = bench_common.freshness_regression_gate(
-        ledger_path=lp, capture_if_empty=False, baseline_path=bp)
-    assert res["ok"] and "environment mismatch" in res["skipped"]
 
 
 # ---------------------------------------------------------------------------

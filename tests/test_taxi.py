@@ -1,20 +1,15 @@
-"""NYC-taxi bench specs at CI scale vs the oracle (bench_taxi.py shares
-this harness; BASELINE.md config 4)."""
-import os
-import sys
-
+"""NYC-taxi specs at CI scale vs the numpy oracle (BASELINE.md config
+4)."""
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-import bench_taxi  # noqa: E402
+from pinot_tpu.tools import corpus
 
 N = 1 << 15
 
 
 @pytest.fixture(scope="module")
 def setup(tmp_path_factory, monkeypatch=None):
-    seg = bench_taxi.build_segment(N, str(tmp_path_factory.mktemp("taxi")))
+    seg = corpus.build_taxi_segment(N, str(tmp_path_factory.mktemp("taxi")))
     from pinot_tpu.broker import Broker
     from pinot_tpu.server import TableDataManager
 
@@ -25,13 +20,13 @@ def setup(tmp_path_factory, monkeypatch=None):
     return seg, b
 
 
-@pytest.mark.parametrize("qid,key,where", bench_taxi.QUERIES,
-                         ids=[q[0] for q in bench_taxi.QUERIES])
+@pytest.mark.parametrize("qid,key,where", corpus.TAXI_QUERIES,
+                         ids=[q[0] for q in corpus.TAXI_QUERIES])
 def test_taxi_query(setup, qid, key, where):
     seg, b = setup
-    sql = bench_taxi._sql(key, where)
-    oracle, _ = bench_taxi.oracle_run(seg, key, where)
-    res = b.query(sql + bench_taxi.OPTION)
+    sql = corpus.taxi_sql(key, where)
+    oracle = corpus.taxi_oracle(seg, key, where)
+    res = b.query(sql + corpus.OPTION)
     got = {int(r[0]): (int(r[1]), float(r[2])) for r in res.rows}
     assert set(got) == set(oracle)
     for k, (c, a) in oracle.items():

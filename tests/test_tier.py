@@ -39,6 +39,7 @@ from pinot_tpu.segment import SegmentBuilder  # noqa: E402
 from pinot_tpu.server import TableDataManager  # noqa: E402
 from pinot_tpu.spi import (DataType, FieldSpec, FieldType,  # noqa: E402
                            Schema, TableConfig)
+from pinot_tpu.tools import corpus  # noqa: E402
 from pinot_tpu.utils import ledger as uledger  # noqa: E402
 from pinot_tpu.utils.devmem import DeviceMemoryRegistry  # noqa: E402
 from pinot_tpu.utils.devmem import global_device_memory  # noqa: E402
@@ -175,9 +176,8 @@ def test_warm_budget_trims_hot_segments_stash(tmp_path):
     b.register_table(dm)
     global_tier.configure(budget_bytes=1 << 40)
     try:
-        import bench
-        by_id = {q[0]: q for q in bench.QUERIES}
-        sql = bench.spec_to_sql(*by_id["q1.1"][1:]) + \
+        by_id = {q[0]: q for q in corpus.SSB_QUERIES}
+        sql = corpus.spec_to_sql(*by_id["q1.1"][1:]) + \
             " OPTION(timeoutMs=300000)"
         rows = b.query(sql).rows
         segs = dm.acquire_segments()
@@ -205,15 +205,14 @@ def _ssb_broker(tmp, rows=512, n_segments=2):
 
 
 def test_digest_equal_hot_warm_cold(tmp_path):
-    import bench
     b, dm = _ssb_broker(tmp_path)
-    by_id = {q[0]: q for q in bench.QUERIES}
-    sql = bench.spec_to_sql(*by_id["q4.1"][1:]) + \
+    by_id = {q[0]: q for q in corpus.SSB_QUERIES}
+    sql = corpus.spec_to_sql(*by_id["q4.1"][1:]) + \
         " OPTION(timeoutMs=300000)"
     # arm an ample budget so warm host arrays are stashed
     global_tier.configure(budget_bytes=1 << 40)
     try:
-        hot = bench._digest([tuple(r) for r in b.query(sql).rows])
+        hot = corpus.digest([tuple(r) for r in b.query(sql).rows])
         segs = dm.acquire_segments()
         assert all(segment_tier(s) == TIER_HOT for s in segs)
         p0 = global_tier.promotions
@@ -221,14 +220,14 @@ def test_digest_equal_hot_warm_cold(tmp_path):
         for s in segs:
             assert global_tier.demote(s, TIER_WARM)
         assert all(segment_tier(s) == TIER_WARM for s in segs)
-        warm = bench._digest([tuple(r) for r in b.query(sql).rows])
+        warm = corpus.digest([tuple(r) for r in b.query(sql).rows])
         assert warm == hot
         assert global_tier.promotions >= p0 + len(segs)
         # demote to COLD: mmap only
         for s in segs:
             assert global_tier.demote(s, TIER_COLD)
         assert all(segment_tier(s) == TIER_COLD for s in segs)
-        cold = bench._digest([tuple(r) for r in b.query(sql).rows])
+        cold = corpus.digest([tuple(r) for r in b.query(sql).rows])
         assert cold == hot
         assert global_metrics.snapshot()["counters"].get(
             "tier_promotions", 0) > 0
@@ -247,8 +246,6 @@ def _total_uploads():
 
 
 def test_constrained_budget_beats_evict_all_uploads(tmp_path):
-    import bench
-
     # start from devmem-synced caches: earlier suite tests' cube/stack
     # entries survive the per-test accounting reset (conftest fixture
     # doc) and would fail the byte-exact reconcile through no fault of
@@ -264,10 +261,10 @@ def test_constrained_budget_beats_evict_all_uploads(tmp_path):
     b = Broker()
     b.register_table(dm)
     b.register_table(dm2)
-    by_id = {q[0]: q for q in bench.QUERIES}
+    by_id = {q[0]: q for q in corpus.SSB_QUERIES}
     mix = []
     for qid in ("q1.1", "q4.1"):
-        sql = bench.spec_to_sql(*by_id[qid][1:]) + \
+        sql = corpus.spec_to_sql(*by_id[qid][1:]) + \
             " OPTION(timeoutMs=300000)"
         mix.append((qid, "a", sql))
         mix.append((qid, "b", sql.replace("FROM lineorder ",
@@ -275,7 +272,7 @@ def test_constrained_budget_beats_evict_all_uploads(tmp_path):
     segs = dm.acquire_segments() + dm2.acquire_segments()
 
     def run_mix():
-        return {(qid, t): bench._digest([tuple(r)
+        return {(qid, t): corpus.digest([tuple(r)
                                          for r in b.query(sql).rows])
                 for qid, t, sql in mix}
 
@@ -290,7 +287,7 @@ def test_constrained_budget_beats_evict_all_uploads(tmp_path):
     straw = {}
     for qid, t, sql in mix:
         evict_all()
-        straw[qid, t] = bench._digest([tuple(r)
+        straw[qid, t] = corpus.digest([tuple(r)
                                        for r in b.query(sql).rows])
     straw_uploads = _total_uploads() - u0
     assert straw == base

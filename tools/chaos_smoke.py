@@ -86,12 +86,12 @@ OPTION = " OPTION(timeoutMs=300000)"
 
 def smoke_queries(qids=SMOKE_QUERY_IDS):
     """(qid, sql) for the smoke subset of the SSB suite."""
-    import bench
-    by_id = {q[0]: q for q in bench.QUERIES}
+    from pinot_tpu.tools import corpus
+    by_id = {q[0]: q for q in corpus.SSB_QUERIES}
     out = []
     for qid in qids:
         _, preds, vexpr, gcols = by_id[qid]
-        out.append((qid, bench.spec_to_sql(preds, vexpr, gcols)))
+        out.append((qid, corpus.spec_to_sql(preds, vexpr, gcols)))
     return out
 
 
@@ -101,14 +101,14 @@ def build_ssb_cluster(tmp: str, rows: int = 4096, n_segments: int = 4,
     (replication 2) and a ``lineorder_r1`` twin (replication 1) built
     from the same segment directories. Returns (ctrl, servers, broker,
     stop)."""
-    import bench
     from pinot_tpu.cluster import BrokerNode, Controller, ServerNode
     from pinot_tpu.segment import SegmentBuilder
     from pinot_tpu.segment.builder import Categorical
     from pinot_tpu.spi import Schema, TableConfig
+    from pinot_tpu.tools import corpus
 
-    cols = bench.gen_columns(rows)
-    fields = bench._ssb_fields(cols)
+    cols = corpus.ssb_columns(rows)
+    fields = corpus.ssb_fields(cols)
 
     ctrl = Controller(os.path.join(tmp, "ctrl"), heartbeat_timeout=5.0,
                       reconcile_interval=0.2)
@@ -157,8 +157,8 @@ def build_ssb_cluster(tmp: str, rows: int = 4096, n_segments: int = 4,
 
 
 def digest(resp: dict):
-    import bench
-    return bench._digest([tuple(r) for r in resp["resultTable"]["rows"]])
+    from pinot_tpu.tools import corpus
+    return corpus.digest([tuple(r) for r in resp["resultTable"]["rows"]])
 
 
 def _iter_kind(path: str, kind: str):
@@ -288,16 +288,16 @@ TIER_ROWS = 2048
 def build_ssb_table(tmp: str, rows: int, n_segments: int = 4,
                     table: str = "lineorder", seg_prefix: str = "seg_"):
     """In-process SSB-lite table: ``n_segments`` segments split from
-    one seeded bench.gen_columns draw. Returns (TableDataManager,
+    one seeded corpus.ssb_columns draw. Returns (TableDataManager,
     segment dirs)."""
-    import bench
     from pinot_tpu.segment import SegmentBuilder
     from pinot_tpu.segment.builder import Categorical
     from pinot_tpu.server import TableDataManager
     from pinot_tpu.spi import Schema, TableConfig
+    from pinot_tpu.tools import corpus
 
-    cols = bench.gen_columns(rows)
-    schema = Schema(table, bench._ssb_fields(cols))
+    cols = corpus.ssb_columns(rows)
+    schema = Schema(table, corpus.ssb_fields(cols))
     builder = SegmentBuilder(schema, TableConfig(table))
     dm = TableDataManager(table)
     step = rows // n_segments
@@ -319,9 +319,9 @@ def main_tier(args) -> int:
     ``tier.evict`` demotion recovers byte-exact with same-seed
     determinism, and a constrained budget demotes coldest-first with
     every devmem pool reconciling to the byte."""
-    import bench
     from pinot_tpu.broker import Broker
     from pinot_tpu.engine.tier import global_tier, reconcile_devmem
+    from pinot_tpu.tools import corpus
     from pinot_tpu.utils import faults
     from pinot_tpu.utils.devmem import global_device_memory
     from pinot_tpu.utils.metrics import global_metrics
@@ -373,7 +373,7 @@ def main_tier(args) -> int:
                 res = broker.query(
                     sql + f" OPTION(timeoutMs=300000,"
                           f"queryId=tier.{tag}.{qid})")
-                out[qid] = bench._digest([tuple(r) for r in res.rows])
+                out[qid] = corpus.digest([tuple(r) for r in res.rows])
             return out
 
         baseline = run_all("base")
@@ -437,8 +437,7 @@ def main_tier(args) -> int:
               "constrained budget never demoted")
         # the four pools this gate resets at start; plan_cache_acc is
         # suite-wide compile warmth (donated buffers, TPU only) whose
-        # accounting a warm pytest process has already zeroed — the
-        # fresh-process bench covers all five
+        # accounting a warm pytest process has already zeroed
         rec = reconcile_devmem(
             dm.acquire_segments() + dm2.acquire_segments(),
             pools=("segment_cols", "stack_cache", "cube_cache",
@@ -481,14 +480,14 @@ def build_rebalance_cluster(tmp: str, rows: int, poll: float = 0.1):
     then server_1 joins and the protected ``lineorder_s`` twin (2
     segments) lands on it least-loaded. Returns (ctrl, servers,
     broker, stop)."""
-    import bench
     from pinot_tpu.cluster import BrokerNode, Controller, ServerNode
     from pinot_tpu.segment import SegmentBuilder
     from pinot_tpu.segment.builder import Categorical
     from pinot_tpu.spi import Schema, TableConfig
+    from pinot_tpu.tools import corpus
 
-    cols = bench.gen_columns(rows)
-    fields = bench._ssb_fields(cols)
+    cols = corpus.ssb_columns(rows)
+    fields = corpus.ssb_fields(cols)
     ctrl = Controller(os.path.join(tmp, "ctrl"), heartbeat_timeout=5.0,
                       reconcile_interval=0.2)
     servers = [ServerNode("server_0", ctrl.url, poll_interval=poll)]
@@ -1561,8 +1560,7 @@ def main_rate(args) -> int:
               and lres["kinds"].get("ingest_stats", 0) >= 2,
               f"kinds={lres['kinds']}")
         # (c) the freshness ratchet: fresh fault-free gate-corpus
-        # capture checked against the checked-in baseline (the same
-        # check bench_common.finish() runs on every bench capture)
+        # capture checked against the checked-in baseline
         gate_ledger = os.path.join(tmp, "gate_corpus.jsonl")
         try:
             FG.capture(gate_ledger, iters=args.gate_iters)

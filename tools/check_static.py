@@ -22,8 +22,8 @@ alongside tools/check_ledger.py):
    tools/traffic_replay.py), ratcheted against
    tools/detlint_baseline.json.
 4. **Plan verifier** (analysis/plan_verify.py) over every plan the
-   planner produces for the full SSB query set (bench.QUERIES), the
-   NYC-taxi set (bench_taxi.QUERIES), and ``--fuzz N`` seeded
+   planner produces for the full SSB query set (corpus.SSB_QUERIES), the
+   NYC-taxi set (corpus.TAXI_QUERIES), and ``--fuzz N`` seeded
    fuzzer-generated queries (pinot_tpu/tools/fuzzer.py) — all at CI
    scale, plan-only (no kernels execute). Any diagnostic fails.
 
@@ -203,28 +203,27 @@ def run_verify(fuzz_n: int) -> dict:
 
 
 def _run_verify(fuzz_n: int) -> dict:
-    import bench
-    import bench_taxi
+    from pinot_tpu.tools import corpus
     from pinot_tpu.tools.fuzzer import (QueryGenerator,
                                         build_fuzz_segment, render_sql)
 
     corpora: dict = {}
     diags: list = []
     with tempfile.TemporaryDirectory() as tmp:
-        seg = bench.build_segment(1 << 12, os.path.join(tmp, "ssb"))
+        seg = corpus.build_ssb_segment(1 << 12, os.path.join(tmp, "ssb"))
         corpora["ssb"] = {"queries": 0, "plans": 0, "skipped": 0}
         _verify_corpus(
             "ssb", seg,
-            [bench.spec_to_sql(p, v, g) + bench.OPTION
-             for _q, p, v, g in bench.QUERIES],
+            [corpus.spec_to_sql(p, v, g) + corpus.OPTION
+             for _q, p, v, g in corpus.SSB_QUERIES],
             corpora["ssb"], diags)
 
-        seg_t = bench_taxi.build_segment(1 << 12, os.path.join(tmp, "taxi"))
+        seg_t = corpus.build_taxi_segment(1 << 12, os.path.join(tmp, "taxi"))
         corpora["taxi"] = {"queries": 0, "plans": 0, "skipped": 0}
         _verify_corpus(
             "taxi", seg_t,
-            [bench_taxi._sql(k, w) + bench_taxi.OPTION
-             for _q, k, w in bench_taxi.QUERIES],
+            [corpus.taxi_sql(k, w) + corpus.OPTION
+             for _q, k, w in corpus.TAXI_QUERIES],
             corpora["taxi"], diags)
 
         seg_f = build_fuzz_segment(2000, tmp)

@@ -3,7 +3,7 @@ against a checked-in baseline — the ingest plane's ratchet, built the
 way tools/span_diff.py ratchets query phases.
 
 Round 11 gave freshness a ledger (``ingest_stats``) and round 16 gives
-it a benchmark (bench_ingest.py / pinot_tpu/engine/loadgen.py); this
+it a load harness (pinot_tpu/engine/loadgen.py); this
 tool gives it the regression BAR the ROADMAP demands ("a regression bar
 on freshness like the >=5x SSB bar"):
 
@@ -32,9 +32,8 @@ sub-floor-vs-sub-floor jitter from tripping while still catching a
 tiny metric regressing to something large (the span_diff floor rule).
 
 Environment pinning reuses span_diff's header verbatim: ``update``
-stamps JAX_PLATFORMS/x64/backend, ``check`` exits 3 on a mismatch, and
-bench_common.freshness_regression_gate surfaces that as an explicit
-skip. Re-capture the baseline in the FULL tier-1 environment
+stamps JAX_PLATFORMS/x64/backend and ``check`` exits 3 on a mismatch.
+Re-capture the baseline in the FULL tier-1 environment
 (JAX_PLATFORMS=cpu PINOT_CPU_FAST_GROUPBY=0
 XLA_FLAGS=--xla_force_host_platform_device_count=8), same as the span
 baseline.
@@ -46,8 +45,7 @@ baseline.
 Exit 0 green / 1 regression / 2 usage / 3 environment mismatch; one
 summary JSON line last, check_ledger-style. tier-1 runs capture+check
 through tools/chaos_smoke.py --rate (tests/test_faults.py) and the
-synthetic trip/calibration tests in tests/test_ingest_bench.py;
-bench_common.finish() runs check on every bench capture.
+synthetic trip/calibration tests in tests/test_ingest_bench.py.
 """
 from __future__ import annotations
 

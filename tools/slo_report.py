@@ -18,10 +18,10 @@ the same corpus yields the same verdict byte-for-byte) and gates it:
 remaining, event/bad counts) for every table in the corpus plus the
 recorded slo_status/alert/incident counts, one summary JSON line last.
 
-``gate`` is the ratchet bench_common.finish() runs as the FIFTH gate
-beside span / freshness / overload / warmup: any objective whose slow-
-window burn reaches the threshold — i.e. the bench corpus itself would
-have paged — fails with exit 1 and ``GATE FAIL:`` lines. ``--min-events``
+``gate`` is the ratchet beside span / freshness / overload / warmup
+(tests/test_slo.py runs it): any objective whose slow-window burn
+reaches the threshold — i.e. the corpus itself would have paged —
+fails with exit 1 and ``GATE FAIL:`` lines. ``--min-events``
 (default 1) guards the structurally vacuous green: a corpus with no
 ``query_stats`` records means the forensics plane is broken, not that
 the SLOs are healthy.

@@ -4,8 +4,7 @@ ledger records, diffed against a checked-in per-query-shape baseline.
 The round-7/10/12 observability stack lands span trees in the ledger
 (EXPLAIN ANALYZE, OPTION(ledgerTrace=true), and traceRatio production
 sampling); until now a perf regression sat in those records until a
-human ran a bench round. This tool closes that loop, jaxlint-ratchet
-style:
+human read them. This tool closes that loop, jaxlint-ratchet style:
 
 - ``capture``  runs a small deterministic query corpus (in-process
   broker, seeded 2-segment table, traceRatio=1.0) and appends one
@@ -24,8 +23,8 @@ shift (machine load, different host) moves every wall equally and
 cancels; a single phase regressing 2x in one shape barely moves the
 cross-shape median, so it trips the bar. (A regression hitting the
 dominant phase of EVERY shape at once would be absorbed into the
-calibration — that class is what bench.py's vs_baseline wall gate is
-for.) Candidate phases below ``--min-ms`` are skipped and sub-ms
+calibration — that class is what the chip benchmark, benchmark/run.py,
+is for.) Candidate phases below ``--min-ms`` are skipped and sub-ms
 baselines are floored at ``--min-ms`` (sub-ms-vs-sub-ms jitter cannot
 trip the bar, but a tiny phase regressing to something large still
 does), and medians over the capture iterations absorb per-run jitter.
@@ -49,8 +48,8 @@ environment (JAX_PLATFORMS, jax_enable_x64, backend) into the baseline
 header, and ``check`` FAILS LOUDLY (exit 3) when the current
 environment differs — baselines captured outside the tier-1 env
 (JAX_PLATFORMS=cpu, x64 on) silently miscalibrated every phase before.
-bench_common.span_regression_gate surfaces exit 3 as an explicit
-"environment mismatch" skip rather than a phase regression.
+Exit 3 is distinct so a caller can tell an environment mismatch from a
+phase regression.
 
 Fleet mode (round 14): ``check --fleet`` groups a fleet ledger's
 ``query_trace`` records by their ``node`` provenance stamp
@@ -61,8 +60,7 @@ phase regressing still does.
 
 Exit 0 when no phase regresses; one summary JSON line last,
 check_ledger-style. tier-1 runs capture+update+check through
-tests/test_perf_forensics.py; bench_common.finish() runs check over the
-repo ledger so a bench capture fails loudly on a span regression.
+tests/test_perf_forensics.py.
 """
 from __future__ import annotations
 
@@ -466,8 +464,7 @@ def main(argv=None) -> int:
         # fail LOUDLY instead of silently miscalibrating: a cpu-captured
         # baseline checked on a tpu backend (or x64 flipped) makes every
         # per-phase ratio meaningless. Distinct exit code so callers
-        # (bench_common.span_regression_gate) can surface the skip
-        # without reading it as a phase regression.
+        # can surface the skip without reading it as a phase regression.
         print("ENVIRONMENT MISMATCH vs baseline "
               f"{os.path.basename(args.baseline)}: "
               + "; ".join(f"{k}: baseline={b!r} current={c!r}"

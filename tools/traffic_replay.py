@@ -3,7 +3,7 @@
 ROADMAP direction 3 named the missing half of the millions-of-users
 story: replay real query mixes "at replayable multiples against a
 scaling cluster, with per-tenant accountant budgets enforcing QoS — the
-millions-of-users benchmark bench.py can't express". This harness is
+millions-of-users benchmark a query loop can't express". This harness is
 that loop, closed end to end:
 
 1. **Record** — a seeded three-tenant query mix (``protected`` /
@@ -38,9 +38,8 @@ that loop, closed end to end:
    floor — no metastable retry-storm state.
 
 The summary lands as one validated ``replay_bench`` ledger record
-(utils/ledger.py). Consumers: ``tools/chaos_smoke.py --overload``
-(tier-1, cluster mode) and ``bench_common.finish()``'s overload gate
-(local mode).
+(utils/ledger.py). Consumer: ``tools/chaos_smoke.py --overload``
+(tier-1, cluster mode); ``--mode local`` is the fast in-process form.
 
     python tools/traffic_replay.py gate [--multiple 4] [--seed N]
         [--queries 48] [--mode cluster|local] [--no-chaos]
@@ -250,8 +249,8 @@ def build_cluster(tmp: str, rows: int = 4096, poll: float = 0.1):
 
 
 def build_local(tmp: str, rows: int = 4096):
-    """In-process Broker hosting the same tenant tables (the
-    bench_common overload gate's fast mode)."""
+    """In-process Broker hosting the same tenant tables (``--mode
+    local``, the fast mode)."""
     from pinot_tpu.broker import Broker
     from pinot_tpu.segment import SegmentBuilder
     from pinot_tpu.server import TableDataManager

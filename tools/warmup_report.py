@@ -17,11 +17,11 @@ ms, trigger breakdown, warmup cost = compiles x median — the same
 ranking cluster/rollup.py ships as ``fleet_rollup.plan_shapes``) plus
 the per-trigger and per-site totals, one summary JSON line last.
 
-``gate`` is the ratchet bench_common.finish() runs beside the span /
-freshness / overload gates: post-warmup compiles (trigger retrace or
+``gate`` is the ratchet beside the span / freshness / overload gates
+(tests/test_compile_forensics.py runs it): post-warmup compiles (trigger retrace or
 lru_evict_rebuild) above ``--max-post-warmup`` (default 0) fail with
 exit 1 — a warmed engine paying unexplained compiles is the compile
-storm's leading indicator, caught at bench time instead of as a silent
+storm's leading indicator, caught at test time instead of as a silent
 QPS cliff. ``--min-events`` (default 1) guards against a structurally
 vacuous green: a gate corpus that emitted NO compile events means the
 instrumentation is broken, not that warmup debt is zero.
