@@ -399,6 +399,17 @@ def run_kernel(plan: CompiledPlan, xfer_compact: bool = True,
                 host = entry.run(cols, n, params)
             host.pop("overflow", None)
             annotate(group_overflow_retry=True)
+        from ..ops.kernels import (cpu_scatter_default, sparse_post_probes,
+                                   takes_sparse_post)
+        if "group_idx" in host and takes_sparse_post(
+                plan.kernel_plan, xfer_compact, cpu_scatter_default()):
+            # which rung of its probe ladder the sparse post's tail took:
+            # the host holds group_idx, so it applies the kernel's rule
+            n_live = int(np.count_nonzero(  # jaxlint: ok host-sync
+                host["group_idx"] < plan.kernel_plan.group_space))
+            global_metrics.count("sparse_post_results")
+            global_metrics.count(
+                f"sparse_post_probes_{sparse_post_probes(n_live)}")
         from ..query.planner import _truthy
         from ..utils.spans import tracing_active
         if tracing_active() and _truthy(

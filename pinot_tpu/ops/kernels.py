@@ -38,6 +38,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..utils import phases as ph
 from .ir import (AggSpec, And, Bin, Case, Cmp, Col, EqId, FalseP, Func,
@@ -1006,18 +1007,27 @@ def _post_sizes(cap_rows: int, step: int = 8,
     return sorted(set(sizes))
 
 
+def _ladder_index(sizes: List[int], n_valid, xp=jnp):
+    """Index of the smallest ladder size (slot rows of LANES elements)
+    whose element capacity covers n_valid; the largest where none does.
+    ``xp`` is jnp under trace and numpy where the host applies the same
+    rule to a count it holds (sparse_post_probes)."""
+    from .compact import LANES
+
+    if not sizes[:-1]:
+        return xp.int32(0)
+    thresholds = xp.asarray([s * LANES for s in sizes[:-1]],
+                            dtype=xp.int32)
+    return xp.sum((thresholds < n_valid).astype(xp.int32))
+
+
 def _ladder_switch(sizes: List[int], n_valid, make_branch,
                    extra_branch=None, extra_when=None):
     """Dispatch the post-aggregation at the smallest ladder size whose
     element capacity covers n_valid. extra_branch (with its extra_when
     device predicate) appends an override branch — the two-pass path's
     pass-1 fallback on pass-2 overflow."""
-    from .compact import LANES
-
-    thresholds = jnp.asarray([s * LANES for s in sizes[:-1]],
-                             dtype=jnp.int32)
-    idx = jnp.sum((thresholds < n_valid).astype(jnp.int32)) \
-        if sizes[:-1] else jnp.int32(0)
+    idx = _ladder_index(sizes, n_valid)
     branches = [make_branch(s) for s in sizes]
     if extra_branch is not None:
         idx = jnp.where(extra_when, jnp.int32(len(sizes)), idx)
@@ -1480,6 +1490,26 @@ def _sorted_post(sum_jobs, mm_jobs, ord_modes, keys, valid, payloads,
             _extreme(acc, 1 if spec.kind == "min" else -1))
 
 
+def _sparse_post_sizes(cap: int) -> List[int]:
+    """Probe-count ladder of the sparse post's per-group tail, in slot
+    rows of LANES probes: 512 / 4,096 / 32,768 probes at GROUP_XFER_CAP."""
+    from .compact import LANES
+    return _post_sizes(cap // LANES, min_rows=4)
+
+
+def sparse_post_probes(n_live) -> int:
+    """Probe count the sparse post's tail runs at for ``n_live`` live
+    groups: the kernel's own rule (_ladder_index over _sparse_post_sizes)
+    applied by the host to a count it holds, so the counters
+    sparse_post_probes_<P> (engine/executor.run_kernel) cannot fork from
+    the branch the device took."""
+    from .compact import LANES
+    sizes = _sparse_post_sizes(GROUP_XFER_CAP)
+    # numpy over a host count — never a device value
+    idx = int(_ladder_index(sizes, n_live, np))  # jaxlint: ok host-sync
+    return sizes[idx] * LANES
+
+
 def _sorted_post_sparse(sum_jobs, mm_jobs, ord_modes, keys, valid, payloads,
                         space: int, cap: int,
                         out: Dict[str, jax.Array]) -> None:
@@ -1491,12 +1521,19 @@ def _sorted_post_sparse(sum_jobs, mm_jobs, ord_modes, keys, valid, payloads,
     kernel (space-sized searchsorted probes + several (space,) arrays
     for ~13 live groups). Here run boundaries come from the sorted
     keys themselves: first-occurrence flags -> unique ranks -> one
-    searchsorted of cap probes over the rank vector, so every output
-    is (cap,) and cost scales with the compacted rows, not the space.
+    searchsorted of the rank vector. What the cost follows: the sort,
+    the flags and the cumsums the compacted rows; the per-group tail
+    (the boundary search and every gather behind it) the LIVE GROUPS.
+    The tail runs at a probe count picked on the device from n_live
+    (_sparse_post_sizes: a searchsorted is log2(rows) serial gathers of
+    one element a probe, so cap probes for a few hundred live groups
+    was most of the kernel) and pads its rows to (cap,).
     Output contract matches _compact_group_xfer exactly (group_idx
     holds dense space ids, sentinel rows carry count 0, group_overflow
     flags >cap live groups for the dense retry), so extraction and the
     batched dispatch are oblivious to which path produced it."""
+    from .compact import LANES
+
     acc_f = float_acc_dtype()
     cnt_dtype = int_acc_dtype()
 
@@ -1514,49 +1551,82 @@ def _sorted_post_sparse(sum_jobs, mm_jobs, ord_modes, keys, valid, payloads,
     ranks = chunked_cumsum(uniq.astype(jnp.int32)).astype(jnp.int32)
     n_live = ranks[-1]
     n_matched = jnp.searchsorted(sk, jnp.int32(space)).astype(jnp.int32)
-    rids = jnp.arange(1, cap + 1, dtype=jnp.int32)
-    starts = jnp.searchsorted(ranks, rids, side="left").astype(jnp.int32)
-    ends = jnp.minimum(
-        jnp.searchsorted(ranks, rids, side="right").astype(jnp.int32),
-        n_matched)
-    alive = rids <= n_live
-    out["group_idx"] = jnp.where(
-        alive, sk.at[jnp.minimum(starts, n_rows - 1)].get(mode="clip"),
-        jnp.int32(space))
-    counts = jnp.where(alive, (ends - starts).astype(cnt_dtype), 0)
-    out["group_count"] = counts
-    out["group_overflow"] = (n_live > cap).astype(jnp.int32)
 
-    sums_done: Dict[Tuple[int, bool], jax.Array] = {}
-    for i, spec, slot in sum_jobs:
-        name = _agg_name(i, spec)
-        s = sums_done.get((slot, spec.integral))
-        if s is None:
+    # everything whose cost goes by rows stays out here: a tail branch
+    # holds one search and a handful of probe-sized gathers
+    sums_cs: Dict[Tuple[int, bool], jax.Array] = {}
+    for _i, spec, slot in sum_jobs:
+        if (slot, spec.integral) not in sums_cs:
             dtype = int_acc_dtype() if spec.integral else acc_f
             sv = sorted_ops[base + sum_slots.index(slot)]
-            cs = jnp.concatenate(
+            sums_cs[(slot, spec.integral)] = jnp.concatenate(
                 [jnp.zeros(1, dtype), chunked_cumsum(sv.astype(dtype))])
-            s = cs[ends] - cs[starts]
-            sums_done[(slot, spec.integral)] = s
-        if spec.kind == "avg":
-            out[name + "_sum"] = s
-            out[name + "_cnt"] = counts
-        else:
-            out[name] = s
-
     sorted_orderable = _sorted_orderables(keys, payloads, mm_slots,
                                           sorted_ops)
-    pos_min = jnp.minimum(starts, n_rows - 1)
-    pos_max = jnp.clip(ends - 1, 0, n_rows - 1)
-    for i, spec, slot in mm_jobs:
-        name = _agg_name(i, spec)
-        pos = pos_min if spec.kind == "min" else pos_max
-        picked = sorted_orderable[slot].at[pos].get(mode="clip")
-        acc = _acc_dtype(spec)
-        vals = _from_orderable64(picked, ord_modes[slot], acc_f).astype(acc)
-        out[name] = jnp.where(
-            counts > 0, vals,
-            _extreme(acc, 1 if spec.kind == "min" else -1))
+
+    def tail(size: int):
+        probes = size * LANES
+
+        def pad(v, fill):
+            if probes == cap:
+                return v
+            return jnp.concatenate(
+                [v, jnp.full(cap - probes, fill, v.dtype)])
+
+        @jax.named_scope(ph.SCOPE_GROUP_TAIL)
+        def branch() -> Dict[str, jax.Array]:
+            o: Dict[str, jax.Array] = {}
+            rids = jnp.arange(1, probes + 1, dtype=jnp.int32)
+            starts = jnp.searchsorted(ranks, rids,
+                                      side="left").astype(jnp.int32)
+            # ranks are integers, so the run of rank r ends where the run
+            # of r + 1 starts: one search gives both edges, and only the
+            # last probe's successor is looked up apart
+            past = jnp.searchsorted(ranks, jnp.int32(probes + 1),
+                                    side="left").astype(jnp.int32)
+            ends = jnp.minimum(
+                jnp.concatenate([starts[1:], past[None]]), n_matched)
+            alive = rids <= n_live
+            o["group_idx"] = pad(jnp.where(
+                alive,
+                sk.at[jnp.minimum(starts, n_rows - 1)].get(mode="clip"),
+                jnp.int32(space)), space)
+            counts = jnp.where(alive, (ends - starts).astype(cnt_dtype), 0)
+            o["group_count"] = pad(counts, 0)
+
+            sums_done: Dict[Tuple[int, bool], jax.Array] = {}
+            for i, spec, slot in sum_jobs:
+                name = _agg_name(i, spec)
+                s = sums_done.get((slot, spec.integral))
+                if s is None:
+                    cs = sums_cs[(slot, spec.integral)]
+                    s = pad(jnp.where(alive, cs[ends] - cs[starts], 0), 0)
+                    sums_done[(slot, spec.integral)] = s
+                if spec.kind == "avg":
+                    o[name + "_sum"] = s
+                    o[name + "_cnt"] = o["group_count"]
+                else:
+                    o[name] = s
+
+            pos_min = jnp.minimum(starts, n_rows - 1)
+            pos_max = jnp.clip(ends - 1, 0, n_rows - 1)
+            for i, spec, slot in mm_jobs:
+                name = _agg_name(i, spec)
+                pos = pos_min if spec.kind == "min" else pos_max
+                picked = sorted_orderable[slot].at[pos].get(mode="clip")
+                acc = _acc_dtype(spec)
+                vals = _from_orderable64(
+                    picked, ord_modes[slot], acc_f).astype(acc)
+                extreme = _extreme(acc, 1 if spec.kind == "min" else -1)
+                o[name] = pad(jnp.where(counts > 0, vals, extreme),
+                              extreme)
+            return o
+
+        return branch
+
+    # n_live > cap takes the largest branch and flags the dense retry
+    out.update(_ladder_switch(_sparse_post_sizes(cap), n_live, tail))
+    out["group_overflow"] = (n_live > cap).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -1611,8 +1681,7 @@ def build_kernel(plan: KernelPlan, bucket: int,
             # sparse sorted post (q4.3): the sorted core emits
             # (group_idx, value) pairs directly at big spaces, so the
             # densify-then-compact _compact_group_xfer never runs there
-            sparse = (xfer_compact and not scatter and _needs_sort(plan)
-                      and plan.group_space >= GROUP_XFER_SPACE)
+            sparse = takes_sparse_post(plan, xfer_compact, scatter)
             _compact_group_aggs(plan, mask, cols, params, total, cap, out,
                                 platform, scatter, two_pass_mode,
                                 ladder_min, xfer_sparse=sparse)
@@ -1642,6 +1711,16 @@ def build_kernel(plan: KernelPlan, bucket: int,
 # measured)
 GROUP_XFER_SPACE = 1 << 15
 GROUP_XFER_CAP = 1 << 15
+
+
+def takes_sparse_post(plan: KernelPlan, xfer_compact: bool = True,
+                      scatter: bool = False) -> bool:
+    """Whether a compact plan's group rows leave the kernel through the
+    sparse sorted post (_sorted_post_sparse) and not through dense
+    outputs and _compact_group_xfer: the builders' rule, and the host's
+    where it counts what the post did (engine/executor.run_kernel)."""
+    return (xfer_compact and not scatter and _needs_sort(plan)
+            and plan.group_space >= GROUP_XFER_SPACE)
 
 
 @jax.named_scope(ph.SCOPE_XFER_COMPACT)
@@ -1903,8 +1982,7 @@ def build_segmented_compact_kernel(plan: KernelPlan, bucket: int,
                             if _needs_sort(plan2)
                             else default_slots_cap(total))
         out: Dict[str, jax.Array] = {}
-        sparse = (xfer_compact and not scatter and _needs_sort(plan2)
-                  and plan2.group_space >= GROUP_XFER_SPACE)
+        sparse = takes_sparse_post(plan2, xfer_compact, scatter)
         _compact_group_aggs(plan2, masks.reshape(total), tuple(flat_cols),
                             vparams, total, cap, out, platform, scatter,
                             two_pass_mode, ladder_min, xfer_sparse=sparse)

@@ -150,10 +150,11 @@ SCOPE_GROUP_KEY = "pinot.group_key"      # cartesian key + sentinel
 SCOPE_PAYLOAD = "pinot.payload"          # aggregation inputs, pre-compaction
 SCOPE_COMPACT = "pinot.compact"          # ops/compact.compact
 SCOPE_AGGREGATE = "pinot.aggregate"      # scalar, one-hot, sorted, scatter
+SCOPE_GROUP_TAIL = "pinot.group_tail"    # sparse sorted post, per live group
 SCOPE_XFER_COMPACT = "pinot.xfer_compact"  # live-group gather pre-transfer
 SCOPE_TOPK = "pinot.topk"                # selection order key + top_k
 SCOPE_COMBINE = "pinot.combine"          # cube mask + cell reduction
 KERNEL_SCOPES = frozenset(
     {SCOPE_MASK, SCOPE_DECODE_DICT, SCOPE_GROUP_KEY, SCOPE_PAYLOAD,
-     SCOPE_COMPACT, SCOPE_AGGREGATE, SCOPE_XFER_COMPACT, SCOPE_TOPK,
-     SCOPE_COMBINE})
+     SCOPE_COMPACT, SCOPE_AGGREGATE, SCOPE_GROUP_TAIL, SCOPE_XFER_COMPACT,
+     SCOPE_TOPK, SCOPE_COMBINE})

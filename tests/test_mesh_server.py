@@ -40,6 +40,20 @@ SHAPES = statements.load_shapes()
 WARM = " OPTION(timeoutMs=600000)"
 # the statements whose group space puts them on the sort core
 SORT_CORE = ["q3.2", "q3.3", "q3.4", "q4.3"]
+# 250 x 250 city pairs: a group space over GROUP_XFER_SPACE on the sparse
+# sorted post, whose live groups a segment follow the quantity's bound (a
+# literal: one program), so its three tails (``probes``, what
+# ops/kernels.sparse_post_probes gives a segment's live groups: under 512,
+# under 4,096, more) each hand their rows to the mesh's _densify
+CITY_PAIRS = {
+    f"cities.qty_lt_{bound}": {
+        "id": f"cities.qty_lt_{bound}", "probes": probes,
+        "preds": [["d_year", "eq", 1993], ["lo_quantity", "lt", bound]],
+        "value": ["lo_revenue"], "group": ["c_city", "s_city"],
+        "order": [["c_city", "asc"], ["s_city", "asc"]]}
+    for bound, probes in ((3, 512), (9, 4096),
+                          (51, kernels.GROUP_XFER_CAP))}
+ALL_SHAPES = {**SHAPES, **CITY_PAIRS}
 MESH_FAMILIES = [ph.MESH_DENSE, ph.MESH_COMPACT,
                  ph.MESH_COMPACT_PER_SEGMENT]
 
@@ -99,7 +113,7 @@ class Trio:
 
     def expected(self, key, segments=None):
         return oracle.answer(self.host if segments is None else segments,
-                             SHAPES[key])
+                             ALL_SHAPES[key])
 
     def stop(self):
         for node in (self.broker, self.server, self.ctrl):
@@ -167,7 +181,7 @@ def test_the_response_stands_for_every_segment(mesh_trio):
 # -- the sort core routed inside the mesh program --------------------------
 
 def _ctx(key):
-    return build_query_context(parse_sql(statements.to_sql(SHAPES[key])))
+    return build_query_context(parse_sql(statements.to_sql(ALL_SHAPES[key])))
 
 
 @pytest.fixture(scope="module")
@@ -175,20 +189,26 @@ def segments(mesh_trio):
     return mesh_trio.dm.acquire_segments()
 
 
-@pytest.mark.parametrize("key", SORT_CORE)
+@pytest.mark.parametrize("key", SORT_CORE + sorted(CITY_PAIRS))
 def test_the_routed_sort_core_equals_the_flattened_route(mesh_trio,
                                                          segments, key):
     """With the row limit under a local shard's rows (the table's own
     argument), a sort-core statement runs per local segment inside the
     mesh program and gives what the flattened shard and the reference
-    give."""
+    give; the city pairs from each tail of the sparse post."""
     from pinot_tpu.engine.reduce import reduce_partials
     mesh = segment_mesh(devices=jax.devices()[:N_DEV])
+    if key in CITY_PAIRS:
+        live = [len(oracle.segment_sums(seg, CITY_PAIRS[key]))
+                for seg in mesh_trio.host]
+        assert {kernels.sparse_post_probes(n) for n in live} \
+            == {CITY_PAIRS[key]["probes"]}, live
     answers = {}
     for name, limit in (("flattened", None), ("routed", ROWS)):
         dist = DistributedTable(segments, mesh, sort_row_limit=limit)
         plan = dist.mesh_plan(_ctx(key))
         assert plan.kernel_plan.strategy == "compact"
+        assert kernels.takes_sparse_post(plan.kernel_plan)
         before = counters()
         partial = dist.execute(plan)
         assert mesh_launches(moved(before)) == {
@@ -197,7 +217,7 @@ def test_the_routed_sort_core_equals_the_flattened_route(mesh_trio,
         answers[name] = reduce_partials(_ctx(key), [partial]).rows
     assert answers["routed"] == answers["flattened"]
     assert oracle.same(answers["routed"], mesh_trio.expected(key),
-                       SHAPES[key])
+                       ALL_SHAPES[key])
 
 
 def test_the_factorized_core_stays_flattened_under_any_limit(segments):

@@ -29,7 +29,10 @@ first (default ``chiprun_out/<cell>.<seed>.xplane.pb``), prints the run's
 result line, then reads the kept file. Nothing under ``benchmark/``
 changes for it. After the result line it prints how far the work counters
 ``kernel_dispatches``, ``dict_decode_select`` and ``dict_decode_gather``
-(``pinot_tpu/utils/spans.count_dispatch``) moved a request of the window.
+(``pinot_tpu/utils/spans.count_dispatch``) and ``sparse_post_results`` /
+``sparse_post_probes_<P>`` (``engine/executor.run_kernel``: the per-segment
+route's sparse posts, by the probe count their tail took) moved a request
+of the window.
 Interval arithmetic (``merge``, ``clip``, ``self_times``)
 is ``benchmark/trace/reduce.py``'s, by import.
 
@@ -64,7 +67,10 @@ PHASE_PREFIX = "pinot."
 SCOPE = re.compile(r"pinot\.[a-z_]+")
 RUN_ID = re.compile(r"\(\d+\)$")          # jit_pinot_dense_vmap(1234567)
 WORK_COUNTERS = ("kernel_dispatches", "dict_decode_select",
-                 "dict_decode_gather")
+                 "dict_decode_gather", "sparse_post_results")
+# and every counter of this prefix the program has counted: one a probe
+# count of the kernel's ladder (ops/kernels._sparse_post_sizes)
+PROBE_COUNTERS = "sparse_post_probes_"
 UNATTRIBUTED = "(in request, no program phase open)"
 NO_REQUEST = "(no request open)"
 Event = Tuple[str, float, float, dict]    # name, start s, end s, stats
@@ -293,7 +299,8 @@ def traced_run(cell: str, seed: int, seconds: float, keep: str) -> None:
         before = global_metrics.snapshot()["counters"]
         t0, requests = drive(*a, **kw)
         after = global_metrics.snapshot()["counters"]
-        for name in WORK_COUNTERS:
+        for name in WORK_COUNTERS + tuple(sorted(
+                k for k in after if k.startswith(PROBE_COUNTERS))):
             work[name] = ((after.get(name, 0) - before.get(name, 0))
                           / max(len(requests), 1))
         return t0, requests
