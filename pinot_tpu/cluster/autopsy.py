@@ -234,6 +234,11 @@ def _compile_split(win: Dict[str, Any]
     out: Dict[str, List[Dict[str, Any]]] = {"storm": [], "tier": [],
                                             "drift": []}
     for rec in win["events"]["compile_event"]:
+        if rec.get("site") == "ragged":
+            # the micro-batcher's programs are made by its background
+            # thread and the batch that missed them answered solo
+            # (engine/ragged.py, PR 33): no query waited for this one
+            continue
         trig = str(rec.get("trigger") or "")
         if trig in _TIER_TRIGGERS:
             out["tier"].append(rec)

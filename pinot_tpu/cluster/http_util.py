@@ -118,6 +118,15 @@ class JsonHandler(BaseHTTPRequestHandler):
         self._dispatch("DELETE")
 
 
+class _Server(ThreadingHTTPServer):
+    # the stdlib's listen backlog is 5: eight clients that connect in the
+    # same instant overflow it, the kernel drops the handshake and the
+    # sixth to eighth wait a second for TCP's retransmit (PR 33: the 1.1 s
+    # request that opened every window of ssb1.dash_c8, a third of all
+    # eight-client bursts on an idle loopback). Netty's default is 128.
+    request_queue_size = 128
+
+
 def start_http(handler_cls, port: int = 0) -> Tuple[ThreadingHTTPServer,
                                                     int, threading.Thread]:
     """Bind host: loopback by default (in-process clusters, tests);
@@ -126,7 +135,7 @@ def start_http(handler_cls, port: int = 0) -> Tuple[ThreadingHTTPServer,
     (deploy/)."""
     import os
     host = os.environ.get("PINOT_BIND_HOST", "127.0.0.1")
-    srv = ThreadingHTTPServer((host, port), handler_cls)
+    srv = _Server((host, port), handler_cls)
     t = threading.Thread(target=srv.serve_forever, daemon=True)
     t.start()
     return srv, srv.server_address[1], t

@@ -34,7 +34,33 @@ Fairness and admission: a query near its accountant deadline, or a
 plan the cube cost model rejects, dispatches solo immediately — never
 queue-blocked. The per-key ``estimate_ms()`` EWMA (the engine-side
 analog of the adaptive instance selector's latency estimator) feeds
-the deadline check. Every query wraps its wait + dispatch in a
+the deadline check.
+
+**Cold means not ready now** (PR 33). A query never pays for a cube or
+for a fused program: ``submit`` looks up what is resident at that moment.
+If a (spec, segment) cube of the group is missing from
+``global_cube_cache`` or, once the batch has formed (``_lead``), its
+stacked cubes or the combine program of its (spec, segment count, padded
+items, param signature) have not been made, the submission and every
+member of that batch dispatch solo at once, counted
+``solo_fallback_cold``, and the missing pieces go to ``_Background``: one
+worker thread (builds are device work: they queue behind each other),
+single-flight per (spec, segment uid) and per program key, attached to no
+query's accountant and under no query's deadline. A program job makes the
+shape's whole ladder (every pow2 padded count from two whole-table queries
+up to ``max_batch`` of them or the cell budget), so which peers meet later
+decides no compile. The background compile
+goes through the same ``_KernelRegistry`` (``kernel_jit`` + ``StagedFn``)
+as a foreground one would. The gauge ``cube_builds_pending`` counts jobs
+queued or running, ``cube_builds_background`` and
+``fused_compiles_background`` the jobs done, and ``wait_ready(timeout)``
+blocks until the gauge is 0: a node's warm-up, the tests and the
+benchmark's entry call it after a burst. A cube evicted with its segment
+is rebuilt the same way, in the background. So every fused dispatch on a
+query's thread is a warm one, and only those feed ``_record_ms``: one
+slow first attempt can no longer send a shape solo for good.
+
+Every query wraps its wait + dispatch in a
 ``ragged_dispatch`` span on its own thread (queue_wait_ms annotated)
 so per-query wall attribution survives the fusion, and the accountant
 carries batched/batch_size per query for the query_stats ledger.
@@ -50,10 +76,11 @@ or interleaved arbitrarily — chaos soaks run with batching armed.
 """
 from __future__ import annotations
 
+import logging
 import os
 import threading
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from concurrent.futures import Future
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
@@ -67,6 +94,8 @@ from ..utils.metrics import global_metrics
 from ..utils.spans import (annotate, count_dispatch, device_fence, phase,
                            span)
 from .scheduler import MicroBatchQueue
+
+log = logging.getLogger(__name__)
 
 # cost-model caps: the cube must stay small relative to the data it
 # collapses, the per-item masked-cell work must stay bounded, and raw
@@ -82,7 +111,7 @@ DEFAULT_MAX_BATCH = 32
 # solo_fallback_<reason>; a globally disabled batcher never reaches the
 # admission path, so it is deliberately NOT a reason here)
 _SOLO_REASONS = ("incompatible", "no_peers", "deadline",
-                 "window_expired", "timeout", "leader_error")
+                 "window_expired", "timeout", "leader_error", "cold")
 
 
 @dataclass(frozen=True)
@@ -335,7 +364,7 @@ def build_cube_kernel(spec: CubeSpec):
         if slot is not None and slot not in slot_values:
             slot_values[slot] = agg.value
 
-    @jax.named_scope(ph.SCOPE_AGGREGATE)
+    @jax.named_scope(ph.SCOPE_CUBE_BUILD)
     def kernel(cols, n_docs, params):
         valid = jnp.arange(spec.bucket, dtype=jnp.int32) < n_docs
         key, ok = _dim_digits(spec, cols)
@@ -364,7 +393,7 @@ def build_cube_combine_kernel(spec: CubeSpec):
     G, P = spec.group_space, spec.pred_space
     grouped = spec.kp.is_group_by
 
-    @jax.named_scope(ph.SCOPE_COMBINE)
+    @jax.named_scope(ph.SCOPE_CUBE_COMBINE)
     def kernel(cubes, seg_idx, params):
         grid = _grid_cols(spec)
 
@@ -415,9 +444,11 @@ class _KernelRegistry:
     RE-compile of a key already seen in an earlier query generation —
     an LRU eviction rebuild, a flipped knob — is flagged exactly like
     a plan-cache retrace. A key's FIRST-ever compile is warmup by the
-    detector's own rule, so benches that want compile-free measured
-    windows must visit their pow2 rungs during warmup (bench.py's
-    --concurrency mode does)."""
+    detector's own rule, and since PR 33 it is the background's: a
+    query's thread only takes a program that ``ready`` hands out, so a
+    run that wants every batch of its window fused visits the pow2 rungs
+    during warm-up and waits (``RaggedBatcher.wait_ready``;
+    benchmark/entries/served_http_dash.py does)."""
 
     def __init__(self, maxsize: int = 256):
         self._lock = threading.Lock()
@@ -425,6 +456,9 @@ class _KernelRegistry:
         # keys the LRU dropped: their rebuild classifies as
         # lru_evict_rebuild in the compile-event taxonomy
         self._evicted: "OrderedDict[Tuple, bool]" = OrderedDict()
+        # keys whose program has run once (compiled, or read back from
+        # the persistent cache): the only ones a query's thread calls
+        self._ready: set = set()
         self._maxsize = maxsize
 
     def get(self, key: Tuple, make, family: str = ph.RAGGED_FUSED):
@@ -449,14 +483,30 @@ class _KernelRegistry:
             while len(self._fns) > self._maxsize:
                 old_key, _old = self._fns.popitem(last=False)
                 self._evicted[old_key] = True
+                self._ready.discard(old_key)
                 while len(self._evicted) > 4 * self._maxsize:
                     self._evicted.popitem(last=False)
             return fn
+
+    def ready(self, key: Tuple):
+        """The program of ``key`` if it has been made (``mark_ready``),
+        else None: a query's thread never compiles one."""
+        with self._lock:
+            if key not in self._ready:
+                return None
+            self._fns.move_to_end(key)
+            return self._fns[key]
+
+    def mark_ready(self, key: Tuple) -> None:
+        with self._lock:
+            if key in self._fns:
+                self._ready.add(key)
 
     def clear(self):
         with self._lock:
             self._fns.clear()
             self._evicted.clear()
+            self._ready.clear()
 
 
 _kernels = _KernelRegistry()
@@ -495,6 +545,109 @@ class _Submission:
         self.abandoned = False
 
 
+class _Packed:
+    """One batch laid out for the combine program: items = (submission,
+    plan, host params) in submission order, the unique segments' plans
+    in first-seen order, the pow2-padded item count, and the registry
+    key and launch arguments of the program at a padded count."""
+    __slots__ = ("spec", "items", "seg_plans", "seg_order", "npad", "sig")
+
+    def __init__(self, spec: CubeSpec, batch: List[_Submission]):
+        from .executor import param_sig
+        self.spec = spec
+        self.items = [(sub, plan, params) for sub in batch
+                      for plan, params in zip(sub.plans, sub.hosts)]
+        self.seg_order: Dict[int, int] = {}
+        self.seg_plans: List[Any] = []
+        for _sub, plan, _p in self.items:
+            if plan.segment.uid not in self.seg_order:
+                self.seg_order[plan.segment.uid] = len(self.seg_plans)
+                self.seg_plans.append(plan)
+        self.npad = _pow2(len(self.items))
+        self.sig = param_sig(self.items[0][1], self.items[0][2])
+
+    def program_key(self, npad: Optional[int] = None) -> Tuple:
+        return ("combine", self.spec, len(self.seg_plans),
+                npad or self.npad, self.sig)
+
+    def launch_args(self, stacked, npad: Optional[int] = None) -> Tuple:
+        """(stacked cubes, seg_idx, params) of the program at ``npad``
+        padded items (the batch's own count, or another rung of the
+        ladder for the background): literals stacked on the host
+        (executor.stack_params); pads repeat item 0 and are sliced off
+        at unpack, so shapes stay cache-stable."""
+        from .executor import resident_param, stack_params
+        npad = npad or self.npad
+        n = min(len(self.items), npad)
+        padded = [self.items[k if k < n else 0] for k in range(npad)]
+        seg_idx = np.asarray(  # jaxlint: ok host-sync — host ints
+            [self.seg_order[plan.segment.uid] for _s, plan, _h in padded],
+            dtype=np.int32)
+        dev_seg_idx, params = stack_params(
+            [hosts for _s, _plan, hosts in padded], seg_idx,
+            lambda m: jnp.stack([resident_param(plan.segment, m)
+                                 for _s, plan, _h in padded]))
+        return stacked, dev_seg_idx, params
+
+
+class _Background:
+    """The builder of what a cold submission found missing: a FIFO and
+    one worker thread (started at need, gone when the queue is empty),
+    single-flight by job key. A job that raised is logged, counted
+    ``cube_build_errors`` and not asked for again until ``clear``: its
+    shape keeps dispatching solo."""
+
+    def __init__(self):
+        self._cond = threading.Condition()
+        self._jobs: deque = deque()
+        self._keys: set = set()          # queued or running
+        self._failed: set = set()
+        self._thread: Optional[threading.Thread] = None
+
+    def request(self, key: Tuple, job) -> None:
+        with self._cond:
+            if key in self._keys or key in self._failed:
+                return
+            self._keys.add(key)
+            self._jobs.append((key, job))
+            global_metrics.gauge("cube_builds_pending", len(self._keys))
+            if self._thread is None or not self._thread.is_alive():
+                self._thread = threading.Thread(
+                    target=self._run, name="ragged-background", daemon=True)
+                self._thread.start()
+
+    def _run(self) -> None:
+        while True:
+            with self._cond:
+                if not self._jobs:
+                    self._thread = None
+                    return
+                key, job = self._jobs.popleft()
+            failed = True
+            try:
+                job()
+                failed = False
+            except Exception:  # noqa: BLE001 — no query waits on this
+                global_metrics.count("cube_build_errors")
+                log.exception("background build %r failed", key[0])
+            finally:
+                with self._cond:
+                    self._keys.discard(key)
+                    if failed:
+                        self._failed.add(key)
+                    global_metrics.gauge("cube_builds_pending",
+                                         len(self._keys))
+                    self._cond.notify_all()
+
+    def wait_idle(self, timeout: Optional[float]) -> bool:
+        with self._cond:
+            return self._cond.wait_for(lambda: not self._keys, timeout)
+
+    def clear(self) -> None:
+        with self._cond:
+            self._failed.clear()
+
+
 class RaggedBatcher:
     """The cross-query micro-batching dispatcher (module docstring)."""
 
@@ -513,6 +666,7 @@ class RaggedBatcher:
         self.queue = MicroBatchQueue()
         self._lock = threading.Lock()
         self._est_ms: Dict[Any, float] = {}
+        self._background = _Background()
 
     def configure(self, enabled: Optional[bool] = None,
                   window_ms: Optional[float] = None,
@@ -525,6 +679,12 @@ class RaggedBatcher:
             self.max_batch = int(max_batch)
         return self
 
+    def wait_ready(self, timeout: Optional[float] = None) -> bool:
+        """Block until no background build or compile is queued or
+        running (the gauge ``cube_builds_pending`` is 0); False if
+        ``timeout`` seconds passed first."""
+        return self._background.wait_idle(timeout)
+
     # -- admission ---------------------------------------------------------
     def estimate_ms(self, key: Any) -> Optional[float]:
         """EWMA of fused-dispatch wall ms for a compatibility key (the
@@ -533,6 +693,8 @@ class RaggedBatcher:
             return self._est_ms.get(key)
 
     def _record_ms(self, key: Any, ms: float) -> None:
+        # fed by _lead after a fused dispatch, which is a warm one by
+        # construction: a cold batch never reaches _execute_fused
         with self._lock:
             prev = self._est_ms.get(key)
             self._est_ms[key] = ms if prev is None \
@@ -553,8 +715,8 @@ class RaggedBatcher:
         concurrent peers. Returns per-plan partials, or None — the
         caller then runs the ordinary solo dispatch (reason counted in
         solo_fallback_* and annotated on the span). Never queue-blocks
-        a query that should dispatch solo: ineligible, peer-less and
-        deadline-pressured queries bail before enqueueing."""
+        a query that should dispatch solo: ineligible, peer-less, cold
+        and deadline-pressured queries bail before enqueueing."""
         if not self.enabled:
             return None
         from .accounting import global_accountant
@@ -568,20 +730,32 @@ class RaggedBatcher:
         if spec is None:
             return self._solo("incompatible")
         # the budget bounds what the kernel EXECUTES — the pow2-padded
-        # item count, not the raw one (pad rows do real work)
-        if _pow2(len(plans)) * spec.cube_space > ITEM_CELL_BUDGET:
+        # item count, not the raw one (pad rows do real work) — and it
+        # has to hold this group twice, or no peer like it could ever
+        # join: such a query neither waits the window nor has cubes built
+        if _pow2(2 * len(plans)) * spec.cube_space > ITEM_CELL_BUDGET:
             return self._solo("incompatible")
         # dim cardinalities are segment state (dictionaries differ per
         # segment): every segment in this group must derive the same
         # spec or the shared grid would mis-decode its ids
-        seen_uids = {plans[0].segment.uid}
+        by_uid = {plans[0].segment.uid: plans[0]}
         for plan in plans[1:]:
-            if plan.segment.uid in seen_uids:
+            if plan.segment.uid in by_uid:
                 continue
-            seen_uids.add(plan.segment.uid)
+            by_uid[plan.segment.uid] = plan
             other, _w = cube_spec_for(plan)
             if other != spec:
                 return self._solo("incompatible")
+        # what is not resident NOW is built behind the query, never by it
+        from ..ops.plan_cache import global_cube_cache
+        missing = global_cube_cache.missing(
+            spec, [p.segment for p in by_uid.values()])
+        if missing:
+            for uid in missing:
+                self._background.request(
+                    ("cube", spec, uid),
+                    lambda p=by_uid[uid]: self._cube_job(spec, p))
+            return self._solo("cold")
         qid = global_accountant.current_query_id()
         key = (spec, bucket, group_sig)
         window_ms = self.window_ms * self.window_scale
@@ -599,9 +773,10 @@ class RaggedBatcher:
         with span(ph.RAGGED_DISPATCH, bucket=bucket,
                   strategy=spec.kp.strategy):
             global_metrics.gauge("batch_queue_depth", self.queue.depth())
-            batch = self.queue.offer(
-                key, sub, window_ms / 1e3, self.max_batch,
-                max_weight=max_weight, weight=sub.n_items)
+            with phase(ph.RAGGED_WAIT):
+                batch = self.queue.offer(
+                    key, sub, window_ms / 1e3, self.max_batch,
+                    max_weight=max_weight, weight=sub.n_items)
             # re-read after the offer resolves so a drained queue
             # reports 0 instead of freezing at the last pre-offer value
             global_metrics.gauge("batch_queue_depth", self.queue.depth())
@@ -623,9 +798,9 @@ class RaggedBatcher:
             # a guaranteed deadline kill after the wait
             rem = usage.deadline - time.perf_counter()
             timeout = max(min(rem * 0.5, 60.0), 0.05)
-        reason = "leader_error"
         try:
-            result = sub.future.result(timeout=timeout)
+            with phase(ph.RAGGED_WAIT):
+                result = sub.future.result(timeout=timeout)
         except FutTimeout:
             # abandon BEFORE the last-chance re-check: either the
             # leader already set the result (use it — nothing was
@@ -634,13 +809,14 @@ class RaggedBatcher:
             # instant may still count one abandoned query as batched —
             # an accepted, annotated-in-review race, not a hang.
             sub.abandoned = True
-            result = sub.future.result(0) if sub.future.done() else None
-            reason = "timeout"
+            result = sub.future.result(0) if sub.future.done() else "timeout"
         except Exception:
-            result = None
+            result = "leader_error"
         wait_ms = (time.perf_counter() - sub.t0) * 1e3
-        if result is None:
-            return self._solo(reason)
+        if isinstance(result, str):
+            # the leader's word for why its batch answers solo: "cold"
+            # (nothing was ready) or "leader_error"
+            return self._solo(result)
         partials, batch_size, exec_ms = result
         annotate(batched=True, batch_size=batch_size,
                  queue_wait_ms=round(wait_ms - exec_ms, 3),
@@ -651,17 +827,35 @@ class RaggedBatcher:
     # -- fused execution (leader thread) -----------------------------------
     def _lead(self, key, spec: CubeSpec, batch: List[_Submission],
               own: _Submission) -> Optional[List]:
-        t_exec = time.perf_counter()
-        try:
-            results = self._execute_fused(key, spec, batch)
-        except BaseException as e:  # noqa: BLE001 — followers must not hang
+        from ..ops.plan_cache import global_cube_cache
+
+        def send_solo(reason: str) -> None:
             for sub in batch:
                 if sub is not own and not sub.future.done():
-                    sub.future.set_result(None)
+                    sub.future.set_result(reason)
+            return self._solo(reason)
+
+        t_exec = time.perf_counter()
+        try:
+            packed = _Packed(spec, batch)
+            stacked = global_cube_cache.stacked_if_ready(
+                spec, [p.segment for p in packed.seg_plans])
+            fn = _kernels.ready(packed.program_key())
+            if stacked is None or fn is None:
+                # the cubes were evicted since submit looked, or this
+                # batch's (segments, padded items) program has not been
+                # made: the whole batch answers solo, now
+                self._background.request(
+                    packed.program_key(),
+                    lambda: self._program_job(spec, packed))
+                return send_solo("cold")
+            results = self._execute_fused(spec, batch, packed, stacked, fn)
+        except BaseException as e:  # noqa: BLE001 — followers must not hang
             global_metrics.count("fused_dispatch_errors")
+            send_solo("leader_error")
             if isinstance(e, (KeyboardInterrupt, SystemExit)):
                 raise
-            return self._solo("leader_error")
+            return None
         exec_ms = (time.perf_counter() - t_exec) * 1e3
         self._record_ms(key, exec_ms)
         n_queries = len(batch)
@@ -685,56 +879,20 @@ class RaggedBatcher:
                  fused_ms=round(exec_ms, 3))
         return results[id(own)]
 
-    def _execute_fused(self, key, spec: CubeSpec,
-                       batch: List[_Submission]) -> Dict[int, List]:
-        from ..ops.plan_cache import global_cube_cache
-        from .executor import (extract_partial, param_sig, resident_param,
-                               stack_params)
+    def _execute_fused(self, spec: CubeSpec, batch: List[_Submission],
+                       packed: _Packed, stacked, fn) -> Dict[int, List]:
+        from .executor import extract_partial
 
-        items: List[Tuple[_Submission, Any, Tuple]] = []
-        for sub in batch:
-            for plan, params in zip(sub.plans, sub.hosts):
-                items.append((sub, plan, params))
-
-        # per-unique-segment cubes (cached device-resident; one unmasked
-        # scan each on a cold cache, zero scans when warm)
-        seg_order: Dict[int, int] = {}
-        seg_plans: List[Any] = []
-        for _sub, plan, _p in items:
-            uid = plan.segment.uid
-            if uid not in seg_order:
-                seg_order[uid] = len(seg_plans)
-                seg_plans.append(plan)
-        cubes: List[Dict[str, jax.Array]] = []
-        for plan in seg_plans:
-            cubes.append(global_cube_cache.entry(
-                spec, plan.segment,
-                lambda p=plan: self._build_cube(spec, p)))
-        stacked = global_cube_cache.stacked(
-            spec, [p.segment for p in seg_plans], cubes)
-
-        # ragged pack: pow2-padded item axis (pads repeat item 0 and are
-        # sliced off at unpack, so shapes stay cache-stable)
+        items, npad = packed.items, packed.npad
         n_items = len(items)
-        npad = _pow2(n_items)
-        seg_idx = np.zeros(npad, dtype=np.int32)
-        for k, (_s, plan, _p) in enumerate(items):
-            seg_idx[k] = seg_order[plan.segment.uid]
-        padded = [items[k if k < n_items else 0] for k in range(npad)]
-        dev_seg_idx, stacked_params = stack_params(
-            [hosts for _s, _plan, hosts in padded], seg_idx,
-            lambda m: jnp.stack([resident_param(plan.segment, m)
-                                 for _s, plan, _h in padded]))
-        fn = _kernels.get(
-            ("combine", spec, len(cubes), npad,
-             param_sig(items[0][1], items[0][2])),
-            lambda: build_cube_combine_kernel(spec), ph.RAGGED_FUSED)
-        with span(ph.FUSED_EXECUTE, queries=len(batch), items=n_items,
-                  padded=npad, segments=len(cubes),
-                  cube_space=spec.cube_space):
+        with phase(ph.FUSED_EXECUTE, queries=len(batch), items=n_items,
+                   padded=npad, segments=len(packed.seg_plans),
+                   cube_space=spec.cube_space):
+            with phase(ph.DISPATCH_PREPARE):
+                args = packed.launch_args(stacked)
             count_dispatch(ph.RAGGED_FUSED)
             with phase(ph.DEVICE_EXECUTE):
-                dev = fn(stacked, dev_seg_idx, stacked_params)
+                dev = fn(*args)
                 device_fence(dev)
             with phase(ph.DEVICE_TRANSFER):
                 host = jax.device_get(dev)  # jaxlint: ok host-sync
@@ -759,6 +917,45 @@ class RaggedBatcher:
                 results[id(sub)].append(extract_partial(plan, per_item))
         return results
 
+    # -- the background's jobs (no query's thread runs these) --------------
+    def _cube_job(self, spec: CubeSpec, plan) -> Dict[str, jax.Array]:
+        from ..ops.plan_cache import global_cube_cache
+        return global_cube_cache.entry(
+            spec, plan.segment, lambda: self._build_cube(spec, plan))
+
+    def _program_job(self, spec: CubeSpec, packed: _Packed) -> None:
+        """Make a batch's stacked cubes and the combine programs of its
+        ladder by running the batch once at each rung, the answers
+        dropped: every pow2 padded count that whole-table queries over
+        these segments can reach, from two queries up to ``max_batch``
+        of them or the cell budget, so who meets whom later decides no
+        compile. The first call of a rung compiles
+        (utils/compileplane.StagedFn) or reads the persistent cache."""
+        from ..ops.plan_cache import global_cube_cache
+        cubes = [self._cube_job(spec, p) for p in packed.seg_plans]
+        stacked = global_cube_cache.stacked(
+            spec, [p.segment for p in packed.seg_plans], cubes)
+        n = len(cubes)
+        rungs = {packed.npad}
+        npad = _pow2(2 * n)
+        while npad <= _pow2(self.max_batch * n) \
+                and npad * spec.cube_space <= ITEM_CELL_BUDGET:
+            rungs.add(npad)
+            npad *= 2
+        for npad in sorted(rungs):
+            key = packed.program_key(npad)
+            if _kernels.ready(key) is not None:
+                continue
+            fn = _kernels.get(key, lambda: build_cube_combine_kernel(spec),
+                              ph.RAGGED_FUSED)
+            with span(ph.FUSED_EXECUTE, background=True, padded=npad,
+                      segments=n, cube_space=spec.cube_space):
+                count_dispatch(ph.RAGGED_FUSED)
+                jax.block_until_ready(
+                    fn(*packed.launch_args(stacked, npad)))
+            _kernels.mark_ready(key)
+            global_metrics.count("fused_compiles_background")
+
     def _build_cube(self, spec: CubeSpec, plan) -> Dict[str, jax.Array]:
         from ..ops.kernels import dict_decode_forms
         from .executor import resolve_params
@@ -768,20 +965,21 @@ class RaggedBatcher:
                           ph.CUBE_BUILD_KERNEL)
         with span(ph.CUBE_BUILD, segment=seg.name, bucket=seg.bucket,
                   cube_space=spec.cube_space):
-            with phase(ph.DISPATCH_PREPARE):
-                cols = seg.device_cols(plan.col_names)
-                params = resolve_params(plan)
+            cols = seg.device_cols(plan.col_names)
+            params = resolve_params(plan)
             count_dispatch(ph.CUBE_BUILD_KERNEL,
                            dict_decode_forms(spec.kp, params))
-            with phase(ph.DEVICE_EXECUTE):
-                out = fn(cols, jnp.int32(seg.n_docs), params)
-                device_fence(out)
-            return out
+            out = fn(cols, jnp.int32(seg.n_docs), params)
+            jax.block_until_ready(out)
+        global_metrics.count("cube_builds_background")
+        return out
 
     def clear(self) -> None:
-        """Test hook: drop kernel caches and estimates (the cube cache
-        is cleared through ops/plan_cache.global_cube_cache)."""
+        """Test hook: drop kernel caches, estimates and the memory of
+        failed background jobs (the cube cache is cleared through
+        ops/plan_cache.global_cube_cache)."""
         _kernels.clear()
+        self._background.clear()
         with self._lock:
             self._est_ms.clear()
 
@@ -812,6 +1010,14 @@ def batching_health(snapshot: Dict[str, Any]) -> Dict[str, Any]:
         "fused_batch_size_gt_32", 0)
     out["batch_queue_depth"] = snapshot["gauges"].get(
         "batch_queue_depth", 0)
+    # "cold" among the solo fallbacks: a cube or a fused program was not
+    # ready when the query asked; these say what the background builder
+    # (module docstring) has queued or running, and what it has made
+    out["cube_builds_pending"] = snapshot["gauges"].get(
+        "cube_builds_pending", 0)
+    out["cube_builds_background"] = c.get("cube_builds_background", 0)
+    out["fused_compiles_background"] = c.get(
+        "fused_compiles_background", 0)
     # live device bytes the fusion plane holds resident (utils/devmem
     # gauges mirrored by the cube cache) — rendered on /ui next to the
     # hit counters so cache pressure is visible where batching is tuned

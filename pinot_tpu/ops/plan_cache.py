@@ -527,7 +527,13 @@ class CubeCache:
     can never serve stale cells, and the name rides along only for
     evict_cubes_containing."""
 
-    def __init__(self, maxsize: int = 16):
+    MAX_STACKED = 16
+
+    def __init__(self, maxsize: int = 128):
+        # one entry a (spec, segment): a served table of 8 segments and
+        # a dashboard's seven fusable statement shapes hold 56 at once
+        # (PR 33; 16 evicted them by turns). A stack is one a (spec,
+        # segment set). Bytes are the tier budget's to bound.
         self._entries: "OrderedDict[Tuple, Dict[str, Any]]" = OrderedDict()
         # (spec, uid tuple) -> {name: [S, ...]} stacked device arrays:
         # the warm fused path would otherwise re-copy every per-segment
@@ -595,14 +601,38 @@ class CubeCache:
         global_tier.enforce(protect={segment.uid})
         return built
 
+    @staticmethod
+    def _stack_key(spec, segments) -> Tuple:
+        return (spec, tuple(s.uid for s in segments),
+                tuple(s.name for s in segments))
+
+    def missing(self, spec, segments) -> List[int]:
+        """Uids of ``segments`` with no resident cube for ``spec``. A
+        look, not a use: nothing is counted or reordered."""
+        with self._lock:
+            return [s.uid for s in segments
+                    if (spec, s.uid, s.name) not in self._entries]
+
+    def stacked_if_ready(self, spec, segments) -> Optional[Dict[str, Any]]:
+        """The cached ``stacked`` of these segments, or None: the warm
+        fused path reads it and never stacks on a query's thread."""
+        key = self._stack_key(spec, segments)
+        with self._lock:
+            hit = self._stacked.get(key)
+            if hit is not None:
+                self._stacked.move_to_end(key)
+                self.hits += 1
+        if hit is not None:
+            global_metrics.count("cube_cache_hits")
+        return hit
+
     def stacked(self, spec, segments, per_segment: List[Dict[str, Any]]
                 ) -> Dict[str, Any]:
         """{name: [S, ...]} stack of the given segments' cubes, cached
         by (spec, uid tuple) so a warm fused dispatch pays zero device
         copies. ``per_segment`` must be the entry() results for the
         same segments, in order."""
-        key = (spec, tuple(s.uid for s in segments),
-               tuple(s.name for s in segments))
+        key = self._stack_key(spec, segments)
         with self._lock:
             hit = self._stacked.get(key)
             if hit is not None:
@@ -615,7 +645,7 @@ class CubeCache:
             global_device_memory.add("cube_stacked", key,
                                      nbytes_of(stacked))
             self._stacked.move_to_end(key)
-            while len(self._stacked) > self._maxsize:
+            while len(self._stacked) > self.MAX_STACKED:
                 old_key, _old = self._stacked.popitem(last=False)
                 global_device_memory.remove("cube_stacked", old_key)
         # shared-budget admission (engine/tier.py), outside self._lock

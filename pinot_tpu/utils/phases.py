@@ -64,7 +64,10 @@ COLLECTIVE_EXCHANGE = "collective_exchange"
 # additionally parents the cube_build/fused_execute children.
 RAGGED_DISPATCH = "ragged_dispatch"
 CUBE_BUILD = "cube_build"
-FUSED_EXECUTE = "fused_execute"
+FUSED_EXECUTE = "fused_execute"    # metered too (PR 33): the leader's launch
+# the time a query spends in the admission window (the leader, in
+# MicroBatchQueue.offer) or waiting for its leader's answer (a follower)
+RAGGED_WAIT = "ragged_wait"
 
 # vector search subsystem (engine/vector_exec.py): one span per
 # (query, segment) device search — batched or solo annotated on it
@@ -104,7 +107,7 @@ SPAN_NAMES = TRACED_PHASES | frozenset(
      LEAF_SCAN, JOIN_STAGE, EXCHANGE, WINDOW_STAGE, FINAL_STAGE,
      FUSED_PLAN, COLLECTIVE_EXCHANGE,
      STAGE, STAGE_CALL, STAGE_DISPATCH,
-     RAGGED_DISPATCH, CUBE_BUILD, FUSED_EXECUTE})
+     RAGGED_DISPATCH, CUBE_BUILD, FUSED_EXECUTE, RAGGED_WAIT})
 
 # every name utils/spans.phase accepts (anything else is a KeyError at
 # the call site: a metered boundary is named here or not at all)
@@ -112,7 +115,7 @@ METERED_PHASES = TRACED_PHASES | frozenset(
     {BROKER_QUERY, BROKER_PARSE, BROKER_ROUTE, BROKER_SELECT, SCATTER,
      SCATTER_CALL, WIRE_DECODE, BROKER_RESPOND, SERVER_HTTP, SERVER_QUEUE,
      SERVER_PARSE, DISPATCH_PREPARE, DEVICE_EXECUTE, DEVICE_TRANSFER,
-     EXTRACT_PARTIAL, SERVER_ENCODE})
+     EXTRACT_PARTIAL, SERVER_ENCODE, RAGGED_WAIT, FUSED_EXECUTE})
 
 # kernel families: the jitted function of each is named
 # "pinot_<family>" (utils/compileplane.kernel_jit), so the profiler's
@@ -153,8 +156,11 @@ SCOPE_AGGREGATE = "pinot.aggregate"      # scalar, one-hot, sorted, scatter
 SCOPE_GROUP_TAIL = "pinot.group_tail"    # sparse sorted post, per live group
 SCOPE_XFER_COMPACT = "pinot.xfer_compact"  # live-group gather pre-transfer
 SCOPE_TOPK = "pinot.topk"                # selection order key + top_k
-SCOPE_COMBINE = "pinot.combine"          # cube mask + cell reduction
+SCOPE_COMBINE = "pinot.combine"          # the mesh's per-device combine
+# the micro-batcher's two programs (engine/ragged.py)
+SCOPE_CUBE_BUILD = "pinot.cube_build"      # unmasked scan -> literal-free cube
+SCOPE_CUBE_COMBINE = "pinot.cube_combine"  # per-item mask + cell reduction
 KERNEL_SCOPES = frozenset(
     {SCOPE_MASK, SCOPE_DECODE_DICT, SCOPE_GROUP_KEY, SCOPE_PAYLOAD,
      SCOPE_COMPACT, SCOPE_AGGREGATE, SCOPE_GROUP_TAIL, SCOPE_XFER_COMPACT,
-     SCOPE_TOPK, SCOPE_COMBINE})
+     SCOPE_TOPK, SCOPE_COMBINE, SCOPE_CUBE_BUILD, SCOPE_CUBE_COMBINE})

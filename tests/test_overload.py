@@ -436,6 +436,20 @@ def test_http_plane_renders_capacity_errors_as_429():
         srv.server_close()
 
 
+def test_http_plane_listens_with_a_backlog_for_concurrent_clients():
+    """Eight closed-loop clients connect in the same instant; the
+    stdlib's backlog of 5 made the sixth to eighth wait a second for
+    TCP's retransmit (PR 33). Every node's plane comes from start_http."""
+    from pinot_tpu.cluster.http_util import JsonHandler, start_http
+
+    srv, _port, _t = start_http(JsonHandler, 0)
+    try:
+        assert srv.request_queue_size >= 128
+    finally:
+        srv.shutdown()
+        srv.server_close()
+
+
 def test_governor_unsticks_when_signals_removed():
     """Removing the last signal with pressure high must drop back to
     rung 0 — nothing could ever lower a stale cached rung again."""

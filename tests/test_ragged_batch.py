@@ -120,6 +120,20 @@ def _concurrent(broker, sqls, barrier_timeout=30):
     return results
 
 
+def _warmed(broker, sqls, rounds=8):
+    """Run the wave until one of its rounds meets nothing cold: since PR
+    33 a cube or a fused program that is not ready sends its batch solo
+    (``solo_fallback_cold``) and is made behind the queries, so the first
+    rounds of a shape build and compile and a later one can fuse."""
+    for _ in range(rounds):
+        cold0 = _counter("solo_fallback_cold")
+        results = _concurrent(broker, sqls)
+        assert global_batcher.wait_ready(120.0)
+        if _counter("solo_fallback_cold") == cold0:
+            return results
+    raise AssertionError(f"still cold after {rounds} rounds")
+
+
 # -- fused-vs-solo digest exactness -----------------------------------------
 
 @pytest.mark.parametrize("concurrency", [2, 8, 32])
@@ -136,7 +150,7 @@ def test_fused_vs_solo_digests(ssb, grouped, concurrency):
         global_batcher.configure(enabled=True, window_ms=30.0,
                                  max_batch=concurrency)
         fused0 = _counter("batched_queries")
-        results = _concurrent(brk, sqls)
+        results = _warmed(brk, sqls)
         for r, s in zip(results, solo):
             assert corpus.digest(r.rows) == corpus.digest(s.rows)
         if concurrency >= 8:
@@ -337,7 +351,7 @@ def test_zero_retraces_across_pow2_ladder(grouped):
     global_batcher.configure(enabled=True, window_ms=30.0)
     sizes = (2, 3, 8)          # pads to 2 / 4 / 8
     for n in sizes:            # warmup: compiles are expected here
-        _concurrent(broker, [_grp(i) + corpus.OPTION for i in range(n)])
+        _warmed(broker, [_grp(i) + corpus.OPTION for i in range(n)])
     det0 = global_plan_cache.detector.retraces
     fused0 = _counter("batched_queries")
     for n in sizes:
@@ -366,7 +380,9 @@ def test_span_attribution_inside_fused_dispatch(grouped, tmp_path):
     from pinot_tpu.engine.accounting import global_accountant
     global_accountant.register("span-test-peer")
     try:
-        _concurrent(traced, [_grp(i) + corpus.OPTION for i in range(n)])
+        sqls = [_grp(i) + corpus.OPTION for i in range(n)]
+        _warmed(broker, sqls)        # cubes and programs in place first
+        _concurrent(traced, sqls)
     finally:
         global_accountant.unregister("span-test-peer")
     recs = [r for r in _read_jsonl(path) if r.get("kind") == "query_trace"]
@@ -410,9 +426,12 @@ def _find_spans(node, name):
 def test_cube_cache_hits_and_eviction(grouped):
     _dm, broker = grouped
     global_batcher.configure(enabled=True, window_ms=30.0)
-    _concurrent(broker, [_grp(i) + corpus.OPTION for i in range(3)])
+    _warmed(broker, [_grp(i) + corpus.OPTION for i in range(3)])
     hits0 = _counter("cube_cache_hits")
-    _concurrent(broker, [_grp(i) + corpus.OPTION for i in range(3)])
+    for _ in range(5):          # who meets whom is the scheduler's
+        _concurrent(broker, [_grp(i) + corpus.OPTION for i in range(3)])
+        if _counter("cube_cache_hits") > hits0:
+            break
     assert _counter("cube_cache_hits") > hits0
     # eviction by segment name drops the device cube
     seg = _dm.acquire_segments()[0]
@@ -548,9 +567,11 @@ def test_batching_health_and_ledger_fields():
     block = batching_health(snap)
     assert set(block["solo_fallbacks"]) == {
         "incompatible", "no_peers", "deadline",
-        "window_expired", "timeout", "leader_error"}
+        "window_expired", "timeout", "leader_error", "cold"}
     assert "le_8" in block["batch_size_histogram"]
     assert "enabled" in block and "batch_queue_depth" in block
+    assert {"cube_builds_pending", "cube_builds_background",
+            "fused_compiles_background"} <= set(block)
     # query_stats grows batched/batch_size — writer-validated
     from pinot_tpu.utils import ledger as uledger
     rec = uledger.make_record(
@@ -617,3 +638,321 @@ def test_micro_batch_queue_leader_follower():
     t.join(5)
     assert got["wbatch"] == ["L"]   # closed without the overflow item
     assert big == ["B"]             # which led its own (solo) window
+
+
+# -- PR 33: a query never pays for a cube or a fused program ----------------
+
+@pytest.fixture
+def cold(grouped):
+    """The grouped table with nothing of the fusion plane in place, a
+    standing peer (so ``no_peers`` never races the wave) and a window
+    that one wave always fills: every wave below is ONE batch."""
+    from pinot_tpu.engine.accounting import global_accountant
+    dm, broker = grouped
+    assert global_batcher.wait_ready(120.0)
+    global_cube_cache.clear()
+    global_batcher.clear()
+    global_accountant.register("cold-test-peer")
+    yield dm, broker
+    global_accountant.unregister("cold-test-peer")
+    assert global_batcher.wait_ready(120.0)
+
+
+def _wave(broker, n, offset=0):
+    """n variants of the grouped shape at once, as one batch."""
+    global_batcher.configure(enabled=True, window_ms=2000.0, max_batch=n)
+    return _concurrent(
+        broker, [_grp(offset + i) + corpus.OPTION for i in range(n)])
+
+
+def _solo_digests(broker, n, offset=0):
+    global_batcher.configure(enabled=False)
+    return [corpus.digest(broker.query(_grp(offset + i) + corpus.OPTION).rows)
+            for i in range(n)]
+
+
+def test_a_cold_group_answers_solo_and_right(cold):
+    _dm, broker = cold
+    want = _solo_digests(broker, 4)
+    cold0, fused0 = (_counter("solo_fallback_cold"),
+                     _counter("batched_queries"))
+    got = _wave(broker, 4)
+    assert [corpus.digest(r.rows) for r in got] == want
+    assert _counter("solo_fallback_cold") == cold0 + 4
+    assert _counter("batched_queries") == fused0
+    assert global_batcher.wait_ready(120.0)
+
+
+def test_the_background_builds_each_cube_once(cold):
+    """Four cold submissions of one shape over one segment ask for one
+    cube; a second cold wave (the program is missing now) builds none."""
+    _dm, broker = cold
+    builds0 = _counter("kernel_dispatches_cube_build")
+    bg0 = _counter("cube_builds_background")
+    _wave(broker, 4)
+    assert global_batcher.wait_ready(120.0)
+    assert _counter("kernel_dispatches_cube_build") == builds0 + 1
+    assert _counter("cube_builds_background") == bg0 + 1
+    compiles0 = _counter("fused_compiles_background")
+    _wave(broker, 4)
+    assert global_batcher.wait_ready(120.0)
+    assert _counter("kernel_dispatches_cube_build") == builds0 + 1
+    # the shape's whole ladder under max_batch 4: 2 and 4 padded items
+    assert _counter("fused_compiles_background") == compiles0 + 2
+
+
+def test_after_wait_ready_the_same_burst_fuses_byte_for_byte(cold):
+    _dm, broker = cold
+    want = _solo_digests(broker, 4)
+    for _ in range(2):      # the cubes, then the 4-item program
+        _wave(broker, 4)
+        assert global_batcher.wait_ready(120.0)
+    cold0, fused0 = (_counter("solo_fallback_cold"),
+                     _counter("batched_queries"))
+    n_wait, n_exec = (_counter("phase_n_ragged_wait"),
+                      _counter("phase_n_fused_execute"))
+    got = _wave(broker, 4)
+    assert [corpus.digest(r.rows) for r in got] == want
+    assert _counter("batched_queries") == fused0 + 4
+    assert _counter("solo_fallback_cold") == cold0
+    # every member crosses it in MicroBatchQueue.offer (the leader holds
+    # the window there, a follower returns at once) and the three
+    # followers again while they wait for the leader; one launch
+    assert _counter("phase_n_ragged_wait") == n_wait + 4 + 3
+    assert _counter("phase_n_fused_execute") == n_exec + 1
+    assert batching_health(
+        global_metrics.snapshot())["cube_builds_pending"] == 0
+
+
+def test_one_cold_batch_makes_the_whole_ladder(cold):
+    """Who meets whom later decides no compile: after one batch of four
+    was cold, batches of two and of three (4 padded items) fuse at once."""
+    _dm, broker = cold
+    for _ in range(2):      # the cubes, then the programs at 2 and 4
+        _wave(broker, 4)
+        assert global_batcher.wait_ready(120.0)
+    cold0, compiles0 = (_counter("solo_fallback_cold"),
+                        _counter("fused_compiles_background"))
+    for n in (2, 3):
+        fused0 = _counter("batched_queries")
+        want = _solo_digests(broker, n, offset=n)
+        got = _wave(broker, n, offset=n)
+        assert [corpus.digest(r.rows) for r in got] == want
+        assert _counter("batched_queries") == fused0 + n
+    assert global_batcher.wait_ready(1.0)
+    assert _counter("solo_fallback_cold") == cold0
+    assert _counter("fused_compiles_background") == compiles0
+
+
+def test_a_build_slower_than_the_deadline_fails_no_query(cold, monkeypatch):
+    """The cube build outlasts every query's timeoutMs: the queries
+    answer solo inside it, and the build still lands."""
+    _dm, broker = cold
+    want = _solo_digests(broker, 3)
+    real = RaggedBatcher._build_cube
+    started = threading.Event()
+
+    def slow(self, spec, plan):
+        started.set()
+        time.sleep(1.5)
+        return real(self, spec, plan)
+
+    monkeypatch.setattr(RaggedBatcher, "_build_cube", slow)
+    global_batcher.configure(enabled=True, window_ms=20.0, max_batch=3)
+    t0 = time.perf_counter()
+    got = _concurrent(broker, [_grp(i) + " OPTION(timeoutMs=1000)"
+                               for i in range(3)])
+    wall = time.perf_counter() - t0
+    assert [corpus.digest(r.rows) for r in got] == want
+    assert started.wait(5.0) and wall < 1.0, wall
+    assert not global_batcher.wait_ready(0.01)      # still building
+    assert global_batcher.wait_ready(120.0)
+    assert not global_cube_cache.missing(
+        cube_spec_for(_plan_of(_dm, _grp(0)))[0], _dm.acquire_segments())
+
+
+def _plan_of(dm, sql):
+    from pinot_tpu.query.context import build_query_context
+    from pinot_tpu.query.planner import SegmentPlanner
+    from pinot_tpu.query.sql import parse_sql
+    return SegmentPlanner(build_query_context(parse_sql(sql)),
+                          dm.acquire_segments()[0]).plan()
+
+
+def test_the_latency_estimate_is_fed_by_warm_dispatches_only(cold):
+    _dm, broker = cold
+    for _ in range(2):
+        _wave(broker, 2)
+        assert global_batcher.wait_ready(120.0)
+        # two cold rounds, a cube build and a compile behind them: no
+        # estimate, so no shape is sent solo for what its first try cost
+        assert not global_batcher._est_ms
+    _wave(broker, 2)
+    assert len(global_batcher._est_ms) == 1
+
+
+def test_a_lone_query_returns_no_peers_first_even_when_cold(grouped):
+    _dm, broker = grouped
+    assert global_batcher.wait_ready(120.0)
+    global_cube_cache.clear()
+    global_batcher.clear()
+    global_batcher.configure(enabled=True, window_ms=2000.0)
+    lone0, cold0, builds0 = (_counter("solo_fallback_no_peers"),
+                             _counter("solo_fallback_cold"),
+                             _counter("kernel_dispatches_cube_build"))
+    assert broker.query(_grp(0) + corpus.OPTION).rows
+    assert _counter("solo_fallback_no_peers") == lone0 + 1
+    assert _counter("solo_fallback_cold") == cold0
+    assert global_batcher.wait_ready(1.0)
+    assert _counter("kernel_dispatches_cube_build") == builds0
+
+
+def test_an_evicted_cube_is_rebuilt_in_the_background(cold):
+    dm, broker = cold
+    for _ in range(3):
+        _wave(broker, 2)
+        assert global_batcher.wait_ready(120.0)
+    dm.acquire_segments()[0].evict_device()     # drops cube and stack
+    want = _solo_digests(broker, 2)
+    cold0, builds0 = (_counter("solo_fallback_cold"),
+                      _counter("kernel_dispatches_cube_build"))
+    got = _wave(broker, 2)
+    assert [corpus.digest(r.rows) for r in got] == want
+    assert _counter("solo_fallback_cold") == cold0 + 2
+    assert global_batcher.wait_ready(120.0)
+    assert _counter("kernel_dispatches_cube_build") == builds0 + 1
+
+
+def test_a_failed_build_fails_no_query_and_is_not_asked_for_again(
+        cold, monkeypatch):
+    _dm, broker = cold
+    want = _solo_digests(broker, 2)
+    calls = []
+
+    def broken(self, spec, plan):
+        calls.append(plan.segment.name)
+        raise RuntimeError("planted: the cube program does not build")
+
+    monkeypatch.setattr(RaggedBatcher, "_build_cube", broken)
+    errors0 = _counter("cube_build_errors")
+    for _ in range(2):
+        got = _wave(broker, 2)
+        assert [corpus.digest(r.rows) for r in got] == want
+        assert global_batcher.wait_ready(120.0)
+    assert len(calls) == 1
+    assert _counter("cube_build_errors") == errors0 + 1
+
+
+def test_a_group_the_budget_cannot_hold_twice_is_incompatible(
+        cold, monkeypatch):
+    """No peer could ever join it: it neither waits the window nor has
+    a cube built for nothing."""
+    from pinot_tpu.engine import ragged
+    dm, broker = cold
+    spec, _ = cube_spec_for(_plan_of(dm, _grp(0)))
+    monkeypatch.setattr(ragged, "ITEM_CELL_BUDGET", spec.cube_space)
+    inc0 = _counter("solo_fallback_incompatible")
+    t0 = time.perf_counter()
+    _wave(broker, 2)
+    assert time.perf_counter() - t0 < 1.5       # the window is 2 s
+    assert _counter("solo_fallback_incompatible") == inc0 + 2
+    assert global_batcher.wait_ready(1.0)
+    assert global_cube_cache.missing(spec, dm.acquire_segments())
+
+
+def test_the_two_programs_carry_their_own_scopes(grouped):
+    """``pinot.cube_build`` and ``pinot.cube_combine`` name the batcher's
+    operations in a device trace (HLO metadata only)."""
+    import jax
+
+    from pinot_tpu.engine import ragged
+    from pinot_tpu.engine.executor import resolve_params
+    from pinot_tpu.utils import phases as ph
+    dm, _broker = grouped
+    plan = _plan_of(dm, _grp(0))
+    spec, _ = cube_spec_for(plan)
+    seg = plan.segment
+    params = resolve_params(plan)
+    built = jax.jit(ragged.build_cube_kernel(spec)).lower(
+        seg.device_cols(plan.col_names), np.int32(seg.n_docs), params)
+    assert ph.SCOPE_CUBE_BUILD in built.as_text(debug_info=True)
+    cube = {k: np.zeros((1, spec.cube_space), np.int64)
+            for k in ("cnt", "s0")}
+    stacked = tuple(np.stack([np.asarray(p)] * 2) for p in params)
+    combined = jax.jit(ragged.build_cube_combine_kernel(spec)).lower(
+        cube, np.zeros(2, np.int32), stacked)
+    assert ph.SCOPE_CUBE_COMBINE in combined.as_text(debug_info=True)
+    assert {ph.SCOPE_CUBE_BUILD, ph.SCOPE_CUBE_COMBINE} <= ph.KERNEL_SCOPES
+    assert {ph.RAGGED_WAIT, ph.FUSED_EXECUTE} <= ph.METERED_PHASES
+
+
+def test_prometheus_shows_the_new_names(cold):
+    """They are ``global_metrics`` names, so ``GET /metrics/prometheus``
+    (cluster/broker_node.py renders this text) carries them once a cold
+    wave and a warm one have passed."""
+    _dm, broker = cold
+    for _ in range(3):
+        _wave(broker, 2)
+        assert global_batcher.wait_ready(120.0)
+    text = global_metrics.prometheus()
+    for line in ("pinot_tpu_solo_fallback_cold_total",
+                 "pinot_tpu_cube_builds_background_total",
+                 "pinot_tpu_fused_compiles_background_total",
+                 "pinot_tpu_phase_us_ragged_wait_total",
+                 "pinot_tpu_phase_n_fused_execute_total",
+                 "pinot_tpu_cube_builds_pending 0"):
+        assert line in text, line
+
+
+def test_the_background_runs_each_key_once_under_contention():
+    """More requesters than cores, a short switch interval: every key's
+    job runs exactly once while it is queued or running, a job that
+    raised is not asked for again, ``wait_idle`` sees the queue drain and
+    the worker thread is gone afterwards."""
+    import sys
+
+    from pinot_tpu.engine.ragged import _Background
+    bg = _Background()
+    ran: dict = {}
+    lock = threading.Lock()
+
+    def job(k):
+        def run():
+            with lock:
+                ran[k] = ran.get(k, 0) + 1
+            if k % 7 == 0:
+                raise RuntimeError("planted")
+        return run
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        def requester(i):
+            for k in range(64):
+                bg.request(("stress", k), job(k))
+
+        threads = [threading.Thread(target=requester, args=(i,))
+                   for i in range(32)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30.0)
+            assert not t.is_alive()
+        assert bg.wait_idle(30.0)
+        # the failed ones stay refused, the others may be asked again
+        for k in range(64):
+            bg.request(("stress", k), job(k))
+        assert bg.wait_idle(30.0)
+    finally:
+        sys.setswitchinterval(interval)
+    for k in range(64):
+        if k % 7 == 0:
+            assert ran[k] == 1, (k, ran[k])
+        else:
+            # once while queued or running; again only after it finished
+            assert 1 <= ran[k] <= 33, (k, ran[k])
+    assert len(ran) == 64 and not bg._keys and not bg._jobs
+    deadline = time.time() + 5.0
+    while bg._thread is not None and time.time() < deadline:
+        time.sleep(0.01)
+    assert bg._thread is None

@@ -29,10 +29,15 @@ first (default ``chiprun_out/<cell>.<seed>.xplane.pb``), prints the run's
 result line, then reads the kept file. Nothing under ``benchmark/``
 changes for it. After the result line it prints how far the work counters
 ``kernel_dispatches``, ``dict_decode_select`` and ``dict_decode_gather``
-(``pinot_tpu/utils/spans.count_dispatch``) and ``sparse_post_results`` /
+(``pinot_tpu/utils/spans.count_dispatch``), ``sparse_post_results`` /
 ``sparse_post_probes_<P>`` (``engine/executor.run_kernel``: the per-segment
-route's sparse posts, by the probe count their tail took) moved a request
-of the window.
+route's sparse posts, by the probe count their tail took) and the
+micro-batcher's (``engine/ragged.py``: ``batched_queries``,
+``solo_fallback_<reason>``, the background's builds and compiles, the
+crossings of ``ragged_wait`` and ``fused_execute``) moved a request of the
+window. The batcher's two programs show in table (c) as
+``jit_pinot_cube_build`` / ``jit_pinot_ragged_fused`` and under the scopes
+``pinot.cube_build`` / ``pinot.cube_combine``.
 Interval arithmetic (``merge``, ``clip``, ``self_times``)
 is ``benchmark/trace/reduce.py``'s, by import.
 
@@ -67,10 +72,20 @@ PHASE_PREFIX = "pinot."
 SCOPE = re.compile(r"pinot\.[a-z_]+")
 RUN_ID = re.compile(r"\(\d+\)$")          # jit_pinot_dense_vmap(1234567)
 WORK_COUNTERS = ("kernel_dispatches", "dict_decode_select",
-                 "dict_decode_gather", "sparse_post_results")
-# and every counter of this prefix the program has counted: one a probe
-# count of the kernel's ladder (ops/kernels._sparse_post_sizes)
-PROBE_COUNTERS = "sparse_post_probes_"
+                 "dict_decode_gather", "sparse_post_results",
+                 # the micro-batcher (engine/ragged.py): queries answered
+                 # by a fused launch, the launches, the cube builds and
+                 # combine compiles its background made, and how often the
+                 # two phases it owns were crossed
+                 "batched_queries", "batched_dispatches",
+                 "kernel_dispatches_ragged_fused",
+                 "kernel_dispatches_cube_build", "cube_builds_background",
+                 "fused_compiles_background", "phase_n_ragged_wait",
+                 "phase_n_fused_execute")
+# and every counter of these prefixes the program has counted: one a
+# probe count of the kernel's ladder (ops/kernels._sparse_post_sizes),
+# one a reason a submission to the micro-batcher went solo
+PROBE_COUNTERS = ("sparse_post_probes_", "solo_fallback_")
 UNATTRIBUTED = "(in request, no program phase open)"
 NO_REQUEST = "(no request open)"
 Event = Tuple[str, float, float, dict]    # name, start s, end s, stats
