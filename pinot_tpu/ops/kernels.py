@@ -1705,10 +1705,10 @@ def build_kernel(plan: KernelPlan, bucket: int,
     return kernel
 
 
-# dense (space,) group outputs above this space are compacted on device to
-# the non-empty groups before transfer — a 437k-group dense row set is
-# ~10MB over several arrays (the transfer cost on this host's link: not
-# measured)
+# group outputs over this space reach the host as (group_idx, value) rows
+# of the non-empty groups, GROUP_XFER_CAP of them: from the sorted core's
+# sparse post, from the mesh's list of its devices' ids (parallel/
+# distributed._gather_live_groups) or, dense outputs, _compact_group_xfer
 GROUP_XFER_SPACE = 1 << 15
 GROUP_XFER_CAP = 1 << 15
 

@@ -28,7 +28,9 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..engine.executor import extract_partial, resolve_params
-from ..ops.kernels import build_kernel, dict_decode_forms, sort_core_fits
+from ..ops.kernels import (build_kernel, cpu_scatter_default,
+                           dict_decode_forms, sort_core_fits,
+                           takes_sparse_post)
 from ..utils import phases as ph
 from ..utils.devmem import global_device_memory
 from ..utils.metrics import global_metrics
@@ -312,6 +314,14 @@ class DistributedTable:
                                         xfer_compact=False)
                 host.pop("overflow", None)
                 annotate(group_overflow_retry=True)
+            if "group_idx" in host:
+                # the result came back compacted to its live groups:
+                # count where the program took the list of them from
+                global_metrics.count(
+                    "mesh_live_list_sparse" if lists_live_groups_sparse(
+                        plan.kernel_plan, family, True, cpu_scatter_default(
+                            self.mesh.devices.flat[0].platform))
+                    else "mesh_live_list_dense")
             if "matched" in host:
                 matched = int(np.asarray(host["matched"]).sum())
                 annotate(matched=matched,
@@ -325,8 +335,7 @@ def _distributed_kernel(kernel_plan, bucket: int, mesh: Mesh,
                         n_cols: int, n_params: int,
                         slots_cap: Optional[int], family: str,
                         xfer_compact: bool = True):
-    from ..ops.kernels import (_ladder_min_elems, _two_pass_mode,
-                               cpu_scatter_default)
+    from ..ops.kernels import _ladder_min_elems, _two_pass_mode
 
     platform = mesh.devices.flat[0].platform
     # the compact-path env knobs resolve HERE so they are part of the
@@ -348,12 +357,28 @@ def _fold(name: str, v: jax.Array) -> jax.Array:
     return v.min(axis=0) if op == "min" else v.max(axis=0)  # max, 'or'
 
 
+def lists_live_groups_sparse(kernel_plan, family: str, xfer_compact: bool,
+                             scatter: bool) -> bool:
+    """Whether a mesh program lists the live groups of its combined
+    result from the devices' own sparse rows (_gather_live_groups) and
+    not by a nonzero over the dense group space (_compact_group_xfer):
+    exactly when the per-device kernel hands its groups over sparse. The
+    program's rule, and the host's where it counts which of the two a
+    transfer-compacted result took (mesh_live_list_sparse / _dense in
+    DistributedTable._run), so the counter cannot fork from the branch
+    the device took."""
+    return (family != ph.MESH_DENSE
+            and takes_sparse_post(kernel_plan, xfer_compact, scatter))
+
+
 def _densify(out: Dict[str, jax.Array], space: int) -> Dict[str, jax.Array]:
     """Sparse group outputs — (group_idx, value) rows as the sorted core's
-    sparse post and _compact_group_xfer emit them, of one kernel call or
-    stacked over the local segments — scattered into this device's dense
-    (space,) partial, which the positional collectives can combine. A
-    sentinel row (group_idx == space) falls outside and is dropped."""
+    sparse post emits them, of one kernel call or stacked over the local
+    segments — scattered into this device's dense (space,) partial, which
+    the positional collectives can combine. A sentinel row (group_idx ==
+    space) falls outside and is dropped. The ids themselves are not lost
+    with it: _gather_live_groups lists the combined result's live groups
+    from them after the collectives."""
     from ..ops.kernels import _extreme
     idx = out["group_idx"].reshape(-1)
     dense = {"group_overflow": out["group_overflow"].sum()}
@@ -370,6 +395,38 @@ def _densify(out: Dict[str, jax.Array], space: int) -> Dict[str, jax.Array]:
         combine = {"sum": at.add, "min": at.min}.get(op, at.max)
         dense[k] = combine(v.reshape(-1), mode="drop")
     return dense
+
+
+@jax.named_scope(ph.SCOPE_XFER_COMPACT)
+def _gather_live_groups(space: int, ids: jax.Array,
+                        red: Dict[str, jax.Array]) -> None:
+    """_compact_group_xfer's contract for a combined result whose
+    devices emitted their groups sparse, at a cost that follows the ids
+    and not the group space: ``ids`` are this device's rows of dense
+    space ids (live groups first, the sentinel ``space`` behind them);
+    the live groups of the combined result are exactly the union of every
+    device's ids (a sparse row's id is live iff its count is positive),
+    so the sorted distinct union IS nonzero(group_count > 0): ascending,
+    padded with ``space`` to GROUP_XFER_CAP. Every device computes the
+    same replicated list and gathers the dense combined outputs at it,
+    sentinel rows zeroed; group_overflow flags more distinct ids than
+    the list holds (the executor retries with xfer_compact=False)."""
+    from ..ops.kernels import GROUP_XFER_CAP
+    ids = jnp.sort(jax.lax.all_gather(ids.reshape(-1), SEG_AXIS,
+                                      tiled=True))
+    repeat = jnp.concatenate(
+        [jnp.zeros(1, jnp.bool_), ids[1:] == ids[:-1]])
+    ids = jnp.where(repeat, jnp.int32(space), ids)
+    # distinct ids first, ascending; the sentinel sorts behind them
+    idx = jnp.sort(ids)[:GROUP_XFER_CAP]
+    dense = [k for k in red if k not in ("matched", "overflow")]
+    red["group_idx"] = idx
+    red["group_overflow"] = (jnp.sum(ids < space, dtype=jnp.int32)
+                             > GROUP_XFER_CAP).astype(jnp.int32)
+    for k in dense:
+        v = red[k]
+        red[k] = jnp.where(idx < space, v.at[idx].get(mode="clip"),
+                           jnp.zeros((), dtype=v.dtype))
 
 
 @functools.lru_cache(maxsize=512)
@@ -389,12 +446,18 @@ def _distributed_kernel_cached(kernel_plan, bucket: int, mesh: Mesh,
     # groups sparse (the sorted core's sparse post: cost by compacted
     # rows, not by the space — q4.3's 1.75M groups); _densify scatters
     # them into the device's dense partial before the collectives, and
-    # the combined result is compacted to its live groups for the
-    # transfer, as on one chip. platform pins the kernel lowering to the
-    # mesh's backend (the driver's dryrun runs a CPU mesh under a TPU
-    # process default).
+    # the combined result is gathered at its live groups for the
+    # transfer: listed from the devices' own ids (_gather_live_groups),
+    # so no pass over the space stands between the collectives and the
+    # copy back. A kernel that emits dense groups over a large space
+    # (the dense strategy, the scatter core) has no such ids and keeps
+    # the one-chip _compact_group_xfer. platform pins the kernel
+    # lowering to the mesh's backend (the driver's dryrun runs a CPU
+    # mesh under a TPU process default).
     platform = mesh.devices.flat[0].platform
     compact = family != ph.MESH_DENSE
+    sparse_list = lists_live_groups_sparse(kernel_plan, family,
+                                           xfer_compact, scatter)
 
     def per_device(cols, n_docs, params):
         # cols: tuple of (L, bucket) local shards; n_docs: (L,)
@@ -418,7 +481,10 @@ def _distributed_kernel_cached(kernel_plan, bucket: int, mesh: Mesh,
                                 (cols, n_docs))
         else:
             local = jax.vmap(lambda c, n: kern(c, n, params))(cols, n_docs)
-        if "group_idx" in local:
+        # the sparse post's ids: (cap,) on the flattened route, (L, cap)
+        # on the routed core
+        ids = local.get("group_idx")
+        if ids is not None:
             local = _densify(local, kernel_plan.group_space)
         elif family != ph.MESH_COMPACT:
             local = {k: _fold(k, v) for k, v in local.items()}
@@ -439,7 +505,10 @@ def _distributed_kernel_cached(kernel_plan, bucket: int, mesh: Mesh,
             # live groups only over the wire to the host; a segment whose
             # sparse post overflowed counts with a result that does
             spilled = red.pop("group_overflow", 0)
-            _compact_group_xfer(kernel_plan, red)
+            if sparse_list:
+                _gather_live_groups(kernel_plan.group_space, ids, red)
+            else:
+                _compact_group_xfer(kernel_plan, red)
             if "group_overflow" in red:
                 red["group_overflow"] = red["group_overflow"] + spilled
             else:

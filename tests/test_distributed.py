@@ -324,3 +324,195 @@ def test_distributed_expression_group_key(tmp_path_factory):
                  int(amt[years == y].sum()))
                 for y in np.unique(years)]
     assert rows == expected
+
+
+# ---------------------------------------------------------------------------
+# the transfer compaction's live list, from the devices' own sparse rows
+# ---------------------------------------------------------------------------
+
+PAIR_SEGMENTS = 8           # two a device of a four-device mesh
+PAIR_ROWS = 2048
+SMALL_CAP = 512             # GROUP_XFER_CAP shrunk, for the overflow cases
+
+
+def _pair(g):
+    """A group of the 256 x 256 pair space from one number."""
+    return g // 256, g % 256
+
+
+def _pair_rows():
+    """Rows (ka, kb, tag, v) a segment; a tag is one case's filter."""
+    rng = np.random.default_rng(34)
+    rows = [[] for _ in range(PAIR_SEGMENTS)]
+
+    def put(seg, tag, g, n=1):
+        ka, kb = _pair(g)
+        rows[seg].extend((ka, kb, tag, int(v))
+                         for v in rng.integers(-10**6, 10**6, n))
+
+    for j in range(256):                  # every dictionary comes out whole
+        put(0, 0, 257 * j)
+    for g in (772, 773, 51217, 65535, 0):  # tag 1: one segment only
+        put(1, 1, g, 3)
+    for seg in range(PAIR_SEGMENTS):      # tag 2: the same ids everywhere
+        for g in (257, 762, 25700, 65027, 300, 301, 40000):
+            put(seg, 2, g, 2)
+        put(seg, 3, 1000 + seg)           # tag 3 AND ka = 9: no row at all
+    for k in range(SMALL_CAP + 1):        # tags 4, 5: SMALL_CAP ids, and one
+        for seg in (k % PAIR_SEGMENTS, (k + 1) % PAIR_SEGMENTS):
+            if k < SMALL_CAP:
+                put(seg, 4, 7 * k + 3)
+            put(seg, 5, 7 * k + 3)
+    for k in range(SMALL_CAP + 5):        # tag 6: one segment's post spills
+        put(2, 6, 11 * k + 1)
+    assert max(len(r) for r in rows) <= PAIR_ROWS
+    return rows
+
+
+@pytest.fixture(scope="module")
+def pair_table(tmp_path_factory):
+    """Two keys of 256 values: a group space of 65,536, over
+    GROUP_XFER_SPACE and on the sort core's sparse post."""
+    schema = Schema("pairs", [
+        FieldSpec("ka", DataType.INT, FieldType.DIMENSION),
+        FieldSpec("kb", DataType.INT, FieldType.DIMENSION),
+        FieldSpec("tag", DataType.INT, FieldType.DIMENSION),
+        FieldSpec("v", DataType.LONG, FieldType.METRIC)])
+    cfg = TableConfig("pairs")
+    chunks = []
+    for seg in _pair_rows():
+        ka, kb, tag, v = (np.array(c) for c in zip(*seg))
+        chunks.append({"ka": ka.astype(np.int32), "kb": kb.astype(np.int32),
+                       "tag": tag.astype(np.int32),
+                       "v": v.astype(np.int64)})
+    shared = build_table_dictionaries(schema, cfg, chunks)
+    builder = SegmentBuilder(schema, cfg)
+    out = tmp_path_factory.mktemp("pairs_table")
+    dm = TableDataManager("pairs")
+    for i, chunk in enumerate(chunks):
+        dm.add_segment_dir(builder.build(chunk, str(out), f"seg_{i}",
+                                         shared_dicts=shared))
+    data = {k: np.concatenate([c[k] for c in chunks]) for k in chunks[0]}
+    return dm, data
+
+
+@pytest.fixture()
+def fresh_mesh_programs():
+    """A mesh program reads GROUP_XFER_CAP as it is traced: none traced
+    under one capacity may answer under another."""
+    from pinot_tpu.parallel import distributed
+    distributed._distributed_kernel_cached.cache_clear()
+    yield
+    distributed._distributed_kernel_cached.cache_clear()
+
+
+# case: (WHERE, the same filter over the host columns, GROUP_XFER_CAP or
+#        None for the real one, live groups, whether the dense retry has
+#        to answer)
+LIVE_LIST_CASES = {
+    "one_device_only": ("tag = 1", lambda d: d["tag"] == 1, None, 5, False),
+    "repeats_collapse": ("tag = 2", lambda d: d["tag"] == 2, None, 7, False),
+    "no_live_group": ("tag = 3 AND ka = 9",
+                      lambda d: (d["tag"] == 3) & (d["ka"] == 9),
+                      None, 0, False),
+    "exactly_the_cap": ("tag = 4", lambda d: d["tag"] == 4,
+                        SMALL_CAP, SMALL_CAP, False),
+    "one_over_the_cap": ("tag = 5", lambda d: d["tag"] == 5,
+                         SMALL_CAP, SMALL_CAP + 1, True),
+    "a_segment_spilled": ("tag = 6", lambda d: d["tag"] == 6,
+                          SMALL_CAP, SMALL_CAP + 5, True),
+}
+
+
+def _same_bytes(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    return (a.dtype == b.dtype and a.shape == b.shape
+            and a.tobytes() == b.tobytes())
+
+
+@pytest.mark.parametrize("route", ["flattened", "routed"])
+@pytest.mark.parametrize("case", sorted(LIVE_LIST_CASES))
+def test_the_live_list_is_the_nonzero_of_the_combined_counts(
+        pair_table, fresh_mesh_programs, monkeypatch, case, route):
+    """The mesh program lists its combined result's live groups from the
+    devices' own sparse rows, (cap,) ids on the flattened route and
+    (L, cap) on the routed core: the list is jnp.nonzero(group_count > 0,
+    size=cap, fill_value=space) of the dense combined result and every
+    output (sum, min, max, avg's parts, the counts) what
+    _compact_group_xfer gathers there, byte for byte; more distinct ids
+    than the list holds, or a segment whose own post spilled, flag
+    group_overflow and the dense retry answers."""
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from pinot_tpu.engine.executor import resolve_params
+    from pinot_tpu.engine.reduce import reduce_partials
+    from pinot_tpu.ops import kernels
+    from pinot_tpu.parallel import distributed
+    from pinot_tpu.utils import phases as ph
+    from pinot_tpu.utils.metrics import global_metrics
+
+    where, matches, cap, n_live, retried = LIVE_LIST_CASES[case]
+    if cap is not None:
+        monkeypatch.setattr(kernels, "GROUP_XFER_CAP", cap)
+    cap = kernels.GROUP_XFER_CAP
+    dm, data = pair_table
+    dist = DistributedTable(
+        dm.acquire_segments(), segment_mesh(devices=jax.devices()[:4]),
+        # any limit under a local shard's rows routes per local segment
+        sort_row_limit=1 if route == "routed" else None)
+    ctx = _ctx("SELECT ka, kb, COUNT(*), SUM(v), MIN(v), MAX(v), AVG(v) "
+               f"FROM pairs WHERE {where} GROUP BY ka, kb "
+               "ORDER BY ka, kb LIMIT 100000")
+    plan = dist.mesh_plan(ctx)
+    kp = plan.kernel_plan
+    family = dist._route(kp)
+    assert family == (ph.MESH_COMPACT_PER_SEGMENT if route == "routed"
+                      else ph.MESH_COMPACT)
+    assert dist.local_segments == 2
+    assert distributed.lists_live_groups_sparse(kp, family, True, False)
+    space = kp.group_space
+    assert space == 65536 >= kernels.GROUP_XFER_SPACE
+
+    cols = tuple(dist.device_col(n) for n in plan.col_names)
+    params = resolve_params(plan, sharding=dist._sharding(P()))
+    slots = dist._cost_model_cap(
+        plan, dist.bucket * (1 if route == "routed" else 2))
+    got = dist._launch(plan, family, slots, cols, params)
+    dense = dist._launch(plan, family, slots, cols, params,
+                         xfer_compact=False)
+    assert not int(got.pop("overflow")) and not int(dense.pop("overflow"))
+    assert dense["group_count"].shape == (space,)
+    assert int((dense["group_count"] > 0).sum()) == n_live
+
+    want_idx, = jnp.nonzero(jnp.asarray(dense["group_count"]) > 0,
+                            size=cap, fill_value=space)
+    assert _same_bytes(got["group_idx"], want_idx.astype(jnp.int32))
+    want = {k: jnp.asarray(v) for k, v in dense.items()}
+    kernels._compact_group_xfer(kp, want)
+    assert int(want.pop("group_overflow")) == int(n_live > cap)
+    assert bool(got.pop("group_overflow")) == retried
+    assert sorted(got) == sorted(want)
+    assert {"agg1_sum", "agg2_min", "agg3_max", "agg4_avg_sum",
+            "agg4_avg_cnt", "group_count"} <= set(got)
+    for k in want:
+        assert _same_bytes(got[k], want[k]), k
+
+    before = dict(global_metrics.snapshot()["counters"])
+    rows = reduce_partials(ctx, [dist.execute(plan)]).rows
+    after = global_metrics.snapshot()["counters"]
+    moved = {k: after.get(k, 0) - before.get(k, 0)
+             for k in ("group_xfer_overflow_retries",
+                       "mesh_live_list_sparse", "mesh_live_list_dense")}
+    assert moved == {"group_xfer_overflow_retries": int(retried),
+                     "mesh_live_list_sparse": int(not retried),
+                     "mesh_live_list_dense": 0}
+    mask = matches(data)
+    groups = {}
+    for ka, kb, v in zip(data["ka"][mask], data["kb"][mask],
+                         data["v"][mask]):
+        groups.setdefault((int(ka), int(kb)), []).append(int(v))
+    assert len(groups) == n_live
+    assert [tuple(r) for r in rows] == [
+        (ka, kb, len(vs), sum(vs), min(vs), max(vs),
+         pytest.approx(sum(vs) / len(vs)))
+        for (ka, kb), vs in sorted(groups.items())]

@@ -281,8 +281,9 @@ def test_an_overflow_retries_at_full_capacity_once(mesh_trio, segments,
     capacity that cannot overflow (of the shard, or of one segment on the
     routed core), the answer is right, and the next execution of the plan
     goes straight there. The group outputs are sparse (the sorted core's
-    sparse post, densified on the device for the collectives, compacted
-    again for the transfer) until they spill."""
+    sparse post, densified on the device for the collectives, gathered
+    for the transfer at the live list the devices' own ids give) until
+    they spill."""
     from pinot_tpu.engine.reduce import reduce_partials
     dist = DistributedTable(segments,
                             segment_mesh(devices=jax.devices()[:N_DEV]),
@@ -433,25 +434,169 @@ def test_a_sampled_mesh_query_names_its_route(mesh_trio):
     assert "route=mesh_" in detail and "slots_cap=" in detail
 
 
-def test_the_mesh_programs_carry_their_names(segments):
-    """XLA Modules reads jit_pinot_mesh_<route>; the collectives sit
-    under pinot.combine."""
+def _lowered(dist, key, xfer_compact=True):
+    """(kernel plan, route, lowered program) of a statement on ``dist``."""
     from pinot_tpu.engine.executor import resolve_params
     from pinot_tpu.parallel import distributed
     from jax.sharding import PartitionSpec as P
+    plan = dist.mesh_plan(_ctx(key))
+    family = dist._route(plan.kernel_plan)
+    cols = tuple(dist.device_col(n) for n in plan.col_names)
+    params = resolve_params(plan, sharding=dist._sharding(P()))
+    rows = dist.bucket * (1 if family == ph.MESH_COMPACT_PER_SEGMENT
+                          else dist.local_segments)
+    fn = distributed._distributed_kernel(
+        plan.kernel_plan, dist.bucket, dist.mesh, len(cols), len(params),
+        dist._cost_model_cap(plan, rows), family, xfer_compact)
+    return (plan.kernel_plan, family,
+            fn._fn.lower(cols, dist._n_docs, params))
+
+
+def test_the_mesh_programs_carry_their_names(segments):
+    """XLA Modules reads jit_pinot_mesh_<route>; the collectives sit
+    under pinot.combine."""
     mesh = segment_mesh(devices=jax.devices()[:N_DEV])
     dist = DistributedTable(segments, mesh, sort_row_limit=ROWS)
     for key in ("q1.1", "q2.1", "q3.2"):
-        plan = dist.mesh_plan(_ctx(key))
-        family = dist._route(plan.kernel_plan)
-        cols = tuple(dist.device_col(n) for n in plan.col_names)
-        params = resolve_params(plan, sharding=dist._sharding(P()))
-        fn = distributed._distributed_kernel(
-            plan.kernel_plan, dist.bucket, mesh, len(cols), len(params),
-            None, family)
-        lowered = fn._fn.lower(cols, dist._n_docs, params)
+        _kp, family, lowered = _lowered(dist, key)
         assert f"@jit_pinot_{family}" in lowered.as_text()
         assert ph.SCOPE_COMBINE in lowered.as_text(debug_info=True)
+
+
+def _after_the_collectives(text):
+    """The lines of the mesh program's body behind its last collective,
+    and the signatures of the private functions they call."""
+    import re
+    lines = text.splitlines()
+    last = max(i for i, line in enumerate(lines) if "stablehlo.all_" in line)
+    end = next(i for i in range(last, len(lines))
+               if lines[i].strip().startswith(("return", "func.return",
+                                               "sdy.return")))
+    tail = lines[last + 1:end]
+    called = set(re.findall(r"call @([\w.]+)", "\n".join(tail)))
+    heads = [line for line in lines
+             if any(f"@{name}(" in line for name in called)
+             and "func.func" in line]
+    assert len(heads) == len(called), (called, heads)
+    return lines[last], tail, heads
+
+
+@pytest.mark.parametrize("key,limit", [("q4.3", ROWS), ("q3.2", ROWS),
+                                       ("q3.3", None), ("q3.4", None)])
+def test_no_pass_over_the_space_stands_behind_the_collectives(segments,
+                                                              key, limit):
+    """Between the collectives and the outputs of a program whose devices
+    emitted sparse rows, nothing has an operand or a result of
+    group_space elements but the gathers' operands: the live list comes
+    from an all_gather of the ids and two sorts of them, and a nonzero
+    over the space cannot come back unnoticed. The dense retry's program
+    (xfer_compact=False) and a scatter-core program keep what they had."""
+    dist = DistributedTable(segments,
+                            segment_mesh(devices=jax.devices()[:N_DEV]),
+                            sort_row_limit=limit)
+    kp, family, lowered = _lowered(dist, key)
+    text = lowered.as_text()
+    assert family == (ph.MESH_COMPACT if limit is None
+                      else ph.MESH_COMPACT_PER_SEGMENT)
+    wide = f"tensor<{kp.group_space}x"
+    assert wide in text                  # the dense partials, the psums
+    last, tail, heads = _after_the_collectives(text)
+    assert "stablehlo.all_gather" in last and "xi32>" in last
+    assert text.count("stablehlo.all_gather") == 1
+    assert sum("call @sort" in line or "stablehlo.sort" in line
+               for line in tail) == 2
+    gathers = [line for line in tail if wide in line]
+    assert gathers and not any(wide in h for h in heads)
+    for line in gathers:
+        assert '"stablehlo.gather"' in line, line
+        operands, result = line.rsplit("->", 1)
+        assert wide in operands and wide not in result, line
+    assert len(gathers) >= 2             # the counts and an aggregate
+    # the dense retry's program has no list at all
+    dense = _lowered(dist, key, xfer_compact=False)[2].as_text()
+    assert "stablehlo.all_gather" not in dense
+
+
+def test_a_small_space_program_is_lowered_as_before(segments, monkeypatch):
+    """The nine programs whose kernels hand over dense groups (three
+    dense, six flattened compact over 175-7,000 groups) never reach the
+    live list: no all_gather, no sort behind the collectives, and the
+    same text with the branch taken out of the module."""
+    from pinot_tpu.parallel import distributed
+    dist = DistributedTable(segments,
+                            segment_mesh(devices=jax.devices()[:N_DEV]))
+    texts = {}
+    for key in ("q1.1", "q2.1", "q3.1", "q4.2"):
+        kp, family, lowered = _lowered(dist, key)
+        texts[key] = lowered.as_text()
+        assert kp.group_space < kernels.GROUP_XFER_SPACE or not kp.is_group_by
+        assert not distributed.lists_live_groups_sparse(kp, family, True,
+                                                        False)
+        assert "stablehlo.all_gather" not in texts[key]
+        _last, tail, heads = _after_the_collectives(texts[key])
+        assert not any("sort" in line for line in tail + heads)
+
+    def gone(*_a, **_k):
+        raise AssertionError("a small-space program asked for the list")
+    monkeypatch.setattr(distributed, "_gather_live_groups", gone)
+    monkeypatch.setattr(distributed, "lists_live_groups_sparse",
+                        lambda *_a: False)
+    distributed._distributed_kernel_cached.cache_clear()
+    try:
+        for key, text in texts.items():
+            assert _lowered(dist, key)[2].as_text() == text, key
+    finally:
+        distributed._distributed_kernel_cached.cache_clear()
+
+
+@pytest.mark.parametrize("key,sparse", [("q3.2", 1), ("q3.3", 1),
+                                        ("q3.4", 1), ("q4.3", 1),
+                                        ("q2.1", 0), ("q1.1", 0)])
+def test_a_transfer_compacted_query_counts_where_its_list_came_from(
+        mesh_trio, key, sparse):
+    """mesh_live_list_sparse moves once for each statement whose result
+    comes back compacted to its live groups (the four over
+    GROUP_XFER_SPACE, whose devices emit sparse rows) and not at all for
+    a small-space statement; both names reach Prometheus."""
+    import urllib.request
+    before = counters()
+    rows = mesh_trio.rows(key)
+    d = moved(before)
+    assert d.get("mesh_live_list_sparse", 0) == sparse
+    assert d.get("mesh_live_list_dense", 0) == 0
+    assert oracle.same(rows, mesh_trio.expected(key), SHAPES[key])
+    if sparse:
+        with urllib.request.urlopen(
+                f"{mesh_trio.broker.url}/metrics/prometheus") as r:
+            assert b"mesh_live_list_sparse" in r.read()
+
+
+def test_a_dense_kernel_keeps_the_nonzero_and_is_counted(mesh_trio, segments,
+                                                         monkeypatch):
+    """The scatter core hands over dense (space,) groups: nothing lists
+    them but the nonzero over the space (_compact_group_xfer), the answer
+    is the same, and the query counts mesh_live_list_dense."""
+    import urllib.request
+    from pinot_tpu.engine.reduce import reduce_partials
+    from pinot_tpu.parallel import distributed
+    monkeypatch.setenv("PINOT_CPU_FAST_GROUPBY", "1")
+    dist = DistributedTable(segments,
+                            segment_mesh(devices=jax.devices()[:N_DEV]))
+    plan = dist.mesh_plan(_ctx("q3.2"))
+    kp = plan.kernel_plan
+    assert kp.group_space >= kernels.GROUP_XFER_SPACE
+    assert not distributed.lists_live_groups_sparse(
+        kp, dist._route(kp), True, kernels.cpu_scatter_default("cpu"))
+    before = counters()
+    rows = reduce_partials(_ctx("q3.2"), [dist.execute(plan)]).rows
+    d = moved(before)
+    assert d.get("mesh_live_list_dense", 0) == 1
+    assert d.get("mesh_live_list_sparse", 0) == 0
+    assert oracle.same(rows, mesh_trio.expected("q3.2"), SHAPES["q3.2"])
+    assert "stablehlo.all_gather" not in _lowered(dist, "q3.2")[2].as_text()
+    with urllib.request.urlopen(
+            f"{mesh_trio.broker.url}/metrics/prometheus") as r:
+        assert b"mesh_live_list_dense" in r.read()
 
 
 # -- the segment set changes ---------------------------------------------------

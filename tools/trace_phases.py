@@ -31,7 +31,9 @@ changes for it. After the result line it prints how far the work counters
 ``kernel_dispatches``, ``dict_decode_select`` and ``dict_decode_gather``
 (``pinot_tpu/utils/spans.count_dispatch``), ``sparse_post_results`` /
 ``sparse_post_probes_<P>`` (``engine/executor.run_kernel``: the per-segment
-route's sparse posts, by the probe count their tail took) and the
+route's sparse posts, by the probe count their tail took),
+``mesh_live_list_sparse`` / ``_dense`` (``parallel/distributed``: where a
+mesh query's transfer compaction took its live list from) and the
 micro-batcher's (``engine/ragged.py``: ``batched_queries``,
 ``solo_fallback_<reason>``, the background's builds and compiles, the
 crossings of ``ragged_wait`` and ``fused_execute``) moved a request of the
@@ -84,8 +86,11 @@ WORK_COUNTERS = ("kernel_dispatches", "dict_decode_select",
                  "phase_n_fused_execute")
 # and every counter of these prefixes the program has counted: one a
 # probe count of the kernel's ladder (ops/kernels._sparse_post_sizes),
-# one a reason a submission to the micro-batcher went solo
-PROBE_COUNTERS = ("sparse_post_probes_", "solo_fallback_")
+# one a reason a submission to the micro-batcher went solo, one where a
+# mesh program took the live list of its transfer compaction from
+# (parallel/distributed.lists_live_groups_sparse)
+PROBE_COUNTERS = ("sparse_post_probes_", "solo_fallback_",
+                  "mesh_live_list_")
 UNATTRIBUTED = "(in request, no program phase open)"
 NO_REQUEST = "(no request open)"
 Event = Tuple[str, float, float, dict]    # name, start s, end s, stats
