@@ -17,8 +17,13 @@ Brand-new design with the capabilities of Apache Pinot (reference:
 
 OLAP needs exact 64-bit arithmetic (long counts, double sums — Pinot
 returns double for SUM over any numeric column). We therefore enable
-jax x64 at import; accumulator dtypes degrade gracefully on backends
-where f64 is emulated (see pinot_tpu.ops.aggregations.acc_dtypes).
+jax x64 at import, and with it on every float aggregate accumulates in
+float64 on every backend (pinot_tpu.ops.kernels.float_acc_dtype, the one
+rule). Where float64 is emulated (XLA:TPU: a pair of float32, 48 bits)
+the sums are blocked, and a SUM or AVG over 2^26 rows is within 1e-12
+relative of the exact decimal value; the launches that still pass
+through float32 (the compact group-by's payloads there) count
+float_acc_narrow.
 """
 
 import os

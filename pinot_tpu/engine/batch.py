@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.kernels import build_kernel, dict_decode_forms
+from ..ops.kernels import build_kernel, launch_forms
 from ..query.planner import CompiledPlan
 from ..utils import phases as ph
 from ..utils.devmem import global_device_memory
@@ -287,7 +287,7 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
                 _maybe_profile_phases(group_plans[0])
                 fn = _vmapped_kernel(plan_struct, bucket)
                 count_dispatch(ph.DENSE_VMAP,
-                               dict_decode_forms(plan_struct, params))
+                               *launch_forms(plan_struct, params))
                 with phase(ph.DEVICE_EXECUTE):
                     dev = fn(cols, n_docs, params)
                     device_fence(dev)
@@ -349,7 +349,7 @@ def _run_segmented_compact(plans, idxs, plan_struct, bucket, cols, n_docs,
               est_sel=plans[idxs[0]].est_selectivity):
         _maybe_profile_phases(plans[idxs[0]])
         fn = jitted_segmented_compact(plan_struct, bucket, n_seg, cap)
-        forms = dict_decode_forms(plan_struct, params, segmented=True)
+        forms = launch_forms(plan_struct, params, segmented=True)
         out = _launch_segmented(fn, cols, n_docs, params, forms)
         # retry-ladder checks + slicing below read host numpy behind the
         # fence above — host-sync [jaxlint baseline]
@@ -403,9 +403,11 @@ def _run_segmented_compact(plans, idxs, plan_struct, bucket, cols, n_docs,
 
 
 def _launch_segmented(fn, cols, n_docs, params,
-                      dict_forms: Tuple[int, int]) -> Dict[str, Any]:
-    """One launch of the segmented compact program and its copy back."""
-    count_dispatch(ph.COMPACT_SEGMENTED, dict_forms)
+                      forms: Tuple[Tuple[int, int], Tuple[int, int]]
+                      ) -> Dict[str, Any]:
+    """One launch of the segmented compact program and its copy back;
+    ``forms`` is its ops/kernels.launch_forms."""
+    count_dispatch(ph.COMPACT_SEGMENTED, *forms)
     with phase(ph.DEVICE_EXECUTE):
         dev = fn(cols, n_docs, params)
         device_fence(dev)

@@ -75,7 +75,7 @@ def execute_kernel_plans_pipelined(plans: List[CompiledPlan],
     (jax frees the buffers when the last reference dies after the
     dependent computation completes).
     """
-    from ..ops.kernels import dict_decode_forms, jitted_kernel
+    from ..ops.kernels import jitted_kernel, launch_forms
     from .accounting import global_accountant
     from .executor import extract_partial, resolve_params
 
@@ -86,7 +86,7 @@ def execute_kernel_plans_pipelined(plans: List[CompiledPlan],
     params = [resolve_params(p, host=host_params[i])
               for p, i in zip(group, idxs)]
     # one signature group: every segment's dictionaries have one shape
-    forms = dict_decode_forms(plan_struct, params[0])
+    forms = launch_forms(plan_struct, params[0])
 
     def stage(k: int):
         seg = group[k].segment
@@ -103,7 +103,7 @@ def execute_kernel_plans_pipelined(plans: List[CompiledPlan],
         # enqueue the NEXT transfer before compute: async dispatch lets
         # the H2D copy overlap this kernel on the transfer engine
         staged = stage(k + 1) if k + 1 < len(group) else None
-        count_dispatch(family, forms)
+        count_dispatch(family, *forms)
         out = fn(cur, jnp.int32(plan.segment.n_docs), params[k])
         outs.append(out)
         del cur  # last py-reference; freed once the kernel consumes it
@@ -131,7 +131,7 @@ def execute_kernel_plans_pipelined(plans: List[CompiledPlan],
             cols = tuple(jax.device_put(seg.host_col_padded(c, bucket))
                          for c in plan.col_names)
             from ..ops.plan_cache import global_plan_cache
-            count_dispatch(family, forms)
+            count_dispatch(family, *forms)
             with global_plan_cache.detector.expected():
                 # a deliberate dense rerun (compile-event taxonomy:
                 # overflow_retry, never a retrace)

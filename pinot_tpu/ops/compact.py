@@ -155,9 +155,13 @@ def compact(mask: jax.Array, cols: Tuple[jax.Array, ...], slots_cap: int,
 
     mask: (N,) bool; cols: tuple of (N,) arrays. 64-bit columns are
     bit-split into int32 pairs around the kernel. float64 columns on
-    backends without f64 bitcast support (TPU) are carried as float32 —
-    value-identical to the dense strategy there, which accumulates
-    float_acc_dtype()=f32 anyway (kernels.py documented tolerance).
+    backends without f64 bitcast support (TPU: a float64 is a pair of
+    float32 there and has no bit view) are carried as float32: the one
+    place a float aggregate's input still narrows. It is outside the
+    1e-12 bound the dense strategy meets on the chip, and a launch that
+    passes through here counts float_acc_narrow, not float_acc_wide
+    (kernels.float_acc_forms); carrying the pair's two planes is open
+    (PERF.md section 7).
     Returns (valid, out_cols, n_valid_rows, matched, overflow) with
     valid/out_cols of length slots_cap*128.
     """

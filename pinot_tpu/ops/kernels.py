@@ -21,8 +21,11 @@ stay EXACT by decomposing |v| into int8 limbs (base 2^b with
 (2^b-1)*bucket <= int32max so the MXU's int8xint8->int32 accumulation
 can't overflow), one row per limb per sign, recombined in int64.
 DISTINCTCOUNT presence is the same trick squared:
-one_hot(keys)^T @ one_hot(ids) > 0. Float sums accumulate in
-float_acc_dtype (f64 on CPU, f32 on TPU — documented tolerance).
+one_hot(keys)^T @ one_hot(ids) > 0. Float sums never ride a float32
+matmul: they accumulate in float_acc_dtype, float64 on every backend
+(XLA:TPU carries a float64 as a pair of float32, 48 bits), as blocked
+reductions (_float_sums) — a Q1 or Q6 answer over 2^26 rows is within
+1e-12 relative of the exact decimal sum (PERF.md section 6, PR 35).
 The dense cartesian dict-id key is DictionaryBasedGroupKeyGenerator
 .java:63 arithmetic.
 
@@ -56,6 +59,9 @@ DISTINCT_ONEHOT_CARD = 1 << 12
 # unrolled masked-reduce limit for group MIN/MAX (no matmul form exists;
 # above this the planner routes to segment ops on CPU or the host path)
 MINMAX_UNROLL_GROUPS = 64
+# dense group spaces up to this take one blocked masked float sum a group
+# (_group_float_sums); the same unroll, for the same reason
+FLOAT_UNROLL_GROUPS = MINMAX_UNROLL_GROUPS
 # longest dictionary _decode_dict decodes by a select chain instead of a
 # gather. From the v5e rows of PERF.md section 6 (PR 26): the Q1-shaped
 # dense kernel over 8 x 2^23 rows, chain | gather in ms a launch (chain's
@@ -83,14 +89,26 @@ def cpu_scatter_default(platform: Optional[str] = None) -> bool:
 
 
 def float_acc_dtype() -> jnp.dtype:
-    """Float accumulator dtype. Pinot SUM/MIN/MAX/AVG return double; on CPU
-    (tests — digest-exact vs numpy float64 oracle) we match that. On TPU
-    f64 is emulated and slow, so accumulate f32 and accept documented
-    tolerance (BASELINE.md: tolerance only where the reference itself is
-    order-dependent — float summation order already differs)."""
-    if jax.config.jax_enable_x64 and jax.default_backend() == "cpu":
-        return jnp.float64
-    return jnp.float32
+    """Float accumulator dtype: the ONE rule for every float SUM / AVG /
+    MIN / MAX, the arithmetic of a float value expression and the host's
+    count of what a launch did (float_acc_forms). Pinot's float
+    aggregates return double, so: float64 wherever jax_enable_x64 is on
+    (pinot_tpu/__init__ turns it on at import), on every backend. XLA:TPU
+    has no native float64: it carries one as a pair of float32 (hi + lo,
+    48 bits of significand, float32's exponent range) and lowers + and *
+    to TwoSum / Dekker sequences over the pair, about 20 float32
+    operations an add. That is the price of the stated bound: with the
+    sums blocked (_float_sums) a SUM or AVG over 2^26 rows of prices is
+    within 1e-12 relative of the exact decimal value on the chip
+    (measured: PERF.md section 6, PR 35), where a float32 accumulator
+    read 1e-7 and a float32 VALUE alone 5e-12. float32 only with x64
+    off, where no wider type exists."""
+    return jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+
+
+# addends of one running float sum: _float_sums reduces blocks of this
+# many rows, then the block sums
+FLOAT_SUM_BLOCK = 1 << 12
 
 
 def int_acc_dtype() -> jnp.dtype:
@@ -657,8 +675,18 @@ def _scalar_agg(i: int, spec: AggSpec, mask, cols, params,
         counts = _int8_dot(mask.astype(jnp.int8)[None, :], oh)[0]
         out[name + "_present"] = counts > 0
         return
-    vals = _eval_value(spec.value, cols, params, promote=spec.integral)
     acc = _acc_dtype(spec)
+    if spec.kind in ("sum", "avg") and not spec.integral:
+        # the one group of _float_sums: matched rows carry key 0
+        total = _float_sums([spec.value], cols, params,
+                            jnp.where(mask, 0, 1), 1)[0, 0]
+        if spec.kind == "avg":
+            out[name + "_sum"] = total
+            out[name + "_cnt"] = jnp.sum(mask, dtype=cnt_dtype)
+        else:
+            out[name] = total
+        return
+    vals = _eval_value(spec.value, cols, params, promote=spec.integral)
     if spec.kind == "sum":
         out[name] = jnp.sum(jnp.where(mask, vals, 0).astype(acc))
     elif spec.kind == "min":
@@ -764,9 +792,7 @@ def _group_aggs(plan: KernelPlan, mask, cols, params, bucket: int,
     int_rows: List[jax.Array] = [mask.astype(jnp.int8)]  # row 0: counts
     int_row_meta: List[Tuple[int, List[int], int]] = []  # (start, signs, b)
 
-    acc_f = float_acc_dtype()
-    float_rows: List[jax.Array] = []
-    float_row_names: List[str] = []
+    float_slots: Dict[ValueExpr, int] = {}   # SUM(x) and AVG(x) share x
 
     deferred: List[Tuple[int, AggSpec, str]] = []
 
@@ -786,9 +812,7 @@ def _group_aggs(plan: KernelPlan, mask, cols, params, bucket: int,
             int_rows.extend(rows)
             deferred.append((i, spec, "int_sum"))
         elif kind in ("sum", "avg"):
-            vals = _eval_value(spec.value, cols, params)
-            float_rows.append(jnp.where(mask, vals, 0).astype(acc_f))
-            float_row_names.append(name)
+            float_slots.setdefault(spec.value, len(float_slots))
             deferred.append((i, spec, "float_sum"))
         elif kind in ("min", "max"):
             deferred.append((i, spec, "minmax"))
@@ -802,14 +826,11 @@ def _group_aggs(plan: KernelPlan, mask, cols, params, bucket: int,
     counts = S[0].astype(int_acc_dtype())
     out["group_count"] = counts
 
-    if float_rows:
-        ohf = jax.nn.one_hot(keys_s, space, dtype=acc_f)
-        F = jax.lax.dot_general(jnp.stack(float_rows), ohf,
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=acc_f)
+    if float_slots:
+        F = _group_float_sums(list(float_slots), mask, keys_s, space, cols,
+                              params)
 
     meta_iter = iter(int_row_meta)
-    float_idx = 0
     for i, spec, how in deferred:
         name = _agg_name(i, spec)
         if how == "int_sum":
@@ -826,8 +847,7 @@ def _group_aggs(plan: KernelPlan, mask, cols, params, bucket: int,
             else:
                 out[name] = total
         elif how == "float_sum":
-            row = F[float_idx]
-            float_idx += 1
+            row = F[float_slots[spec.value]]
             if spec.kind == "avg":
                 out[name + "_sum"] = row
                 out[name + "_cnt"] = counts
@@ -845,6 +865,82 @@ def _group_aggs(plan: KernelPlan, mask, cols, params, bucket: int,
                 jnp.swapaxes(oh8, 0, 1), oh_ids, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.int32)  # (space, card)
             out[name + "_present"] = pair_counts > 0
+
+
+def _has_case(ve: ValueExpr) -> bool:
+    if isinstance(ve, Case):
+        return True
+    if isinstance(ve, Bin):
+        return _has_case(ve.lhs) or _has_case(ve.rhs)
+    if isinstance(ve, Func):
+        return any(_has_case(a) for a in ve.args)
+    return False
+
+
+@jax.named_scope(ph.SCOPE_FLOAT_ACC)
+def _float_sums(values: List[ValueExpr], cols, params, keys: jax.Array,
+                space: int) -> jax.Array:
+    """(len(values), space) sums of float value expressions by ``keys``
+    (ids in [0, space); any other id is in no sum) at float_acc_dtype().
+    The scalar scan is the case of one group.
+
+    One blocked masked sum a group and expression, as _group_minmax
+    unrolls — never a float32 matmul: the MXU multiplies float32 in
+    bfloat16 passes and accumulates in float32. Blocked: the rows are
+    laid out as (rows / FLOAT_SUM_BLOCK, FLOAT_SUM_BLOCK), a block's
+    addends are summed, then the block sums, so no accumulator takes more
+    addends than a block holds whatever order the backend's reduce walks.
+
+    The COLUMNS are laid out in blocks, before the expressions are
+    evaluated, not the addends after: XLA:TPU lowers a float64 sum to a
+    two-plane reduce and fuses the masked expression into it only when no
+    reshape stands between them. With one, every masked addend vector
+    goes through HBM, and TPC-H Q1's thirty (6 groups x 5 expressions)
+    over 8 x 2^23 rows are 15 GB: the chip's compiler refused that
+    program (PERF.md section 6, PR 35). An expression with a CASE is
+    evaluated flat (its predicates are written for (rows,) columns)."""
+    acc_f = float_acc_dtype()
+    zero = jnp.zeros((), acc_f)
+    n = keys.shape[0]
+    blocked = n > FLOAT_SUM_BLOCK and n % FLOAT_SUM_BLOCK == 0
+
+    def blocks(a):
+        return a.reshape((n // FLOAT_SUM_BLOCK, FLOAT_SUM_BLOCK)
+                         + a.shape[1:]) if blocked else a
+
+    keys_b, cols_b = blocks(keys), tuple(blocks(c) for c in cols)
+    out = []
+    for ve in values:
+        if _has_case(ve):
+            v = blocks(jnp.broadcast_to(_eval_value(ve, cols, params),
+                                        (n,)))
+        else:
+            v = _eval_value(ve, cols_b, params)
+        v = jnp.broadcast_to(v.astype(acc_f), keys_b.shape)
+        sums = [jnp.where(keys_b == g, v, zero).sum(axis=-1)
+                for g in range(space)]
+        out.append(jnp.stack([s.sum() for s in sums]))
+    return jnp.stack(out)
+
+
+def _group_float_sums(values: List[ValueExpr], mask, keys_s, space: int,
+                      cols, params) -> jax.Array:
+    """(len(values), space) dense group sums of float expressions. Up to
+    FLOAT_UNROLL_GROUPS groups: _float_sums. Larger spaces: the one-hot
+    dot_general at the accumulator dtype, which XLA:TPU lowers through
+    its float64 pairs; it holds the dtype and not the blocking, so such
+    a plan's float aggregates count as narrow on backends that emulate
+    float64 (float_acc_forms)."""
+    if space <= FLOAT_UNROLL_GROUPS:
+        return _float_sums(values, cols, params, keys_s, space)
+    acc_f = float_acc_dtype()
+    with jax.named_scope(ph.SCOPE_FLOAT_ACC):
+        rows = jnp.stack([
+            jnp.where(mask, _eval_value(ve, cols, params), 0).astype(acc_f)
+            for ve in values])
+        ohf = jax.nn.one_hot(keys_s, space, dtype=acc_f)
+        return jax.lax.dot_general(rows, ohf, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=acc_f)
 
 
 def _group_minmax(i: int, spec: AggSpec, mask, keys, space: int, cols,
@@ -1329,7 +1425,10 @@ def _factorized_post(sum_jobs, keys, valid, payloads, space, m, out):
         xs = xs + (fr_b,)
 
     S0 = jnp.zeros((n_int, n_hi, 128), jnp.int32)
-    F0 = jnp.zeros((len(frows), n_hi, 128), acc_f)
+    # no float rows: the empty carry keeps the dtype integer plans have
+    # always compiled with, so their programs are the same programs
+    F0 = jnp.zeros((len(frows), n_hi, 128),
+                   acc_f if frows else jnp.float32)
 
     def body(carry, xb):
         S, F = carry
@@ -1860,6 +1959,49 @@ def dict_decode_forms(plan: KernelPlan, params,
         n_select += _decodes_by_select(
             shape[0] * shape[1] if segmented else shape[-1])
     return n_select, len(pis) - n_select
+
+
+# aggregates whose state is a float accumulator when their value is not
+# integral (the sketches' mergeable summaries are not sums of the rows)
+_FLOAT_ACC_KINDS = frozenset({"sum", "avg", "min", "max"})
+
+
+def float_acc_forms(plan: KernelPlan, platform: Optional[str] = None
+                    ) -> Tuple[int, int]:
+    """(wide, narrow): how many float aggregates (SUM, AVG, MIN or MAX of
+    a value that is not integral) a launch of ``plan`` on ``platform``
+    keeps at float64 from the column to the partial it hands the host,
+    its sums blocked (_float_sums), and how many do not. Counted beside
+    dict_decode_forms (launch_forms, utils/spans.count_dispatch):
+    float_acc_wide and float_acc_narrow. Narrow is:
+
+    - every one where float_acc_dtype() is float32 (x64 off);
+    - on a backend that emulates float64 (every one but the CPU:
+      compact.f64_bitcast_ok), a grouped plan of the compact strategy
+      (ops/compact.compact carries a float64 payload as float32 there,
+      having no bit view of the pair) and a dense group-by over more
+      than FLOAT_UNROLL_GROUPS groups (_group_float_sums: an unblocked
+      dot_general through the emulation, held to no bound).
+
+    The scalar scan and the dense group-by of a few groups (TPC-H Q6 and
+    Q1) are wide everywhere."""
+    from .compact import f64_bitcast_ok
+    n = sum(1 for s in plan.aggs if s.kind in _FLOAT_ACC_KINDS
+            and s.value is not None and not s.integral)
+    narrow = n and (float_acc_dtype() != jnp.float64 or (
+        not f64_bitcast_ok(platform) and plan.is_group_by
+        and (plan.strategy == "compact"
+             or plan.group_space > FLOAT_UNROLL_GROUPS)))
+    return (0, n) if narrow else (n, 0)
+
+
+def launch_forms(plan: KernelPlan, params, segmented: bool = False,
+                 platform: Optional[str] = None
+                 ) -> Tuple[Tuple[int, int], Tuple[int, int]]:
+    """(dict_decode_forms, float_acc_forms) of one launch: what every
+    launch site hands utils/spans.count_dispatch after the family."""
+    return (dict_decode_forms(plan, params, segmented),
+            float_acc_forms(plan, platform))
 
 
 def segmented_compact_ok(plan: KernelPlan) -> bool:

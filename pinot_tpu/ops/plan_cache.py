@@ -194,7 +194,7 @@ class PlanCacheEntry:
             import uuid
             plan = ("plan_cache", uuid.uuid4().hex)
         self.family = ph.plan_family(plan)
-        # the structure dict_decode_forms reads at each launch (direct
+        # the structure _forms reads at each launch (direct
         # constructions pass a bare token: nothing to count)
         self._kernel_plan = plan if isinstance(plan, KernelPlan) else None
         if donate:
@@ -235,11 +235,11 @@ class PlanCacheEntry:
         return jax.tree_util.tree_map(
             lambda s: jnp.zeros(s.shape, s.dtype), shapes)
 
-    def _dict_forms(self, params) -> Tuple[int, int]:
+    def _forms(self, params) -> Tuple[Tuple[int, int], Tuple[int, int]]:
         if self._kernel_plan is None:
-            return (0, 0)
-        from .kernels import dict_decode_forms
-        return dict_decode_forms(self._kernel_plan, params)
+            return (0, 0), (0, 0)
+        from .kernels import launch_forms
+        return launch_forms(self._kernel_plan, params)
 
     def run(self, cols, n_docs, params) -> Dict[str, Any]:
         """Execute and return HOST numpy outputs.
@@ -258,7 +258,7 @@ class PlanCacheEntry:
             with self.lock:
                 self.runs += 1
                 first = self.runs == 1
-            count_dispatch(self.family, self._dict_forms(params))
+            count_dispatch(self.family, *self._forms(params))
             with phase(ph.DEVICE_EXECUTE, compiled=first):
                 out = self.fn(cols, n_docs, params)
                 device_fence(out)
@@ -270,7 +270,7 @@ class PlanCacheEntry:
             first = self.runs == 1
             if self._acc is None:
                 self._acc = self.make_acc(cols, n_docs, params)
-            count_dispatch(self.family, self._dict_forms(params))
+            count_dispatch(self.family, *self._forms(params))
             with phase(ph.DEVICE_EXECUTE, compiled=first, donated=True):
                 out = self.fn(cols, n_docs, params, self._acc)
                 device_fence(out)

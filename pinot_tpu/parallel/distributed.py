@@ -29,7 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..engine.executor import extract_partial, resolve_params
 from ..ops.kernels import (build_kernel, cpu_scatter_default,
-                           dict_decode_forms, sort_core_fits,
+                           launch_forms, sort_core_fits,
                            takes_sparse_post)
 from ..utils import phases as ph
 from ..utils.devmem import global_device_memory
@@ -259,7 +259,9 @@ class DistributedTable:
         fn = _distributed_kernel(plan.kernel_plan, self.bucket, self.mesh,
                                  len(cols), len(params), cap, family,
                                  xfer_compact)
-        count_dispatch(family, dict_decode_forms(plan.kernel_plan, params))
+        count_dispatch(family, *launch_forms(
+            plan.kernel_plan, params,
+            platform=self.mesh.devices.flat[0].platform))
         with phase(ph.DEVICE_EXECUTE):
             dev = fn(cols, self._n_docs, params)
             device_fence(dev)

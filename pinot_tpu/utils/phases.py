@@ -153,6 +153,7 @@ SCOPE_GROUP_KEY = "pinot.group_key"      # cartesian key + sentinel
 SCOPE_PAYLOAD = "pinot.payload"          # aggregation inputs, pre-compaction
 SCOPE_COMPACT = "pinot.compact"          # ops/compact.compact
 SCOPE_AGGREGATE = "pinot.aggregate"      # scalar, one-hot, sorted, scatter
+SCOPE_FLOAT_ACC = "pinot.float_acc"      # wide float sums, inside aggregate
 SCOPE_GROUP_TAIL = "pinot.group_tail"    # sparse sorted post, per live group
 SCOPE_XFER_COMPACT = "pinot.xfer_compact"  # live-group gather pre-transfer
 SCOPE_TOPK = "pinot.topk"                # selection order key + top_k
@@ -162,5 +163,6 @@ SCOPE_CUBE_BUILD = "pinot.cube_build"      # unmasked scan -> literal-free cube
 SCOPE_CUBE_COMBINE = "pinot.cube_combine"  # per-item mask + cell reduction
 KERNEL_SCOPES = frozenset(
     {SCOPE_MASK, SCOPE_DECODE_DICT, SCOPE_GROUP_KEY, SCOPE_PAYLOAD,
-     SCOPE_COMPACT, SCOPE_AGGREGATE, SCOPE_GROUP_TAIL, SCOPE_XFER_COMPACT,
-     SCOPE_TOPK, SCOPE_COMBINE, SCOPE_CUBE_BUILD, SCOPE_CUBE_COMBINE})
+     SCOPE_COMPACT, SCOPE_AGGREGATE, SCOPE_FLOAT_ACC, SCOPE_GROUP_TAIL,
+     SCOPE_XFER_COMPACT, SCOPE_TOPK, SCOPE_COMBINE, SCOPE_CUBE_BUILD,
+     SCOPE_CUBE_COMBINE})

@@ -300,18 +300,24 @@ def record_phase(name: str, seconds: float) -> None:
     global_metrics.count_pair(us_key, round(seconds * 1e6), n_key, 1)
 
 
-def count_dispatch(family: str,
-                   dict_forms: Tuple[int, int] = (0, 0)) -> None:
+def count_dispatch(family: str, dict_forms: Tuple[int, int] = (0, 0),
+                   float_forms: Tuple[int, int] = (0, 0)) -> None:
     """One kernel program launched: ``kernel_dispatches`` and
     ``kernel_dispatches_<family>`` (phases.KERNEL_FAMILIES). Where the
     launched plan decodes dictionary-encoded value columns,
     ``dict_forms`` (ops/kernels.dict_decode_forms) says how many by a
     select chain and how many by a gather: ``dict_decode_select`` and
-    ``dict_decode_gather``."""
+    ``dict_decode_gather``. Where it has float aggregates,
+    ``float_forms`` (ops/kernels.float_acc_forms) says how many it keeps
+    at float64 with blocked sums and how many pass through float32:
+    ``float_acc_wide`` and ``float_acc_narrow``."""
     global_metrics.count_pair("kernel_dispatches", 1, _DISPATCH[family], 1)
     if dict_forms != (0, 0):
         global_metrics.count_pair("dict_decode_select", dict_forms[0],
                                   "dict_decode_gather", dict_forms[1])
+    if float_forms != (0, 0):
+        global_metrics.count_pair("float_acc_wide", float_forms[0],
+                                  "float_acc_narrow", float_forms[1])
 
 
 def device_fence(out: Any) -> None:

@@ -75,6 +75,18 @@ def counters():
                              "dict_decode_"))}
 
 
+def settled(quiet_s=0.05, tries=200):
+    """The counters once two snapshots ``quiet_s`` apart are equal."""
+    last = counters()
+    for _ in range(tries):
+        time.sleep(quiet_s)
+        now = counters()
+        if now == last:
+            break
+        last = now
+    return last
+
+
 def moved(before, after):
     return {k: after[k] - before.get(k, 0) for k in after}
 
@@ -105,13 +117,19 @@ def trio(tmp_path_factory):
     assert broker.wait_for_version(version)
 
     def query(sql):
-        key = "phase_n_" + ph.BROKER_QUERY
-        seen = counters().get(key, 0)
+        # the two handlers' phases close after their client has its
+        # answer, each on its own thread: the broker's after this
+        # client's, the server's after the broker's (under six workers
+        # that can be after the broker's closed). A statement is over
+        # when both have counted it, so a snapshot taken then is a window
+        # of this statement and nothing else's
+        keys = ["phase_n_" + ph.BROKER_QUERY, "phase_n_" + ph.SERVER_HTTP]
+        seen = [counters().get(k, 0) for k in keys]
         out = http_json("POST", f"{broker.url}/query/sql", {"sql": sql},
                         timeout=300.0)
-        # the handler's phases close after the client has its answer
         for _ in range(5000):
-            if counters().get(key, 0) > seen:
+            now = counters()
+            if all(now.get(k, 0) > n for k, n in zip(keys, seen)):
                 break
             time.sleep(0.002)
         return out
@@ -129,8 +147,11 @@ def trio(tmp_path_factory):
 
 @pytest.fixture(scope="module")
 def plain_run(trio):
-    """N plain DENSE statements; (counters before, between, after)."""
-    snaps = [counters()]
+    """N plain DENSE statements; (counters before, between, after). The
+    counters are the process's: the first snapshot waits until nothing
+    that ran before this module (under ``--dist loadfile`` a worker runs
+    file after file in one process) is still closing a phase."""
+    snaps = [settled()]
     for _ in range(N_QUERIES):
         trio(DENSE)
         snaps.append(counters())

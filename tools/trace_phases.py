@@ -28,7 +28,8 @@ first form keeps the file itself: it calls ``benchmark.run.run_cell`` with
 first (default ``chiprun_out/<cell>.<seed>.xplane.pb``), prints the run's
 result line, then reads the kept file. Nothing under ``benchmark/``
 changes for it. After the result line it prints how far the work counters
-``kernel_dispatches``, ``dict_decode_select`` and ``dict_decode_gather``
+``kernel_dispatches``, ``dict_decode_select`` / ``dict_decode_gather`` and
+``float_acc_wide`` / ``float_acc_narrow``
 (``pinot_tpu/utils/spans.count_dispatch``), ``sparse_post_results`` /
 ``sparse_post_probes_<P>`` (``engine/executor.run_kernel``: the per-segment
 route's sparse posts, by the probe count their tail took),
@@ -88,9 +89,10 @@ WORK_COUNTERS = ("kernel_dispatches", "dict_decode_select",
 # probe count of the kernel's ladder (ops/kernels._sparse_post_sizes),
 # one a reason a submission to the micro-batcher went solo, one where a
 # mesh program took the live list of its transfer compaction from
-# (parallel/distributed.lists_live_groups_sparse)
+# (parallel/distributed.lists_live_groups_sparse), one whether a launched
+# plan's float aggregates stayed float64 (ops/kernels.float_acc_forms)
 PROBE_COUNTERS = ("sparse_post_probes_", "solo_fallback_",
-                  "mesh_live_list_")
+                  "mesh_live_list_", "float_acc_")
 UNATTRIBUTED = "(in request, no program phase open)"
 NO_REQUEST = "(no request open)"
 Event = Tuple[str, float, float, dict]    # name, start s, end s, stats
