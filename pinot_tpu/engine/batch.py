@@ -29,8 +29,9 @@ from ..utils.devmem import global_device_memory
 from ..utils.metrics import global_metrics
 from ..utils.spans import (annotate, count_dispatch, device_fence, phase,
                            span)
-from .executor import (execute_plan, extract_partial, param_sig,
-                       resident_param, resolve_params_host, stack_params)
+from .executor import (execute_kernel_plans, execute_plan, extract_partial,
+                       param_sig, resident_param, resolve_params_host,
+                       stack_params)
 
 # stack cache: ((segment uid, name) pairs, what, bucket) -> (stamp, tuple
 # of stacked device arrays), where `what` is a plan's column names
@@ -179,9 +180,13 @@ def clear_stack_cache() -> None:
 
 def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
     """Execute all plans; kernel plans with matching structure run in one
-    vmapped dispatch. Returns partials in input order."""
+    vmapped dispatch, the ones that run one program a segment in one
+    launch window (executor.execute_kernel_plans). Returns partials in
+    input order."""
     results: List[Any] = [None] * len(plans)
     groups: Dict[Tuple, List[int]] = {}
+    # plan indexes of the statement's per-segment route
+    per_segment: List[int] = []
     # plan index -> its params in host form (executor.resolve_params_host):
     # the group key reads shapes and dtypes from it, a batched group
     # stacks it, the per-segment route takes it along — nothing reaches
@@ -214,7 +219,7 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
                 # the per-segment launches the Pallas compaction forced
                 kind = "segc"
             else:
-                results[i] = execute_plan(plan)
+                per_segment.append(i)
                 continue
         else:
             kind = "dense"
@@ -241,8 +246,7 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
         n_seg = len(idxs)
         if n_seg == 1 or (kind == "segc" and not segmented_compact_fits(
                 plan_struct, bucket, n_seg)):
-            for i in idxs:
-                results[i] = execute_plan(plans[i], host_params=hosts[i])
+            per_segment.extend(idxs)
             continue
         group_plans = [plans[i] for i in idxs]
         if kind == "dense":
@@ -311,6 +315,12 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
                     # crosses the same boundaries again)
                     results[i] = execute_plan(plans[i], xfer_compact=False,
                                               host_params=hosts[i])
+    if per_segment:
+        per_segment.sort()
+        for i, partial in zip(per_segment, execute_kernel_plans(
+                [plans[i] for i in per_segment],
+                [hosts.get(i) for i in per_segment])):
+            results[i] = partial
     return results
 
 

@@ -10,6 +10,7 @@ parallel/distributed.py.
 """
 from __future__ import annotations
 
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -300,13 +301,164 @@ def resolve_params(plan: CompiledPlan, sharding=None,
         else next(literals) for p in host)
 
 
+@dataclass
+class KernelFlight:
+    """One segment's kernel between its launch and its collection
+    (``launch_kernel`` -> ``finish_kernel``): what the retry ladder
+    needs to run the segment again, and the device outputs owed one
+    ``PlanCacheEntry.collect``."""
+    plan: CompiledPlan
+    xfer_compact: bool
+    cols: Tuple[Any, ...]
+    n: Any
+    params: Tuple[Any, ...]
+    entry: Any
+    cap: Optional[int]
+    out: Dict[str, Any]
+
+
+def _count_launch(windowed: bool) -> None:
+    """A plan-cache launch issued from a window of two or more segments
+    (``plan_launch_windowed``), or issued and collected alone
+    (``plan_launch_solo``: a one-segment group, every retry)."""
+    global_metrics.count("plan_launch_windowed" if windowed
+                         else "plan_launch_solo")
+
+
+def launch_kernel(plan: CompiledPlan, xfer_compact: bool = True,
+                  host_params: Optional[Tuple[Any, ...]] = None,
+                  windowed: bool = False) -> KernelFlight:
+    """The first half of ``run_kernel``: the segment's columns and
+    params, its plan-cache entry, and the launch, which does not wait
+    for the device."""
+    from ..ops.plan_cache import global_plan_cache
+    seg = plan.segment
+    with phase(ph.DISPATCH_PREPARE):
+        cols = seg.device_cols(plan.col_names)
+        params = resolve_params(plan, host=host_params)
+    n = np.int32(seg.n_docs)
+    cap = plan.slots_cap
+    # drift_requantized: the compile at the measured-selectivity
+    # capacity is a deliberate, counted recompile — never a retrace.
+    # The cache brackets only the actual miss, so the warm
+    # re-plannings of a drifted shape (hits) stay outside expected()
+    # and genuine retraces remain visible.
+    entry = global_plan_cache.entry(
+        plan.kernel_plan, seg.bucket, cap, xfer_compact=xfer_compact,
+        expected_compile=plan.drift_requantized)
+    if plan.drift_requantized:
+        annotate(drift_requantized=True)
+    if entry.overflowed:
+        # this capacity already overflowed for this plan: go straight
+        # to the (already compiled) full-capacity kernel instead of
+        # paying the doomed tight kernel plus the retry on every
+        # execution
+        from ..ops.compact import full_slots_cap
+        cap = full_slots_cap(seg.bucket)
+        with global_plan_cache.detector.expected():
+            entry = global_plan_cache.entry(
+                plan.kernel_plan, seg.bucket, cap,
+                xfer_compact=xfer_compact)
+        annotate(slots_cap=cap, known_overflow=True)
+    _count_launch(windowed)
+    return KernelFlight(plan, xfer_compact, cols, n, params, entry, cap,
+                        entry.launch(cols, n, params))
+
+
+def finish_kernel(flight: KernelFlight) -> Dict[str, np.ndarray]:
+    """The second half of ``run_kernel``: block on this segment's host
+    copy, then everything that reads its result — the measured
+    selectivity, the retry ladder (each rung a solo blocking run), the
+    sparse post's probe counters, the accounting fence."""
+    from ..ops.plan_cache import global_plan_cache
+    plan, xfer_compact = flight.plan, flight.xfer_compact
+    cols, n, params = flight.cols, flight.n, flight.params
+    entry, cap, seg = flight.entry, flight.cap, plan.segment
+
+    def rerun(cap: Optional[int], xfer_compact: bool):
+        _count_launch(False)
+        return global_plan_cache.entry(
+            plan.kernel_plan, seg.bucket, cap,
+            xfer_compact=xfer_compact).run(cols, n, params)
+
+    # everything below the collect fence is host numpy: the int()s of
+    # the ladder read values that are already here
+    host = entry.collect(flight.out)
+    if "matched" in host:
+        matched = int(host["matched"].sum())  # jaxlint: ok host-sync
+        global_plan_cache.record_measured(
+            plan.kernel_plan, seg.bucket, entry, matched, seg.n_docs,
+            segment=seg, params=plan.params)
+        annotate(matched=matched,
+                 meas_sel=matched / max(seg.n_docs, 1))
+    # chaos hook: force the overflow retry ladder on kernels that
+    # report overflow (result-identical — the full-capacity rerun
+    # recomputes the same answer; exercises the retry path + retrace
+    # bracketing under test)
+    from ..utils.faults import fault_fires
+    forced_overflow = "overflow" in host and \
+        fault_fires("device.overflow", key=seg.name)
+    overflow = int(host.pop("overflow", 0))  # jaxlint: ok host-sync
+    if overflow or forced_overflow:
+        # compact-strategy capacity exceeded (the selectivity estimate
+        # undershot): rerun with a capacity that cannot overflow
+        from ..ops.compact import full_slots_cap
+        entry.mark_overflowed()
+        cap = full_slots_cap(seg.bucket)
+        global_metrics.count("compact_overflow_retries")
+        with span("overflow_retry", slots_cap=cap), \
+                global_plan_cache.detector.expected():
+            host = rerun(cap, xfer_compact)
+        host.pop("overflow", None)
+        annotate(overflow_retry=True, slots_cap=cap)
+    if int(host.pop("group_overflow", 0)):  # jaxlint: ok host-sync
+        # more live groups than the transfer-compaction cap: rerun
+        # with dense (space,) outputs
+        global_metrics.count("group_xfer_overflow_retries")
+        with span("group_overflow_retry"), \
+                global_plan_cache.detector.expected():
+            host = rerun(cap, False)
+        host.pop("overflow", None)
+        annotate(group_overflow_retry=True)
+    from ..ops.kernels import (cpu_scatter_default, sparse_post_probes,
+                               takes_sparse_post)
+    if "group_idx" in host and takes_sparse_post(
+            plan.kernel_plan, xfer_compact, cpu_scatter_default()):
+        # which rung of its probe ladder the sparse post's tail took:
+        # the host holds group_idx, so it applies the kernel's rule
+        n_live = int(np.count_nonzero(  # jaxlint: ok host-sync
+            host["group_idx"] < plan.kernel_plan.group_space))
+        global_metrics.count("sparse_post_results")
+        global_metrics.count(
+            f"sparse_post_probes_{sparse_post_probes(n_live)}")
+    from ..query.planner import _truthy
+    from ..utils.spans import tracing_active
+    if tracing_active() and _truthy(
+            plan.ctx.options.get("profilePhases")):
+        # EXPLAIN ANALYZE deep mode: re-measure the kernel's internal
+        # mask/fuse/compact/sort/aggregate/transfer ladder and attach
+        # it as child spans (compiles profiling prefixes — opt-in)
+        from ..ops.phase_profile import (attach_phase_spans,
+                                         profile_plan)
+        with span("phase_profile"):
+            prof = profile_plan(plan, iters=2)
+            attach_phase_spans(prof)
+    from .accounting import global_accountant
+    global_accountant.track_result(host)
+    return host
+
+
 def run_kernel(plan: CompiledPlan, xfer_compact: bool = True,
                host_params: Optional[Tuple[Any, ...]] = None
                ) -> Dict[str, np.ndarray]:
     """Execute the compiled kernel through the keyed plan cache
-    (ops/plan_cache.py): one compiled XLA program + donated accumulator
-    buffers per (plan, bucket, slots_cap, platform, flags), so repeated
-    iterations of the same query never re-trace or re-allocate.
+    (ops/plan_cache.py): one compiled XLA program per (plan, bucket,
+    slots_cap, platform, flags), so repeated iterations of the same
+    query never re-trace. No buffer is donated, so nothing has to wait
+    for a host copy before the next launch (ops/plan_cache.py says
+    why). This is the window of one segment: ``launch_kernel``, then
+    ``finish_kernel`` (``execute_kernel_plans`` holds a statement's
+    launches ahead of its first collection).
 
     The compact strategy's compaction capacity comes from the planner's
     cost model (CompiledPlan.slots_cap — selectivity-estimate-derived and
@@ -317,114 +469,64 @@ def run_kernel(plan: CompiledPlan, xfer_compact: bool = True,
     vmapped path). ``host_params`` is the plan's ``resolve_params_host``
     where engine/batch.py made it for its group key; called on its own
     the kernel resolves for itself."""
-    from ..ops.plan_cache import global_plan_cache
     from .tier import global_tier
     seg = plan.segment
     with span("segment_kernel", segment=seg.name, bucket=seg.bucket,
               strategy=plan.kernel_plan.strategy,
               est_sel=plan.est_selectivity, slots_cap=plan.slots_cap), \
             global_tier.pinned({seg.uid}):
-        # pinned for the WHOLE solo execution: the plan-cache entry's
-        # first-run accumulator registration enforces the tier budget,
-        # and without the pin it could demote the very segment whose
-        # columns this query just uploaded (engine/tier anti-thrash)
-        with phase(ph.DISPATCH_PREPARE):
-            cols = seg.device_cols(plan.col_names)
-            params = resolve_params(plan, host=host_params)
-        n = np.int32(seg.n_docs)
-        cap = plan.slots_cap
-        # drift_requantized: the compile at the measured-selectivity
-        # capacity is a deliberate, counted recompile — never a retrace.
-        # The cache brackets only the actual miss, so the warm
-        # re-plannings of a drifted shape (hits) stay outside expected()
-        # and genuine retraces remain visible.
-        entry = global_plan_cache.entry(
-            plan.kernel_plan, seg.bucket, cap, xfer_compact=xfer_compact,
-            expected_compile=plan.drift_requantized)
-        if plan.drift_requantized:
-            annotate(drift_requantized=True)
-        if entry.overflowed:
-            # this capacity already overflowed for this plan: go straight
-            # to the (already compiled) full-capacity kernel instead of
-            # paying the doomed tight kernel plus the retry on every
-            # execution
-            from ..ops.compact import full_slots_cap
-            cap = full_slots_cap(seg.bucket)
-            with global_plan_cache.detector.expected():
-                entry = global_plan_cache.entry(
-                    plan.kernel_plan, seg.bucket, cap,
-                    xfer_compact=xfer_compact)
-            annotate(slots_cap=cap, known_overflow=True)
-        # everything below the entry.run fence is host numpy (entry.run
-        # device_gets inside its lock) — host-sync [jaxlint baseline]
-        host = entry.run(cols, n, params)
-        if "matched" in host:
-            matched = int(np.asarray(host["matched"]).sum())
-            global_plan_cache.record_measured(
-                plan.kernel_plan, seg.bucket, entry, matched, seg.n_docs,
-                segment=seg, params=plan.params)
-            annotate(matched=matched,
-                     meas_sel=matched / max(seg.n_docs, 1))
-        # chaos hook: force the overflow retry ladder on kernels that
-        # report overflow (result-identical — the full-capacity rerun
-        # recomputes the same answer; exercises the retry path + retrace
-        # bracketing under test)
-        from ..utils.faults import fault_fires
-        forced_overflow = "overflow" in host and \
-            fault_fires("device.overflow", key=seg.name)
-        if int(host.pop("overflow", 0)) or forced_overflow:
-            # compact-strategy capacity exceeded (the selectivity estimate
-            # undershot): rerun with a capacity that cannot overflow
-            from ..ops.compact import full_slots_cap
-            entry.mark_overflowed()
-            cap = full_slots_cap(seg.bucket)
-            global_metrics.count("compact_overflow_retries")
-            with span("overflow_retry", slots_cap=cap), \
-                    global_plan_cache.detector.expected():
-                entry = global_plan_cache.entry(
-                    plan.kernel_plan, seg.bucket, cap,
-                    xfer_compact=xfer_compact)
-                host = entry.run(cols, n, params)
-            host.pop("overflow", None)
-            annotate(overflow_retry=True, slots_cap=cap)
-        if int(host.pop("group_overflow", 0)):
-            # more live groups than the transfer-compaction cap: rerun
-            # with dense (space,) outputs
-            global_metrics.count("group_xfer_overflow_retries")
-            with span("group_overflow_retry"), \
-                    global_plan_cache.detector.expected():
-                entry = global_plan_cache.entry(
-                    plan.kernel_plan, seg.bucket, cap,
-                    xfer_compact=False)
-                host = entry.run(cols, n, params)
-            host.pop("overflow", None)
-            annotate(group_overflow_retry=True)
-        from ..ops.kernels import (cpu_scatter_default, sparse_post_probes,
-                                   takes_sparse_post)
-        if "group_idx" in host and takes_sparse_post(
-                plan.kernel_plan, xfer_compact, cpu_scatter_default()):
-            # which rung of its probe ladder the sparse post's tail took:
-            # the host holds group_idx, so it applies the kernel's rule
-            n_live = int(np.count_nonzero(  # jaxlint: ok host-sync
-                host["group_idx"] < plan.kernel_plan.group_space))
-            global_metrics.count("sparse_post_results")
-            global_metrics.count(
-                f"sparse_post_probes_{sparse_post_probes(n_live)}")
-        from ..query.planner import _truthy
-        from ..utils.spans import tracing_active
-        if tracing_active() and _truthy(
-                plan.ctx.options.get("profilePhases")):
-            # EXPLAIN ANALYZE deep mode: re-measure the kernel's internal
-            # mask/fuse/compact/sort/aggregate/transfer ladder and attach
-            # it as child spans (compiles profiling prefixes — opt-in)
-            from ..ops.phase_profile import (attach_phase_spans,
-                                             profile_plan)
-            with span("phase_profile"):
-                prof = profile_plan(plan, iters=2)
-                attach_phase_spans(prof)
-        from .accounting import global_accountant
-        global_accountant.track_result(host)
-        return host
+        # pinned for the WHOLE solo execution: a budget enforcement
+        # that a nested admission triggers on this thread must not
+        # demote the very segment whose columns this query just
+        # uploaded (engine/tier anti-thrash)
+        return finish_kernel(launch_kernel(plan, xfer_compact,
+                                           host_params))
+
+
+def execute_kernel_plans(plans: List[CompiledPlan],
+                         host_params: List[Optional[Tuple[Any, ...]]]
+                         ) -> List[Any]:
+    """``execute_plan`` for the kernel plans of one statement that run
+    one program a segment (engine/batch.py's per-segment route), as a
+    launch window: every segment's kernel is launched before the first
+    is collected, and segment k's retry ladder and extraction run on
+    the host while the later segments' kernels run on the device. No
+    blocking copy sits between two kernels of the statement. Partials
+    come back in input order, each what ``execute_plan`` returns.
+
+    The look-ahead is the whole statement: a queued launch holds its
+    outputs and nothing else on the device (0.66 MB a segment of q3.2
+    at 2^23 rows; a program's 69 MB of temporaries are the running
+    program's alone: 128 launches queued read 84 MB more in use than
+    none — PERF.md, PR 36), and 4 ahead was within 2 ms of 8 on every
+    statement timed. A span tree nests and fences every launch
+    (utils/spans.device_fence), so a traced statement runs its
+    segments one by one, as one segment does."""
+    from ..utils.spans import tracing_active
+    from .accounting import global_accountant
+    from .tier import global_tier
+    if len(plans) < 2 or tracing_active():
+        return [execute_plan(p, host_params=h)
+                for p, h in zip(plans, host_params)]
+    results: List[Any] = []
+    flights: deque = deque()
+    # pinned for as long as a launch of theirs is uncollected (the
+    # reason is run_kernel's)
+    with global_tier.pinned({p.segment.uid for p in plans}):
+        for p, h in zip(plans, host_params):
+            # preemption point between launches, and below between
+            # collections: raises on kill/timeout, and the launches not
+            # yet collected are dropped with their buffers
+            global_accountant.sample()
+            flights.append(launch_kernel(p, host_params=h, windowed=True))
+        while flights:
+            global_accountant.sample()
+            flight = flights.popleft()
+            host = finish_kernel(flight)
+            with phase(ph.EXTRACT_PARTIAL,
+                       segment=flight.plan.segment.name):
+                results.append(extract_partial(flight.plan, host))
+    return results
 
 
 def extract_partial(plan: CompiledPlan, out: Dict[str, np.ndarray]):

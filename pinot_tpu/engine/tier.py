@@ -3,8 +3,7 @@
 Pinot's entire performance layer is off-heap mmap (PAPER.md §2.9); the
 TPU analog is HBM residency. Before this module every device cache —
 segment columns (segment/immutable), the stack cache (engine/batch),
-the cube caches (ops/plan_cache.CubeCache) and the donated plan-cache
-accumulators — grew unboundedly and independently, so a node serving
+the cube caches (ops/plan_cache.CubeCache) — grew unboundedly and independently, so a node serving
 more table-bytes than fit in HBM either OOMed or re-uploaded per query.
 This is the managed memory hierarchy ROADMAP direction 1 called for:
 
@@ -508,14 +507,12 @@ def reconcile_devmem(segments, pools=None) -> Dict[str, Dict[str, int]]:
     device caches back the segment_cols pool. Reads the caches'
     internals; verification-only, never on a serving path. Callers in
     long-lived/shared processes must start from devmem-synced caches
-    (the pytest fixture resets accounting but keeps warm cube/plan
+    (the pytest fixture resets accounting but keeps warm cube
     entries — clear those first, or pass ``pools`` to restrict the
-    check to the pools that ARE synced; e.g. chaos_smoke --tier skips
-    plan_cache_acc, whose donated buffers are suite-wide compile
-    warmth it must not wipe)."""
+    check to the pools that ARE synced)."""
     from ..engine import batch as eb
     from ..index import vector as vix
-    from ..ops.plan_cache import global_cube_cache, global_plan_cache
+    from ..ops.plan_cache import global_cube_cache
     from ..utils.devmem import nbytes_of
     actual = {
         "segment_cols": sum(
@@ -530,10 +527,6 @@ def reconcile_devmem(segments, pools=None) -> Dict[str, Dict[str, int]]:
         "cube_stacked": sum(
             nbytes_of(v)
             for v in list(global_cube_cache._stacked.values())),
-        "plan_cache_acc": sum(
-            nbytes_of(e._acc)
-            for e in list(global_plan_cache._entries.values())
-            if e._acc is not None),
     }
     snap = global_device_memory.snapshot()
     return {p: {"tracked": snap.get(p, {}).get("bytes", 0),

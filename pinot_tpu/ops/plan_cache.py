@@ -1,30 +1,35 @@
-"""Keyed kernel-plan cache with donated accumulator buffers.
+"""Keyed kernel-plan cache: one compiled program an entry, launched
+without waiting and collected apart.
 
-Round-6 tentpole: repeated SSB iterations must never re-trace or
-re-allocate. jax.jit already caches traces, but nothing (a) surfaced a
-hit/miss counter the bench can assert zero-retrace against, (b) kept the
-per-plan output buffers alive so XLA can reuse them, or (c) recorded the
-measured selectivity a plan actually saw (the observability input for
-the cost model in multistage/costs.py).
+Round-6 tentpole: repeated SSB iterations must never re-trace. jax.jit
+already caches traces, but nothing (a) surfaced a hit/miss counter a test
+can assert zero-retrace against, or (b) recorded the measured selectivity
+a plan actually saw (the observability input for the cost model in
+multistage/costs.py).
 
 The cache key is the full kernel identity — (plan structure, bucket,
 slots_cap, platform, xfer_compact, scatter core, compact-path env knobs)
 — exactly the signature the jitted-kernel lru caches use, so one entry
 maps to one compiled XLA program.
 
-Donation: each entry threads the previous call's device output dict back
-in as a donated argument, so XLA aliases the new outputs onto the old
-buffers instead of allocating fresh ones every query iteration. The
-accumulator is only an aliasing source — the kernel never reads it. The
-first call builds a zeroed accumulator from jax.eval_shape (trace-only,
-no extra compile). run() device_gets inside the entry lock, so a buffer
-is never donated while another thread's host copy is in flight.
+Launch and collection are two steps: launch() dispatches the program and
+returns its device outputs with their copy to the host started,
+collect() blocks on that one launch's copy. The entry lock guards
+bookkeeping alone (the run count, the measured selectivity), never a
+device_get, so many launches of one entry can be in flight: a
+statement's segments (engine/executor.py's window), or two workers on
+literal variants of one statement. No buffer is donated: a donated
+output cannot go to the next launch before its own host copy was
+collected, which is what would put the lock back over the copy, and on
+the chip a pool of accumulators (one a launch in flight) timed the same
+as no donation at all: an output is at most 32,768 rows of a few int32
+columns (PERF.md, PR 36).
 
 Round-7 observability: every hit/miss also counts into
 utils.metrics.global_metrics (one snapshot covers the whole engine), a
 RetraceDetector flags any compile of an already-warm plan structure
 after its first query (a retrace: shape change, evicted entry, flipped
-env knob) as a span annotation + counter, and run() splits
+env knob) as a span annotation + counter, and launch()/collect() split
 compile-vs-execute-vs-transfer into utils/spans spans when a trace is
 being taken.
 """
@@ -44,12 +49,6 @@ from ..utils.metrics import global_metrics
 from ..utils.spans import (count_dispatch, device_fence, phase, span,
                            span_tracer)
 from .ir import KernelPlan
-
-
-def _donation_supported() -> bool:
-    """Buffer donation is a TPU/GPU optimization; XLA:CPU ignores it.
-    Enable only where it buys anything."""
-    return jax.default_backend() != "cpu"
 
 
 class RetraceDetector:
@@ -171,15 +170,12 @@ class RetraceDetector:
 
 
 class PlanCacheEntry:
-    """One compiled kernel + its donated accumulator + run statistics."""
+    """One compiled kernel + its run statistics."""
 
-    def __init__(self, base_fn, donate: bool, plan: Any = None,
-                 key: Any = None,
+    def __init__(self, base_fn, plan: Any = None, key: Any = None,
                  stage_hints: Optional[Dict[str, Any]] = None):
         from ..utils.compileplane import (kernel_jit, key_fingerprint,
                                           staged)
-        self._base = base_fn     # unjitted builder (eval_shape surface)
-        self.donate = donate
         # compile-plane forensics: the jit is wrapped in explicit AOT
         # staging (utils/compileplane.StagedFn) so the first run's
         # lower/compile split, executable memory bytes and trigger
@@ -197,26 +193,12 @@ class PlanCacheEntry:
         # the structure _forms reads at each launch (direct
         # constructions pass a bare token: nothing to count)
         self._kernel_plan = plan if isinstance(plan, KernelPlan) else None
-        if donate:
-            def _wrapped(cols, n_docs, params, acc):
-                del acc          # aliasing source only, never read
-                return base_fn(cols, n_docs, params)
-            self.fn = staged(kernel_jit(_wrapped, self.family,
-                                        donate_argnums=(3,)),
-                             "plan_cache", plan, donated=True,
-                             hints=stage_hints)
-        else:
-            self.fn = staged(kernel_jit(base_fn, self.family),
-                             "plan_cache", plan, hints=stage_hints)
+        self.fn = staged(kernel_jit(base_fn, self.family),
+                         "plan_cache", plan, hints=stage_hints)
         if key is not None:
             self.fn.key_fp = key_fingerprint(key)
-        self._acc: Any = None
         self.lock = threading.Lock()
         self.runs = 0
-        # set by the cache's LRU eviction: an entry evicted BEFORE its
-        # first run completes must not leave phantom accumulator bytes
-        # in the device-memory registry (run() re-checks after adding)
-        self.devmem_evicted = False
         # measured selectivity feedback: what the kernel actually matched.
         # Mutated through record_measured/mark_overflowed ONLY — the
         # entry lock guards them, and analysis/jaxlint's
@@ -228,76 +210,42 @@ class PlanCacheEntry:
         # instead of paying the overflowing tight kernel forever
         self.overflowed = False
 
-    def make_acc(self, cols, n_docs, params):
-        """Zeroed accumulator matching the kernel's output structure
-        (trace-only via eval_shape — no extra compile)."""
-        shapes = jax.eval_shape(self._base, cols, n_docs, params)
-        return jax.tree_util.tree_map(
-            lambda s: jnp.zeros(s.shape, s.dtype), shapes)
-
     def _forms(self, params) -> Tuple[Tuple[int, int], Tuple[int, int]]:
         if self._kernel_plan is None:
             return (0, 0), (0, 0)
         from .kernels import launch_forms
         return launch_forms(self._kernel_plan, params)
 
-    def run(self, cols, n_docs, params) -> Dict[str, Any]:
-        """Execute and return HOST numpy outputs.
+    def launch(self, cols, n_docs, params) -> Dict[str, Any]:
+        """Dispatch the compiled program and return its DEVICE outputs
+        without waiting for them; their copy to the host is started.
+        ``collect`` waits for it; a launch nobody collects (a query
+        killed between two collections) is dropped with its buffers.
 
-        Non-donating entries (CPU) go straight through the thread-safe
-        jitted function — concurrent same-plan queries keep executing in
-        parallel exactly as the lru-jitted path always did. Only the
-        donation path takes the entry lock: the accumulator swap and the
-        device_get must serialize so a buffer is never donated while
-        another thread's host copy is still in flight.
-
-        Under an active span trace the first-run (compile) vs execute vs
-        transfer split is fenced with block_until_ready; untraced runs
-        keep async dispatch."""
-        if not self.donate:
-            with self.lock:
-                self.runs += 1
-                first = self.runs == 1
-            count_dispatch(self.family, *self._forms(params))
-            with phase(ph.DEVICE_EXECUTE, compiled=first):
-                out = self.fn(cols, n_docs, params)
-                device_fence(out)
-            with phase(ph.DEVICE_TRANSFER):
-                # THE transfer fence for undonated entries
-                return jax.device_get(out)  # jaxlint: ok host-sync
+        Under an active span trace the first-run (compile) vs execute
+        split is fenced with block_until_ready; untraced launches keep
+        async dispatch."""
         with self.lock:
             self.runs += 1
             first = self.runs == 1
-            if self._acc is None:
-                self._acc = self.make_acc(cols, n_docs, params)
-            count_dispatch(self.family, *self._forms(params))
-            with phase(ph.DEVICE_EXECUTE, compiled=first, donated=True):
-                out = self.fn(cols, n_docs, params, self._acc)
-                device_fence(out)
-            with phase(ph.DEVICE_TRANSFER):
-                # THE transfer fence for donated entries (must complete
-                # inside the lock, before the buffers are re-donated)
-                host = jax.device_get(out)  # jaxlint: ok host-sync
-            self._acc = out      # next call donates these buffers
-            if first:
-                # device-memory telemetry: the donated accumulator is a
-                # live HBM resident; shapes are fixed per entry so one
-                # report per entry suffices (re-registered on eviction
-                # rebuilds because the entry object is new). Re-check
-                # the eviction flag AFTER adding: an entry LRU-evicted
-                # between build and first run would otherwise register
-                # bytes nothing ever removes.
-                global_device_memory.add("plan_cache_acc", id(self),
-                                         nbytes_of(out))
-                if self.devmem_evicted:
-                    global_device_memory.remove("plan_cache_acc",
-                                                id(self), evicted=False)
-        if first:
-            # shared-budget admission (engine/tier.py) — OUTSIDE the
-            # entry lock: the demotion path takes the stack/cube locks
-            from ..engine.tier import global_tier
-            global_tier.enforce()
-        return host
+        count_dispatch(self.family, *self._forms(params))
+        with phase(ph.DEVICE_EXECUTE, compiled=first):
+            out = self.fn(cols, n_docs, params)
+            device_fence(out)
+        for leaf in jax.tree_util.tree_leaves(out):
+            leaf.copy_to_host_async()
+        return out
+
+    @staticmethod
+    def collect(out: Dict[str, Any]) -> Dict[str, Any]:
+        """Block on ONE launch's host copy and return host numpy."""
+        with phase(ph.DEVICE_TRANSFER):
+            # THE transfer fence of a plan-cache launch
+            return jax.device_get(out)  # jaxlint: ok host-sync
+
+    def run(self, cols, n_docs, params) -> Dict[str, Any]:
+        """One launch, collected at once: HOST numpy outputs."""
+        return self.collect(self.launch(cols, n_docs, params))
 
     def record_measured(self, matched: int, rows: int) -> None:
         with self.lock:
@@ -320,7 +268,8 @@ class PlanCacheEntry:
 
 class KernelPlanCache:
     """(plan, bucket, slots_cap, platform, flags) -> PlanCacheEntry with
-    hit/miss counters (the bench's zero-retrace assertion reads these)."""
+    hit/miss counters (tests/test_plan_cache.py's zero-retrace assertion
+    reads these)."""
 
     def __init__(self, maxsize: int = 512):
         self._entries: "OrderedDict[Tuple, PlanCacheEntry]" = OrderedDict()
@@ -399,11 +348,11 @@ class KernelPlanCache:
             base = build_kernel(plan, bucket, slots_cap, platform,
                                 xfer_compact, scatter=scatter,
                                 two_pass_mode=key[6], ladder_min=key[7])
-            ent = PlanCacheEntry(base, _donation_supported(), plan=plan,
-                                 key=key, stage_hints=stage_hints)
+            ent = PlanCacheEntry(base, plan=plan, key=key,
+                                 stage_hints=stage_hints)
         with self._lock:
             # a concurrent miss may have built the same entry; keep the
-            # first one registered so its run stats/accumulator survive
+            # first one registered so its run stats survive
             ent = self._entries.setdefault(key, ent)
             if key in self._evicted_keys:
                 # eviction-rebuild attribution attaches to the
@@ -418,9 +367,7 @@ class KernelPlanCache:
                 ent.fn.set_hints(evicted=True)
             self._entries.move_to_end(key)
             while len(self._entries) > self._maxsize:
-                old_key, old = self._entries.popitem(last=False)
-                old.devmem_evicted = True  # before remove: run() rechecks
-                global_device_memory.remove("plan_cache_acc", id(old))
+                old_key, _old = self._entries.popitem(last=False)
                 # remember the evicted key (bounded): its next miss is
                 # an lru_evict_rebuild, not an unexplained retrace
                 self._evicted_keys[old_key] = True
@@ -511,7 +458,6 @@ class KernelPlanCache:
             self._evicted_keys.clear()
             self.hits = 0
             self.misses = 0
-        global_device_memory.drop_pool("plan_cache_acc")
         self.detector.clear()
 
 

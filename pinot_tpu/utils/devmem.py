@@ -4,10 +4,9 @@ PAPERS.md's *Query Processing on Tensor Computation Runtimes* treats the
 device tier as the hot level of the memory hierarchy; Pinot's own
 performance layer is off-heap mmap it can introspect. Until round 14 we
 had neither view: the stack cache (engine/batch), the cube cache
-(ops/plan_cache.CubeCache), the donated plan-cache accumulators and the
-per-segment padded column cache (segment/immutable) all hold
-device-resident buffers with NO accounting of live bytes, entry counts
-or evictions — exactly the admission/eviction signal ROADMAP direction
+(ops/plan_cache.CubeCache) and the per-segment padded column cache
+(segment/immutable) all hold device-resident buffers with NO
+accounting of live bytes, entry counts or evictions — exactly the admission/eviction signal ROADMAP direction
 3's HBM-tiered segment cache needs before it can exist.
 
 This registry is that accounting: each cache reports its inserts and
@@ -34,12 +33,11 @@ from .metrics import global_metrics
 #   stack_cache     engine/batch._STACK_CACHE stacked column tuples
 #   cube_cache      ops/plan_cache.CubeCache per-segment cubes
 #   cube_stacked    ops/plan_cache.CubeCache warm stacked-cube tensors
-#   plan_cache_acc  ops/plan_cache.PlanCacheEntry donated accumulators
 #   segment_cols    segment/immutable.ImmutableSegment._device arrays
 #   vector          index/vector.VectorIndexReader device residents
 #                   (matrix / centroids / IVF pages — round 19)
-POOLS = ("stack_cache", "cube_cache", "cube_stacked", "plan_cache_acc",
-         "segment_cols", "vector")
+POOLS = ("stack_cache", "cube_cache", "cube_stacked", "segment_cols",
+         "vector")
 
 
 def nbytes_of(tree: Any) -> int:

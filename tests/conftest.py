@@ -70,8 +70,8 @@ def _reset_telemetry_registries():
 
     The stack cache is dropped THROUGH its devmem-synced clear so its
     pool accounting stays reconciled (rebuild is one jnp.stack per
-    group, cheap). The long-lived caches (plan cache + donated
-    accumulators, cube cache, segment device columns) are deliberately
+    group, cheap). The long-lived caches (plan cache, cube cache,
+    segment device columns) are deliberately
     NOT evicted — they are the suite's compile/upload warmth — so their
     accounting restarts at zero each test; devmem.remove tolerates
     untracked keys by design, and reconciliation tests build their own

@@ -307,8 +307,6 @@ def test_constrained_budget_beats_evict_all_uploads(tmp_path):
             f"tier paid {tier_uploads} uploads vs strawman " \
             f"{straw_uploads}"
         # zero unaccounted devmem bytes across the demotion churn
-        # (plan_cache_acc excluded: its donated buffers are suite-wide
-        # compile warmth whose accounting the per-test reset zeroed)
         rec = reconcile_devmem(
             segs, pools=("segment_cols", "stack_cache", "cube_cache",
                          "cube_stacked"))

@@ -435,9 +435,7 @@ def main_tier(args) -> int:
                   "digest mismatch under constrained budget")
         check("tier_budget.demoted", global_tier.demotions > d1,
               "constrained budget never demoted")
-        # the four pools this gate resets at start; plan_cache_acc is
-        # suite-wide compile warmth (donated buffers, TPU only) whose
-        # accounting a warm pytest process has already zeroed
+        # the four pools this gate resets at start
         rec = reconcile_devmem(
             dm.acquire_segments() + dm2.acquire_segments(),
             pools=("segment_cols", "stack_cache", "cube_cache",
@@ -713,7 +711,7 @@ def main_rebalance(args) -> int:
               "second stalled pass changed placement")
 
         # (e) pools reconcile to the byte after the drain (the gate's
-        # devmem subset — plan_cache_acc is suite-wide compile warmth)
+        # devmem subset)
         segs = []
         for s in servers:
             for dm in s._tables.values():

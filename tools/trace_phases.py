@@ -31,8 +31,10 @@ changes for it. After the result line it prints how far the work counters
 ``kernel_dispatches``, ``dict_decode_select`` / ``dict_decode_gather`` and
 ``float_acc_wide`` / ``float_acc_narrow``
 (``pinot_tpu/utils/spans.count_dispatch``), ``sparse_post_results`` /
-``sparse_post_probes_<P>`` (``engine/executor.run_kernel``: the per-segment
+``sparse_post_probes_<P>`` (``engine/executor.finish_kernel``: the per-segment
 route's sparse posts, by the probe count their tail took),
+``plan_launch_windowed`` / ``_solo`` (``engine/executor``: the route's
+launches issued from a launch window, and alone),
 ``mesh_live_list_sparse`` / ``_dense`` (``parallel/distributed``: where a
 mesh query's transfer compaction took its live list from) and the
 micro-batcher's (``engine/ragged.py``: ``batched_queries``,
@@ -90,9 +92,11 @@ WORK_COUNTERS = ("kernel_dispatches", "dict_decode_select",
 # one a reason a submission to the micro-batcher went solo, one where a
 # mesh program took the live list of its transfer compaction from
 # (parallel/distributed.lists_live_groups_sparse), one whether a launched
-# plan's float aggregates stayed float64 (ops/kernels.float_acc_forms)
+# plan's float aggregates stayed float64 (ops/kernels.float_acc_forms),
+# one whether a plan-cache launch was issued from a launch window or
+# alone (engine/executor.execute_kernel_plans)
 PROBE_COUNTERS = ("sparse_post_probes_", "solo_fallback_",
-                  "mesh_live_list_", "float_acc_")
+                  "mesh_live_list_", "float_acc_", "plan_launch_")
 UNATTRIBUTED = "(in request, no program phase open)"
 NO_REQUEST = "(no request open)"
 Event = Tuple[str, float, float, dict]    # name, start s, end s, stats
