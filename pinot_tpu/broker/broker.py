@@ -146,12 +146,11 @@ class Broker:
     # -- query path --------------------------------------------------------
     def query(self, sql: str) -> ResultTable:
         global_metrics.count("broker_queries")
-        with global_metrics.timer("broker_query"):
-            try:
-                return self._query(sql)
-            except SqlError:
-                global_metrics.count("broker_query_exceptions")
-                raise
+        try:
+            return self._query(sql)
+        except SqlError:
+            global_metrics.count("broker_query_exceptions")
+            raise
 
     def _query(self, sql: str) -> ResultTable:
         t0 = time.perf_counter()

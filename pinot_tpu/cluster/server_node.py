@@ -28,7 +28,7 @@ from ..query.sql import parse_sql
 from ..segment.immutable import ImmutableSegment
 from ..server.data_manager import TableDataManager
 from ..utils import phases as ph
-from ..utils.spans import phase, record_phase, set_query_id
+from ..utils.spans import phase, queue_event, record_phase, set_query_id
 from .http_util import (JsonHandler, http_json, start_http,
                         trace_context_from)
 
@@ -290,12 +290,19 @@ class ServerNode:
         # the server's threads, as it does on the broker's
         broker_qid = (trace_ctx or {}).get("queryId")
         set_query_id(broker_qid)
+        # inside a profiler session the queue's event opens here, at
+        # arrival, and the worker that starts the query closes it, so no
+        # thread waits on it (outside one nothing is made)
+        queued = queue_event(broker_qid)
+        waiting = None if queued is None else [queued]
 
         def run() -> Dict[str, Any]:
             # the scheduler runs this on a worker thread — the span
             # tracer is thread-local, so the tree must root HERE, not in
             # the HTTP handler thread that admitted the query
             record_phase(ph.SERVER_QUEUE, time.perf_counter() - t_arrive)
+            if waiting:
+                waiting.pop().__exit__(None, None, None)
             set_query_id(broker_qid)
             if not sampled:
                 return self._execute(sql, segment_names, query_id,
@@ -326,6 +333,8 @@ class ServerNode:
             resp = self.scheduler.execute(run, query_id,
                                           priority=priority)
         finally:
+            if waiting:             # the job never ran: rejected, stopped
+                waiting.pop().__exit__(None, None, None)
             usage = global_accountant.unregister(query_id)
         if usage is not None and usage.batched_dispatches:
             # cross-query micro-batching participation (engine/ragged):

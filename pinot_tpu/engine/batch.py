@@ -195,38 +195,47 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
 
     from ..ops.kernels import segmented_compact_fits, segmented_compact_ok
     from .accounting import global_accountant
+    kernel_plans: List[int] = []
     for i, plan in enumerate(plans):
         # preemption point between per-segment launches (the hot-loop
         # ThreadAccountantOps.sample analog): raises on kill/timeout
         global_accountant.sample()
         if plan.kind != "kernel":
             results[i] = execute_plan(plan)
-            continue
-        kp = plan.kernel_plan
-        # column shapes join the group key: same-plan segments can differ
-        # in MV padded width (maxValues), and a stack needs equal shapes
-        shape_sig = tuple(
-            getattr(plan.segment.columns[c], "max_values", None) or 0
-            for c in plan.col_names)
-        if kp.strategy == "compact":
-            sv_only = all(getattr(plan.segment.columns[c],
-                                  "single_value", True)
-                          for c in plan.col_names)
-            if segmented_compact_ok(kp) and sv_only:
-                # compact group-bys batch via the segmented kernel: the
-                # segment index becomes the leading group-key factor
-                # (ops/kernels.build_segmented_compact_kernel), replacing
-                # the per-segment launches the Pallas compaction forced
-                kind = "segc"
-            else:
-                per_segment.append(i)
-                continue
         else:
-            kind = "dense"
-        hosts[i] = resolve_params_host(plan)
-        key = (kind, kp, plan.segment.bucket,
-               param_sig(plan, hosts[i]) + shape_sig)
-        groups.setdefault(key, []).append(i)
+            kernel_plans.append(i)
+    # the kernel plans' host params and group keys: one leaf crossing a
+    # statement, with nothing metered inside it
+    with phase(ph.PARAMS_HOST, segments=len(kernel_plans)):
+        for i in kernel_plans:
+            plan = plans[i]
+            kp = plan.kernel_plan
+            # column shapes join the group key: same-plan segments can
+            # differ in MV padded width (maxValues), and a stack needs
+            # equal shapes
+            shape_sig = tuple(
+                getattr(plan.segment.columns[c], "max_values", None) or 0
+                for c in plan.col_names)
+            hosts[i] = resolve_params_host(plan)
+            if kp.strategy == "compact":
+                sv_only = all(getattr(plan.segment.columns[c],
+                                      "single_value", True)
+                              for c in plan.col_names)
+                if segmented_compact_ok(kp) and sv_only:
+                    # compact group-bys batch via the segmented kernel:
+                    # the segment index becomes the leading group-key
+                    # factor (ops/kernels.build_segmented_compact_kernel),
+                    # replacing the per-segment launches the Pallas
+                    # compaction forced
+                    kind = "segc"
+                else:
+                    per_segment.append(i)
+                    continue
+            else:
+                kind = "dense"
+            key = (kind, kp, plan.segment.bucket,
+                   param_sig(plan, hosts[i]) + shape_sig)
+            groups.setdefault(key, []).append(i)
 
     from .ragged import global_batcher
     for (kind, plan_struct, bucket, sig), idxs in groups.items():
@@ -288,7 +297,6 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
                 continue
             with span("vmap_dispatch", segments=n_seg, bucket=bucket,
                       strategy=plan_struct.strategy):
-                _maybe_profile_phases(group_plans[0])
                 fn = _vmapped_kernel(plan_struct, bucket)
                 count_dispatch(ph.DENSE_VMAP,
                                *launch_forms(plan_struct, params))
@@ -319,25 +327,9 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
         per_segment.sort()
         for i, partial in zip(per_segment, execute_kernel_plans(
                 [plans[i] for i in per_segment],
-                [hosts.get(i) for i in per_segment])):
+                [hosts[i] for i in per_segment])):
             results[i] = partial
     return results
-
-
-def _maybe_profile_phases(plan: CompiledPlan) -> None:
-    """EXPLAIN ANALYZE OPTION(profilePhases=true) on a batched dispatch:
-    attach the phase ladder of ONE representative segment (the group
-    shares plan structure and bucket, so phases scale uniformly) as
-    child spans — the fused paths bypass run_kernel's attach point."""
-    from ..query.planner import _truthy
-    from ..utils.spans import tracing_active
-    if not (tracing_active()
-            and _truthy(plan.ctx.options.get("profilePhases"))):
-        return
-    from ..ops.phase_profile import attach_phase_spans, profile_plan
-    with span("phase_profile", segment=plan.segment.name,
-              representative=True):
-        attach_phase_spans(profile_plan(plan, iters=2))
 
 
 def _run_segmented_compact(plans, idxs, plan_struct, bucket, cols, n_docs,
@@ -357,7 +349,6 @@ def _run_segmented_compact(plans, idxs, plan_struct, bucket, cols, n_docs,
     with span("segmented_compact_dispatch", segments=n_seg, bucket=bucket,
               strategy=plan_struct.strategy, slots_cap=cap,
               est_sel=plans[idxs[0]].est_selectivity):
-        _maybe_profile_phases(plans[idxs[0]])
         fn = jitted_segmented_compact(plan_struct, bucket, n_seg, cap)
         forms = launch_forms(plan_struct, params, segmented=True)
         out = _launch_segmented(fn, cols, n_docs, params, forms)

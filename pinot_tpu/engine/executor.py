@@ -333,6 +333,12 @@ def launch_kernel(plan: CompiledPlan, xfer_compact: bool = True,
     for the device."""
     from ..ops.plan_cache import global_plan_cache
     seg = plan.segment
+    if host_params is None:
+        # the per-segment route's own resolution (a compact plan that
+        # engine/batch.py did not group): a leaf of its own, beside the
+        # launch's preparation and not inside it
+        with phase(ph.PARAMS_HOST):
+            host_params = resolve_params_host(plan)
     with phase(ph.DISPATCH_PREPARE):
         cols = seg.device_cols(plan.col_names)
         params = resolve_params(plan, host=host_params)
@@ -431,18 +437,6 @@ def finish_kernel(flight: KernelFlight) -> Dict[str, np.ndarray]:
         global_metrics.count("sparse_post_results")
         global_metrics.count(
             f"sparse_post_probes_{sparse_post_probes(n_live)}")
-    from ..query.planner import _truthy
-    from ..utils.spans import tracing_active
-    if tracing_active() and _truthy(
-            plan.ctx.options.get("profilePhases")):
-        # EXPLAIN ANALYZE deep mode: re-measure the kernel's internal
-        # mask/fuse/compact/sort/aggregate/transfer ladder and attach
-        # it as child spans (compiles profiling prefixes — opt-in)
-        from ..ops.phase_profile import (attach_phase_spans,
-                                         profile_plan)
-        with span("phase_profile"):
-            prof = profile_plan(plan, iters=2)
-            attach_phase_spans(prof)
     from .accounting import global_accountant
     global_accountant.track_result(host)
     return host

@@ -3,7 +3,8 @@
 Before round 7 the writers appended ad-hoc shapes to one JSONL file, so
 nothing could validate the history or diff captures field-for-field.
 ``bench_capture``, ``multistage_bench`` and ``vector_bench`` lost their
-writer with the pre-chip harness (PR 31); they validate old captures.
+writer with the pre-chip harness (PR 31), ``phase_profile`` with
+ops/phase_profile.py (PR 37); they validate old captures.
 Now every line is a **v2 record**: common envelope
 ``{"v": 2, "ts": ..., "kind": ...}`` plus a per-kind field contract
 below. tools/check_ledger.py validates the whole file (tier-1 runs it);
@@ -13,9 +14,9 @@ parse-checked.
 Kinds:
 - ``bench_capture``    — the old harness's headline summaries (metric,
   value, vs_baseline, per-query detail); no writer since PR 31.
-- ``phase_profile``    — ops/phase_profile.py, OPTION(profilePhases=true):
-  kernel phase decompositions (mask/fuse/compact/sort/aggregate/
-  transfer) with the cost-model trace.
+- ``phase_profile``    — kernel phase decompositions (mask/fuse/compact/
+  sort/aggregate/transfer) with the cost-model trace; no writer since
+  PR 37 (the device trace's ``pinot.<stage>`` scopes give the stages).
 - ``query_trace``      — utils/spans.py span trees (EXPLAIN ANALYZE /
   OPTION(ledgerTrace=true)); the span fields are designed to be diffed
   across CPU-smoke and TPU hardware rounds.
@@ -147,6 +148,8 @@ KINDS: Dict[str, Dict[str, set]] = {
     },
     "metrics_snapshot": {
         "required": {"counters"},
+        # ``timers``: captures from before PR 37, when the registry
+        # still kept wall-ms samples beside its counters
         "optional": {"gauges", "timers", "backend"},
     },
     "query_stats": {

@@ -1,4 +1,4 @@
-"""Metrics: counters, gauges, timers with a global registry.
+"""Metrics: counters and gauges with a global registry.
 
 Reference parity: pinot-common/.../metrics/AbstractMetrics.java +
 pinot-spi metrics SPI (pluggable yammer/dropwizard backends). The registry
@@ -10,8 +10,7 @@ from __future__ import annotations
 import re
 import threading
 import time
-from contextlib import contextmanager
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Optional
 
 
 class MetricsRegistry:
@@ -27,7 +26,6 @@ class MetricsRegistry:
         # staleness tests don't sleep.
         self._gauge_ts: Dict[str, float] = {}
         self._now = time.monotonic  # guarded-by: none — test injection
-        self._timers: Dict[str, List[float]] = {}
 
     def count(self, name: str, n: int = 1) -> None:
         with self._lock:
@@ -41,6 +39,18 @@ class MetricsRegistry:
             c = self._counters
             c[a] = c.get(a, 0) + na
             c[b] = c.get(b, 0) + nb
+
+    def count_four(self, a: str, na: int, b: str, nb: int,
+                   c3: str, n3: int, c4: str, n4: int) -> None:
+        """Four counters under one lock acquisition: a phase crossing's
+        elapsed microseconds, its call count, its self CPU and the wall
+        time the CPU was read over (utils/spans.phase)."""
+        with self._lock:
+            c = self._counters
+            c[a] = c.get(a, 0) + na
+            c[b] = c.get(b, 0) + nb
+            c[c3] = c.get(c3, 0) + n3
+            c[c4] = c.get(c4, 0) + n4
 
     def gauge(self, name: str, value: float) -> None:
         with self._lock:
@@ -64,31 +74,8 @@ class MetricsRegistry:
                 return None
             return max(self._now() - ts, 0.0)
 
-    @contextmanager
-    def timer(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = (time.perf_counter() - t0) * 1e3
-            with self._lock:
-                self._timers.setdefault(name, []).append(dt)
-                if len(self._timers[name]) > 1024:  # bound memory
-                    self._timers[name] = self._timers[name][-512:]
-
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
-            timers = {}
-            for name, vals in self._timers.items():
-                if not vals:
-                    continue
-                s = sorted(vals)
-                timers[name] = {
-                    "count": len(s),
-                    "p50": s[len(s) // 2],
-                    "p99": s[min(len(s) - 1, int(len(s) * 0.99))],
-                    "max": s[-1],
-                }
             # ``gauge_age_s`` rides beside ``gauges`` (a NEW key — every
             # existing consumer reads ``gauges`` as plain name->float
             # and keeps working): seconds since each gauge's last write,
@@ -98,8 +85,7 @@ class MetricsRegistry:
                     "gauges": dict(self._gauges),
                     "gauge_age_s": {
                         k: round(max(now - ts, 0.0), 3)
-                        for k, ts in self._gauge_ts.items()},
-                    "timers": timers}
+                        for k, ts in self._gauge_ts.items()}}
 
     def prometheus(self) -> str:
         return render_prometheus(self.snapshot())
@@ -195,9 +181,6 @@ def render_prometheus(snapshot: Dict[str, Any],
         lines.append(f"{prefix}_{_prom_name(k)}_total {v}")
     for k, v in snapshot["gauges"].items():
         lines.append(f"{prefix}_{_prom_name(k)} {v}")
-    for k, t in snapshot["timers"].items():
-        lines.append(f"{prefix}_{_prom_name(k)}_ms_p50 {t['p50']:.3f}")
-        lines.append(f"{prefix}_{_prom_name(k)}_ms_p99 {t['p99']:.3f}")
     return "\n".join(lines) + "\n"
 
 

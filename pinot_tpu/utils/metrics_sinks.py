@@ -28,8 +28,8 @@ class MetricsSink:
 
 
 class StatsdSink(MetricsSink):
-    """Fire-and-forget UDP statsd lines (counters |c, gauges |g, timer
-    p50/p99 as gauges) — the statsd/datadog exporter shape."""
+    """Fire-and-forget UDP statsd lines (counters |c, gauges |g) — the
+    statsd/datadog exporter shape."""
 
     def __init__(self, host: str = "127.0.0.1", port: int = 8125,
                  prefix: str = "pinot_tpu"):
@@ -42,7 +42,7 @@ class StatsdSink(MetricsSink):
         # counters first, each advancing its baseline as its datagram is
         # handed to the kernel: a mid-flush OSError then neither loses a
         # delivered delta (no re-send) nor drops an unsent one (re-emits
-        # next flush); gauges/timers are absolute and safely droppable
+        # next flush); gauges are absolute and safely droppable
         for k, v in snapshot["counters"].items():
             delta = v - self._last_counters.get(k, 0)
             if not delta:
@@ -56,9 +56,6 @@ class StatsdSink(MetricsSink):
         lines: List[str] = []
         for k, v in snapshot["gauges"].items():
             lines.append(f"{self.prefix}.{k}:{v}|g")
-        for k, t in snapshot["timers"].items():
-            lines.append(f"{self.prefix}.{k}.p50:{t['p50']:.3f}|g")
-            lines.append(f"{self.prefix}.{k}.p99:{t['p99']:.3f}|g")
         for line in lines:
             try:
                 self.sock.sendto(line.encode(), self.addr)
@@ -108,8 +105,7 @@ class LedgerSink(MetricsSink):
         uledger.append_record(
             uledger.make_record("metrics_snapshot",
                                 counters=snapshot.get("counters", {}),
-                                gauges=snapshot.get("gauges", {}),
-                                timers=snapshot.get("timers", {})),
+                                gauges=snapshot.get("gauges", {})),
             self.path)
 
 

@@ -89,6 +89,7 @@ BROKER_RESPOND = "broker_respond"    # to_dict, JSON encode, write
 SERVER_HTTP = "server_http"          # HTTP handler: body parsed -> written
 SERVER_QUEUE = "server_queue"        # arrival -> scheduler worker starts
 SERVER_PARSE = "server_parse"        # parse_sql, deadline, context, acquire
+PARAMS_HOST = "params_host"          # plans' host params and group keys
 DISPATCH_PREPARE = "dispatch_prepare"  # stacks, params: host work pre-launch
 DEVICE_EXECUTE = "device_execute"    # the dispatch call (fenced if sampled)
 DEVICE_TRANSFER = "device_transfer"  # jax.device_get: wait + copy back
@@ -114,8 +115,26 @@ SPAN_NAMES = TRACED_PHASES | frozenset(
 METERED_PHASES = TRACED_PHASES | frozenset(
     {BROKER_QUERY, BROKER_PARSE, BROKER_ROUTE, BROKER_SELECT, SCATTER,
      SCATTER_CALL, WIRE_DECODE, BROKER_RESPOND, SERVER_HTTP, SERVER_QUEUE,
-     SERVER_PARSE, DISPATCH_PREPARE, DEVICE_EXECUTE, DEVICE_TRANSFER,
-     EXTRACT_PARTIAL, SERVER_ENCODE, RAGGED_WAIT, FUSED_EXECUTE})
+     SERVER_PARSE, PARAMS_HOST, DISPATCH_PREPARE, DEVICE_EXECUTE,
+     DEVICE_TRANSFER, EXTRACT_PARTIAL, SERVER_ENCODE, RAGGED_WAIT,
+     FUSED_EXECUTE})
+
+# the metered leaves that are pure host work, with no device wait inside
+# them: what one of these spends off its thread's CPU (``phase_us_<p>``
+# less ``phase_cpu_us_<p>``) is time the thread waited for the
+# interpreter lock or for a CPU the host did not give
+# (benchmark/metrics/host_offcpu_ms_per_query.json lists the same names).
+# On the chip hosts the thread CPU clock moves in 10 ms ticks booked at
+# the next system call, and it is read in one nest in eight, so only the
+# leaves of a millisecond or more a request can be read: the six here
+# never read more CPU than wall time on the chip (PERF.md, PR 37).
+# ``broker_respond`` (a socket write) and ``dispatch_prepare`` (a copy to
+# the device) read up to 143 %, ``broker_route``, ``broker_select``,
+# ``server_parse`` and ``params_host`` (under half a millisecond) up to
+# 194 %: they are leaves too, and count in host_cpu_ms_per_query alone
+HOST_WORK_PHASES = (
+    BROKER_PARSE, REDUCE, WIRE_DECODE, PLANNING, EXTRACT_PARTIAL,
+    SERVER_ENCODE)
 
 # kernel families: the jitted function of each is named
 # "pinot_<family>" (utils/compileplane.kernel_jit), so the profiler's

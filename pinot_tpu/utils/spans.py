@@ -24,9 +24,11 @@ rounds diff span-for-span.
 
 ``phase()`` is the same thing for a LAYER BOUNDARY (utils/phases.py
 METERED_PHASES): one call, three outputs. It always adds its elapsed
-microseconds and a count to ``global_metrics`` (``phase_us_<name>``,
-``phase_n_<name>``: the /metrics scrape and the benchmark's per-layer
-readers); while a ``jax.profiler`` session runs it is also a
+microseconds, a count and, in a sampled share of nests, its self CPU
+time to ``global_metrics`` (``phase_us_<name>``, ``phase_n_<name>``,
+``phase_cpu_us_<name>`` and ``phase_cpu_wall_us_<name>``: the /metrics
+scrape and the benchmark's per-layer readers); while a
+``jax.profiler`` session runs it is also a
 ``TraceAnnotation("pinot.<name>", qid=...)``, so the program's spans sit
 on the clock of the device's ``XLA Ops`` (tools/trace_phases.py); and
 when the query is sampled it is the tree node ``span()`` would have
@@ -34,6 +36,7 @@ been, and feeds the flat ``OPTION(trace=true)`` envelope.
 """
 from __future__ import annotations
 
+import random
 import threading
 import time
 from contextlib import contextmanager
@@ -102,12 +105,19 @@ class Span:
         return s
 
 
+class _Stack(threading.local):
+    # a thread that never started a tree reads the class's None, where a
+    # getattr with a default raised and caught an AttributeError: half a
+    # microsecond, twice a phase crossing
+    stack: Optional[List[Span]] = None
+
+
 class SpanTracer:
     """Thread-local span stack. start()/stop() bracket one traced query;
     span()/annotate() are permanent no-ops outside that bracket."""
 
     def __init__(self):
-        self._local = threading.local()
+        self._local = _Stack()
 
     # -- lifecycle ---------------------------------------------------------
     def start(self, name: str, **attrs: Any) -> Span:
@@ -116,7 +126,7 @@ class SpanTracer:
         return root
 
     def stop(self) -> Optional[Span]:
-        stack = getattr(self._local, "stack", None)
+        stack = self._local.stack
         self._local.stack = None
         if not stack:
             return None
@@ -129,16 +139,16 @@ class SpanTracer:
         return root
 
     def active(self) -> bool:
-        return bool(getattr(self._local, "stack", None))
+        return bool(self._local.stack)
 
     def current(self) -> Optional[Span]:
-        stack = getattr(self._local, "stack", None)
+        stack = self._local.stack
         return stack[-1] if stack else None
 
     # -- recording ---------------------------------------------------------
     @contextmanager
     def span(self, name: str, **attrs: Any):
-        stack = getattr(self._local, "stack", None)
+        stack = self._local.stack
         if not stack:
             yield None
             return
@@ -159,8 +169,8 @@ class SpanTracer:
 
     def add_event(self, name: str, duration_ms: float,
                   **attrs: Any) -> None:
-        """Attach a pre-measured child span (a re-measured kernel phase
-        from ops/phase_profile.py) under the current span."""
+        """Attach a pre-measured child span (an injected fault's delay,
+        utils/faults.py) under the current span."""
         cur = self.current()
         if cur is not None:
             s = Span(name, **attrs)
@@ -210,13 +220,32 @@ def tracing_active() -> bool:
 # layer boundaries: counters always, profiler events and tree nodes on demand
 # ---------------------------------------------------------------------------
 
-# per metered phase: its two counters and its profiler event's name (no
-# "#" in one: TraceMe cuts a name there)
-_KEYS = {n: ("phase_us_" + n, "phase_n_" + n, "pinot." + n)
+# per metered phase: its counters and its profiler event's name (no "#"
+# in one: TraceMe cuts a name there)
+_KEYS = {n: ("phase_us_" + n, "phase_n_" + n, "pinot." + n,
+             "phase_cpu_us_" + n, "phase_cpu_wall_us_" + n)
          for n in ph.METERED_PHASES}
 _DISPATCH = {f: "kernel_dispatches_" + f for f in ph.KERNEL_FAMILIES}
 _query = threading.local()      # .qid: the broker's id of the query in hand
 _annotation: Any = None         # jax.profiler.TraceAnnotation, on first use
+_thread_ns = time.thread_time_ns
+_rand = random.random
+# The share of a thread's outermost crossings whose nest reads the
+# thread's CPU clock. On the chip hosts that read is a system call of
+# 6 µs (PERF.md, PR 37: 2.4 -> 15.4 µs a crossing when every crossing
+# read it, and the clock moves in 10 ms ticks there), so one nest in
+# eight is read: a Q1 request pays 40 µs, not 250.
+CPU_SHARE = 0.125
+
+
+class _Open(threading.local):
+    """This thread's open crossings, innermost last."""
+
+    def __init__(self):
+        self.stack: List["phase"] = []
+
+
+_open = _Open()
 
 
 def _annotation_cls():
@@ -240,10 +269,25 @@ class phase:
     ``.ms`` holds the elapsed wall-ms after exit and ``.t0`` the start
     (``time.perf_counter``), for callers that report the same
     measurement elsewhere (``ScatterResult.serde_ms``, the wire header's
-    ``serdeEncodeMs``); ``.span`` is the tree node, or None."""
+    ``serdeEncodeMs``); ``.span`` is the tree node, or None.
+
+    A crossing that reads the CPU clock adds its self CPU to
+    ``phase_cpu_us_<name>`` and its wall time to
+    ``phase_cpu_wall_us_<name>``; one that does not adds 0 to both. Self
+    CPU is this thread's CPU time (``time.thread_time_ns``) inside the
+    crossing less that of the metered crossings nested inside it on the
+    same thread (a stack of open crossings a thread), so summed over the
+    phases it counts each CPU microsecond of a thread once, and a child
+    on another thread (``scatter``'s pool) takes nothing from its
+    parent. Whether the clock is read is drawn once a nest, at its
+    outermost crossing on the thread (``CPU_SHARE``), so a parent and
+    its children are read together or not at all. A phase's CPU time is
+    then ``phase_us`` × ``phase_cpu_us`` ÷ ``phase_cpu_wall_us``; wall
+    time less CPU time is the thread off its CPU: blocked on the device
+    or a peer, or waiting for the interpreter lock or for a core."""
 
     __slots__ = ("name", "attrs", "qid", "span", "t0", "ms", "_keys",
-                 "_event")
+                 "_event", "_c0", "_child_ns", "_stack")
 
     def __init__(self, name: str, qid: Optional[str] = None, **attrs: Any):
         self.name = name
@@ -255,7 +299,7 @@ class phase:
         # KeyError: the name is not in phases.METERED_PHASES
         self._keys = _KEYS[self.name]
         self.span = self._event = None
-        stack = getattr(span_tracer._local, "stack", None)
+        stack = span_tracer._local.stack
         if stack:
             s = self.span = Span(self.name, **self.attrs)
             stack[-1].children.append(s)
@@ -265,11 +309,24 @@ class phase:
             kw = self.attrs if qid is None else {"qid": qid, **self.attrs}
             self._event = _annotation(self._keys[2], **kw)
             self._event.__enter__()
+        self._stack = stack = _open.stack
+        read = (stack[-1]._c0 is not None) if stack else _rand() < CPU_SHARE
+        stack.append(self)
         self.t0 = time.perf_counter()
+        if read:
+            self._child_ns = 0
+            self._c0 = _thread_ns()
+        else:
+            self._c0 = None
         return self
 
     def __exit__(self, *exc) -> None:
         dt = time.perf_counter() - self.t0
+        c0 = self._c0
+        if c0 is not None:
+            cpu_ns = _thread_ns() - c0
+        stack = self._stack
+        stack.pop()
         name = self.name
         self.ms = ms = dt * 1e3
         if self._event is not None:
@@ -281,23 +338,51 @@ class phase:
         s = self.span
         if s is not None:
             s._t0, s.duration_ms = self.t0, ms
-            stack = getattr(span_tracer._local, "stack", None)
-            if stack and stack[-1] is s:
-                stack.pop()
+            tree = span_tracer._local.stack
+            if tree and tree[-1] is s:
+                tree.pop()
         if name in ph.TRACED_PHASES:
             scope = Tracing.active()
             if scope is not None:
                 scope.add_phase(name, ms)
-        us_key, n_key, _event = self._keys
-        global_metrics.count_pair(us_key, round(dt * 1e6), n_key, 1)
+        us_key, n_key, _event, cpu_key, cpu_wall_key = self._keys
+        us = round(dt * 1e6)
+        if c0 is None:
+            # zeros, so that a phase crossed in a window always has its
+            # CPU counters beside it, read or not
+            global_metrics.count_four(us_key, us, n_key, 1,
+                                      cpu_key, 0, cpu_wall_key, 0)
+            return
+        if stack:
+            stack[-1]._child_ns += cpu_ns
+        global_metrics.count_four(us_key, us, n_key, 1,
+                                  cpu_key, (cpu_ns - self._child_ns + 500)
+                                  // 1000, cpu_wall_key, us)
 
 
 def record_phase(name: str, seconds: float) -> None:
     """Counters of a boundary crossed on two threads (``server_queue``:
     arrival on the handler's thread to the scheduler worker's start),
-    which no ``with`` block can bracket."""
-    us_key, n_key, _event = _KEYS[name]
+    which no ``with`` block can bracket. It has no CPU counter: the wait
+    belongs to no thread."""
+    us_key, n_key = _KEYS[name][:2]
     global_metrics.count_pair(us_key, round(seconds * 1e6), n_key, 1)
+
+
+def queue_event(qid: Optional[str]) -> Any:
+    """The ``pinot.server_queue`` profiler event of a query that waits
+    for a scheduler worker, open from now, or None while no profiler
+    session runs (the untraced path makes nothing). The handler's thread
+    opens it at arrival and the worker that starts the query closes it,
+    so it lands on the worker's thread and the handler is never woken for
+    it: the wait spans two threads, so its counters come from
+    ``record_phase``."""
+    if not (_annotation or _annotation_cls()).is_enabled():
+        return None
+    event = _annotation(_KEYS[ph.SERVER_QUEUE][2],
+                        **({} if qid is None else {"qid": qid}))
+    event.__enter__()
+    return event
 
 
 def count_dispatch(family: str, dict_forms: Tuple[int, int] = (0, 0),

@@ -45,14 +45,17 @@ LONG_DICT = "SELECT SUM(amount * code) FROM ptab WHERE code < 200"
 
 # what one plain DENSE statement crosses: every phase of PERF.md's table,
 # once — one vmapped launch answers the four segments, and the host work
-# before it is one crossing too (the stacks and the one params upload;
-# the per-segment resolve_params passes went with PR 30)
+# before it is two crossings: the plans' host params and group keys
+# (params_host), then the stacks and the one params upload
+# (dispatch_prepare; the per-segment resolve_params passes went with
+# PR 30)
 CROSSINGS = {
     ph.BROKER_QUERY: 1, ph.BROKER_PARSE: 1, ph.BROKER_ROUTE: 1,
     ph.BROKER_SELECT: 1, ph.SCATTER: 1, ph.SCATTER_CALL: 1,
     ph.WIRE_DECODE: 1, ph.REDUCE: 1, ph.BROKER_RESPOND: 1,
     ph.SERVER_HTTP: 1, ph.SERVER_QUEUE: 1, ph.SERVER_PARSE: 1,
-    ph.PLANNING: 1, ph.EXECUTION: 1, ph.DISPATCH_PREPARE: 1,
+    ph.PLANNING: 1, ph.EXECUTION: 1, ph.PARAMS_HOST: 1,
+    ph.DISPATCH_PREPARE: 1,
     ph.DEVICE_EXECUTE: 1, ph.DEVICE_TRANSFER: 1, ph.EXTRACT_PARTIAL: 1,
     ph.SERVER_ENCODE: 1,
 }
@@ -64,7 +67,7 @@ CHILDREN = {
     ph.SCATTER_CALL: [ph.SERVER_HTTP],
     ph.SERVER_HTTP: [ph.SERVER_QUEUE, ph.SERVER_PARSE, ph.PLANNING,
                      ph.EXECUTION, ph.SERVER_ENCODE],
-    ph.EXECUTION: [ph.DISPATCH_PREPARE, ph.DEVICE_EXECUTE,
+    ph.EXECUTION: [ph.PARAMS_HOST, ph.DISPATCH_PREPARE, ph.DEVICE_EXECUTE,
                    ph.DEVICE_TRANSFER, ph.EXTRACT_PARTIAL],
 }
 
@@ -325,14 +328,21 @@ def test_a_profiler_session_sees_the_phases_under_one_qid(trio, tmp_path):
     events = _host_events(str(tmp_path))
     ours = [(line, name, st) for line, name, st in events
             if name.startswith("pinot.")]
+    # server_queue too: the handler's thread holds its event while the
+    # statement waits for a scheduler worker
     assert {name for _l, name, _s in ours} == {
-        "pinot." + p for p in CROSSINGS if p != ph.SERVER_QUEUE}
+        "pinot." + p for p in CROSSINGS}
     assert not any("#" in name for _l, name, _s in ours)
     # one query id on every event, broker's and server's threads alike:
     # the handlers' threads, the scatter pool's and the scheduler worker's
     qids = {st.get("qid") for _l, _n, st in ours}
     assert len(qids) == 1 and None not in qids
     assert len({line for line, _n, _s in ours}) >= 4
+    # the queue's event is closed by the worker that starts the query, so
+    # the profiler files it on that worker's thread
+    lines = {name: line for line, name, _s in ours}
+    assert lines["pinot." + ph.SERVER_QUEUE] == \
+        lines["pinot." + ph.SERVER_PARSE]
     # the compiled programs carry the family's name
     assert any("pinot_" + ph.DENSE_VMAP in name or
                any("pinot_" + ph.DENSE_VMAP in str(v) for v in st.values())

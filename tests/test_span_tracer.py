@@ -112,23 +112,8 @@ def test_span_strategy_completeness(broker, strategy):
     _tree_ok(res.rows)
 
 
-def test_span_phase_ladder_sorted_post(broker):
-    # MIN forces the sorted post; profilePhases must then emit the sort
-    # phase between compact and aggregate
-    sql = ("EXPLAIN ANALYZE SELECT k, MIN(v) FROM obs GROUP BY k "
-           "OPTION(groupByStrategy=compact, profilePhases=true)")
-    names = [r[0] for r in broker.query(sql).rows]
-    for ph in ("phase_mask", "phase_fuse", "phase_compact", "phase_sort",
-               "phase_aggregate", "phase_transfer"):
-        assert ph in names, f"missing {ph} in {names}"
 
 
-def test_span_phase_ladder_dense(broker):
-    sql = ("EXPLAIN ANALYZE SELECT k, SUM(v) FROM obs GROUP BY k "
-           "OPTION(groupByStrategy=dense, profilePhases=true)")
-    names = [r[0] for r in broker.query(sql).rows]
-    assert "phase_mask" in names and "phase_aggregate" in names
-    assert "phase_compact" not in names  # dense has no compaction
 
 
 def test_span_scatter_core(broker, monkeypatch):
@@ -213,22 +198,6 @@ def test_retrace_detector_token_dedup():
     assert det.observe_compile(("plan", 2)) is False
 
 
-def test_profile_phases_on_batched_dispatch(tmp_path):
-    """profilePhases must emit phase spans even when same-plan segments
-    fuse into one batched dispatch (which bypasses run_kernel)."""
-    dm = TableDataManager("obs")
-    dm.add_segment_dir(_build_seg_dir(tmp_path / "a", "s0", n=4000, seed=1))
-    dm.add_segment_dir(_build_seg_dir(tmp_path / "b", "s1", n=4000, seed=2))
-    b = Broker()
-    b.register_table(dm)
-    # profilePhases compiles profiling prefixes inside the query, so
-    # give it a bench-style budget (the untraced path is unaffected)
-    res = b.query("EXPLAIN ANALYZE SELECT k, SUM(v) FROM obs GROUP BY k "
-                  "OPTION(groupByStrategy=compact, profilePhases=true, "
-                  "timeoutMs=600000)")
-    names = [r[0] for r in res.rows]
-    assert any(n.endswith("_dispatch") for n in names), names
-    assert "phase_mask" in names and "phase_compact" in names, names
 
 
 def test_retrace_detector_integration(tmp_path):

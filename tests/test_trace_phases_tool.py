@@ -112,6 +112,75 @@ def test_render_lists_every_table(tables):
         assert needle in text
 
 
+# the window's counters of the run, in µs: three phases' wall time, the
+# CPU time where their clock was read and the wall time it was read over
+# (a quarter of it); server_queue has no CPU counter (record_phase)
+COUNTERS = {"phase_us_dispatch_prepare": 600_000,
+            "phase_cpu_us_dispatch_prepare": 37_500,
+            "phase_cpu_wall_us_dispatch_prepare": 150_000,
+            "phase_us_device_transfer": 1_400_000,
+            "phase_cpu_us_device_transfer": 17_500,
+            "phase_cpu_wall_us_device_transfer": 350_000,
+            "phase_us_execution": 3_200_000,
+            "phase_cpu_us_execution": 0,
+            "phase_cpu_wall_us_execution": 800_000,
+            "phase_us_server_queue": 5_000}
+
+
+@pytest.mark.parametrize("phase,cpu_ms,off", [
+    ("pinot.dispatch_prepare", 150.0 / 2, 1 - 0.15 / 0.6),
+    ("pinot.device_transfer", 70.0 / 2, 1 - 0.07 / 1.4),
+    ("pinot.execution", 0.0, 1.0),
+])
+def test_cpu_columns_from_the_windows_counters(phase, cpu_ms, off):
+    rows = {r[0]: r for r in tp.tables(HOST, DEVICES,
+                                       counters=COUNTERS)["phases"]}
+    assert rows[phase][6] == pytest.approx(cpu_ms)
+    assert rows[phase][7] == pytest.approx(off)
+
+
+def test_cpu_columns_are_empty_without_counters(tables):
+    assert all(r[6] is None and r[7] is None for r in tables["phases"])
+    with_counters = tp.tables(HOST, DEVICES, counters=COUNTERS)
+    row = {r[0]: r for r in with_counters["phases"]}["pinot.broker_query"]
+    assert row[6:] == [None, None]      # no counter named for it
+    text = tp.render({"file": "made-up", **with_counters})
+    assert "off-CPU % of self" in text
+    line = next(ln for ln in text.splitlines()
+                if ln.strip().startswith("pinot.dispatch_prepare"))
+    assert line.split()[-2:] == ["75.000", "75.0"]
+
+
+def test_lock_wait_summarises_the_probes_wakes():
+    lates = [0.0001] * 8 + [0.004, 0.012]
+    got = tp.lock_wait(lates)
+    assert got["wakes"] == 10
+    assert got["mean_ms"] == pytest.approx(1.68)
+    assert got["p50_ms"] == pytest.approx(0.1)
+    assert got["p90_ms"] == pytest.approx(12.0)
+    assert got["late_1ms_share"] == pytest.approx(0.2)
+    assert tp.lock_wait([]) == {"wakes": 0}
+
+
+def test_the_probe_stops_and_records_its_wakes():
+    import threading
+    stop, lates = threading.Event(), []
+    t = threading.Thread(target=tp.lock_probe, args=(stop, lates))
+    t.start()
+    while len(lates) < 3:
+        stop.wait(0.01)
+    stop.set()
+    t.join(timeout=5.0)
+    assert not t.is_alive()
+    assert all(x > -1e-3 for x in lates)
+
+
+def test_host_work_per_second_sums_the_leaves_only():
+    window = {"phase_us_planning": 3_000_000, "phase_us_server_encode":
+              1_000_000, "phase_us_device_execute": 9_000_000}
+    assert tp.host_work_per_s(window, 2.0) == pytest.approx(2.0)
+
+
 def test_two_windows_are_refused():
     with pytest.raises(RuntimeError):
         tp.tables(HOST + [ev("bench_window", 11, 12)], DEVICES)

@@ -10,7 +10,10 @@ From one ``.xplane.pb`` of a traced run of a benchmark cell it prints
 (a) per metered phase (``pinot.<name>`` host events, written by
     ``pinot_tpu/utils/spans.phase`` while a profiler session runs): count,
     total, self time (its duration less the part its children cover) and
-    milliseconds a query;
+    milliseconds a query; and, from the window's ``phase_cpu_us_<name>``
+    and ``phase_cpu_wall_us_<name>`` counters (a run of a cell; not from a
+    bare ``--xplane``), its self CPU a query and the share of its self
+    time its thread was off a CPU;
 (b) the device's idle gaps inside the window, each attributed to the
     innermost ``pinot.*`` event open on the host at that time (of the open
     events, the one that started last: a request walks client -> broker
@@ -43,6 +46,13 @@ crossings of ``ragged_wait`` and ``fused_execute``) moved a request of the
 window. The batcher's two programs show in table (c) as
 ``jit_pinot_cube_build`` / ``jit_pinot_ragged_fused`` and under the scopes
 ``pinot.cube_build`` / ``pinot.cube_combine``.
+Then two readings of the interpreter lock that do not rest on the thread
+CPU clock: a probe thread's lateness over the window (it sleeps
+``LOCK_PROBE_S`` in a loop, and waking needs the lock, so past the host's
+timer slack, which a one-client cell reads, the lateness is the wait any
+thread that wants the lock meets), and the host-work leaves' wall time a
+second of the window (``phases.HOST_WORK_PHASES``: above 1, more of them
+are under way than the one thread the lock lets run Python at a time).
 Interval arithmetic (``merge``, ``clip``, ``self_times``)
 is ``benchmark/trace/reduce.py``'s, by import.
 
@@ -62,6 +72,8 @@ import os
 import re
 import shutil
 import sys
+import threading
+import time
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
@@ -70,6 +82,7 @@ sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import xplane_raw  # noqa: E402  (tools/, beside this file)
+from benchmark.readers import phase_cpu  # noqa: E402
 from benchmark.trace import reduce as red  # noqa: E402
 from benchmark.trace import xplane  # noqa: E402
 
@@ -97,6 +110,7 @@ WORK_COUNTERS = ("kernel_dispatches", "dict_decode_select",
 # alone (engine/executor.execute_kernel_plans)
 PROBE_COUNTERS = ("sparse_post_probes_", "solo_fallback_",
                   "mesh_live_list_", "float_acc_", "plan_launch_")
+LOCK_PROBE_S = 0.002
 UNATTRIBUTED = "(in request, no program phase open)"
 NO_REQUEST = "(no request open)"
 Event = Tuple[str, float, float, dict]    # name, start s, end s, stats
@@ -220,13 +234,31 @@ def device_tables(ops: List[Event], mods: List[Event], lo: float, hi: float):
     return by_prog, by_scope, dict(scope_keys)
 
 
-def analyse(path: str, top: int = 25) -> Dict[str, object]:
-    return {"file": path, **tables(*read_planes(path), top=top)}
+def analyse(path: str, top: int = 25,
+            counters: Optional[Dict[str, float]] = None) -> Dict[str, object]:
+    return {"file": path, **tables(*read_planes(path), top=top,
+                                   counters=counters)}
 
 
-def tables(host: List[Event], devices, top: int = 25) -> Dict[str, object]:
+def cpu_columns(name: str, own_s: float, n_req: int,
+                counters: Optional[Dict[str, float]]):
+    """(self CPU ms a query, off-CPU share of the self time) of the phase
+    event ``name`` from the window's counters (the estimate of
+    ``host_cpu_ms_per_query``'s reader); (None, None) without them, or
+    for a phase with no CPU counter (``server_queue``)."""
+    c, p = counters or {}, name[len(PHASE_PREFIX):]
+    if phase_cpu.CPU + p not in c:
+        return None, None
+    cpu_s = phase_cpu.cpu_us(c, p) / 1e6
+    off = 1.0 - cpu_s / own_s if own_s > 0 else None
+    return 1e3 * cpu_s / n_req, off
+
+
+def tables(host: List[Event], devices, top: int = 25,
+           counters: Optional[Dict[str, float]] = None) -> Dict[str, object]:
     """The three tables from the host's events and each device's
-    (operations, programs)."""
+    (operations, programs); ``counters``, the window's delta of the
+    program's counters, adds the CPU columns of table (a)."""
     window = [ev for ev in host if ev[0] == red.WINDOW_SPAN]
     if len(window) != 1:
         raise RuntimeError(f"the trace holds {len(window)} "
@@ -270,7 +302,8 @@ def tables(host: List[Event], devices, top: int = 25) -> Dict[str, object]:
         "idle_s": idle, "idle_in_request_s": in_request,
         "idle_named_share": named / in_request if in_request else None,
         "qids": len({ev[3].get("qid") for ev in phases}),
-        "phases": [[n, int(c), tot, own, 1e3 * tot / n_req, 1e3 * own / n_req]
+        "phases": [[n, int(c), tot, own, 1e3 * tot / n_req, 1e3 * own / n_req,
+                    *cpu_columns(n, own, n_req, counters)]
                    for n, (c, tot, own) in sorted(
                        table.items(), key=lambda kv: -kv[1][1])],
         "idle_by_phase": rank(by_phase), "idle_by_request": rank(by_shape),
@@ -284,10 +317,13 @@ def render(a: Dict[str, object]) -> str:
            f" {a['requests']} requests, {a['qids']} query ids, device busy "
            f"{a['busy_s']:.2f} s, idle {a['idle_s']:.2f} s "
            f"({a['idle_in_request_s']:.2f} s inside requests)", "",
-           "(a) phases: count, total s, self s, ms/query, self ms/query"]
-    for n, c, tot, own, ms, own_ms in a["phases"]:
+           "(a) phases: count, total s, self s, ms/query, self ms/query, "
+           "self CPU ms/query, off-CPU % of self"]
+    for n, c, tot, own, ms, own_ms, cpu_ms, off in a["phases"]:
         out.append(f"  {n:<24}{c:>7}{tot:>10.3f}{own:>10.3f}{ms:>10.3f}"
-                   f"{own_ms:>10.3f}")
+                   f"{own_ms:>10.3f}"
+                   + ("         -" if cpu_ms is None else f"{cpu_ms:>10.3f}")
+                   + ("         -" if off is None else f"{100 * off:>10.1f}"))
     share = a["idle_named_share"]
     out += ["", "(b) device idle seconds by the innermost program phase open "
             f"(named share of in-request idle: "
@@ -302,10 +338,12 @@ def render(a: Dict[str, object]) -> str:
     return "\n".join(out)
 
 
-def traced_run(cell: str, seed: int, seconds: float, keep: str) -> None:
+def traced_run(cell: str, seed: int, seconds: float,
+               keep: str) -> Dict[str, float]:
     """One traced run of ``cell`` through the benchmark's own
     ``run_cell``, its ``.xplane.pb`` copied to ``keep`` before the run
-    removes it. Prints the run's result line."""
+    removes it. Prints the run's result line; returns the window's delta
+    of the program's counters."""
     from benchmark import run
 
     os.makedirs(os.path.dirname(os.path.abspath(keep)), exist_ok=True)
@@ -320,15 +358,26 @@ def traced_run(cell: str, seed: int, seconds: float, keep: str) -> None:
     from pinot_tpu.utils.metrics import global_metrics
     drive = run.tr.drive
     work: Dict[str, float] = {}
+    window: Dict[str, float] = {}
+    lates: List[float] = []
+    spent: List[float] = []
 
     def counted_drive(*a, **kw):
         before = global_metrics.snapshot()["counters"]
+        stop = threading.Event()
+        del lates[:], spent[:]
+        probe = threading.Thread(target=lock_probe, args=(stop, lates),
+                                 daemon=True)
+        probe.start()
         t0, requests = drive(*a, **kw)
+        spent.append(time.perf_counter() - t0)
+        stop.set()
+        probe.join()
         after = global_metrics.snapshot()["counters"]
+        window.update({k: v - before.get(k, 0) for k, v in after.items()})
         for name in WORK_COUNTERS + tuple(sorted(
                 k for k in after if k.startswith(PROBE_COUNTERS))):
-            work[name] = ((after.get(name, 0) - before.get(name, 0))
-                          / max(len(requests), 1))
+            work[name] = window.get(name, 0) / max(len(requests), 1)
         return t0, requests
 
     xplane.load, run.tr.drive = keep_then_load, counted_drive
@@ -339,6 +388,43 @@ def traced_run(cell: str, seed: int, seconds: float, keep: str) -> None:
     print(json.dumps(result), flush=True)
     print("work counters, a request of the window: " + ", ".join(
         f"{name} {value:.4f}" for name, value in work.items()), flush=True)
+    print("interpreter-lock probe: " + json.dumps(lock_wait(lates)),
+          flush=True)
+    print(f"host-work leaves' wall a second of the window: "
+          f"{host_work_per_s(window, spent[0]):.4f}", flush=True)
+    return window
+
+
+def lock_probe(stop: threading.Event, lates: List[float]) -> None:
+    """Until ``stop`` is set: sleep ``LOCK_PROBE_S``, then add how late
+    this thread ran again to ``lates`` (seconds)."""
+    while not stop.is_set():
+        t = time.perf_counter()
+        time.sleep(LOCK_PROBE_S)
+        lates.append(time.perf_counter() - t - LOCK_PROBE_S)
+
+
+def lock_wait(lates: List[float]) -> Dict[str, float]:
+    """The probe's wakes: how many, their mean lateness and quantiles in
+    ms, and the share of them later than 1 ms."""
+    if not lates:
+        return {"wakes": 0}
+    s = sorted(lates)
+
+    def q(f):
+        return 1e3 * s[min(len(s) - 1, int(f * len(s)))]
+
+    return {"wakes": len(s), "mean_ms": 1e3 * sum(s) / len(s),
+            "p50_ms": q(0.5), "p90_ms": q(0.9), "p99_ms": q(0.99),
+            "late_1ms_share": sum(x > 1e-3 for x in s) / len(s)}
+
+
+def host_work_per_s(window: Dict[str, float], seconds: float) -> float:
+    """Wall seconds of the host-work leaves, over every thread, a second
+    of the window."""
+    from pinot_tpu.utils import phases as ph
+    return sum(window.get("phase_us_" + p, 0)
+               for p in ph.HOST_WORK_PHASES) / 1e6 / seconds
 
 
 def sample_device_events(path: str, n: int) -> List[dict]:
@@ -358,16 +444,19 @@ def sample_device_events(path: str, n: int) -> List[dict]:
 
 def phase_cost(calls: int) -> Dict[str, float]:
     """Microseconds one ``phase()`` crossing costs on this host: while no
-    profiler session runs and no query is sampled (counters alone),
-    beside the plain ``span()`` it replaced at some sites; then inside a
-    profiler session set up as the benchmark's (an event with a ``qid``
-    a crossing)."""
+    profiler session runs and no query is sampled (counters alone, the
+    thread's CPU clock read in ``spans.CPU_SHARE`` of the nests), beside
+    the plain ``span()`` it replaced at some sites; a crossing that reads
+    the clock (``phase_us_per_call_cpu_read``: two reads, whose own cost
+    is ``thread_time_us_per_call``); then inside a profiler session set
+    up as the benchmark's (an event with a ``qid`` a crossing)."""
     import tempfile
     import time
 
     import jax
 
     from pinot_tpu.utils import phases as ph
+    from pinot_tpu.utils import spans
     from pinot_tpu.utils.spans import phase, set_query_id, span
 
     def timed(make) -> float:
@@ -379,6 +468,16 @@ def phase_cost(calls: int) -> Dict[str, float]:
 
     out = {"phase_us_per_call": timed(lambda: phase(ph.DISPATCH_PREPARE)),
            "span_us_per_call": timed(lambda: span("device_execute"))}
+    share, spans.CPU_SHARE = spans.CPU_SHARE, 1.0
+    try:
+        out["phase_us_per_call_cpu_read"] = timed(
+            lambda: phase(ph.DISPATCH_PREPARE))
+    finally:
+        spans.CPU_SHARE = share
+    t = time.perf_counter()
+    for _ in range(calls):
+        time.thread_time_ns()
+    out["thread_time_us_per_call"] = (time.perf_counter() - t) / calls * 1e6
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level, opts.host_tracer_level = 0, 1
     with tempfile.TemporaryDirectory() as tmp:
@@ -412,13 +511,14 @@ def main(argv: Optional[List[str]] = None) -> int:
         print(json.dumps(phase_cost(args.phase_cost)))
         return 0
     path = args.xplane
+    counters = None
     if path is None:
         if not args.workload:
             ap.error("give --xplane or --workload")
         path = args.keep or os.path.join(
             REPO, "chiprun_out", f"{args.workload}.{args.seed}.xplane.pb")
-        traced_run(args.workload, args.seed, args.seconds, path)
-    a = analyse(path)
+        counters = traced_run(args.workload, args.seed, args.seconds, path)
+    a = analyse(path, counters=counters)
     print(render(a))
     if args.json:
         print(json.dumps(a))
