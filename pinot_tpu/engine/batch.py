@@ -29,9 +29,9 @@ from ..utils.devmem import global_device_memory
 from ..utils.metrics import global_metrics
 from ..utils.spans import (annotate, count_dispatch, device_fence, phase,
                            span)
-from .executor import (execute_kernel_plans, execute_plan, extract_partial,
-                       param_sig, resident_param, resolve_params_host,
-                       stack_params)
+from .executor import (count_compact_steps, execute_kernel_plans,
+                       execute_plan, extract_partial, param_sig,
+                       resident_param, resolve_params_host, stack_params)
 
 # stack cache: ((segment uid, name) pairs, what, bucket) -> (stamp, tuple
 # of stacked device arrays), where `what` is a plan's column names
@@ -380,6 +380,7 @@ def _run_segmented_compact(plans, idxs, plan_struct, bucket, cols, n_docs,
         global_accountant.track_result(out)
     space = plan_struct.group_space
     with phase(ph.EXTRACT_PARTIAL, segments=n_seg):
+        count_compact_steps(out)
         matched = out.pop("matched")
         gi = out.pop("group_idx", None)
         for k, i in enumerate(idxs):

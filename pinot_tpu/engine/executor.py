@@ -371,6 +371,19 @@ def launch_kernel(plan: CompiledPlan, xfer_compact: bool = True,
                         entry.launch(cols, n, params))
 
 
+def count_compact_steps(host: Dict[str, Any]) -> None:
+    """Take the compactor's grid steps by form out of a collected
+    result and count them (``compact_steps_narrow`` / ``_wide``), after
+    any retry, so each route counts the launch that answered: here,
+    engine/batch.py's segmented route and the mesh's collect. Host
+    numpy; a result that did not compact carries neither."""
+    from ..ops.kernels import COMPACT_STEP_OUTPUTS
+    for name in COMPACT_STEP_OUTPUTS:
+        n = host.pop(name, None)
+        if n is not None:
+            global_metrics.count(name, int(np.sum(n)))  # jaxlint: ok host-sync
+
+
 def finish_kernel(flight: KernelFlight) -> Dict[str, np.ndarray]:
     """The second half of ``run_kernel``: block on this segment's host
     copy, then everything that reads its result — the measured
@@ -437,6 +450,8 @@ def finish_kernel(flight: KernelFlight) -> Dict[str, np.ndarray]:
         global_metrics.count("sparse_post_results")
         global_metrics.count(
             f"sparse_post_probes_{sparse_post_probes(n_live)}")
+    # the launch that answered, after its retries, as on the other routes
+    count_compact_steps(host)
     from .accounting import global_accountant
     global_accountant.track_result(host)
     return host

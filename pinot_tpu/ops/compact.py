@@ -16,13 +16,24 @@ batch-gathers projected columns for them. The TPU analog cannot scatter
   times a small inflation factor, never more than the input;
 - a running slot offset carried in SMEM across the (sequential) TPU grid
   places each tile's rows; each DMA writes a full fixed-size staging
-  block and the next tile's DMA overwrites the garbage tail.
+  block and the next tile's DMA overwrites the garbage tail;
+- a grid step computes only the slot rows it can fill: the one-hot,
+  the gather-sums and the placement run in chunks of NARROW = R/4 slot
+  rows a subtile, as many chunks as the step's largest advance needs
+  (each row's in-lane slot, the lane counts and the placement's offsets
+  are taken once a step, ahead of the chunks). Where no subtile advances past NARROW (a narrow step: every step at
+  SSB's selectivities, a few percent) that is one chunk, a quarter of
+  the 32-row work; a dense step takes up to four. The rows past a
+  subtile's advance are zeros, so the staging block is the same for any
+  number of chunks that covers the advance; the kernel reports how many
+  of its steps were narrow.
 
 Order is NOT preserved — group-by / aggregation consumers don't need it.
 
 Outputs are (slots_cap*128,) arrays + (n_slots, matched, overflow)
-scalars. Rows at index >= n_slots*128 are uninitialized; consumers must
-mask with `valid & (iota < n_slots*128)`. overflow != 0 means capacity
+scalars + the (narrow, wide) grid step counts. Rows at index >=
+n_slots*128 are uninitialized; consumers must mask with
+`valid & (iota < n_slots*128)`. overflow != 0 means capacity
 was exceeded and the result is incomplete — retry with full capacity
 (`full_slots_cap(n)` can never overflow).
 
@@ -53,6 +64,7 @@ K_MIN = 8              # minimum subtiles per grid step (gate + capacity math)
 K_MAX = 16
 STEP = K_MIN * R       # minimum rows per grid step (pallas gate, caps)
 STAGE = K_MIN * R + R  # staging rows at K_MIN (capacity math only)
+NARROW = R // 4        # slot rows a subtile computes in one chunk of a step
 
 
 def _interpret() -> bool:
@@ -162,8 +174,9 @@ def compact(mask: jax.Array, cols: Tuple[jax.Array, ...], slots_cap: int,
     passes through here counts float_acc_narrow, not float_acc_wide
     (kernels.float_acc_forms); carrying the pair's two planes is open
     (PERF.md section 7).
-    Returns (valid, out_cols, n_valid_rows, matched, overflow) with
-    valid/out_cols of length slots_cap*128.
+    Returns (valid, out_cols, n_valid_rows, matched, overflow, steps)
+    with valid/out_cols of length slots_cap*128 and steps the Pallas
+    grid's (narrow, wide) step counts ((0, 0) on the XLA path).
     """
     n = mask.shape[0]
     # split 64-bit columns into int32 pairs (exact for int64 and float64)
@@ -198,13 +211,14 @@ def compact(mask: jax.Array, cols: Tuple[jax.Array, ...], slots_cap: int,
             pad = step_rows - rem
             mask = jnp.pad(mask, (0, pad))
             split_cols = [jnp.pad(c, (0, pad)) for c in split_cols]
-        valid, outs, n_slots, matched, overflow = _compact_pallas(
+        valid, outs, n_slots, matched, overflow, steps = _compact_pallas(
             mask, tuple(split_cols),
             n + (step_rows - rem if rem else 0), slots_cap, k_sub,
             _interpret())
     else:
         valid, outs, n_slots, matched, overflow = _compact_xla(
             mask, tuple(split_cols), n, slots_cap)
+        steps = (jnp.int32(0), jnp.int32(0))
 
     # recombine split columns
     out_cols = []
@@ -219,7 +233,7 @@ def compact(mask: jax.Array, cols: Tuple[jax.Array, ...], slots_cap: int,
                             if dtype != jnp.int32 else outs[i])
             i += 1
     n_valid = n_slots * LANES
-    return valid, tuple(out_cols), n_valid, matched, overflow
+    return valid, tuple(out_cols), n_valid, matched, overflow, steps
 
 
 def _use_pallas(n: int, platform: str = None) -> bool:
@@ -281,10 +295,12 @@ def _kernel(mask_ref, *rest, n_cols: int, slots_cap: int, n_steps: int,
     nslots_ref = rest[2 * n_cols + 1]
     matched_ref = rest[2 * n_cols + 2]
     overflow_ref = rest[2 * n_cols + 3]
-    carry = rest[2 * n_cols + 4]            # SMEM (2,): [off, matched]
-    oflow = rest[2 * n_cols + 5]            # SMEM (1,)
-    stages = rest[2 * n_cols + 6: 3 * n_cols + 7]   # VMEM staging per col
-    sems = rest[3 * n_cols + 7]             # DMA sems (n_cols + 1,)
+    narrow_ref = rest[2 * n_cols + 4]
+    # SMEM (4,): [off, matched, narrow steps, this step's largest advance]
+    carry = rest[2 * n_cols + 5]
+    oflow = rest[2 * n_cols + 6]            # SMEM (1,)
+    stages = rest[2 * n_cols + 7: 3 * n_cols + 8]   # VMEM staging per col
+    sems = rest[3 * n_cols + 8]             # DMA sems (n_cols + 1,)
 
     step = pl.program_id(0)
 
@@ -295,97 +311,140 @@ def _kernel(mask_ref, *rest, n_cols: int, slots_cap: int, n_steps: int,
         # jaxpr under an x64-enabled process (dtype-mismatched ref swap)
         carry[0] = jnp.int32(0)
         carry[1] = jnp.int32(0)
+        carry[2] = jnp.int32(0)
         oflow[0] = jnp.int32(0)
 
     # strict lower triangular (R x R): exclusive in-lane running count
     row_i = jax.lax.broadcasted_iota(jnp.int32, (R, R), 0)
     col_i = jax.lax.broadcasted_iota(jnp.int32, (R, R), 1)
     stril = (row_i > col_i).astype(jnp.int32).astype(jnp.float32)
-    out_iota = jax.lax.broadcasted_iota(jnp.int32, (R, R, LANES), 0)
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (R, LANES), 0)
-    stage_iota = jax.lax.broadcasted_iota(jnp.int32, (stage_rows, R), 0)
-    sub_iota = jax.lax.broadcasted_iota(jnp.int32, (stage_rows, R), 1)
 
-    # Per subtile: in-lane compaction (dest via the stril matmul, then a
-    # one-hot gather-sum). Placement into the staging block happens in ONE
-    # deep matmul per byte part across all k_sub subtiles:
-    #     staging = stack_all @ vstack(subtile parts)
-    # stack_all (stage_rows, k_sub*R) stacks each subtile's one-hot
-    # placement at its running offset; invalid slots are exact zeros, so
-    # overlapping garbage rows can't corrupt the sums. A k_sub*R-deep
-    # contraction keeps the 128x128 MXU fed (per-subtile R=32-deep
-    # matmuls ran it at ~25% depth utilization). Values stay bf16-exact:
-    # columns are split into bytes (|v| <= 255) and recombined after f32
-    # accumulation.
-    valid_tiles = []
-    part_tiles = [[[] for _ in range(4)] for _ in range(n_cols)]
-    offs = []
+    # Once a step, ahead of the chunks: each subtile's in-lane slot of
+    # every row (dest via the stril matmul; -1 where the mask is off), its
+    # lane counts and its advance (its largest in-lane count). The step's
+    # largest advance says how many slot rows of a subtile can hold
+    # anything at all.
+    slots, cnts, offs = [], [], []
     local_off = jnp.int32(0)
+    step_adv = jnp.int32(0)
     total = jnp.int32(0)
     for k in range(k_sub):
-        sl = slice(k * R, (k + 1) * R)
-        m = mask_ref[sl, :] != 0                       # (R, 128)
+        m = mask_ref[k * R:(k + 1) * R, :] != 0        # (R, 128)
         mf = m.astype(jnp.int32).astype(jnp.float32)
+        dest = jax.lax.dot_general(
+            stril, mf, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(jnp.int32)
+        slots.append(jnp.where(m, dest, jnp.int32(-1)))
         # f32 reductions (exact: counts <= R=32): written for a Mosaic
         # that could not lower integer sum/max reductions; this form
         # compiles and runs exact on jax 0.9.0 / libtpu 0.0.34 (whether
         # the integer form lowers there now: not measured)
         cntf = jnp.sum(mf, axis=0, dtype=jnp.float32)  # (128,)
-        cnt = cntf.astype(jnp.int32)
+        cnts.append(cntf.astype(jnp.int32))
         adv = jnp.max(cntf).astype(jnp.int32)
-        dest = jax.lax.dot_general(
-            stril, mf, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(jnp.int32)
-        scat = (dest[None, :, :] == out_iota) & m[None, :, :]  # (R, R, 128)
-        valid_tiles.append((row_iota < cnt[None, :]).astype(jnp.int32)
-                           .astype(jnp.bfloat16))
-        for ci in range(n_cols):
-            x = col_refs[ci][sl, :]
-            # byte-split BEFORE the one-hot gather-sum so the reduction
-            # runs in f32 (exact: one-hot selects a single byte <= 255
-            # per output slot) — no integer reduction (see above)
-            for b in range(4):
-                if b < 3:
-                    part = jax.lax.bitwise_and(
-                        jax.lax.shift_right_logical(x, jnp.int32(8 * b)),
-                        jnp.int32(0xFF))
-                else:
-                    part = jax.lax.shift_right_arithmetic(x, jnp.int32(24))
-                partf = part.astype(jnp.float32)
-                compb = jnp.sum(
-                    jnp.where(scat, partf[None, :, :], jnp.float32(0)),
-                    axis=1, dtype=jnp.float32)         # (R, 128) f32
-                part_tiles[ci][b].append(compb.astype(jnp.bfloat16))
         offs.append(local_off)
         local_off = local_off + adv
+        step_adv = jnp.maximum(step_adv, adv)
         # f32 scalar sum (exact: <= 4096 per step); jnp.sum-to-scalar on
         # int32 sneaks an int64 intermediate past the Mosaic lowering
         total = total + jnp.sum(cntf, dtype=jnp.float32).astype(jnp.int32)
 
-    stack_all = jnp.concatenate(
-        [(stage_iota == offs[k] + sub_iota).astype(jnp.int32)
-         .astype(jnp.bfloat16) for k in range(k_sub)],
-        axis=1)                                        # (stage_rows, k_sub*R)
+    # the placement's staging row for slot row j (< NARROW) of subtile k
+    # in the first chunk: offs[k] + j at column k*NARROW + j; chunk c adds
+    # c*NARROW
+    shape = (stage_rows, k_sub * NARROW)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    sub = jax.lax.shift_right_logical(
+        col, jnp.int32(NARROW.bit_length() - 1))      # col // NARROW
+    dst0 = jax.lax.bitwise_and(col, jnp.int32(NARROW - 1))
+    for k in range(1, k_sub):
+        dst0 = jnp.where(sub == k, dst0 + offs[k], dst0)
 
-    def place_all(tiles):
-        t = jnp.concatenate(tiles, axis=0)             # (k_sub*R, 128)
-        return jax.lax.dot_general(
-            stack_all, t, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def stage_chunk(c, acc):
+        """Add slot rows [c*NARROW, (c+1)*NARROW) of every subtile to the
+        staging blocks. A subtile's slot rows at or past its advance are
+        exact zeros, so the chunks up to the step's largest advance make
+        the whole block, and each staging row takes one value or zeros.
+
+        Per subtile: in-lane compaction (a one-hot gather-sum over
+        (NARROW, R, 128) at the rows' slots). Placement into the staging
+        block happens in ONE deep matmul per byte part across all k_sub
+        subtiles:
+            staging += stack_all @ vstack(subtile parts)
+        stack_all (stage_rows, k_sub*NARROW) stacks each subtile's
+        one-hot placement at its running offset; invalid slots are exact
+        zeros, so overlapping garbage rows can't corrupt the sums. A deep
+        contraction keeps the 128x128 MXU fed (per-subtile R=32-deep
+        matmuls ran it at ~25% depth utilization). Values stay
+        bf16-exact: columns are split into bytes (|v| <= 255) and
+        recombined after f32 accumulation."""
+        lo = c * jnp.int32(NARROW)                   # the chunk's first row
+        out_iota = jax.lax.broadcasted_iota(
+            jnp.int32, (NARROW, R, LANES), 0) + lo
+        row_iota = jax.lax.broadcasted_iota(jnp.int32, (NARROW, LANES), 0) + lo
+        valid_tiles = []
+        part_tiles = [[[] for _ in range(4)] for _ in range(n_cols)]
+        for k in range(k_sub):
+            sl = slice(k * R, (k + 1) * R)
+            scat = slots[k][None, :, :] == out_iota    # (NARROW, R, 128)
+            valid_tiles.append(
+                (row_iota < cnts[k][None, :])
+                .astype(jnp.int32).astype(jnp.float32))
+            for ci in range(n_cols):
+                x = col_refs[ci][sl, :]
+                # byte-split BEFORE the one-hot gather-sum so the
+                # reduction runs in f32 (exact: one-hot selects a single
+                # byte <= 255 per output slot) — no integer reduction
+                for b in range(4):
+                    if b < 3:
+                        part = jax.lax.bitwise_and(
+                            jax.lax.shift_right_logical(x, jnp.int32(8 * b)),
+                            jnp.int32(0xFF))
+                    else:
+                        part = jax.lax.shift_right_arithmetic(
+                            x, jnp.int32(24))
+                    partf = part.astype(jnp.float32)
+                    part_tiles[ci][b].append(jnp.sum(
+                        jnp.where(scat, partf[None, :, :], jnp.float32(0)),
+                        axis=1, dtype=jnp.float32))    # (NARROW, 128) f32
+
+        # stack_all[s, k*NARROW + j] = 1 where slot row lo + j of subtile
+        # k lands: staging row offs[k] + lo + j
+        stack_all = (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                     == dst0 + lo).astype(jnp.int32).astype(jnp.bfloat16)
+
+        def place_all(tiles):
+            # f32 tiles: (8, 128) is one whole f32 tile, half a bf16 one
+            t = jnp.concatenate(tiles, axis=0).astype(jnp.bfloat16)
+            return jax.lax.dot_general(
+                stack_all, t, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        for ci in range(n_cols + 1):
+            if ci == 0:
+                val = place_all(valid_tiles).astype(jnp.int32)
+            else:
+                part = [place_all(part_tiles[ci - 1][b]) for b in range(4)]
+                val = (((part[3].astype(jnp.int32) * jnp.int32(256)
+                         + part[2].astype(jnp.int32)) * jnp.int32(256)
+                        + part[1].astype(jnp.int32)) * jnp.int32(256)
+                       + part[0].astype(jnp.int32))
+            stages[ci][:] = stages[ci][:] + val
+        return acc
+
+    # as many chunks as the step's largest advance needs: one where no
+    # subtile advances past NARROW (a narrow step), up to R // NARROW.
+    # The trip count is read from SMEM: a scalar, not a vector lane.
+    carry[3] = step_adv
+    adv_s = carry[3]
+    carry[2] = carry[2] + (adv_s <= NARROW).astype(jnp.int32)
+    for ci in range(n_cols + 1):
+        stages[ci][:] = jnp.zeros((stage_rows, LANES), jnp.int32)
+    jax.lax.fori_loop(jnp.int32(0), (adv_s + (NARROW - 1)) // NARROW,
+                      stage_chunk, jnp.int32(0))
 
     off = carry[0]
     fits = off + stage_rows <= slots_cap
-
-    for ci in range(n_cols + 1):
-        if ci == 0:
-            val = place_all(valid_tiles).astype(jnp.int32)
-        else:
-            acc = [place_all(part_tiles[ci - 1][b]) for b in range(4)]
-            val = (((acc[3].astype(jnp.int32) * jnp.int32(256)
-                     + acc[2].astype(jnp.int32)) * jnp.int32(256)
-                    + acc[1].astype(jnp.int32)) * jnp.int32(256)
-                   + acc[0].astype(jnp.int32))
-        stages[ci][:] = val
 
     # DMA start + synchronous wait inside one conditional block: a skipped
     # step (overflow) skips both, so no semaphore imbalance across steps
@@ -414,6 +473,7 @@ def _kernel(mask_ref, *rest, n_cols: int, slots_cap: int, n_steps: int,
         nslots_ref[0, 0] = carry[0]
         matched_ref[0, 0] = carry[1]
         overflow_ref[0, 0] = oflow[0]
+        narrow_ref[0, 0] = carry[2]
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
@@ -435,9 +495,9 @@ def _compact_pallas(mask, cols, n, slots_cap, k_sub, interp):
                              memory_space=pltpu.VMEM)] * (n_cols + 1)
     out_shapes = ([jax.ShapeDtypeStruct((slots_cap, LANES), jnp.int32)]
                   * (n_cols + 1)
-                  + [jax.ShapeDtypeStruct((1, 1), jnp.int32)] * 3)
+                  + [jax.ShapeDtypeStruct((1, 1), jnp.int32)] * 4)
     out_specs = ([pl.BlockSpec(memory_space=pl.ANY)] * (n_cols + 1)
-                 + [pl.BlockSpec(memory_space=pltpu.SMEM)] * 3)
+                 + [pl.BlockSpec(memory_space=pltpu.SMEM)] * 4)
 
     kern = functools.partial(_kernel, n_cols=n_cols, slots_cap=slots_cap,
                              n_steps=n_steps, k_sub=k_sub)
@@ -448,7 +508,7 @@ def _compact_pallas(mask, cols, n, slots_cap, k_sub, interp):
         out_shape=out_shapes,
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.SMEM((2,), jnp.int32),
+            pltpu.SMEM((4,), jnp.int32),
             pltpu.SMEM((1,), jnp.int32),
         ] + [pltpu.VMEM((stage_rows, LANES), jnp.int32)] * (n_cols + 1)
           + [pltpu.SemaphoreType.DMA((n_cols + 1,))],
@@ -464,6 +524,7 @@ def _compact_pallas(mask, cols, n, slots_cap, k_sub, interp):
     n_slots = outs[n_cols + 1][0, 0]
     matched = outs[n_cols + 2][0, 0]
     overflow = outs[n_cols + 3][0, 0]
+    narrow = outs[n_cols + 4][0, 0]
 
     cap_rows = slots_cap * LANES
     row_ok = (jnp.arange(cap_rows, dtype=jnp.int32)
@@ -471,4 +532,5 @@ def _compact_pallas(mask, cols, n, slots_cap, k_sub, interp):
     valid = (valid2d.reshape(cap_rows) != 0) & row_ok
     out_cols = tuple(jnp.where(valid, c.reshape(cap_rows), 0)
                      for c in col2d)
-    return valid, out_cols, n_slots, matched, overflow
+    return (valid, out_cols, n_slots, matched, overflow,
+            (narrow, jnp.int32(n_steps) - narrow))

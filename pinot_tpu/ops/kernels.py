@@ -1262,10 +1262,11 @@ def _compact_group_aggs(plan: KernelPlan, mask, cols, params, bucket: int,
     mask, keys_s = _group_keys_sentinel(plan, mask, cols, params)
     payloads, sum_jobs, mm_jobs, ord_modes = _payload_columns(
         plan, mask, cols, params, platform)
-    valid, comp, n_valid, matched, overflow = compact(
+    valid, comp, n_valid, matched, overflow, steps = compact(
         mask, (keys_s,) + payloads, slots_cap, platform)
     out["overflow"] = overflow
     out["matched"] = matched.astype(int_acc_dtype())
+    out["compact_steps_narrow"], out["compact_steps_wide"] = steps
 
     @jax.named_scope(ph.SCOPE_AGGREGATE)
     def post(valid_a, comp_t, rows: int) -> Dict[str, jax.Array]:
@@ -1300,8 +1301,10 @@ def _compact_group_aggs(plan: KernelPlan, mask, cols, params, bucket: int,
             and cap_rows >= min_elems))
     if two_pass:
         cap2 = max(slots_cap // 4, 512)
-        valid2, comp2, n_valid2, _m2, of2 = compact(
+        valid2, comp2, n_valid2, _m2, of2, steps2 = compact(
             valid, comp, cap2, platform)
+        for name, n in zip(COMPACT_STEP_OUTPUTS, steps2):
+            out[name] = out[name] + n
         out.update(_ladder_switch(
             _post_sizes(valid2.shape[0] // LANES), n_valid2,
             lambda s: functools.partial(post, valid2, comp2, s * LANES),
@@ -1804,6 +1807,12 @@ def build_kernel(plan: KernelPlan, bucket: int,
     return kernel
 
 
+# the compactor's grid steps by form (ops/compact.py: narrow or wide),
+# both passes together; the host counts them where it reads "matched"
+COMPACT_STEP_OUTPUTS = ("compact_steps_narrow", "compact_steps_wide")
+# a kernel's outputs that count rows or steps and are no group arrays
+COUNT_OUTPUTS = ("matched", "overflow") + COMPACT_STEP_OUTPUTS
+
 # group outputs over this space reach the host as (group_idx, value) rows
 # of the non-empty groups, GROUP_XFER_CAP of them: from the sorted core's
 # sparse post, from the mesh's list of its devices' ids (parallel/
@@ -1833,8 +1842,7 @@ def _compact_group_xfer(plan: KernelPlan, out: Dict[str, jax.Array]) -> None:
     space = plan.group_space
     if space < GROUP_XFER_SPACE:
         return
-    dense = {k: v for k, v in out.items()
-             if k not in ("matched", "overflow")}
+    dense = {k: v for k, v in out.items() if k not in COUNT_OUTPUTS}
     if not all(v.ndim == 1 and v.shape[0] == space for v in dense.values()):
         return
     counts = out["group_count"]

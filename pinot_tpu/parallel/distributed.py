@@ -27,7 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..engine.executor import extract_partial, resolve_params
+from ..engine.executor import (count_compact_steps, extract_partial,
+                               resolve_params)
 from ..ops.kernels import (build_kernel, cpu_scatter_default,
                            launch_forms, sort_core_fits,
                            takes_sparse_post)
@@ -325,6 +326,7 @@ class DistributedTable:
                             self.mesh.devices.flat[0].platform))
                     else "mesh_live_list_dense")
             if "matched" in host:
+                count_compact_steps(host)
                 matched = int(np.asarray(host["matched"]).sum())
                 annotate(matched=matched,
                          meas_sel=matched / max(
@@ -413,7 +415,7 @@ def _gather_live_groups(space: int, ids: jax.Array,
     same replicated list and gathers the dense combined outputs at it,
     sentinel rows zeroed; group_overflow flags more distinct ids than
     the list holds (the executor retries with xfer_compact=False)."""
-    from ..ops.kernels import GROUP_XFER_CAP
+    from ..ops.kernels import COUNT_OUTPUTS, GROUP_XFER_CAP
     ids = jnp.sort(jax.lax.all_gather(ids.reshape(-1), SEG_AXIS,
                                       tiled=True))
     repeat = jnp.concatenate(
@@ -421,7 +423,7 @@ def _gather_live_groups(space: int, ids: jax.Array,
     ids = jnp.where(repeat, jnp.int32(space), ids)
     # distinct ids first, ascending; the sentinel sorts behind them
     idx = jnp.sort(ids)[:GROUP_XFER_CAP]
-    dense = [k for k in red if k not in ("matched", "overflow")]
+    dense = [k for k in red if k not in COUNT_OUTPUTS]
     red["group_idx"] = idx
     red["group_overflow"] = (jnp.sum(ids < space, dtype=jnp.int32)
                              > GROUP_XFER_CAP).astype(jnp.int32)

@@ -40,7 +40,7 @@ def test_compact_multiset_and_alignment():
     a = rng.integers(0, 1000, n).astype(np.int32)
     b = rng.integers(-5_000_000_000, 5_000_000_000, n).astype(np.int64)
     cap = C.default_slots_cap(n)
-    valid, (ac, bc), _, matched, ov = C.compact(
+    valid, (ac, bc), _, matched, ov, _ = C.compact(
         jnp.asarray(mask), (jnp.asarray(a), jnp.asarray(b)), cap)
     valid, ac, bc = map(np.asarray, (valid, ac, bc))
     assert int(matched) == mask.sum()
@@ -55,7 +55,7 @@ def test_compact_float64_column():
     n = 1 << 12
     mask = rng.random(n) < 0.3
     f = rng.normal(0, 1e9, n)
-    valid, (fc,), _, matched, ov = C.compact(
+    valid, (fc,), _, matched, ov, _ = C.compact(
         jnp.asarray(mask), (jnp.asarray(f),), C.default_slots_cap(n))
     valid, fc = np.asarray(valid), np.asarray(fc)
     assert np.array_equal(np.sort(f[mask]), np.sort(fc[valid]))
@@ -65,9 +65,9 @@ def test_compact_overflow_flag_and_full_cap():
     n = 1 << 12
     mask = np.ones(n, bool)
     a = np.arange(n, dtype=np.int32)
-    *_, ov = C.compact(jnp.asarray(mask), (jnp.asarray(a),), 4)
+    *_, ov, _ = C.compact(jnp.asarray(mask), (jnp.asarray(a),), 4)
     assert int(ov) == 1
-    valid, (ac,), _, matched, ov = C.compact(
+    valid, (ac,), _, matched, ov, _ = C.compact(
         jnp.asarray(mask), (jnp.asarray(a),), C.full_slots_cap(n))
     assert int(ov) == 0
     assert np.array_equal(np.sort(np.asarray(ac)[np.asarray(valid)]), a)
@@ -75,7 +75,7 @@ def test_compact_overflow_flag_and_full_cap():
 
 def test_compact_empty_mask():
     n = 1 << 12
-    valid, (ac,), _, matched, ov = C.compact(
+    valid, (ac,), _, matched, ov, _ = C.compact(
         jnp.zeros(n, bool), (jnp.arange(n, dtype=jnp.int32),),
         C.default_slots_cap(n))
     assert int(matched) == 0

@@ -113,6 +113,44 @@ def test_overflow_fault_retries_its_segment_alone(segments, per_segment):
                      "kernel_dispatches_compact_per_segment": N_SEG + 1}
 
 
+def test_compact_steps_count_the_launch_that_answered(segments, per_segment,
+                                                     monkeypatch):
+    """The compactor's step counts are taken after the retry ladder: an
+    overflowed launch's counts go with it, the retry's are counted, and
+    no count reaches extract_partial. Each collection is numbered by
+    its narrow count (1, 2, ...): seg_5's first is the sixth, its retry
+    the seventh."""
+    plans = _plans(segments, "q3.1")
+    real, seen, partial_keys = pc.PlanCacheEntry.collect, [], set()
+
+    def numbered(out):
+        host = real(out)
+        seen.append(out)
+        host["compact_steps_narrow"] = np.int32(len(seen))
+        host["compact_steps_wide"] = np.int32(0)
+        return host
+    real_extract = ex.extract_partial
+
+    def extract(plan, out):
+        partial_keys.update(out)
+        return real_extract(plan, out)
+    monkeypatch.setattr(pc.PlanCacheEntry, "collect", staticmethod(numbered))
+    monkeypatch.setattr(ex, "extract_partial", extract)
+    before = global_metrics.snapshot()["counters"]
+    faults.install("seed=3; device.overflow: match=seg_5, times=1")
+    try:
+        execute_plans_batched(plans)
+    finally:
+        faults.clear()
+        pc.global_plan_cache.clear()    # the fault marked the entry
+    after = global_metrics.snapshot()["counters"]
+    assert len(seen) == N_SEG + 1
+    assert (after.get("compact_steps_narrow", 0)
+            - before.get("compact_steps_narrow", 0)) == sum(
+                i for i in range(1, N_SEG + 2) if i != 6)
+    assert not partial_keys & set(K.COMPACT_STEP_OUTPUTS)
+
+
 def test_group_overflow_retries_its_segment_alone(segments, per_segment,
                                                   monkeypatch):
     """A segment whose live groups spill the transfer compaction is run

@@ -28,17 +28,19 @@ def _export_tpu(fn, *args):
     return export.export(jax.jit(fn), platforms=["tpu"])(*args)
 
 
-def test_compact_kernel_lowers_for_tpu():
+@pytest.mark.parametrize("k_sub", [C.K_MIN, C.K_MAX])
+@pytest.mark.parametrize("n_cols", [1, 2, 3])
+def test_compact_kernel_lowers_for_tpu(n_cols, k_sub):
+    """The kernel, with its loop over slot-row chunks sized by each
+    step's advance, lowers for TPU."""
     n = C.K_MAX * C.R * C.LANES * 2
     cap = C.sorted_default_slots_cap(n)
-    k_sub = C._choose_k(2, n)
 
-    def fn(mask, a, b):
-        return C._compact_pallas(mask, (a, b), n, cap, k_sub, False)
+    def fn(mask, *cols):
+        return C._compact_pallas(mask, cols, n, cap, k_sub, False)
 
     _export_tpu(fn, jax.ShapeDtypeStruct((n,), jnp.bool_),
-                jax.ShapeDtypeStruct((n,), jnp.int32),
-                jax.ShapeDtypeStruct((n,), jnp.int32))
+                *[jax.ShapeDtypeStruct((n,), jnp.int32)] * n_cols)
 
 
 @pytest.mark.parametrize("shape", ["sorted_q3", "factorized_q2"])

@@ -71,7 +71,7 @@ def check_compact_dtypes(out):
     cols = tuple(jnp.asarray(v) for v in srcs.values())
     cap = C.default_slots_cap(n)
     assert C._use_pallas(n), "Pallas path must engage on the chip"
-    valid, outs, _nv, matched, ovf = jax.device_get(
+    valid, outs, _nv, matched, ovf, _ = jax.device_get(
         C.compact(mask, cols, cap))
     if int(matched) != int(mask_np.sum()) or int(ovf) != 0:
         raise AssertionError(
@@ -89,7 +89,7 @@ def check_compact_dtypes(out):
     assert C._use_pallas(n_odd), "odd sizes must engage Pallas via padding"
     m_odd = rng.random(n_odd) < 0.2
     x_odd = rng.integers(-500, 500, n_odd).astype(np.int32)
-    v2, (o2,), _nv2, m2, ov2 = jax.device_get(C.compact(
+    v2, (o2,), _nv2, m2, ov2, _ = jax.device_get(C.compact(
         jnp.asarray(m_odd), (jnp.asarray(x_odd),),
         C.full_slots_cap(n_odd)))
     if int(m2) != int(m_odd.sum()) or int(ov2) != 0 or not np.array_equal(
