@@ -120,7 +120,8 @@ def format_diagnostics(diags: Sequence[Diagnostic]) -> str:
 _DEVICE_FUNC_KIND = {
     "cast_long": "int", "cast_int": "int",
     "cast_double": "float", "cast_float": "float",
-    "abs": "same", "floor": "float", "ceil": "float", "sqrt": "float",
+    "abs": "same", "floor": "float", "round": "float", "ceil": "float",
+    "sqrt": "float",
     "exp": "float", "ln": "float",
     "year": "int", "month": "int", "day": "int", "quarter": "int",
     "dayofweek": "int", "hour": "int", "minute": "int", "second": "int",
@@ -528,12 +529,14 @@ def _check_strategy(plan: ir.KernelPlan, c: _Ctx) -> None:
     from ..ops.kernels import COMPACT_GROUP_LIMIT, GROUPED_HLL_LIMIT
     from ..query.planner import MAX_DENSE_GROUPS, MAX_DISTINCT_MATRIX
 
-    if plan.strategy not in ("dense", "compact"):
+    if plan.strategy not in ("dense", "compact", "scan"):
         c.diag("PV107", "strategy",
                f"unknown strategy {plan.strategy!r}")
         return
     space = plan.group_space
     has_expr_keys = any(e is not None for e in (plan.key_exprs or ()))
+    if plan.strategy == "scan":
+        _check_scan(plan, c)
     if plan.strategy == "compact":
         if not plan.is_group_by:
             c.diag("PV107", "strategy",
@@ -561,7 +564,8 @@ def _check_strategy(plan: ir.KernelPlan, c: _Ctx) -> None:
                 c.diag("PV107", f"aggs[{i}].null_param",
                        "per-agg null masking has no compact lowering "
                        "(the planner hosts null-aware group-bys)")
-    elif plan.is_group_by and space > MAX_DENSE_GROUPS:
+    elif plan.strategy == "dense" and plan.is_group_by \
+            and space > MAX_DENSE_GROUPS:
         c.diag("PV107", "group_keys",
                f"dense one-hot over group space {space} exceeds "
                f"MAX_DENSE_GROUPS {MAX_DENSE_GROUPS}")
@@ -583,6 +587,34 @@ def _check_strategy(plan: ir.KernelPlan, c: _Ctx) -> None:
                     c.diag("PV107", f"aggs[{i}].card",
                            "grouped HLL presence bitmap exceeds "
                            "GROUPED_HLL_LIMIT")
+
+
+def _check_scan(plan: ir.KernelPlan, c: _Ctx) -> None:
+    """The scan strategy's gates (query/planner.py, ops/kernels
+    ._scan_group_aggs): a group-by of at most COMPACT_GROUP_LIMIT
+    groups, COUNT / SUM / AVG only, every float sum bounded."""
+    from ..ops.kernels import COMPACT_GROUP_LIMIT, scan_float_ok
+    if not plan.is_group_by:
+        c.diag("PV107", "strategy", "scan strategy without group keys")
+    if plan.group_space > COMPACT_GROUP_LIMIT:
+        c.diag("PV107", "group_keys",
+               f"group space {plan.group_space} exceeds "
+               f"COMPACT_GROUP_LIMIT {COMPACT_GROUP_LIMIT}")
+    for i, spec in enumerate(plan.aggs):
+        if spec.kind not in ("count", "sum", "avg"):
+            c.diag("PV107", f"aggs[{i}].kind",
+                   f"{spec.kind!r} aggregation on the scan path",
+                   "plan compact or route to the host registry")
+        elif isinstance(spec.value, ir.MvReduce) \
+                or spec.null_param is not None:
+            c.diag("PV107", f"aggs[{i}].value",
+                   "MV payloads and per-agg null masks have no scan "
+                   "lowering")
+        elif spec.kind != "count" and not spec.integral \
+                and not scan_float_ok(spec):
+            c.diag("PV107", f"aggs[{i}].bits",
+                   "a float sum without a magnitude bound has no exact "
+                   "fixed point on the scan path")
 
 
 def _check_group_keys(plan: ir.KernelPlan, c: _Ctx,

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.kernels import build_kernel, launch_forms
+from ..ops.kernels import build_kernel, launch_forms, over_segments
 from ..query.planner import CompiledPlan
 from ..utils import phases as ph
 from ..utils.devmem import global_device_memory
@@ -69,12 +69,22 @@ def _seg_key(seg) -> Tuple[int, str]:
     return (seg.uid, seg.name)
 
 
+def _vmap_family(plan_struct) -> str:
+    """The batched launch's kernel family: the scan strategy's own, the
+    dense one's for every other plan that reaches it."""
+    return ph.GROUP_SCAN if plan_struct.strategy == "scan" \
+        else ph.DENSE_VMAP
+
+
 @functools.lru_cache(maxsize=512)
 def _vmapped_kernel_cached(plan_struct, bucket: int, scatter: bool):
+    """One program over the stacked segments (ops/kernels.over_segments)."""
     from ..utils.compileplane import kernel_jit, staged
-    return staged(kernel_jit(jax.vmap(build_kernel(plan_struct, bucket,
-                                                   scatter=scatter)),
-                             ph.DENSE_VMAP),
+    kernel = build_kernel(plan_struct, bucket, scatter=scatter)
+
+    def batched(cols, n_docs, params):
+        return over_segments(plan_struct, kernel, cols, n_docs, params)
+    return staged(kernel_jit(batched, _vmap_family(plan_struct)),
                   "vmap_kernel", ("vmap", plan_struct, bucket, scatter))
 
 
@@ -232,6 +242,7 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
                     per_segment.append(i)
                     continue
             else:
+                # dense and scan: one vmapped launch over the segments
                 kind = "dense"
             key = (kind, kp, plan.segment.bucket,
                    param_sig(plan, hosts[i]) + shape_sig)
@@ -298,7 +309,7 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
             with span("vmap_dispatch", segments=n_seg, bucket=bucket,
                       strategy=plan_struct.strategy):
                 fn = _vmapped_kernel(plan_struct, bucket)
-                count_dispatch(ph.DENSE_VMAP,
+                count_dispatch(_vmap_family(plan_struct),
                                *launch_forms(plan_struct, params))
                 with phase(ph.DEVICE_EXECUTE):
                     dev = fn(cols, n_docs, params)

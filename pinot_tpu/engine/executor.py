@@ -27,6 +27,10 @@ from ..utils.metrics import global_metrics
 from ..utils.spans import annotate, count_dispatch, phase, span
 from . import host_eval
 
+# segments answered by the host path (numpy, no kernel): declared at 0 so
+# that a window in which nothing falls back still reads it
+global_metrics.count("segments_host", 0)
+
 
 @dataclass
 class AggPartial:
@@ -86,6 +90,7 @@ def execute_plan(plan: CompiledPlan, xfer_compact: bool = True,
     if plan.kind == "fast":
         return AggPartial(list(plan.fast_states))
     if plan.kind == "host":
+        global_metrics.count("segments_host")
         with span("segment_host", segment=seg.name):
             if host_eval.null_aware(ctx):
                 mask, _ = host_eval.eval_filter_3vl(ctx.filter, seg)
@@ -574,8 +579,11 @@ def extract_partial(plan: CompiledPlan, out: Dict[str, np.ndarray]):
         rem = rem // card
         if dec[0] == "dict":
             key_cols.append(seg.dictionary(dec[1]).values_for(ids))
-        else:  # ("int", lo, stride, card): expression keys (YEAR(ts)...)
-            key_cols.append(dec[1] + ids.astype(np.int64) * dec[2])
+        else:  # ("int" | "double", lo, stride, card): expression keys
+            # (YEAR(ts) an int, ROUND(x) a double, as the host answers)
+            vals = dec[1] + ids.astype(np.int64) * dec[2]
+            key_cols.append(vals.astype(np.float64) if dec[0] == "double"
+                            else vals)
     key_cols.reverse()
     keys = [tuple(_py(kc[i]) for kc in key_cols) for i in range(len(idxs))]
 

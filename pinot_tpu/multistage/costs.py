@@ -393,24 +393,30 @@ def choose_group_strategy(n_rows: int, space: int, sel: float,
                           platform: str, scatter_fast: bool,
                           needs_sort: bool, n_payloads: int,
                           dense_viable: bool, compact_ok: bool,
-                          force: Optional[str] = None
+                          force: Optional[str] = None,
+                          scan_ok: bool = False
                           ) -> Tuple[str, Dict[str, Any]]:
-    """Pick 'dense' vs 'compact' for a group-by kernel plan from relative
-    cost estimates; returns (strategy, trace). ``force`` (the
-    groupByStrategy query option) overrides the cost comparison when the
-    forced strategy is structurally possible. Structural gates
-    (dense_viable / compact_ok) always win over costs."""
+    """Pick 'dense', 'compact' or 'scan' for a group-by kernel plan;
+    returns (strategy, trace). ``force`` (the groupByStrategy query
+    option) overrides the choice when the forced strategy is
+    structurally possible. Structural gates (dense_viable / compact_ok /
+    scan_ok) always win over costs: the scan strategy takes only a plan
+    neither dense nor compact can. Otherwise dense vs compact from
+    relative cost estimates."""
     import math
 
     trace: Dict[str, Any] = {"sel": round(sel, 8), "space": space,
                              "n_rows": n_rows, "platform": platform,
                              "scatter_fast": scatter_fast}
-    if force in ("dense", "compact"):
-        allowed = (force == "dense" and dense_viable) or \
-                  (force == "compact" and compact_ok)
+    if force in ("dense", "compact", "scan"):
+        allowed = {"dense": dense_viable, "compact": compact_ok,
+                   "scan": scan_ok}[force]
         if allowed:
             trace["forced"] = force
             return force, trace
+    if not dense_viable and not compact_ok and scan_ok:
+        trace["reason"] = "dense and compact structurally unavailable"
+        return "scan", trace
     if not compact_ok:
         trace["reason"] = "compact structurally unavailable"
         return "dense", trace

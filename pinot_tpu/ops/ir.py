@@ -312,7 +312,11 @@ class KernelPlan:
     - 'compact': Pallas masked-row compaction (ops/compact.py), then
       factorized one-hot matmuls (small spaces) or sort + boundary diffs
       (large spaces) over the compacted rows only. The TPU answer to
-      DocIdSetOperator + DefaultGroupByExecutor at SSB selectivities.
+      DocIdSetOperator + DefaultGroupByExecutor at SSB selectivities;
+    - 'scan': the same posts over every row, in blocks, the keys computed
+      in the kernel — spaces over the dense budget that the compact
+      strategy cannot lower: expression keys, and float SUM / AVG where
+      float64 is emulated (ops/kernels._scan_group_aggs).
     """
     pred: Pred
     aggs: Tuple[AggSpec, ...]
@@ -321,8 +325,8 @@ class KernelPlan:
     # expression group keys (GROUP BY YEAR(ts), ...): parallel to
     # group_keys; entry k, when not None, is a ValueExpr already shifted
     # into [0, card_k) — evaluated instead of cols[col_idx]. Expression
-    # keys force the dense strategy (compaction gathers key columns by
-    # index). () means all-column keys.
+    # keys take the dense or the scan strategy (compaction gathers key
+    # columns by index). () means all-column keys.
     key_exprs: Tuple[Optional["ValueExpr"], ...] = ()
 
     @property

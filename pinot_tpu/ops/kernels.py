@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import functools
 import os
+from dataclasses import replace as dc_replace
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -255,20 +256,21 @@ _MS_DAY = 86_400_000
 
 
 def _civil_ymd(days):
+    # only the era needs 64 bits: the day of the era is in [0, 146096],
+    # so the rest is int32 arithmetic. XLA:TPU emulates every int64
+    # division in int32 pairs, and the compiler took minutes over the
+    # all-int64 form of a YEAR group key (PERF.md section 6)
     z = days.astype(jnp.int64) + 719468
     era = jnp.floor_divide(z, 146097)
-    doe = z - era * 146097
-    yoe = jnp.floor_divide(
-        doe - jnp.floor_divide(doe, 1460) + jnp.floor_divide(doe, 36524)
-        - jnp.floor_divide(doe, 146096), 365)
-    y = yoe + era * 400
-    doy = doe - (365 * yoe + jnp.floor_divide(yoe, 4)
-                 - jnp.floor_divide(yoe, 100))
-    mp = jnp.floor_divide(5 * doy + 2, 153)
-    d = doy - jnp.floor_divide(153 * mp + 2, 5) + 1
+    doe = (z - era * 146097).astype(jnp.int32)
+    yoe = (doe - doe // 1460 + doe // 36524 - doe // 146096) // 365
+    doy = doe - (365 * yoe + yoe // 4 - yoe // 100)
+    mp = (5 * doy + 2) // 153
+    d = doy - (153 * mp + 2) // 5 + 1
     m = jnp.where(mp < 10, mp + 3, mp - 9)
+    y = yoe.astype(jnp.int64) + era * 400
     y = jnp.where(m <= 2, y + 1, y)
-    return y, m, d
+    return y, m.astype(jnp.int64), d.astype(jnp.int64)
 
 
 def _days_from_civil(y, m, d):
@@ -279,6 +281,30 @@ def _days_from_civil(y, m, d):
     doy = jnp.floor_divide(153 * mp + 2, 5) + d - 1
     doe = yoe * 365 + jnp.floor_divide(yoe, 4)         - jnp.floor_divide(yoe, 100) + doy
     return era * 146097 + doe - 719468
+
+
+def _whole(x: jax.Array, how: str) -> jax.Array:
+    """floor(x), or round(x) half to even (numpy's rule, which the host
+    path (query/functions.py) and ClickHouse's round of a Float64 follow),
+    from a conversion, a subtraction and compares alone. XLA:TPU's own
+    round of an emulated float64 is wrong where the high float32 of the
+    pair is a tie and the low one is not 0 (on a TPU v5e,
+    round(2.4999999999) read 1.0 and round(0.49999999999) -1.0: PERF.md
+    section 6). The conversion may miss by one there too, so the integer
+    part is corrected until |x - t| < 1; a double of 2^52 or more is
+    whole."""
+    t = x.astype(jnp.int64)
+    d = x - t.astype(x.dtype)
+    t = t + (d >= 1).astype(jnp.int64) - (d <= -1).astype(jnp.int64)
+    f = x - t.astype(x.dtype)                  # in (-1, 1)
+    if how == "floor":
+        r = t - (f < 0).astype(jnp.int64)
+    else:
+        odd = t & 1
+        r = (t + (f > 0.5).astype(jnp.int64) - (f < -0.5).astype(jnp.int64)
+             + ((f == 0.5).astype(jnp.int64)
+                - (f == -0.5).astype(jnp.int64)) * odd)
+    return jnp.where(jnp.abs(x) < 2.0 ** 52, r.astype(x.dtype), x)
 
 
 def _eval_func(name: str, args) -> jax.Array:
@@ -292,8 +318,8 @@ def _eval_func(name: str, args) -> jax.Array:
                         else jnp.float32)
     if name == "abs":
         return jnp.abs(a)
-    if name == "floor":
-        return jnp.floor(a.astype(float_acc_dtype()))
+    if name in ("floor", "round"):
+        return _whole(a.astype(float_acc_dtype()), name)
     if name == "ceil":
         return jnp.ceil(a.astype(float_acc_dtype()))
     if name == "sqrt":
@@ -1732,6 +1758,103 @@ def _sorted_post_sparse(sum_jobs, mm_jobs, ord_modes, keys, valid, payloads,
 
 
 # ---------------------------------------------------------------------------
+# full-scan group-by (strategy 'scan': every row, nothing compacted)
+# ---------------------------------------------------------------------------
+
+# a float SUM / AVG on the scan strategy: a value the planner bounded
+# under 2^bits (AggSpec.bits, from the column's min/max) is summed as the
+# integer round(x * 2^(SCAN_FIXED_BITS - bits)), |q| <= 2^SCAN_FIXED_BITS,
+# cut into two halves of SCAN_HALF_BITS that ride the int8 limb matmul
+# (or the sort's int64 cumsums) exactly. What is not exact is the
+# rounding of each value to the fixed point, 2^-SCAN_FIXED_BITS of the
+# bound a value (4e-19 of it), far under the 48 bits XLA:TPU holds of a
+# float64 (PERF.md section 6)
+SCAN_FIXED_BITS = 61
+SCAN_HALF_BITS = 31
+
+
+def scan_float_ok(spec: AggSpec) -> bool:
+    """Whether the scan strategy can sum this float aggregate exactly:
+    its magnitude is bounded (bits 63 is the planner's 'unprofiled')."""
+    return spec.bits < 63
+
+
+@jax.named_scope(ph.SCOPE_GROUP_SCAN)
+def _scan_group_aggs(plan: KernelPlan, mask, cols, params, bucket: int,
+                     out: Dict[str, jax.Array]) -> None:
+    """Group aggregation over every row of the segment, for a group space
+    over the dense one-hot budget (query/planner.py): the keys are
+    computed in the kernel (dictionary ids, expression keys or both), so
+    nothing is compacted and an expression key needs no key column. The
+    rows go through the compact strategy's posts as they are: the
+    factorized one-hot matmul, a lax.scan over blocks of rows (no (rows,
+    space / 128) operand of the whole segment), up to
+    FACTORIZED_GROUP_LIMIT groups, the sorted post above it.
+
+    Integral sums ride the posts' exact int8 limbs (or int64 cumsums).
+    A float sum rides them as a fixed-point integer in two halves
+    (SCAN_FIXED_BITS) and comes back a float64: every SUM and AVG here is
+    wide, on every backend (float_acc_forms)."""
+    space = plan.group_space
+    mask, keys_s = _group_keys_sentinel(plan, mask, cols, params)
+    payloads: List[jax.Array] = []
+    jobs: List[Tuple[int, AggSpec, int]] = []
+    slots: Dict[ValueExpr, int] = {}
+    fixed: Dict[ValueExpr, Tuple[int, int, int]] = {}
+    floats: List[Tuple[int, AggSpec]] = []
+    half = AggSpec("sum", None, True, bits=SCAN_HALF_BITS, signed=False)
+    for i, spec in enumerate(plan.aggs):
+        if spec.kind == "count":
+            continue
+        if spec.kind not in ("sum", "avg"):
+            raise ValueError(f"scan group-by cannot lower {spec.kind!r}")
+        if spec.integral:
+            slot = slots.get(spec.value)
+            if slot is None:
+                v = _eval_value(spec.value, cols, params, promote=True)
+                slot = slots[spec.value] = len(payloads)
+                payloads.append(jnp.where(mask, v, 0))
+            jobs.append((i, spec, slot))
+            continue
+        if spec.value not in fixed:
+            shift = SCAN_FIXED_BITS - spec.bits
+            with jax.named_scope(ph.SCOPE_FLOAT_ACC):
+                x = _eval_value(spec.value, cols, params).astype(jnp.float64)
+                q = jnp.round(x * jnp.float64(2.0 ** shift)).astype(jnp.int64)
+                q = jnp.where(mask, q, jnp.int64(0))
+            # the names of the two halves' sums: past every real aggregate
+            j = len(plan.aggs) + 2 * len(fixed)
+            fixed[spec.value] = (j, j + 1, shift)
+            jobs.append((j, dc_replace(half, signed=spec.signed),
+                         len(payloads)))
+            jobs.append((j + 1, half, len(payloads) + 1))
+            payloads += [q >> SCAN_HALF_BITS,
+                         q & jnp.int64((1 << SCAN_HALF_BITS) - 1)]
+        floats.append((i, spec))
+    if space <= FACTORIZED_GROUP_LIMIT:
+        _factorized_post(jobs, keys_s, mask, tuple(payloads), space, bucket,
+                         out)
+    else:
+        _sorted_post(jobs, [], {}, keys_s, mask, tuple(payloads), space,
+                     out)
+    with jax.named_scope(ph.SCOPE_FLOAT_ACC):
+        totals = {}
+        for value, (j_hi, j_lo, shift) in fixed.items():
+            hi = out.pop(_agg_name(j_hi, half)).astype(jnp.float64)
+            lo = out.pop(_agg_name(j_lo, half)).astype(jnp.float64)
+            totals[value] = ((hi * jnp.float64(2.0 ** SCAN_HALF_BITS) + lo)
+                             * jnp.float64(2.0 ** -shift)).astype(
+                                 float_acc_dtype())
+        for i, spec in floats:
+            name = _agg_name(i, spec)
+            if spec.kind == "avg":
+                out[name + "_sum"] = totals[spec.value]
+                out[name + "_cnt"] = out["group_count"]
+            else:
+                out[name] = totals[spec.value]
+
+
+# ---------------------------------------------------------------------------
 # kernel assembly
 # ---------------------------------------------------------------------------
 
@@ -1795,7 +1918,13 @@ def build_kernel(plan: KernelPlan, bucket: int,
             return out
         with jax.named_scope(ph.SCOPE_AGGREGATE):
             out["matched"] = jnp.sum(mask, dtype=int_acc_dtype())
-        if plan.is_group_by:
+        if plan.is_group_by and plan.strategy == "scan" and not scatter:
+            _scan_group_aggs(plan, mask, cols, params, total, out)
+            if xfer_compact:
+                _compact_group_xfer(plan, out)
+        elif plan.is_group_by:
+            # a scatter backend's core reads every row already: the scan
+            # strategy is the dense scatter core there
             _group_aggs(plan, mask, cols, params, total, out, scatter)
             if xfer_compact and not scatter:
                 _compact_group_xfer(plan, out)
@@ -1805,6 +1934,20 @@ def build_kernel(plan: KernelPlan, bucket: int,
         return out
 
     return kernel
+
+
+def over_segments(plan: KernelPlan, fn, *stacked):
+    """``fn`` over the leading (segment) axis of each of ``stacked``: one
+    program for a stack of segments, on the one-chip batched launch
+    (engine/batch.py) and on a mesh device's shard (parallel/
+    distributed.py) alike. The scan strategy maps the segments in turn
+    (lax.map): vmap batched its blocked one-hot contraction off the MXU,
+    and for the taxi cell's zone tile XLA:TPU generated 765 MB of code in
+    158 s and ran it in 160 ms a request (PERF.md section 6). Every other
+    strategy vmaps."""
+    if plan.strategy == "scan":
+        return jax.lax.map(lambda a: fn(*a), stacked)
+    return jax.vmap(fn)(*stacked)
 
 
 # the compactor's grid steps by form (ops/compact.py: narrow or wide),
@@ -1991,15 +2134,17 @@ def float_acc_forms(plan: KernelPlan, platform: Optional[str] = None
       than FLOAT_UNROLL_GROUPS groups (_group_float_sums: an unblocked
       dot_general through the emulation, held to no bound).
 
-    The scalar scan and the dense group-by of a few groups (TPC-H Q6 and
-    Q1) are wide everywhere."""
+    The scalar scan, the dense group-by of a few groups (TPC-H Q6 and
+    Q1) and the scan strategy's fixed-point sums (_scan_group_aggs) are
+    wide everywhere."""
     from .compact import f64_bitcast_ok
     n = sum(1 for s in plan.aggs if s.kind in _FLOAT_ACC_KINDS
             and s.value is not None and not s.integral)
     narrow = n and (float_acc_dtype() != jnp.float64 or (
         not f64_bitcast_ok(platform) and plan.is_group_by
         and (plan.strategy == "compact"
-             or plan.group_space > FLOAT_UNROLL_GROUPS)))
+             or (plan.strategy == "dense"
+                 and plan.group_space > FLOAT_UNROLL_GROUPS))))
     return (0, n) if narrow else (n, 0)
 
 

@@ -30,7 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..engine.executor import (count_compact_steps, extract_partial,
                                resolve_params)
 from ..ops.kernels import (build_kernel, cpu_scatter_default,
-                           launch_forms, sort_core_fits,
+                           launch_forms, over_segments, sort_core_fits,
                            takes_sparse_post)
 from ..utils import phases as ph
 from ..utils.devmem import global_device_memory
@@ -484,7 +484,9 @@ def _distributed_kernel_cached(kernel_plan, bucket: int, mesh: Mesh,
             local = jax.lax.map(lambda cn: kern(cn[0], cn[1], params),
                                 (cols, n_docs))
         else:
-            local = jax.vmap(lambda c, n: kern(c, n, params))(cols, n_docs)
+            local = over_segments(kernel_plan,
+                                  lambda c, n: kern(c, n, params),
+                                  cols, n_docs)
         # the sparse post's ids: (cap,) on the flattened route, (L, cap)
         # on the routed core
         ids = local.get("group_idx")

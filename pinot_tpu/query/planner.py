@@ -75,8 +75,9 @@ class CompiledPlan:
     agg_bindings: List["AggBinding"] = field(default_factory=list)
     group_cols: List[str] = field(default_factory=list)   # group key columns
     # per-key decode recipe for extract_partial: ("dict", col, card) |
-    # ("int", lo, stride, card) — expression keys (GROUP BY YEAR(ts))
-    # have no dictionary; their ids decode as lo + id*stride
+    # ("int" | "double", lo, stride, card) — expression keys (GROUP BY
+    # YEAR(ts), ROUND(x)) have no dictionary; their ids decode as
+    # lo + id*stride, a double for ROUND / FLOOR
     group_decoders: List[tuple] = field(default_factory=list)
     # fast path: precomputed states per agg
     fast_states: Optional[List[Any]] = None
@@ -257,8 +258,8 @@ class SegmentPlanner:
         "year": True, "month": True, "day": True, "dayofmonth": True,
         "quarter": True, "dayofweek": True, "hour": True, "minute": True,
         "second": True, "millisecond": True,
-        "abs": None, "floor": False, "ceil": False, "sqrt": False,
-        "exp": False, "ln": False,
+        "abs": None, "floor": False, "round": False, "ceil": False,
+        "sqrt": False, "exp": False, "ln": False,
     }
 
     # constant output ranges of datetime field extractors (lo, hi)
@@ -269,6 +270,21 @@ class SegmentPlanner:
     _TRUNC_STRIDES = {"second": 1000, "minute": 60_000,
                       "hour": 3_600_000, "day": 86_400_000,
                       "week": 7 * 86_400_000}
+
+    @staticmethod
+    def _whole_number_call(g: Any) -> bool:
+        """FLOOR(x), ROUND(x) or ROUND(x, 0): a whole number of one
+        argument, which the host path answers as a DOUBLE
+        (query/functions.py)."""
+        from .functions import canonical
+        if not isinstance(g, FuncCall):
+            return False
+        name = canonical(g.name)
+        scale = g.args[1:]
+        return name in ("round", "floor") and len(g.args) in (1, 2) and (
+            not scale or (name == "round" and isinstance(scale[0], Literal)
+                          and scale[0].value == 0
+                          and not isinstance(scale[0].value, bool)))
 
     def _expr_key_range(self, g: Any):
         """GROUP BY expression -> (lo, stride, cardinality) when the
@@ -284,6 +300,16 @@ class SegmentPlanner:
         name = "day" if name == "dayofmonth" else name
         if name in self._FIELD_RANGES and len(g.args) == 1:
             lo, hi = self._FIELD_RANGES[name]
+            return lo, 1, hi - lo + 1
+        if self._whole_number_call(g):
+            # a whole number of a numeric column or ranged expression:
+            # the range of its argument, rounded as the kernel rounds
+            # (numpy's half to even, the host path's rule)
+            arg_rng = self._range_of(g.args[0])
+            if arg_rng is None:
+                return None
+            fn = np.round if name == "round" else np.floor
+            lo, hi = (int(fn(v)) for v in arg_rng)
             return lo, 1, hi - lo + 1
         arg_rng = None
         if name == "year" and len(g.args) == 1:
@@ -331,6 +357,9 @@ class SegmentPlanner:
             if not vi:
                 raise PlanError("dateTrunc key over non-integer (host)")
             f = FuncIR(f"trunc_{unit}", (v,))
+        elif name in ("round", "floor"):
+            v, _vi = self.resolve_value(g.args[0])
+            f = FuncIR(name, (v,))
         else:
             v, vi = self.resolve_value(g.args[0])
             if not vi:
@@ -357,6 +386,8 @@ class SegmentPlanner:
             raise PlanError(f"dateTrunc unit {unit!r} (host fallback)")
         integral = self._DEVICE_FUNCS.get("day" if name == "dayofmonth"
                                           else name, "missing")
+        if name == "round" and self._whole_number_call(e):
+            e = FuncCall(e.name, e.args[:1], e.distinct)
         if integral == "missing" or len(e.args) != 1 or e.distinct:
             raise PlanError(f"no device lowering for {e.name!r} "
                             "(host fallback)")
@@ -1380,7 +1411,10 @@ class SegmentPlanner:
                         group_keys.append((0, card))
                         key_exprs.append(ve)
                         group_cols.append(_expr_label_of(g))
-                        group_decoders.append(("int", lo, stride, card))
+                        # ROUND / FLOOR answer a DOUBLE, as on the host
+                        group_decoders.append((
+                            "double" if self._whole_number_call(g)
+                            else "int", lo, stride, card))
             except PlanError:
                 return CompiledPlan("host", seg, ctx)
             space = 1
@@ -1403,16 +1437,32 @@ class SegmentPlanner:
                 # MV value columns are (bucket, maxValues) matrices; the
                 # row compaction primitive is 1-D — dense handles them
                 and not any(isinstance(s.value, _MvR) for s in specs))
+            # scan strategy: every row through the factorized or sorted
+            # post in blocks (ops/kernels._scan_group_aggs), keys computed
+            # in the kernel, so expression keys need no key column; COUNT
+            # and SUM / AVG (a float one as an exact fixed point, so its
+            # magnitude must be bounded)
+            from ..ops.kernels import scan_float_ok
+            scan_ok = (
+                space <= COMPACT_GROUP_LIMIT
+                and all(s.kind in ("count", "sum", "avg")
+                        and not isinstance(s.value, _MvR)
+                        and s.null_param is None
+                        and (s.kind == "count" or s.integral
+                             or scan_float_ok(s))
+                        for s in specs))
             # dense-strategy viability (one-hot over all rows)
             dense_viable = space <= MAX_DENSE_GROUPS
             has_expr_keys = any(e is not None for e in key_exprs)
             if (slow_scatter or has_expr_keys) and \
                     seg.bucket * (space + 1) > DENSE_ONEHOT_BUDGET:
                 # the (bucket, space) int8 one-hot operand would not fit /
-                # would dominate HBM traffic; matched-row compaction first
-                # is strictly better at any real selectivity. Expression
-                # keys can't compact (no key column to gather), so the
-                # budget gates them to host on every backend.
+                # would dominate HBM traffic. Over the budget the compact
+                # strategy answers what it can lower, and the scan
+                # strategy (every row, in blocks) the rest. Expression
+                # keys can't compact (no key column to gather): the scan
+                # strategy answers them, and what it cannot lower goes to
+                # the host.
                 dense_viable = False
             for s in specs:
                 if s.kind == "distinct_count" and s.card is not None \
@@ -1428,12 +1478,22 @@ class SegmentPlanner:
                     # no matmul form for min/max; TPU scatter is
                     # pathological (kernels.MINMAX_UNROLL_GROUPS)
                     dense_viable = False
-            if not dense_viable and not compact_ok:
+            from ..ops.compact import f64_bitcast_ok
+            if scan_ok and not f64_bitcast_ok(_jax.default_backend()) \
+                    and any(s.kind in ("sum", "avg") and not s.integral
+                            for s in specs):
+                # the compact post sums a float payload by a float64
+                # one-hot dot_general, which XLA:TPU emulates in float32
+                # pairs and, for a 2^23-row segment, 22 GB of temporaries
+                # at any capacity (compiled for a v5e: PERF.md section 6):
+                # the scan strategy answers
+                compact_ok = False
+            if not dense_viable and not compact_ok and not scan_ok:
                 return CompiledPlan("host", seg, ctx)
             # cost-model strategy choice (round-6 tentpole): dense vs
             # compact driven by IR-measured selectivity x group-space
             # (multistage/costs.py), not the old space>512 heuristic.
-            # OPTION(groupByStrategy=dense|compact) pins it when a
+            # OPTION(groupByStrategy=dense|compact|scan) pins it when a
             # structurally-possible strategy is forced (hardware gates,
             # differential tests).
             from ..multistage import costs as _costs
@@ -1454,7 +1514,7 @@ class SegmentPlanner:
             strategy, strat_trace = _costs.choose_group_strategy(
                 seg.n_docs, space, est_sel, platform, scatter_fast,
                 needs_sort_flag, n_payloads, dense_viable, compact_ok,
-                force)
+                force, scan_ok)
 
         plan = KernelPlan(pred=pred, aggs=tuple(specs),
                           group_keys=tuple(group_keys),

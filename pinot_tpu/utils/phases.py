@@ -151,17 +151,22 @@ CUBE_BUILD_KERNEL = "cube_build"             # micro-batcher's cube scan
 MESH_DENSE = "mesh_dense"                    # local segments vmapped
 MESH_COMPACT = "mesh_compact"                # the local shard flattened
 MESH_COMPACT_PER_SEGMENT = "mesh_compact_per_segment"  # routed sort core
+# the full-scan group-by (strategy 'scan'): S segments vmapped, or one
+# segment through the plan cache
+GROUP_SCAN = "group_scan"
 KERNEL_FAMILIES = frozenset(
     {DENSE_VMAP, DENSE_PER_SEGMENT, COMPACT_SEGMENTED, COMPACT_PER_SEGMENT,
      SELECT_TOPK, RAGGED_FUSED, CUBE_BUILD_KERNEL, MESH_DENSE, MESH_COMPACT,
-     MESH_COMPACT_PER_SEGMENT})
+     MESH_COMPACT_PER_SEGMENT, GROUP_SCAN})
 MODULE_PREFIX = "pinot_"
 
 
 def plan_family(plan) -> str:
     """Family of a single-segment kernel built from ``plan``."""
-    return (COMPACT_PER_SEGMENT if getattr(plan, "strategy", None) == "compact"
-            else DENSE_PER_SEGMENT)
+    strategy = getattr(plan, "strategy", None)
+    if strategy == "compact":
+        return COMPACT_PER_SEGMENT
+    return GROUP_SCAN if strategy == "scan" else DENSE_PER_SEGMENT
 
 
 # jax.named_scope names of the stages inside the kernels (HLO metadata
@@ -174,6 +179,7 @@ SCOPE_COMPACT = "pinot.compact"          # ops/compact.compact
 SCOPE_AGGREGATE = "pinot.aggregate"      # scalar, one-hot, sorted, scatter
 SCOPE_FLOAT_ACC = "pinot.float_acc"      # wide float sums, inside aggregate
 SCOPE_GROUP_TAIL = "pinot.group_tail"    # sparse sorted post, per live group
+SCOPE_GROUP_SCAN = "pinot.group_scan"    # full-scan group-by, every row
 SCOPE_XFER_COMPACT = "pinot.xfer_compact"  # live-group gather pre-transfer
 SCOPE_TOPK = "pinot.topk"                # selection order key + top_k
 SCOPE_COMBINE = "pinot.combine"          # the mesh's per-device combine
@@ -183,5 +189,5 @@ SCOPE_CUBE_COMBINE = "pinot.cube_combine"  # per-item mask + cell reduction
 KERNEL_SCOPES = frozenset(
     {SCOPE_MASK, SCOPE_DECODE_DICT, SCOPE_GROUP_KEY, SCOPE_PAYLOAD,
      SCOPE_COMPACT, SCOPE_AGGREGATE, SCOPE_FLOAT_ACC, SCOPE_GROUP_TAIL,
-     SCOPE_XFER_COMPACT, SCOPE_TOPK, SCOPE_COMBINE, SCOPE_CUBE_BUILD,
-     SCOPE_CUBE_COMBINE})
+     SCOPE_GROUP_SCAN, SCOPE_XFER_COMPACT, SCOPE_TOPK, SCOPE_COMBINE,
+     SCOPE_CUBE_BUILD, SCOPE_CUBE_COMBINE})

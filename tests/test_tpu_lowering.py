@@ -73,3 +73,28 @@ def test_full_compact_query_kernel_lowers_for_tpu(monkeypatch, shape):
                  for _ in range(n_cols))
     params = tuple(jax.ShapeDtypeStruct((), jnp.int32) for _ in range(4))
     _export_tpu(fn, cols, jax.ShapeDtypeStruct((), jnp.int32), params)
+
+
+def test_scan_kernel_over_segments_lowers_for_tpu():
+    """The zone tile's scan-strategy program as the batched launch builds
+    it (ops/kernels.over_segments: 8 segments of 2^23 rows, 265 groups,
+    COUNT and a float AVG as an exact fixed point) lowers for TPU, and
+    maps the segments in turn rather than batching them."""
+    from pinot_tpu.ops.ir import TrueP
+    from pinot_tpu.ops.kernels import over_segments
+    plan = KernelPlan(pred=TrueP(),
+                      aggs=(AggSpec("count", None, True),
+                            AggSpec("avg", Col(1), False, bits=9)),
+                      group_keys=((0, 265),), strategy="scan")
+    seg_rows, n_seg = 1 << 23, 8
+    kern = build_kernel(plan, seg_rows, platform="tpu")
+
+    def fn(cols, n_docs, params):
+        return over_segments(plan, kern, cols, n_docs, params)
+    exp = _export_tpu(
+        fn, (jax.ShapeDtypeStruct((n_seg, seg_rows), jnp.int32),
+             jax.ShapeDtypeStruct((n_seg, seg_rows), jnp.float64)),
+        jax.ShapeDtypeStruct((n_seg,), jnp.int32), ())
+    # one loop over the segments, one over a segment's blocks of rows
+    # (vmap would leave the blocks' loop alone, batched over segments)
+    assert exp.mlir_module().count("stablehlo.while") == 2

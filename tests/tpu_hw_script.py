@@ -14,7 +14,8 @@ X64 rewriter, Pallas Mosaic lowering):
 3. one query through every device path that otherwise only runs on the
    CPU suite: two-pass/ladder compaction, the selectivity x group-space
    grid, device CASE/CAST/datetime + dateTrunc group keys, expression
-   group keys, dictionary-evaluated string predicates, device top_k
+   group keys, ROUND / FLOOR beside every tie and the civil date fields
+   of int64 milliseconds against numpy, dictionary-evaluated string predicates, device top_k
    selection (kselect), segmented multi-segment compact batching, and a
    pipelined over-HBM-budget scan.
 
@@ -42,6 +43,7 @@ def run_hardware_checks(checks: list) -> None:
     check_two_pass_ladder(out, broker, seg, srcs, k)
     run_selectivity_grid(1 << 21, out=out)
     check_device_transforms(out)
+    check_whole_numbers_and_dates(out)
     check_string_predicates(out)
     check_kselect(out)
     check_segmented_batch(out)
@@ -408,6 +410,82 @@ def check_device_transforms(out) -> None:
             or r[1] != int(np.trunc(price).sum()):
         raise AssertionError("CAST value expression mismatch on chip")
     out["checks"].append("device:cast")
+
+
+# doubles at and beside the ties of ROUND, where XLA:TPU's own rounding
+# of an emulated float64 was wrong (PERF.md section 6), past 2^52, NaN
+# and the infinities. The TPU's double is a pair of float32 with their
+# exponent range: it holds nothing past 3.4e38 (1e300 arrives as inf)
+NEAR_TIES = (
+    2.5, 3.5, -2.5, -3.5, 0.5, -0.5, 1.5, 199.5, 198.5, 2.51, 2.49,
+    2.5000000001, 2.4999999999, 0.49999999999, -0.49999999999,
+    2.9999999999, -2.9999999999, 0.0, -0.0, 1e-3, 12.34, 399.99,
+    2.0 ** 47 + 0.5, 2.0 ** 52 - 0.5, 2.0 ** 52, -2.0 ** 53, 2.0 ** 100,
+    float("nan"), float("inf"), float("-inf"))
+# the taxi table's first and last pickup (benchmark/taxi/data.py)
+TAXI_MS = (1_230_768_000_000, 1_451_606_399_999)
+
+
+def whole_number_misses(name: str, ref):
+    """The doubles whose device ``name`` (round | floor, the kernel's
+    _eval_func) differs from numpy's ``ref`` of the same double as the
+    device holds it: NEAR_TIES and 8,192 drawn values, half of them
+    ties."""
+    import jax
+    import numpy as np
+
+    from pinot_tpu.ops import kernels
+    rng = np.random.default_rng(11)
+    xs = np.concatenate([np.array(NEAR_TIES), rng.normal(0, 1e3, 4096),
+                         np.round(rng.normal(0, 1e3, 4096)) + 0.5])
+    xs = np.asarray(jax.device_put(xs))
+    got = np.asarray(jax.jit(lambda x: kernels._eval_func(name, [x]))(xs))
+    want = ref(xs)
+    return xs[~((got == want) | (np.isnan(got) & np.isnan(want)))]
+
+
+def civil_date_misses(name: str):
+    """The int64 milliseconds whose device ``name`` (year | month | day |
+    quarter) differs from numpy's datetime64, or is no int64: over
+    +-2^52 ms, +-10^13 ms, the day edges and TAXI_MS."""
+    import jax
+    import numpy as np
+
+    from pinot_tpu.ops import kernels
+    rng = np.random.default_rng(12)
+    ms = np.concatenate([
+        rng.integers(-2 ** 52, 2 ** 52, 4096),
+        rng.integers(-10 ** 13, 10 ** 13, 4096),
+        np.array([0, -1, 1, 86_399_999, 86_400_000, -86_400_000,
+                  -86_400_001, 951_782_400_000, 951_868_800_000,
+                  *TAXI_MS])]).astype(np.int64)
+    dt = ms.astype("datetime64[ms]")
+    month = dt.astype("datetime64[M]").astype(np.int64) % 12 + 1
+    want = {"year": dt.astype("datetime64[Y]").astype(np.int64) + 1970,
+            "month": month, "quarter": (month - 1) // 3 + 1,
+            "day": (dt.astype("datetime64[D]")
+                    - dt.astype("datetime64[M]")).astype(np.int64) + 1}[name]
+    got = np.asarray(jax.jit(lambda x: kernels._eval_func(name, [x]))(ms))
+    return ms if got.dtype != np.int64 else ms[got != want]
+
+
+def check_whole_numbers_and_dates(out) -> None:
+    """The scan strategy's key functions in the taxi statements: ROUND
+    (half to even) and FLOOR of a double, and the civil date fields of
+    int64 milliseconds, against numpy on the current backend."""
+    import numpy as np
+    for name, ref in (("round", np.round), ("floor", np.floor)):
+        miss = whole_number_misses(name, ref)
+        if miss.size:
+            raise AssertionError(f"device {name} differs from numpy at "
+                                 f"{miss[:8].tolist()}")
+        out["checks"].append(f"device:{name}_beside_ties")
+    for name in ("year", "month", "day", "quarter"):
+        miss = civil_date_misses(name)
+        if miss.size:
+            raise AssertionError(f"device {name} differs from numpy at "
+                                 f"{miss[:8].tolist()} ms")
+        out["checks"].append(f"device:{name}_of_int64_ms")
 
 
 def check_string_predicates(out) -> None:
