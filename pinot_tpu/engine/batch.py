@@ -29,9 +29,10 @@ from ..utils.devmem import global_device_memory
 from ..utils.metrics import global_metrics
 from ..utils.spans import (annotate, count_dispatch, device_fence, phase,
                            span)
-from .executor import (count_compact_steps, execute_kernel_plans,
-                       execute_plan, extract_partial, param_sig,
-                       resident_param, resolve_params_host, stack_params)
+from .executor import (GroupColumns, count_compact_steps,
+                       execute_kernel_plans, execute_plan, extract,
+                       param_sig, place_group_partials, resident_param,
+                       resolve_params_host, stack_params)
 
 # stack cache: ((segment uid, name) pairs, what, bucket) -> (stamp, tuple
 # of stacked device arrays), where `what` is a plan's column names
@@ -188,11 +189,16 @@ def clear_stack_cache() -> None:
         global_device_memory.drop_pool("stack_cache")
 
 
-def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
+def execute_plans_batched(plans: List[CompiledPlan],
+                          stop: Optional[int] = None) -> List[Any]:
     """Execute all plans; kernel plans with matching structure run in one
     vmapped dispatch, the ones that run one program a segment in one
     launch window (executor.execute_kernel_plans). Returns partials in
-    input order."""
+    input order, a statement's kernel group-by segments combined into
+    one at the first of them and an empty one at each other
+    (executor.place_group_partials; ``stop`` is the index of the first
+    plan that a partial the caller puts among them, a rollup's,
+    precedes)."""
     results: List[Any] = [None] * len(plans)
     groups: Dict[Tuple, List[int]] = {}
     # plan indexes of the statement's per-segment route
@@ -202,6 +208,9 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
     # stacks it, the per-segment route takes it along — nothing reaches
     # the device before a launch does
     hosts: Dict[int, Tuple[Any, ...]] = {}
+    # plan index -> its segment's slice of a batched launch's host
+    # outputs: the statement's extraction below takes them all at once
+    outputs: Dict[int, Dict[str, Any]] = {}
 
     from ..ops.kernels import segmented_compact_fits, segmented_compact_ok
     from .accounting import global_accountant
@@ -304,7 +313,7 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
                     lambda m: _stacked_resident(group_plans, m, bucket))
             if kind == "segc":
                 _run_segmented_compact(plans, idxs, plan_struct, bucket,
-                                       cols, n_docs, params, results)
+                                       cols, n_docs, params, outputs)
                 continue
             with span("vmap_dispatch", segments=n_seg, bucket=bucket,
                       strategy=plan_struct.strategy):
@@ -320,33 +329,42 @@ def execute_plans_batched(plans: List[CompiledPlan]) -> List[Any]:
                 # per-segment slicing below runs on host numpy behind
                 # the single fence above — host-sync [jaxlint baseline]
                 spilled: List[int] = []
-                with phase(ph.EXTRACT_PARTIAL, segments=n_seg):
-                    for k, i in enumerate(idxs):
-                        per_seg = {name: v[k] for name, v in out.items()}
-                        if int(per_seg.pop("group_overflow", 0)):
-                            spilled.append(i)
-                        else:
-                            results[i] = extract_partial(plans[i], per_seg)
+                for k, i in enumerate(idxs):
+                    per_seg = {name: v[k] for name, v in out.items()}
+                    if int(per_seg.pop("group_overflow", 0)):
+                        spilled.append(i)
+                    else:
+                        outputs[i] = per_seg
                 for i in spilled:
                     # this segment alone exceeded the transfer-
                     # compaction cap; rerun it solo, straight to dense
-                    # outputs (outside the phase above: the rerun
-                    # crosses the same boundaries again)
+                    # outputs (the rerun crosses the boundaries again)
                     results[i] = execute_plan(plans[i], xfer_compact=False,
                                               host_params=hosts[i])
+    # a group-by's segments come back in array form where more than one
+    # kernel segment could meet in a combine
+    columns = len(kernel_plans) > 1 and plans[kernel_plans[0]].ctx.is_group_by
     if per_segment:
         per_segment.sort()
         for i, partial in zip(per_segment, execute_kernel_plans(
                 [plans[i] for i in per_segment],
-                [hosts[i] for i in per_segment])):
+                [hosts[i] for i in per_segment], columns=columns)):
             results[i] = partial
+    if outputs or any(isinstance(r, GroupColumns) for r in results):
+        # the statement's extraction: one crossing however many batched
+        # launches answered it, and the combine of its group-by segments
+        with phase(ph.EXTRACT_PARTIAL, segments=len(kernel_plans)):
+            for i, out in outputs.items():
+                results[i] = extract(plans[i], out, columns)
+            place_group_partials(results, stop)
     return results
 
 
 def _run_segmented_compact(plans, idxs, plan_struct, bucket, cols, n_docs,
-                           params, results) -> None:
+                           params, outputs) -> None:
     """One device program for S same-plan compact group-by segments;
-    slices the (S*space,) dense outputs apart and extracts per segment."""
+    slices the (S*space,) dense outputs apart, each segment's slice into
+    ``outputs``."""
     from ..ops.compact import full_slots_cap
     from ..ops.kernels import jitted_segmented_compact
     from .accounting import global_accountant
@@ -390,29 +408,28 @@ def _run_segmented_compact(plans, idxs, plan_struct, bucket, cols, n_docs,
             annotate(group_overflow_retry=True)
         global_accountant.track_result(out)
     space = plan_struct.group_space
-    with phase(ph.EXTRACT_PARTIAL, segments=n_seg):
-        count_compact_steps(out)
-        matched = out.pop("matched")
-        gi = out.pop("group_idx", None)
-        for k, i in enumerate(idxs):
-            per_seg = {"matched": matched[k]}
-            if gi is not None:
-                # transfer-compacted: rows are live groups of the combined
-                # S*space; this segment owns flat ids [k*space, (k+1)*space)
-                rows = np.nonzero((gi >= k * space) & (gi < (k + 1) * space)
-                                  & (np.asarray(out["group_count"]) > 0))[0]
-                per_seg["group_idx"] = np.asarray(gi)[rows] - k * space
-                for name, v in out.items():
-                    per_seg[name] = np.asarray(v)[rows]
-            else:
-                for name, v in out.items():
-                    v = np.asarray(v)
-                    if v.ndim >= 1 and v.shape[0] == n_seg * space:
-                        per_seg[name] = v.reshape(
-                            (n_seg, space) + v.shape[1:])[k]
-                    else:
-                        per_seg[name] = v
-            results[i] = extract_partial(plans[i], per_seg)
+    count_compact_steps(out)
+    matched = out.pop("matched")
+    gi = out.pop("group_idx", None)
+    for k, i in enumerate(idxs):
+        per_seg = {"matched": matched[k]}
+        if gi is not None:
+            # transfer-compacted: rows are live groups of the combined
+            # S*space; this segment owns flat ids [k*space, (k+1)*space)
+            rows = np.nonzero((gi >= k * space) & (gi < (k + 1) * space)
+                              & (np.asarray(out["group_count"]) > 0))[0]
+            per_seg["group_idx"] = np.asarray(gi)[rows] - k * space
+            for name, v in out.items():
+                per_seg[name] = np.asarray(v)[rows]
+        else:
+            for name, v in out.items():
+                v = np.asarray(v)
+                if v.ndim >= 1 and v.shape[0] == n_seg * space:
+                    per_seg[name] = v.reshape(
+                        (n_seg, space) + v.shape[1:])[k]
+                else:
+                    per_seg[name] = v
+        outputs[i] = per_seg
 
 
 def _launch_segmented(fn, cols, n_docs, params,

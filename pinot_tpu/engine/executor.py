@@ -30,6 +30,11 @@ from . import host_eval
 # segments answered by the host path (numpy, no kernel): declared at 0 so
 # that a window in which nothing falls back still reads it
 global_metrics.count("segments_host", 0)
+# kernel group-by segments of a statement that entered a combine of two
+# or more (place_group_partials), and those extracted as their own
+# partial: declared at 0 as segments_host is
+global_metrics.count("segments_combined", 0)
+global_metrics.count("segments_extracted", 0)
 
 
 @dataclass
@@ -40,6 +45,25 @@ class AggPartial:
 @dataclass
 class GroupByPartial:
     groups: Dict[Tuple, List[Any]]  # key values -> states per aggregation
+
+
+@dataclass
+class GroupColumns:
+    """One segment's live groups in array form, the first stage of a
+    group-by extraction: the decoded key values, one array a group
+    column, and each aggregation's state as a tuple of arrays, one a
+    part (AVG's sum and count are two), int64 where the part is integral
+    and float64 where it is not. A state that is no number (a distinct
+    set, a sketch) is its list of per-group states already, and leaves
+    the segment uncombinable, as a null-aware plan does."""
+    keys: List[np.ndarray]
+    states: List[Any]
+    kinds: List[str]          # base kind a state (ops/aggregations)
+    combinable: bool
+
+    @property
+    def n(self) -> int:
+        return len(self.keys[0])
 
 
 @dataclass
@@ -78,10 +102,13 @@ def execute_segment(ctx: QueryContext, segment: ImmutableSegment):
 
 
 def execute_plan(plan: CompiledPlan, xfer_compact: bool = True,
-                 host_params: Optional[Tuple[Any, ...]] = None):
+                 host_params: Optional[Tuple[Any, ...]] = None,
+                 columns: bool = False):
     """``xfer_compact=False`` reruns a kernel plan straight to dense
     group outputs, ``host_params`` hands a kernel plan its already
-    resolved host params (both: run_kernel)."""
+    resolved host params (both: run_kernel); ``columns`` answers a
+    kernel group-by with its GroupColumns, for a combine
+    (place_group_partials)."""
     ctx, seg = plan.ctx, plan.segment
     if plan.kind == "pruned":
         if not ctx.is_aggregation and plan.select_names:
@@ -115,7 +142,7 @@ def execute_plan(plan: CompiledPlan, xfer_compact: bool = True,
     assert plan.kind == "kernel"
     out = run_kernel(plan, xfer_compact, host_params)
     with phase(ph.EXTRACT_PARTIAL, segment=seg.name):
-        return extract_partial(plan, out)
+        return extract(plan, out, columns)
 
 
 def run_select_kernel(plan: CompiledPlan) -> Dict[str, np.ndarray]:
@@ -498,15 +525,16 @@ def run_kernel(plan: CompiledPlan, xfer_compact: bool = True,
 
 
 def execute_kernel_plans(plans: List[CompiledPlan],
-                         host_params: List[Optional[Tuple[Any, ...]]]
-                         ) -> List[Any]:
+                         host_params: List[Optional[Tuple[Any, ...]]],
+                         columns: bool = False) -> List[Any]:
     """``execute_plan`` for the kernel plans of one statement that run
     one program a segment (engine/batch.py's per-segment route), as a
     launch window: every segment's kernel is launched before the first
     is collected, and segment k's retry ladder and extraction run on
     the host while the later segments' kernels run on the device. No
     blocking copy sits between two kernels of the statement. Partials
-    come back in input order, each what ``execute_plan`` returns.
+    come back in input order, each what ``execute_plan`` returns (with
+    ``columns``, a group-by's GroupColumns).
 
     The look-ahead is the whole statement: a queued launch holds its
     outputs and nothing else on the device (0.66 MB a segment of q3.2
@@ -520,7 +548,7 @@ def execute_kernel_plans(plans: List[CompiledPlan],
     from .accounting import global_accountant
     from .tier import global_tier
     if len(plans) < 2 or tracing_active():
-        return [execute_plan(p, host_params=h)
+        return [execute_plan(p, host_params=h, columns=columns)
                 for p, h in zip(plans, host_params)]
     results: List[Any] = []
     flights: deque = deque()
@@ -539,8 +567,17 @@ def execute_kernel_plans(plans: List[CompiledPlan],
             host = finish_kernel(flight)
             with phase(ph.EXTRACT_PARTIAL,
                        segment=flight.plan.segment.name):
-                results.append(extract_partial(flight.plan, host))
+                results.append(extract(flight.plan, host, columns))
     return results
+
+
+def extract(plan: CompiledPlan, out: Dict[str, np.ndarray],
+            columns: bool = False):
+    """``extract_partial``, or with ``columns`` a group-by's first
+    stage alone (``group_columns``), which a combine takes."""
+    if columns and plan.ctx.is_group_by:
+        return group_columns(plan, out)
+    return extract_partial(plan, out)
 
 
 def extract_partial(plan: CompiledPlan, out: Dict[str, np.ndarray]):
@@ -549,21 +586,28 @@ def extract_partial(plan: CompiledPlan, out: Dict[str, np.ndarray]):
     # and the _scalar_state/_group_state helpers below never touch
     # device values.
     ctx, seg = plan.ctx, plan.segment
-    matched = int(out["matched"])
     if not ctx.is_group_by:
+        matched = int(out["matched"])
         na = host_eval.null_aware(ctx)
         states: List[Any] = []
         for b in plan.agg_bindings:
             states.append(_scalar_state(b, out, matched, seg, na))
         return AggPartial(states)
+    return group_partial(group_columns(plan, out))
 
+
+def group_columns(plan: CompiledPlan, out: Dict[str, np.ndarray]
+                  ) -> GroupColumns:
+    """The array stage of a group-by extraction: the segment's live
+    groups (count > 0) as GroupColumns. Host numpy throughout."""
+    seg = plan.segment
     gi = out.get("group_idx")
     gc = out["group_count"]
     if gi is not None:
         # device-compacted outputs: arrays are gathered non-empty rows,
         # gi holds their dense space ids (sentinel rows have count 0)
         sel = np.nonzero(gc > 0)[0]
-        idxs = np.asarray(gi)[sel]
+        idxs = np.asarray(gi)[sel]  # jaxlint: ok host-sync — host numpy
     else:
         idxs = np.nonzero(gc > 0)[0]
         sel = idxs
@@ -585,14 +629,206 @@ def extract_partial(plan: CompiledPlan, out: Dict[str, np.ndarray]):
             key_cols.append(vals.astype(np.float64) if dec[0] == "double"
                             else vals)
     key_cols.reverse()
-    keys = [tuple(_py(kc[i]) for kc in key_cols) for i in range(len(idxs))]
-
-    groups: Dict[Tuple, List[Any]] = {k: [] for k in keys}
+    combinable = not host_eval.null_aware(plan.ctx)
+    states: List[Any] = []
     for b in plan.agg_bindings:
-        per_group = _group_state(b, out, sel, seg)
-        for k_i, k in enumerate(keys):
-            groups[k].append(per_group[k_i])
-    return GroupByPartial(groups)
+        arrays = _state_arrays(b, out, sel)
+        if arrays is None:
+            combinable = False
+            states.append(_group_state(b, out, sel, seg))
+        else:
+            states.append(arrays)
+    return GroupColumns(key_cols, states,
+                        [_kind(b) for b in plan.agg_bindings], combinable)
+
+
+def group_partial(cols: GroupColumns) -> GroupByPartial:
+    """The build stage: one segment's GroupByPartial from its
+    GroupColumns (counter ``segments_extracted``)."""
+    global_metrics.count("segments_extracted")
+    return _build_groups(cols.keys, cols.states)
+
+
+def _build_groups(keys: List[np.ndarray], states: List[Any]
+                  ) -> GroupByPartial:
+    """Key tuples and per-group state lists from whole columns
+    (``tolist`` gives the Python scalars ``_py`` gives a cell)."""
+    rows = list(zip(*[k.tolist() for k in keys]))
+    cols: List[List[Any]] = []
+    for s in states:
+        if not isinstance(s, tuple):
+            cols.append(s)
+        elif len(s) == 2:   # AVG: (sum, count) pairs
+            cols.append(list(zip(s[0].tolist(), s[1].tolist())))
+        else:
+            cols.append(s[0].tolist())
+    if not cols:
+        return GroupByPartial({k: [] for k in rows})
+    return GroupByPartial(dict(zip(rows, map(list, zip(*cols)))))
+
+
+def _state_arrays(b: AggBinding, out: Dict[str, np.ndarray],
+                  sel: np.ndarray) -> Optional[Tuple[np.ndarray, ...]]:
+    """A numeric state's parts at ``sel``, int64 where the binding is
+    integral and float64 where it is not (the values ``int()`` and
+    ``float()`` make of them); None for a state that is no number."""
+    name = f"agg{b.index}_{_kind(b)}"
+    k = _kind(b)
+    if k == "count":
+        # group COUNT is served by the kernel's shared count row
+        parts = ((out["group_count"], True),)
+    elif k in ("sum", "min", "max"):
+        parts = ((out[name], b.integral),)
+    elif k == "avg":
+        parts = ((out[name + "_sum"], b.integral),
+                 (out[name + "_cnt"], True))
+    else:
+        return None
+    return tuple(
+        np.asarray(arr)[sel].astype(  # jaxlint: ok host-sync — host numpy
+            np.int64 if integral else np.float64, copy=False)
+        for arr, integral in parts)
+
+
+def combine_group_columns(forms: List[GroupColumns]
+                          ) -> Optional[GroupByPartial]:
+    """ONE GroupByPartial for several segments' GroupColumns of one
+    statement (Pinot's GroupByCombineOperator, on the host in arrays),
+    equal to what the broker's merge of their own partials in this
+    order makes (engine/reduce.py), groups in the order it first meets
+    them, every value alike to the bit: a group takes its first
+    segment's states, and a later segment's are added to them in
+    segment order, or kept where MIN/MAX's ``min(a, b)`` keeps ``a``.
+    None where that cannot be promised: an uncombinable segment, a key
+    column of another kind in another segment or a NaN key (the merge
+    never unites NaN keys), a part of another dtype, or an integral
+    sum that could leave int64 (largest value x segments >= 2^62)."""
+    if not all(f.combinable for f in forms):
+        return None
+    live = [f for f in forms if f.n]
+    if not live:
+        return GroupByPartial({})
+    keys: List[np.ndarray] = []
+    for c in range(len(live[0].keys)):
+        parts = [f.keys[c] for f in live]
+        if len({p.dtype.kind for p in parts}) != 1:
+            return None
+        keys.append(np.concatenate(parts))
+    codes = _key_codes(keys)
+    if codes is None:
+        return None
+    _u, first, inverse = np.unique(codes, return_index=True,
+                                   return_inverse=True)
+    order = np.argsort(first, kind="stable")       # first-seen order
+    rank = np.empty(len(order), dtype=np.int64)
+    rank[order] = np.arange(len(order))
+    gid = rank[inverse.ravel()]
+    first_rows = first[order]
+    later = np.ones(len(codes), dtype=bool)
+    later[first_rows] = False
+    bounds = np.cumsum([0] + [f.n for f in live])
+    spans = [(int(lo), int(hi)) for lo, hi in zip(bounds[:-1], bounds[1:])]
+    states: List[Any] = []
+    for a, kind in enumerate(live[0].kinds):
+        merged = []
+        for p in range(len(live[0].states[a])):
+            parts = [f.states[a][p] for f in live]
+            if len({x.dtype for x in parts}) != 1:
+                return None
+            vals = np.concatenate(parts)
+            acc = _merge_part(kind, vals, gid, first_rows, later, spans)
+            if acc is None:
+                return None
+            merged.append(acc)
+        states.append(tuple(merged))
+    return _build_groups([k[first_rows] for k in keys], states)
+
+
+def _key_codes(keys: List[np.ndarray]) -> Optional[np.ndarray]:
+    """One int64 code a row, equal where every key column is equal as
+    the merge's dict sees it: a column's dense codes (np.unique's
+    inverse; a dict's for objects) joined mixed-radix, made dense again
+    before the radix product could reach 2^62. None on a NaN key."""
+    code: Optional[np.ndarray] = None
+    space = 1
+    for col in keys:
+        if col.dtype.kind == "O":
+            index: Dict[Any, int] = {}
+            inv = np.fromiter((index.setdefault(v, len(index))
+                               for v in col.tolist()),
+                              dtype=np.int64, count=len(col))
+            card = len(index)
+        else:
+            if col.dtype.kind in "fc" and np.isnan(col).any():
+                return None
+            uniq, inv = np.unique(col, return_inverse=True)
+            inv, card = inv.ravel().astype(np.int64, copy=False), len(uniq)
+        if code is None:
+            code, space = inv, card
+            continue
+        if space * card >= 1 << 62:
+            uniq, code = np.unique(code, return_inverse=True)
+            code, space = code.ravel(), len(uniq)
+        code, space = code * card + inv, space * card
+    return code
+
+
+def _merge_part(kind: str, vals: np.ndarray, gid: np.ndarray,
+                first_rows: np.ndarray, later: np.ndarray,
+                spans: List[Tuple[int, int]]) -> Optional[np.ndarray]:
+    """One state part merged by group: ``merge_states`` of each
+    segment's value into its group's, segment by segment."""
+    acc = vals[first_rows]
+    if kind in ("min", "max"):
+        # min(a, b) is b where b < a, else a (max: b > a), NaN and
+        # signed zeros included; np.minimum would differ on both
+        wins = np.less if kind == "min" else np.greater
+        for lo, hi in spans:
+            m = later[lo:hi]
+            g, v = gid[lo:hi][m], vals[lo:hi][m]
+            cur = acc[g]
+            acc[g] = np.where(wins(v, cur), v, cur)
+        return acc
+    bound = -(-(1 << 62) // len(spans))   # |value| x segments < 2^62
+    if vals.dtype.kind == "i" and (vals.max() >= bound
+                                   or vals.min() <= -bound):
+        return None
+    for lo, hi in spans:
+        m = later[lo:hi]
+        np.add.at(acc, gid[lo:hi][m], vals[lo:hi][m])
+    return acc
+
+
+def place_group_partials(results: List[Any],
+                         stop: Optional[int] = None) -> None:
+    """Turn the GroupColumns among a statement's ``results`` (one entry
+    a plan, in plan order) into partials, in place. The kernel
+    group-by segments ahead of the first partial that stays on its own
+    with groups in it (a host-path segment, a spilled rerun, a fused or
+    pipelined one, or ``stop``: where the caller puts a rollup's) are
+    combined into ONE partial at the first of them, an empty one at
+    each other (counter ``segments_combined``): the broker's merge then
+    meets every group in the order and with the values it met them
+    segment by segment. Every other segment is built on its own."""
+    forms = [i for i, p in enumerate(results) if isinstance(p, GroupColumns)]
+    if not forms:
+        return
+    firsts = [i for i, p in enumerate(results)
+              if isinstance(p, GroupByPartial) and p.groups]
+    if stop is not None:
+        firsts.append(stop)
+    stop = min(firsts, default=len(results))
+    ahead = [i for i in forms if i < stop]
+    combined = combine_group_columns([results[i] for i in ahead]) \
+        if len(ahead) > 1 else None
+    if combined is not None:
+        global_metrics.count("segments_combined", len(ahead))
+        results[ahead[0]] = combined
+        for i in ahead[1:]:
+            results[i] = GroupByPartial({})
+    for i in forms:
+        if isinstance(results[i], GroupColumns):
+            results[i] = group_partial(results[i])
 
 
 def _scalar_state(b: AggBinding, out: Dict[str, np.ndarray], matched: int,
@@ -649,23 +885,10 @@ def _scalar_state(b: AggBinding, out: Dict[str, np.ndarray], matched: int,
 
 def _group_state(b: AggBinding, out: Dict[str, np.ndarray],
                  idxs: np.ndarray, seg: ImmutableSegment) -> List[Any]:
+    """The per-group states of an aggregation whose state is no number
+    (``_state_arrays`` takes the numeric ones)."""
     name = f"agg{b.index}_{_kind(b)}"
     k = _kind(b)
-    if k == "count":
-        # group COUNT is served by the kernel's shared count row
-        return [int(x) for x in out["group_count"][idxs]]
-    if k == "sum":
-        arr = out[name][idxs]
-        return [int(x) for x in arr] if b.integral else [float(x) for x in arr]
-    if k in ("min", "max"):
-        arr = out[name][idxs]
-        return [int(x) for x in arr] if b.integral else [float(x) for x in arr]
-    if k == "avg":
-        s = out[name + "_sum"][idxs]
-        c = out[name + "_cnt"][idxs]
-        if b.integral:
-            return [(int(s[i]), int(c[i])) for i in range(len(idxs))]
-        return [(float(s[i]), int(c[i])) for i in range(len(idxs))]
     if k == "distinct_count":
         present = out[name + "_present"][idxs]  # (n_groups, card)
         d = seg.dictionary(b.dict_col)

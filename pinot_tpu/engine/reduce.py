@@ -115,9 +115,10 @@ def _reduce_aggregation(ctx: QueryContext, partials: List[AggPartial]
     return ResultTable(labels, [row])
 
 
-def _reduce_group_by(ctx: QueryContext, partials: List[GroupByPartial]
-                     ) -> ResultTable:
-    aggs = ctx.aggregations
+def merge_groups(aggs: List[AggExpr], partials: List[GroupByPartial]
+                 ) -> Dict[Tuple, List[Any]]:
+    """The group-by partials merged in their order: a group's states are
+    its first partial's, each later one merged into them."""
     merged: Dict[Tuple, List[Any]] = {}
     for p in partials:
         for key, states in p.groups.items():
@@ -127,6 +128,12 @@ def _reduce_group_by(ctx: QueryContext, partials: List[GroupByPartial]
             else:
                 for i, a in enumerate(aggs):
                     cur[i] = merge_state(a, cur[i], states[i])
+    return merged
+
+
+def _reduce_group_by(ctx: QueryContext, partials: List[GroupByPartial]
+                     ) -> ResultTable:
+    merged = merge_groups(ctx.aggregations, partials)
 
     group_labels = [_expr_label(g) for g in ctx.group_by]
     rows: List[tuple] = []

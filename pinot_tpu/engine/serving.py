@@ -83,9 +83,14 @@ def plan_segments(ctx: QueryContext, segments: List[Any],
 def execute_planned(ex: TableExecution) -> List[Any]:
     """Run the batched device dispatch and interleave rollup partials back
     into input order."""
-    with phase(ph.EXECUTION, segments=len(ex.real_plans)):
-        executed = list(execute_plans_batched(ex.real_plans))
     precomputed = getattr(ex, "_precomputed", {})
+    # the real plan that the first rollup partial with groups precedes:
+    # no combine of group-by segments reaches over it
+    stop = next((sum(1 for p in ex.plans[:i] if p is not None)
+                 for i in sorted(precomputed)
+                 if getattr(precomputed[i], "groups", None)), None)
+    with phase(ph.EXECUTION, segments=len(ex.real_plans)):
+        executed = list(execute_plans_batched(ex.real_plans, stop))
     executed = iter(executed)
     ex.partials = [precomputed[i] if p is None else next(executed)
                    for i, p in enumerate(ex.plans)]

@@ -60,7 +60,11 @@ def test_streaming_submit(cluster):
     sql = "SELECT k, SUM(v), COUNT(*) FROM g GROUP BY k ORDER BY k LIMIT 5"
     header, partials = submit_stream(f"127.0.0.1:{server.grpc_port}", sql)
     assert header["segmentsQueried"] == N_SEGMENTS
-    assert len(partials) == N_SEGMENTS  # one streamed block per segment
+    # one streamed block per segment: the server combines the
+    # statement's group-by segments, so the first block holds every
+    # group and each other block is empty
+    assert len(partials) == N_SEGMENTS
+    assert [len(p.groups) for p in partials] == [2] + [0] * (N_SEGMENTS - 1)
     ctx = build_query_context(parse_sql(sql))
     result = reduce_partials(ctx, partials)
     exp = [(k, int(data["v"][data["k"] == k].sum()),

@@ -8,7 +8,10 @@ segment, nothing pinned or in flight after a kill.
 At this size the segmented kernel would take these statements in one
 launch; the tests steer them onto the route the chip takes at 2^23 rows a
 segment (ops/kernels.segmented_compact_fits refuses there) by patching
-that predicate, not through an option of the program."""
+that predicate, not through an option of the program. The route hands
+each segment's groups over in array form, and the statement combines
+them into one partial (executor.place_group_partials): the serial
+partials are held to it through the broker's merge (reduce.merge_groups)."""
 import numpy as np
 import pytest
 
@@ -16,6 +19,7 @@ from pinot_tpu.engine import executor as ex
 from pinot_tpu.engine.accounting import (QueryKilledError,
                                          global_accountant)
 from pinot_tpu.engine.batch import execute_plans_batched
+from pinot_tpu.engine.reduce import merge_groups
 from pinot_tpu.engine.tier import global_tier
 from pinot_tpu.ops import kernels as K
 from pinot_tpu.ops import plan_cache as pc
@@ -33,7 +37,8 @@ N_SEG = 8
 ROWS = 1 << 12
 COUNTERS = ("plan_launch_windowed", "plan_launch_solo",
             "compact_overflow_retries", "group_xfer_overflow_retries",
-            "kernel_dispatches_compact_per_segment")
+            "kernel_dispatches_compact_per_segment", "segments_combined",
+            "segments_extracted")
 
 
 @pytest.fixture(scope="module")
@@ -80,19 +85,28 @@ def _groups(partials):
     return [list(p.groups.items()) for p in partials]
 
 
+def _combined(plans, serial):
+    """The serial partials as the statement answers them: merged in
+    segment order at the first segment, an empty partial at each other."""
+    merged = merge_groups(plans[0].ctx.aggregations, serial)
+    return [ex.GroupByPartial(merged)] + \
+        [ex.GroupByPartial({})] * (len(serial) - 1)
+
+
 @pytest.mark.parametrize("qid", COMPACT)
 def test_window_equals_the_serial_route(segments, per_segment, qid):
     plans = _plans(segments, qid)
     assert {p.kernel_plan.strategy for p in plans} == {"compact"}
     serial = [ex.execute_plan(p) for p in plans]
     windowed, moved = _moved(lambda: execute_plans_batched(plans))
-    assert _groups(windowed) == _groups(serial)
+    assert _groups(windowed) == _groups(_combined(plans, serial))
     # (two cities of each side: a few rows of 2^15 match, or none)
     assert any(p.groups for p in serial) or qid in ("q3.3", "q3.4")
     assert moved == {"plan_launch_windowed": N_SEG, "plan_launch_solo": 0,
                      "compact_overflow_retries": 0,
                      "group_xfer_overflow_retries": 0,
-                     "kernel_dispatches_compact_per_segment": N_SEG}
+                     "kernel_dispatches_compact_per_segment": N_SEG,
+                     "segments_combined": N_SEG, "segments_extracted": 0}
     assert getattr(global_tier._pins, "uids", frozenset()) == frozenset()
 
 
@@ -106,11 +120,12 @@ def test_overflow_fault_retries_its_segment_alone(segments, per_segment):
         faults.clear()
         pc.global_plan_cache.clear()    # the fault marked the entry
     assert [k for _p, k, _n in plan.fired_summary()] == ["seg_5"]
-    assert _groups(windowed) == _groups(serial)
+    assert _groups(windowed) == _groups(_combined(plans, serial))
     assert moved == {"plan_launch_windowed": N_SEG, "plan_launch_solo": 1,
                      "compact_overflow_retries": 1,
                      "group_xfer_overflow_retries": 0,
-                     "kernel_dispatches_compact_per_segment": N_SEG + 1}
+                     "kernel_dispatches_compact_per_segment": N_SEG + 1,
+                     "segments_combined": N_SEG, "segments_extracted": 0}
 
 
 def test_compact_steps_count_the_launch_that_answered(segments, per_segment,
@@ -119,7 +134,8 @@ def test_compact_steps_count_the_launch_that_answered(segments, per_segment,
     overflowed launch's counts go with it, the retry's are counted, and
     no count reaches extract_partial. Each collection is numbered by
     its narrow count (1, 2, ...): seg_5's first is the sixth, its retry
-    the seventh."""
+    the seventh. (The route's extraction is the array stage,
+    group_columns.)"""
     plans = _plans(segments, "q3.1")
     real, seen, partial_keys = pc.PlanCacheEntry.collect, [], set()
 
@@ -129,13 +145,13 @@ def test_compact_steps_count_the_launch_that_answered(segments, per_segment,
         host["compact_steps_narrow"] = np.int32(len(seen))
         host["compact_steps_wide"] = np.int32(0)
         return host
-    real_extract = ex.extract_partial
+    real_extract = ex.group_columns
 
     def extract(plan, out):
         partial_keys.update(out)
         return real_extract(plan, out)
     monkeypatch.setattr(pc.PlanCacheEntry, "collect", staticmethod(numbered))
-    monkeypatch.setattr(ex, "extract_partial", extract)
+    monkeypatch.setattr(ex, "group_columns", extract)
     before = global_metrics.snapshot()["counters"]
     faults.install("seed=3; device.overflow: match=seg_5, times=1")
     try:
@@ -170,12 +186,13 @@ def test_group_overflow_retries_its_segment_alone(segments, per_segment,
     monkeypatch.setattr(pc.PlanCacheEntry, "collect",
                         staticmethod(spill_third))
     windowed, moved = _moved(lambda: execute_plans_batched(plans))
-    assert _groups(windowed) == _groups(serial)
+    assert _groups(windowed) == _groups(_combined(plans, serial))
     assert len(seen) == N_SEG + 1
     assert moved == {"plan_launch_windowed": N_SEG, "plan_launch_solo": 1,
                      "compact_overflow_retries": 0,
                      "group_xfer_overflow_retries": 1,
-                     "kernel_dispatches_compact_per_segment": N_SEG + 1}
+                     "kernel_dispatches_compact_per_segment": N_SEG + 1,
+                     "segments_combined": N_SEG, "segments_extracted": 0}
 
 
 def test_a_kill_between_collections_leaves_nothing_behind(
@@ -207,7 +224,7 @@ def test_a_kill_between_collections_leaves_nothing_behind(
     assert not any(f.entry.lock.locked() for f in done)
     monkeypatch.setattr(ex, "finish_kernel", real)
     again, moved = _moved(lambda: execute_plans_batched(plans))
-    assert _groups(again) == _groups(serial)
+    assert _groups(again) == _groups(_combined(plans, serial))
     assert moved["plan_launch_windowed"] == N_SEG
 
 

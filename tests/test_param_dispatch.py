@@ -6,15 +6,19 @@ stack cache (engine/batch._stacked_resident), and no eager ``jax``
 operation runs between planning and the kernel's launch — on each of
 the three routes of ``execute_plans_batched``: a dense vmapped group, a
 segmented compact group, a compact group sent down the per-segment
-route.
+route. A group-by's segments come back combined into one partial
+(engine/executor.place_group_partials): the segment-by-segment answers
+are held to it through the broker's merge (engine/reduce.merge_groups).
 """
 import jax
 import numpy as np
 import pytest
 
 from pinot_tpu.engine import batch as eb
-from pinot_tpu.engine.executor import (execute_plan, param_sig,
-                                       resolve_params, resolve_params_host)
+from pinot_tpu.engine.executor import (GroupByPartial, execute_plan,
+                                       param_sig, resolve_params,
+                                       resolve_params_host)
+from pinot_tpu.engine.reduce import merge_groups
 from pinot_tpu.ops import kernels as K
 from pinot_tpu.query.context import build_query_context
 from pinot_tpu.query.planner import SegmentPlanner
@@ -107,6 +111,23 @@ def counter(name):
     return global_metrics.snapshot()["counters"].get(name, 0)
 
 
+def ordered(partials):
+    """Partials with a group-by's groups as a list: in their order."""
+    return [list(p.groups.items()) if isinstance(p, GroupByPartial)
+            else p for p in partials]
+
+
+def answered(sql, solo):
+    """The segments' own partials as the statement answers them: a
+    group-by's merged at the first segment, an empty partial at each
+    other."""
+    ctx = build_query_context(parse_sql(sql))
+    if ctx.is_group_by:
+        solo = [GroupByPartial(merge_groups(ctx.aggregations, solo))] + \
+            [GroupByPartial({})] * (len(solo) - 1)
+    return ordered(solo)
+
+
 @pytest.fixture
 def route(request, monkeypatch):
     """One route's name; 'per_segment' refuses the segmented batch the
@@ -172,7 +193,8 @@ def test_answers_equal_execute_plan_segment_by_segment(segments, name):
     launches = counter("kernel_dispatches")
     # twice: the second pass answers from warm stacks
     for _ in range(2):
-        assert eb.execute_plans_batched(plans_for(segments, sql)) == solo
+        assert ordered(eb.execute_plans_batched(plans_for(segments, sql))) \
+            == answered(sql, solo)
     batched = (counter("kernel_dispatches") - launches) // 2
     # the MV statement makes one group a padded width, every other one
     assert batched == (2 if name == "mv_column" else 1)
@@ -181,7 +203,8 @@ def test_answers_equal_execute_plan_segment_by_segment(segments, name):
 def test_per_segment_route_answers_equal(segments, monkeypatch):
     solo = [execute_plan(p) for p in plans_for(segments, COMPACT)]
     monkeypatch.setattr(K, "SEGMENTED_SORT_ROW_LIMIT", 1)
-    assert eb.execute_plans_batched(plans_for(segments, COMPACT)) == solo
+    assert ordered(eb.execute_plans_batched(plans_for(segments, COMPACT))) \
+        == answered(COMPACT, solo)
 
 
 def test_one_literal_becomes_another_id_in_every_segment(segments):
