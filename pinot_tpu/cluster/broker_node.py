@@ -701,7 +701,7 @@ class BrokerNode:
             ctx_p = build_query_context(part_stmt)
             scatters.append(
                 self._scatter(to_sql(part_stmt), ctx_p, snap, deadline,
-                              qid, workload))
+                              qid, workload, whole=False))
         if scatters_out is not None:
             scatters_out.extend(scatters)
         with phase(ph.REDUCE,
@@ -799,8 +799,12 @@ class BrokerNode:
                  snap: Optional[Dict[str, Any]] = None,
                  deadline: Optional[float] = None,
                  qid: Optional[str] = None,
-                 workload: Optional[Dict[str, Any]] = None
-                 ) -> ScatterResult:
+                 workload: Optional[Dict[str, Any]] = None,
+                 whole: bool = True) -> ScatterResult:
+        """``whole``: this scatter's partials are the statement's only
+        ones (a hybrid table's two halves are not), so a call that
+        carries every routed segment asks its server for the trimmed
+        partial (engine/reduce.server_trim_rows)."""
         with phase(ph.BROKER_SELECT):
             # one snapshot for assignment + segment metadata: the refresh
             # thread swaps self._routing, and mixing two snapshots could
@@ -880,6 +884,11 @@ class BrokerNode:
             for seg, pick in picks.items():
                 if pick is not None:
                     by_server.setdefault(pick, []).append(seg)
+            # a server trims only where its partial is the only one: it
+            # was sent every routed segment (a failover's or a hedge's
+            # call may be, where one replica takes them all)
+            routed = sum(len(s) for s in by_server.values()) if whole \
+                else -1
 
         adaptive = getattr(self._selector, "record_start", None)
 
@@ -921,6 +930,8 @@ class BrokerNode:
                 from ..engine.datablock import decode_wire_frame
                 from ..utils.faults import corrupt_bytes
                 body = {"sql": sql, "segments": segs}
+                if len(segs) == routed:
+                    body["trim"] = True
                 if workload:
                     # tenant/tier attribution crosses the wire: the
                     # server registers its accountant entry with it

@@ -28,9 +28,21 @@ from ..query.sql import parse_sql
 from ..segment.immutable import ImmutableSegment
 from ..server.data_manager import TableDataManager
 from ..utils import phases as ph
+from ..utils.metrics import global_metrics
 from ..utils.spans import phase, queue_event, record_phase, set_query_id
 from .http_util import (JsonHandler, http_json, start_http,
                         trace_context_from)
+
+
+# groups the partials this node encodes carry, every route: what the
+# server-side trim saves the wire, the decode and the broker's merge
+global_metrics.count("partial_groups_sent", 0)
+
+
+def _count_groups_sent(partials: List[Any]) -> None:
+    from ..engine.executor import GroupByPartial
+    global_metrics.count("partial_groups_sent", sum(
+        len(p.groups) for p in partials if isinstance(p, GroupByPartial)))
 
 
 class ServerNode:
@@ -192,7 +204,12 @@ class ServerNode:
         # a failed segment load retries on every poll instead of being
         # silently skipped until an unrelated version bump
         for table, segs in a["tables"].items():
-            dm = self._tables.setdefault(table, TableDataManager(table))
+            dm = self._tables.get(table)
+            if dm is None:
+                # a mesh node answers from its mesh residency; the one
+                # holding segments per device merges their dictionaries
+                dm = self._tables[table] = TableDataManager(
+                    table, server_ids=self.mesh is None)
             have = {s.name for s in dm.acquire_segments()}
             for seg_name, location in segs.items():
                 if seg_name not in have:
@@ -266,14 +283,16 @@ class ServerNode:
                 priority: int = 0,
                 deadline_ms: Optional[float] = None,
                 trace_ctx: Optional[Dict[str, Any]] = None,
-                workload: Optional[Dict[str, Any]] = None
-                ) -> Dict[str, Any]:
+                workload: Optional[Dict[str, Any]] = None,
+                trim: bool = False) -> Dict[str, Any]:
         """Admit through the scheduler (QueryScheduler.submit analog) and
         account the query so the watcher can kill it under pressure.
         ``deadline_ms`` is the dispatching broker's REMAINING budget; the
         accountant deadline becomes min(own timeoutMs, broker remaining)
         so a server never works past the point the broker stops
-        listening. A sampled ``trace_ctx`` (http_util.
+        listening. ``trim``: the broker routed this node every segment
+        of the statement, so its group-by partial may be trimmed
+        (engine/reduce.server_trim_rows). A sampled ``trace_ctx`` (http_util.
         inject_trace_context wire shape) activates a remote-rooted span
         tree around the executor and ships it back in the response
         envelope for the broker to stitch."""
@@ -306,7 +325,7 @@ class ServerNode:
             set_query_id(broker_qid)
             if not sampled:
                 return self._execute(sql, segment_names, query_id,
-                                     deadline_ms, t_arrive)
+                                     deadline_ms, t_arrive, trim)
             from ..utils.spans import span_tracer
             root = span_tracer.start(
                 ph.SERVER_QUERY, server=self.instance_id,
@@ -314,7 +333,7 @@ class ServerNode:
                 parent_span_id=trace_ctx.get("parentSpanId"))
             try:
                 resp = self._execute(sql, segment_names, query_id,
-                                     deadline_ms, t_arrive)
+                                     deadline_ms, t_arrive, trim)
             finally:
                 root = span_tracer.stop() or root
             root.annotate(segments=resp.get("segmentsQueried", 0))
@@ -347,7 +366,8 @@ class ServerNode:
     def _execute(self, sql: str, segment_names: Optional[List[str]] = None,
                  query_id: Optional[str] = None,
                  deadline_ms: Optional[float] = None,
-                 t_arrive: Optional[float] = None) -> Dict[str, Any]:
+                 t_arrive: Optional[float] = None,
+                 trim: bool = False) -> Dict[str, Any]:
         with phase(ph.SERVER_PARSE):
             t0 = time.perf_counter()
             stmt = parse_sql(sql)
@@ -405,7 +425,7 @@ class ServerNode:
             return {"explain": {"columns": cols,
                                 "rows": [list(r) for r in rows]},
                     "segmentsQueried": len(segments)}
-        ex = execute_segments(ctx, segments)
+        ex = execute_segments(ctx, segments, trim=trim)
         return {"partials_raw": ex.partials,
                 "segmentsQueried": len(segments)}
 
@@ -413,13 +433,15 @@ class ServerNode:
                      segment_names: Optional[List[str]] = None,
                      deadline_ms: Optional[float] = None,
                      trace_ctx: Optional[Dict[str, Any]] = None,
-                     workload: Optional[Dict[str, Any]] = None
-                     ) -> Dict[str, Any]:
+                     workload: Optional[Dict[str, Any]] = None,
+                     trim: bool = False) -> Dict[str, Any]:
         """Legacy/debuggable JSON wire (also serves EXPLAIN)."""
         resp = self.execute(sql, segment_names, deadline_ms=deadline_ms,
-                            trace_ctx=trace_ctx, workload=workload)
+                            trace_ctx=trace_ctx, workload=workload,
+                            trim=trim)
         raw = resp.pop("partials_raw", None)
         if raw is not None:
+            _count_groups_sent(raw)
             resp["partials"] = [partial_to_wire(p) for p in raw]
         return resp
 
@@ -427,7 +449,8 @@ class ServerNode:
                     segment_names: Optional[List[str]] = None,
                     deadline_ms: Optional[float] = None,
                     trace_ctx: Optional[Dict[str, Any]] = None,
-                    workload: Optional[Dict[str, Any]] = None) -> bytes:
+                    workload: Optional[Dict[str, Any]] = None,
+                    trim: bool = False) -> bytes:
         """Binary data plane: columnar DataBlock partials in one frame.
         The span tree (when sampled) rides the JSON frame header, along
         with ``serdeEncodeMs`` — the partial-encode time this side of
@@ -439,9 +462,11 @@ class ServerNode:
                                         encode_wire_frame_blocks)
         t_arrive = time.perf_counter()
         resp = self.execute(sql, segment_names, deadline_ms=deadline_ms,
-                            trace_ctx=trace_ctx, workload=workload)
+                            trace_ctx=trace_ctx, workload=workload,
+                            trim=trim)
         raw = resp.pop("partials_raw", [])
         with phase(ph.SERVER_ENCODE, partials=len(raw)) as enc:
+            _count_groups_sent(raw)
             blocks = [encode_partial(p) for p in raw]
         resp["serdeEncodeMs"] = round(enc.ms, 3)
         # arrival to frame encoded: the broker's call time less this is
@@ -514,12 +539,14 @@ class ServerNode:
                     200, node.execute_bin(b["sql"], b.get("segments"),
                                           b.get("deadlineMs"),
                                           b.get("traceContext"),
-                                          b.get("workload"))),
+                                          b.get("workload"),
+                                          bool(b.get("trim")))),
                 ("POST", "/query"): lambda h, b: (
                     200, node.execute_json(b["sql"], b.get("segments"),
                                            b.get("deadlineMs"),
                                            b.get("traceContext"),
-                                           b.get("workload"))),
+                                           b.get("workload"),
+                                           bool(b.get("trim")))),
                 # multi-stage data plane (mailbox.proto analog) + stage
                 # dispatch (worker.proto Submit analog; the trace
                 # context rides an HTTP header because the StagePlan

@@ -29,10 +29,10 @@ from ..utils.devmem import global_device_memory
 from ..utils.metrics import global_metrics
 from ..utils.spans import (annotate, count_dispatch, device_fence, phase,
                            span)
-from .executor import (GroupColumns, count_compact_steps,
+from .executor import (GroupByPartial, GroupColumns, count_compact_steps,
                        execute_kernel_plans, execute_plan, extract,
                        param_sig, place_group_partials, resident_param,
-                       resolve_params_host, stack_params)
+                       resolve_params_host, run_topgroups, stack_params)
 
 # stack cache: ((segment uid, name) pairs, what, bucket) -> (stamp, tuple
 # of stacked device arrays), where `what` is a plan's column names
@@ -190,7 +190,8 @@ def clear_stack_cache() -> None:
 
 
 def execute_plans_batched(plans: List[CompiledPlan],
-                          stop: Optional[int] = None) -> List[Any]:
+                          stop: Optional[int] = None,
+                          trim: bool = False) -> List[Any]:
     """Execute all plans; kernel plans with matching structure run in one
     vmapped dispatch, the ones that run one program a segment in one
     launch window (executor.execute_kernel_plans). Returns partials in
@@ -198,8 +199,18 @@ def execute_plans_batched(plans: List[CompiledPlan],
     one at the first of them and an empty one at each other
     (executor.place_group_partials; ``stop`` is the index of the first
     plan that a partial the caller puts among them, a rollup's,
-    precedes)."""
+    precedes). The top-groups segments of a statement run as one launch
+    (``execute_topgroups``); ``trim`` says the caller's partials are the
+    statement's only ones, so that launch may keep only its top
+    groups."""
     results: List[Any] = [None] * len(plans)
+    tops = [i for i, p in enumerate(plans) if p.kind == "topgroups"]
+    if tops:
+        trim = trim and stop is None and len(tops) + sum(
+            1 for p in plans if p.kind == "pruned") == len(plans)
+        execute_topgroups(plans, tops, trim, results)
+        if len(tops) == len(plans):
+            return results
     groups: Dict[Tuple, List[int]] = {}
     # plan indexes of the statement's per-segment route
     per_segment: List[int] = []
@@ -219,6 +230,8 @@ def execute_plans_batched(plans: List[CompiledPlan],
         # preemption point between per-segment launches (the hot-loop
         # ThreadAccountantOps.sample analog): raises on kill/timeout
         global_accountant.sample()
+        if plan.kind == "topgroups":
+            continue
         if plan.kind != "kernel":
             results[i] = execute_plan(plan)
         else:
@@ -358,6 +371,26 @@ def execute_plans_batched(plans: List[CompiledPlan],
                 results[i] = extract(plans[i], out, columns)
             place_group_partials(results, stop)
     return results
+
+
+def execute_topgroups(plans: List[CompiledPlan], tops: List[int],
+                      trim: bool, results: List[Any]) -> None:
+    """The top-groups plans among ``plans`` (indexes ``tops``), one launch
+    an id space (executor.run_topgroups; a segment added or removed
+    while the statement planned leaves two): its partial at the first
+    of its plans, an empty one at each other. With ``trim``, and one
+    launch, the partial keeps the ORDER BY's first
+    reduce.server_trim_rows groups, else every group."""
+    from .reduce import server_trim_rows
+    by_space: Dict[int, List[int]] = {}
+    for i in tops:
+        by_space.setdefault(id(plans[i].topgroups.space), []).append(i)
+    top = server_trim_rows(plans[tops[0]].ctx) \
+        if trim and len(by_space) == 1 else None
+    for idxs in by_space.values():
+        results[idxs[0]] = run_topgroups([plans[i] for i in idxs], top)
+        for i in idxs[1:]:
+            results[i] = GroupByPartial({})
 
 
 def _run_segmented_compact(plans, idxs, plan_struct, bucket, cols, n_docs,

@@ -35,6 +35,10 @@ global_metrics.count("segments_host", 0)
 # partial: declared at 0 as segments_host is
 global_metrics.count("segments_combined", 0)
 global_metrics.count("segments_extracted", 0)
+# the top-groups route (run_topgroups): statements it answered, and the
+# groups its launches found before the trim; declared at 0 as above
+global_metrics.count("topgroups_statements", 0)
+global_metrics.count("topgroups_live_groups", 0)
 
 
 @dataclass
@@ -135,6 +139,8 @@ def execute_plan(plan: CompiledPlan, xfer_compact: bool = True,
                 return AggPartial(host_eval.host_aggregate(ctx, seg, mask))
             labels, rows, okeys = host_eval.host_selection(ctx, seg, mask)
             return SelectionPartial(labels, rows, okeys)
+    if plan.kind == "topgroups":
+        return run_topgroups([plan], None)
     if plan.kind == "kselect":
         out = run_select_kernel(plan)
         with phase(ph.EXTRACT_PARTIAL, segment=seg.name):
@@ -163,6 +169,54 @@ def run_select_kernel(plan: CompiledPlan) -> Dict[str, np.ndarray]:
         from .accounting import global_accountant
         global_accountant.track_result(host)
         return host
+
+
+def run_topgroups(plans: List[CompiledPlan], top: Optional[int]
+                  ) -> GroupByPartial:
+    """ONE top-groups launch over a statement's segments of one server
+    (ops/kernels.build_topgroups_kernel) and its partial: the ``top``
+    groups first by the ORDER BY's count, or with ``top`` None every
+    group, each key decoded through the server's dictionaries. Every
+    plan shares one id space (TopGroupsPlan.space)."""
+    from ..ops.kernels import jitted_topgroups, unpack_keys
+    from ..utils.spans import device_fence
+    from .tier import global_tier
+    tgs = [p.topgroups for p in plans]
+    segs = [p.segment for p in plans]
+    columns = [tgs[0].space.column(k) for k in plans[0].group_cols]
+    with span("topgroups_dispatch", segments=len(plans), top=top), \
+            global_tier.pinned({s.uid for s in segs}):
+        with phase(ph.DISPATCH_PREPARE, segments=len(plans)):
+            cols = tuple(s.device_cols(p.col_names)
+                         for s, p in zip(segs, plans))
+            params = tuple(resolve_params(p) for p in plans)
+            remaps = tuple(tuple(c.device_remap(s) for c in columns)
+                           for s in segs)
+            n_docs = np.asarray(  # jaxlint: ok host-sync — host ints
+                [s.n_docs for s in segs], dtype=np.int32)
+        fn = jitted_topgroups(tuple(t.pred for t in tgs),
+                              tuple(t.key_cols for t in tgs),
+                              tuple(s.bucket for s in segs), tgs[0].widths,
+                              top, tgs[0].descending)
+        count_dispatch(ph.TOPGROUPS)
+        with phase(ph.DEVICE_EXECUTE):
+            dev = fn(cols, n_docs, params, remaps)
+            device_fence(dev)
+        with phase(ph.DEVICE_TRANSFER):
+            out = jax.device_get(dev)  # jaxlint: ok host-sync
+    # host numpy from here on — host-sync [jaxlint baseline]
+    live = int(out["live"])  # jaxlint: ok host-sync — host numpy
+    global_metrics.count("topgroups_statements")
+    global_metrics.count("topgroups_live_groups", live)
+    annotate(live_groups=live)
+    with phase(ph.EXTRACT_PARTIAL, segments=len(plans)):
+        sel = np.nonzero(out["count"] > 0)[0]
+        hi = out["hi"][sel] if "hi" in out else np.zeros(len(sel), np.int64)
+        ids = unpack_keys(hi, out["lo"][sel], tgs[0].widths)
+        keys = zip(*[c.decode(i) for c, i in zip(columns, ids)])
+        n_aggs = len(plans[0].ctx.aggregations)
+        return GroupByPartial({k: [c] * n_aggs for k, c in zip(
+            keys, out["count"][sel].tolist())})
 
 
 def extract_select(plan: CompiledPlan, out: Dict[str, np.ndarray]

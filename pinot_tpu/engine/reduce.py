@@ -23,6 +23,18 @@ from ..query.sql import (Between, BinaryOp, BoolAnd, BoolNot, BoolOr,
 from .executor import AggPartial, GroupByPartial, SelectionPartial
 
 DEFAULT_LIMIT = 10  # Pinot's default LIMIT for selection/group-by results
+# Pinot's server-side trim of a group-by ordered by an aggregate
+# (minServerGroupTrimSize): a server may send only its first
+# max(5 x (OFFSET + LIMIT), 5000) groups by the ORDER BY, and that is
+# exact only where its partial is the statement's only one, so the
+# broker asks for it only where it routes every segment to one server
+SERVER_TRIM_MIN = 5000
+
+
+def server_trim_rows(ctx: QueryContext) -> int:
+    """Groups a trimmed server partial of ``ctx`` carries."""
+    limit = ctx.limit if ctx.limit is not None else DEFAULT_LIMIT
+    return max(5 * (ctx.offset + limit), SERVER_TRIM_MIN)
 
 
 @dataclass

@@ -32,7 +32,7 @@ class TableExecution:
     @property
     def docs_scanned(self) -> int:
         return sum(p.segment.n_docs for p in self.real_plans
-                   if p.kind in ("kernel", "host"))
+                   if p.kind in ("kernel", "host", "topgroups"))
 
 
 def plan_segments(ctx: QueryContext, segments: List[Any],
@@ -65,7 +65,7 @@ def plan_segments(ctx: QueryContext, segments: List[Any],
         # segments by and the future HBM tier admits on
         from ..utils.heat import global_segment_heat
         for p in ex.real_plans:
-            if p.kind in ("kernel", "host"):
+            if p.kind in ("kernel", "host", "topgroups"):
                 global_segment_heat.touch(p.segment, ctx.table,
                                           p.segment.n_docs)
         if ex.real_plans:
@@ -80,9 +80,10 @@ def plan_segments(ctx: QueryContext, segments: List[Any],
     return ex
 
 
-def execute_planned(ex: TableExecution) -> List[Any]:
+def execute_planned(ex: TableExecution, trim: bool = False) -> List[Any]:
     """Run the batched device dispatch and interleave rollup partials back
-    into input order."""
+    into input order. ``trim``: these partials are the statement's only
+    ones (engine/batch.execute_plans_batched)."""
     precomputed = getattr(ex, "_precomputed", {})
     # the real plan that the first rollup partial with groups precedes:
     # no combine of group-by segments reaches over it
@@ -90,7 +91,8 @@ def execute_planned(ex: TableExecution) -> List[Any]:
                  for i in sorted(precomputed)
                  if getattr(precomputed[i], "groups", None)), None)
     with phase(ph.EXECUTION, segments=len(ex.real_plans)):
-        executed = list(execute_plans_batched(ex.real_plans, stop))
+        executed = list(execute_plans_batched(
+            ex.real_plans, stop, trim and not precomputed))
     executed = iter(executed)
     ex.partials = [precomputed[i] if p is None else next(executed)
                    for i, p in enumerate(ex.plans)]
@@ -98,9 +100,10 @@ def execute_planned(ex: TableExecution) -> List[Any]:
 
 
 def execute_segments(ctx: QueryContext, segments: List[Any],
-                     use_rollups: bool = True) -> TableExecution:
+                     use_rollups: bool = True,
+                     trim: bool = False) -> TableExecution:
     ex = plan_segments(ctx, segments, use_rollups)
-    execute_planned(ex)
+    execute_planned(ex, trim)
     return ex
 
 

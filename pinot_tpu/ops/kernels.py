@@ -2360,3 +2360,179 @@ def jitted_kernel(plan: KernelPlan, bucket: int,
 
 jitted_kernel.cache_info = _jitted_kernel_cached.cache_info
 jitted_kernel.cache_clear = _jitted_kernel_cached.cache_clear
+
+
+# ---------------------------------------------------------------------------
+# top-groups: a group-by over a server's id space, trimmed to its top groups
+# ---------------------------------------------------------------------------
+
+# bits of an int32 lane a packed key may use; a key of up to 30 bits is
+# one sort key, up to TOPGROUPS_KEY_BITS two (hi, lo), and the sentinel
+# (INT32_MAX in every lane) sorts after every real key in either form
+_LANE_BITS = 31
+TOPGROUPS_KEY_BITS = 2 * _LANE_BITS - 1
+_TG_SENTINEL = (1 << 31) - 1
+# rows a block of the run-length scan: XLA:TPU's monolithic
+# reduce-window lowering of a cumulative op over 2^24 or more rows runs
+# out of scoped VMEM (chunked_cumsum says the same of cumsum)
+_TG_RUN_CHUNK = 1 << 13
+
+
+def topgroups_lanes(widths: Tuple[int, ...]) -> int:
+    """Int32 sort lanes of a key packed from ids of ``widths`` bits."""
+    return 1 if sum(widths) < _LANE_BITS else 2
+
+
+def _key_offsets(widths: Tuple[int, ...]) -> List[int]:
+    """Bit offset of each key in the packed code, the first key most
+    significant."""
+    return [sum(widths[i + 1:]) for i in range(len(widths))]
+
+
+def _pack_keys(sids: List[jax.Array], widths: Tuple[int, ...]
+               ) -> Tuple[jax.Array, jax.Array]:
+    """(hi, lo) int32 lanes of the packed code: bits 0-30 in lo, the
+    rest in hi; a key may straddle the two."""
+    mask31 = jnp.uint32((1 << _LANE_BITS) - 1)
+    hi = jnp.zeros(sids[0].shape, jnp.uint32)
+    lo = jnp.zeros(sids[0].shape, jnp.uint32)
+    for k, w, off in zip(sids, widths, _key_offsets(widths)):
+        k = k.astype(jnp.uint32)
+        if off + w <= _LANE_BITS:
+            lo = lo | (k << off)
+        elif off >= _LANE_BITS:
+            hi = hi | (k << (off - _LANE_BITS))
+        else:
+            lo = lo | ((k << off) & mask31)
+            hi = hi | (k >> (_LANE_BITS - off))
+    return hi.astype(jnp.int32), lo.astype(jnp.int32)
+
+
+def unpack_keys(hi: np.ndarray, lo: np.ndarray, widths: Tuple[int, ...]
+                ) -> List[np.ndarray]:
+    """The host's inverse of ``_pack_keys``: one int64 id array a key."""
+    # host numpy: the launch's outputs, device_got behind its fence
+    hi = np.asarray(hi, np.int64)  # jaxlint: ok host-sync
+    code = (hi << _LANE_BITS) | np.asarray(  # jaxlint: ok host-sync
+        lo, np.int64)
+    return [(code >> off) & ((1 << w) - 1)
+            for w, off in zip(widths, _key_offsets(widths))]
+
+
+def _cummin_reverse(x: jax.Array) -> jax.Array:
+    """out[i] = min(x[i:]), in blocks of _TG_RUN_CHUNK rows where the
+    length allows (the caller pads it to a multiple)."""
+    n = x.shape[0]
+    if n <= _TG_RUN_CHUNK or n % _TG_RUN_CHUNK:
+        return jax.lax.cummin(x, reverse=True)
+    x2 = x.reshape(n // _TG_RUN_CHUNK, _TG_RUN_CHUNK)
+    within = jax.lax.cummin(x2, axis=1, reverse=True)
+    after = jax.lax.cummin(within[:, 0], reverse=True)
+    carry = jnp.concatenate([after[1:], jnp.full((1,), n, x.dtype)])
+    return jnp.minimum(within, carry[:, None]).reshape(n)
+
+
+def _topgroups_rows(buckets: Tuple[int, ...]) -> int:
+    """Rows the program sorts: the segments' buckets end to end, padded
+    to a whole number of run-scan blocks."""
+    total = sum(buckets)
+    if total <= _TG_RUN_CHUNK:
+        return total
+    return -(-total // _TG_RUN_CHUNK) * _TG_RUN_CHUNK
+
+
+def build_topgroups_kernel(preds: Tuple[Pred, ...],
+                           key_cols: Tuple[Tuple[int, ...], ...],
+                           buckets: Tuple[int, ...],
+                           widths: Tuple[int, ...],
+                           top: Optional[int], descending: bool = True):
+    """COUNT(*) by a tuple of dictionary keys over S segments of one
+    server, as ONE program, keyed by value: each segment's rows pass its
+    own predicate (``preds[s]`` over its own columns and params), their
+    key ids are gathered through the segment's remaps into the server's
+    id space and packed into one or two int32 lanes (``widths``: bits a
+    key), and all S segments' rows are sorted together. A run of equal
+    keys is a group; its count is the distance to the next run's start.
+    With ``top`` the groups come back ordered by count (``descending``)
+    through ``lax.top_k``, ``top`` of them: Pinot's server-side trim,
+    exact only where no other partial of the statement exists. Without
+    it every row position comes back and the host keeps the live runs.
+
+    fn(cols, n_docs, params, remaps): cols[s] and params[s] are segment
+    s's kernel columns and params, n_docs (S,) int32, remaps[s][k] the
+    (local cardinality,) int32 remap of key k. Outputs: ``count``, ``lo``
+    (and ``hi`` on two lanes), of ``top`` rows or of every row, and
+    ``live``, the number of groups before the trim."""
+    lanes = topgroups_lanes(widths)
+    rows = _topgroups_rows(buckets)
+    sent = jnp.int32(_TG_SENTINEL)
+
+    def kernel(cols, n_docs, params, remaps):
+        with jax.named_scope(ph.SCOPE_TOPGROUPS):
+            his, los = [], []
+            for s, bucket in enumerate(buckets):
+                with jax.named_scope(ph.SCOPE_MASK):
+                    valid = jnp.arange(bucket, dtype=jnp.int32) < n_docs[s]
+                    mask = valid & _eval_pred(preds[s], cols[s], params[s],
+                                              bucket)
+                with jax.named_scope(ph.SCOPE_TOPGROUPS_REMAP):
+                    sids = [jnp.take(remaps[s][k],
+                                     cols[s][c].astype(jnp.int32),
+                                     mode="clip")
+                            for k, c in enumerate(key_cols[s])]
+                    hi, lo = _pack_keys(sids, widths)
+                his.append(jnp.where(mask, hi, sent))
+                los.append(jnp.where(mask, lo, sent))
+            pad = rows - sum(buckets)
+            if pad:
+                his.append(jnp.full((pad,), sent))
+                los.append(jnp.full((pad,), sent))
+            lo = jnp.concatenate(los)
+            with jax.named_scope(ph.SCOPE_TOPGROUPS_SORT):
+                if lanes == 1:
+                    lo = jax.lax.sort(lo)
+                    hi = None
+                    differs = lo[1:] != lo[:-1]
+                    live = lo != sent
+                else:
+                    hi, lo = jax.lax.sort((jnp.concatenate(his), lo),
+                                          num_keys=2)
+                    differs = (hi[1:] != hi[:-1]) | (lo[1:] != lo[:-1])
+                    live = hi != sent
+                start = jnp.concatenate([jnp.ones((1,), jnp.bool_), differs])
+                pos = jnp.arange(rows, dtype=jnp.int32)
+                nxt = _cummin_reverse(jnp.where(start, pos, rows))
+                nxt = jnp.concatenate([nxt[1:], jnp.full((1,), rows,
+                                                         jnp.int32)])
+                first = start & live
+                count = jnp.where(first, nxt - pos, 0)
+            out = {"live": jnp.sum(first, dtype=jnp.int32)}
+            if top is None:
+                out.update(count=count, lo=lo)
+                if hi is not None:
+                    out["hi"] = hi
+                return out
+            with jax.named_scope(ph.SCOPE_TOPGROUPS_TOPK):
+                score = count if descending else jnp.where(
+                    first, -count, -(rows + 1))
+                _, idx = jax.lax.top_k(score, min(top, rows))
+                out.update(count=count[idx], lo=lo[idx])
+                if hi is not None:
+                    out["hi"] = hi[idx]
+        return out
+
+    return kernel
+
+
+@functools.lru_cache(maxsize=64)
+def jitted_topgroups(preds: Tuple[Pred, ...],
+                     key_cols: Tuple[Tuple[int, ...], ...],
+                     buckets: Tuple[int, ...], widths: Tuple[int, ...],
+                     top: Optional[int], descending: bool):
+    """One compiled program per (segments' predicates and key columns,
+    buckets, key widths, trim, direction)."""
+    from ..utils.compileplane import kernel_jit, staged
+    key = ("topgroups", preds, key_cols, buckets, widths, top, descending)
+    return staged(kernel_jit(build_topgroups_kernel(
+        preds, key_cols, buckets, widths, top, descending), ph.TOPGROUPS),
+        "topgroups_kernel", key)

@@ -92,6 +92,16 @@ def explain_rows(ctx, plans: List[CompiledPlan], rollup_count: int = 0
     if kinds.get("host"):
         emit(f"HOST_VECTORIZED(segments:{kinds['host']})", combine)
 
+    top = [p for p in plans if p.kind == "topgroups"]
+    if top:
+        tg = top[0].topgroups
+        node = emit(f"TPU_TOPGROUPS(segments:{len(top)},"
+                    f"key_bits:{sum(tg.widths)})", combine)
+        emit(f"FILTER_MASK:{_pred(tg.pred, top[0].col_names)}", node)
+        emit(f"GROUP_BY_SERVER_IDS(keys:[{','.join(top[0].group_cols)}])",
+             node)
+        emit("AGGREGATE:COUNT", node)
+
     kernel_plans = [p for p in plans if p.kind == "kernel"]
     if kernel_plans:
         p = kernel_plans[0]

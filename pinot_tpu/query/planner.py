@@ -63,9 +63,25 @@ def _truthy(v: Any) -> bool:
 # plan kinds
 # ---------------------------------------------------------------------------
 
+@dataclass(frozen=True)
+class TopGroupsPlan:
+    """One segment's part of a top-groups statement
+    (ops/kernels.build_topgroups_kernel): its predicate over its own
+    columns, the column index of each group key, and what the statement
+    shares with the server's other segments: each key's width in bits
+    in the server's id space and the direction of its ORDER BY."""
+    pred: Pred
+    key_cols: Tuple[int, ...]
+    widths: Tuple[int, ...]
+    descending: bool
+    # the id space the keys are gathered into (server/id_space.py);
+    # not part of the program's identity
+    space: Any = field(default=None, compare=False, repr=False)
+
+
 @dataclass
 class CompiledPlan:
-    kind: str  # 'pruned' | 'fast' | 'kernel' | 'host'
+    kind: str  # 'pruned' | 'fast' | 'kernel' | 'host' | 'kselect' | 'topgroups'
     segment: ImmutableSegment
     ctx: QueryContext
     # kernel path
@@ -91,6 +107,8 @@ class CompiledPlan:
     est_selectivity: Optional[float] = None
     slots_cap: Optional[int] = None
     strategy_trace: Optional[dict] = None
+    # top-groups route (TopGroupsPlan)
+    topgroups: Optional[TopGroupsPlan] = None
     # round-12 feedback loop: the plan cache's measured selectivity
     # drifted past the threshold and slots_cap was re-quantized from the
     # measurement — the executor brackets the resulting kernel compile
@@ -1255,6 +1273,44 @@ class SegmentPlanner:
         for call in calls:
             validate_call(self.seg, call)
 
+    def _plan_topgroups(self, pred: Pred, keys: List[str]
+                        ) -> Optional[CompiledPlan]:
+        """The top-groups route, where the statement is COUNT(*) by
+        dictionary keys, ordered by that count alone, with a LIMIT and no
+        HAVING, on a segment a server holds, and its key space in the
+        server's id space is over COMPACT_GROUP_LIMIT (a dense or
+        compact id space could not hold it) yet packs into two int32
+        lanes; None elsewhere, so every other statement keeps its
+        route."""
+        from ..ops.kernels import COMPACT_GROUP_LIMIT, TOPGROUPS_KEY_BITS
+        ctx, seg = self.ctx, self.seg
+        space = getattr(seg, "server_ids", None)
+        if space is None or self.null_aware \
+                or len(keys) != len(ctx.group_by) \
+                or ctx.having is not None or ctx.gapfill is not None \
+                or ctx.limit is None or len(ctx.order_by) != 1 \
+                or ctx.options.get("groupByStrategy") \
+                or not all(a.kind == "count" and a.arg is None
+                           for a in ctx.aggregations) \
+                or _expr_label_of(ctx.order_by[0].expr) != \
+                ctx.aggregations[0].label:
+            return None
+        columns = [space.column(k) for k in keys]
+        if any(c is None for c in columns):
+            return None
+        product, widths = 1, []
+        for c in columns:
+            product *= max(c.cardinality, 1)
+            widths.append(max(1, (c.cardinality - 1).bit_length()))
+        if product <= COMPACT_GROUP_LIMIT or sum(widths) > TOPGROUPS_KEY_BITS:
+            return None
+        key_cols = tuple(self.b.bind_col(k) for k in keys)
+        return CompiledPlan(
+            "topgroups", seg, ctx, col_names=list(self.b.cols),
+            params=list(self.b.params), group_cols=list(keys),
+            topgroups=TopGroupsPlan(pred, key_cols, tuple(widths),
+                                    not ctx.order_by[0].ascending, space))
+
     # -- top-level ---------------------------------------------------------
     def plan(self) -> CompiledPlan:
         """Plan this segment, recording the outcome (plan kind, strategy,
@@ -1359,6 +1415,11 @@ class SegmentPlanner:
                 lo, stride, card = rng
                 gspecs.append(("expr", g, lo, stride, card))
                 space *= max(card, 1)
+            if dense_ok:
+                top = self._plan_topgroups(pred, [s[1] for s in gspecs
+                                                  if s[0] == "col"])
+                if top is not None:
+                    return top
             from ..ops.kernels import COMPACT_GROUP_LIMIT
             space_cap = max(MAX_DENSE_GROUPS, COMPACT_GROUP_LIMIT)
             if not dense_ok or space > space_cap:

@@ -112,6 +112,9 @@ class ImmutableSegment:
         # device-resident copy invalidates on update
         self.valid_docs: Optional[np.ndarray] = None
         self.valid_docs_version = 0
+        # the id space of the server that holds this segment among
+        # others (server/id_space.ServerIdSpace; None outside a server)
+        self.server_ids = None
         if segdir.exists(seg_dir, "valid.bin"):
             bits = np.asarray(segdir.read_array(seg_dir, "valid.bin",
                                                 np.uint8, mmap=False))

@@ -16,8 +16,16 @@ from ..segment.immutable import ImmutableSegment
 
 
 class TableDataManager:
-    def __init__(self, table_name: str, table_config=None):
+    def __init__(self, table_name: str, table_config=None,
+                 server_ids: bool = False):
+        """``server_ids``: keep a ServerIdSpace over the loaded immutable
+        segments (server/id_space.py), made anew on every add and
+        remove and set on each of them as ``segment.server_ids``: the
+        planner's top-groups route reads it (a server node holding its
+        segments per device sets it; a mesh one and the in-process
+        broker do not)."""
         self.table_name = table_name
+        self._server_ids = server_ids
         self.table_config = table_config  # TableConfig | None
         self._segments: Dict[str, ImmutableSegment] = {}
         self._lock = threading.Lock()
@@ -35,6 +43,18 @@ class TableDataManager:
     def add_segment(self, segment: ImmutableSegment) -> None:
         with self._lock:
             self._segments = {**self._segments, segment.name: segment}
+            self._respace()
+
+    def _respace(self) -> None:
+        """A new id space over the segments now held (under _lock)."""
+        if not self._server_ids:
+            return
+        from .id_space import ServerIdSpace
+        held = [s for s in self._segments.values()
+                if not getattr(s, "is_mutable", False)]
+        space = ServerIdSpace(held)
+        for s in held:
+            s.server_ids = space
 
     def add_segment_dir(self, seg_dir: str) -> ImmutableSegment:
         seg = ImmutableSegment.load(seg_dir)
@@ -56,6 +76,7 @@ class TableDataManager:
             segs = dict(self._segments)
             seg = segs.pop(name, None)
             self._segments = segs
+            self._respace()
         if seg is not None and hasattr(seg, "evict_device"):
             # release the device residency NOW (padded columns + stacks
             # + cubes) instead of waiting for GC/LRU: a dropped segment

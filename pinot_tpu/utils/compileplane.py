@@ -613,6 +613,7 @@ def clear_staged_caches() -> None:
     kernels.jitted_select_kernel.cache_clear()
     kernels.jitted_segmented_compact.cache_clear()
     kernels.jitted_kernel.cache_clear()
+    kernels.jitted_topgroups.cache_clear()
     try:
         from ..index import vector
 

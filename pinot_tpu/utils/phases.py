@@ -95,6 +95,11 @@ DEVICE_EXECUTE = "device_execute"    # the dispatch call (fenced if sampled)
 DEVICE_TRANSFER = "device_transfer"  # jax.device_get: wait + copy back
 EXTRACT_PARTIAL = "extract_partial"  # host numpy -> mergeable partials
 SERVER_ENCODE = "server_encode"      # partials -> DataBlock frame
+# a server's id space of a dictionary column over its segments: the
+# merged dictionary and each segment's remap (server/id_space.py), built
+# by the first statement that needs it after a segment is added or
+# removed, not by every request
+SERVER_IDS_BUILD = "server_ids_build"
 
 # names that may appear in the flat trace envelope
 TRACED_PHASES = frozenset(
@@ -117,7 +122,7 @@ METERED_PHASES = TRACED_PHASES | frozenset(
      SCATTER_CALL, WIRE_DECODE, BROKER_RESPOND, SERVER_HTTP, SERVER_QUEUE,
      SERVER_PARSE, PARAMS_HOST, DISPATCH_PREPARE, DEVICE_EXECUTE,
      DEVICE_TRANSFER, EXTRACT_PARTIAL, SERVER_ENCODE, RAGGED_WAIT,
-     FUSED_EXECUTE})
+     FUSED_EXECUTE, SERVER_IDS_BUILD})
 
 # the metered leaves that are pure host work, with no device wait inside
 # them: what one of these spends off its thread's CPU (``phase_us_<p>``
@@ -154,10 +159,13 @@ MESH_COMPACT_PER_SEGMENT = "mesh_compact_per_segment"  # routed sort core
 # the full-scan group-by (strategy 'scan'): S segments vmapped, or one
 # segment through the plan cache
 GROUP_SCAN = "group_scan"
+# the top-groups group-by: one program over a server's segments, keys in
+# the server's id space, sorted, counted and trimmed to the top groups
+TOPGROUPS = "topgroups"
 KERNEL_FAMILIES = frozenset(
     {DENSE_VMAP, DENSE_PER_SEGMENT, COMPACT_SEGMENTED, COMPACT_PER_SEGMENT,
      SELECT_TOPK, RAGGED_FUSED, CUBE_BUILD_KERNEL, MESH_DENSE, MESH_COMPACT,
-     MESH_COMPACT_PER_SEGMENT, GROUP_SCAN})
+     MESH_COMPACT_PER_SEGMENT, GROUP_SCAN, TOPGROUPS})
 MODULE_PREFIX = "pinot_"
 
 
@@ -183,6 +191,12 @@ SCOPE_GROUP_SCAN = "pinot.group_scan"    # full-scan group-by, every row
 SCOPE_XFER_COMPACT = "pinot.xfer_compact"  # live-group gather pre-transfer
 SCOPE_TOPK = "pinot.topk"                # selection order key + top_k
 SCOPE_COMBINE = "pinot.combine"          # the mesh's per-device combine
+# the top-groups program (ops/kernels.build_topgroups_kernel), and its
+# stages: local ids -> server ids, the key sort, the count-ordered top_k
+SCOPE_TOPGROUPS = "pinot.topgroups"
+SCOPE_TOPGROUPS_REMAP = "pinot.topgroups_remap"
+SCOPE_TOPGROUPS_SORT = "pinot.topgroups_sort"
+SCOPE_TOPGROUPS_TOPK = "pinot.topgroups_topk"
 # the micro-batcher's two programs (engine/ragged.py)
 SCOPE_CUBE_BUILD = "pinot.cube_build"      # unmasked scan -> literal-free cube
 SCOPE_CUBE_COMBINE = "pinot.cube_combine"  # per-item mask + cell reduction
@@ -190,4 +204,5 @@ KERNEL_SCOPES = frozenset(
     {SCOPE_MASK, SCOPE_DECODE_DICT, SCOPE_GROUP_KEY, SCOPE_PAYLOAD,
      SCOPE_COMPACT, SCOPE_AGGREGATE, SCOPE_FLOAT_ACC, SCOPE_GROUP_TAIL,
      SCOPE_GROUP_SCAN, SCOPE_XFER_COMPACT, SCOPE_TOPK, SCOPE_COMBINE,
-     SCOPE_CUBE_BUILD, SCOPE_CUBE_COMBINE})
+     SCOPE_CUBE_BUILD, SCOPE_CUBE_COMBINE, SCOPE_TOPGROUPS,
+     SCOPE_TOPGROUPS_REMAP, SCOPE_TOPGROUPS_SORT, SCOPE_TOPGROUPS_TOPK})

@@ -451,11 +451,11 @@ def test_segment_shortfall_fails_over(cluster, monkeypatch):
     orig = servers[0].execute_bin
 
     def shortfall(sql, segment_names=None, deadline_ms=None,
-                  trace_ctx=None, workload=None):
+                  trace_ctx=None, workload=None, trim=False):
         if segment_names and len(segment_names) > 1:
             segment_names = segment_names[:-1]  # silently skip one
         return orig(sql, segment_names, deadline_ms, trace_ctx,
-                    workload)
+                    workload, trim)
 
     monkeypatch.setattr(servers[0], "execute_bin", shortfall)
     # run across several round-robin positions so server_0 is picked

@@ -173,9 +173,12 @@ def test_table_b_is_the_metered_vocabulary():
     """Every metered name is crossed by the served path or by the
     in-process broker (its mesh phase); nothing else is metered."""
     # ... or, for the two the micro-batcher owns, by queries that
-    # overlap (tests/test_ragged_batch.py counts their crossings)
+    # overlap (tests/test_ragged_batch.py counts their crossings), and
+    # for the server id space's build by the first top-groups statement
+    # after a segment comes or goes (tests/test_topgroups.py)
     assert set(CROSSINGS) | {ph.DISTRIBUTED_EXECUTE, ph.RAGGED_WAIT,
-                             ph.FUSED_EXECUTE} == ph.METERED_PHASES
+                             ph.FUSED_EXECUTE,
+                             ph.SERVER_IDS_BUILD} == ph.METERED_PHASES
     with pytest.raises(KeyError):
         with spans.phase("not_a_boundary"):
             pass

@@ -98,3 +98,25 @@ def test_scan_kernel_over_segments_lowers_for_tpu():
     # one loop over the segments, one over a segment's blocks of rows
     # (vmap would leave the blocks' loop alone, batched over segments)
     assert exp.mlir_module().count("stablehlo.while") == 2
+
+
+@pytest.mark.parametrize("widths", [(25,), (25, 23)], ids=["one_lane",
+                                                            "two_lanes"])
+def test_topgroups_kernel_lowers_for_tpu(widths):
+    """ClickBench q15's and q16's top-groups programs as the server
+    launches them (8 segments of 2^23 rows, each key gathered through
+    its segment's remap, one or two sort lanes, the top 5,000 by count)
+    lower for TPU: one key sort, and top_k."""
+    from pinot_tpu.ops.ir import TrueP
+    from pinot_tpu.ops.kernels import build_topgroups_kernel
+    seg_rows, n_seg = 1 << 23, 8
+    keys = tuple(range(len(widths)))
+    fn = build_topgroups_kernel((TrueP(),) * n_seg, (keys,) * n_seg,
+                                (seg_rows,) * n_seg, widths, 5000, True)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    exp = _export_tpu(
+        fn, tuple(tuple(i32(seg_rows) for _ in keys) for _ in range(n_seg)),
+        i32(n_seg), tuple(() for _ in range(n_seg)),
+        tuple(tuple(i32(1 << 22) for _ in keys) for _ in range(n_seg)))
+    text = exp.mlir_module()
+    assert text.count("stablehlo.sort") == 1 and text.count("top_k") == 1
